@@ -28,8 +28,11 @@ from predictionio_tpu.obs.logging import (
     reset_request_context,
     set_request_context,
 )
-from predictionio_tpu.obs.metrics import REGISTRY
-from predictionio_tpu.obs.tracing import install_jax_compile_listener, trace
+from predictionio_tpu.obs.tracing import (
+    install_jax_compile_listener,
+    jax_compile_stats,
+    trace,
+)
 
 log = logging.getLogger("predictionio_tpu.workflow")
 
@@ -47,14 +50,6 @@ class WorkflowParams:
 
 def _now() -> datetime:
     return datetime.now(tz=timezone.utc)
-
-
-def _compile_seconds() -> float:
-    """Total XLA compile seconds recorded so far (jax.monitoring listener)."""
-    fam = REGISTRY.get("pio_jax_compile_seconds")
-    if fam is None:
-        return 0.0
-    return sum(child.sum for _, child in fam.series())
 
 
 def _stage_breakdown(root, compile_delta_s: float | None = None) -> dict:
@@ -134,7 +129,7 @@ def run_train(
     # compile-vs-execute split: XLA compile durations land in
     # pio_jax_compile_seconds alongside the stage spans
     install_jax_compile_listener()
-    compile_s0 = _compile_seconds()
+    compile_s0 = jax_compile_stats()["compile_s"]
     # bind the engine-instance id as the run's correlation id: every log
     # line and span this training run emits carries request_id=<instance>,
     # the same correlation contract the serving path uses per query
@@ -184,7 +179,9 @@ def run_train(
             _record_shard_plan(storage, instance.id, algos, models)
         done = instance.completed()
         instances.update(done)
-        breakdown = _stage_breakdown(root, _compile_seconds() - compile_s0)
+        breakdown = _stage_breakdown(
+            root, jax_compile_stats()["compile_s"] - compile_s0
+        )
         log.info(
             "training finished: engine instance %s",
             instance.id,

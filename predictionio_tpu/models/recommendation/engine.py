@@ -38,6 +38,7 @@ from predictionio_tpu.obs import device as device_obs
 from predictionio_tpu.obs import provenance
 from predictionio_tpu.ops.als import ALSParams, ALSState, train_als
 from predictionio_tpu.ops.topk import (
+    SCORE_PRECISION,
     fused_supported,
     fused_topk_batch,
     host_topk,
@@ -306,7 +307,7 @@ class ALSAlgorithm(Algorithm):
             num_users=len(pd.user_vocab),
             num_items=len(pd.item_vocab),
             params=self._als_params(),
-            mesh=ctx.mesh,
+            mesh=ctx.mesh if ctx.mesh.devices.size > 1 else None,
             init_factors=self._warm_start_init(ctx, pd),
         )
         return ALSModel(
@@ -442,7 +443,9 @@ class ALSAlgorithm(Algorithm):
             lambda: build_sharded_topk(
                 bound.mesh,
                 bound.plan,
-                lambda item_local, q: q @ item_local.T,
+                lambda item_local, q: jnp.matmul(
+                    q, item_local.T, precision=SCORE_PRECISION
+                ),
                 ["item_factors"],
                 n_items=n_items,
                 k=k_pad,
@@ -729,7 +732,8 @@ def _device_score_topk(U, V, uidx, k: int):
     [B, rank] x [rank, n_items] matmul + top-k) instead of three eager
     dispatches — and a jit entry point the device-efficiency layer can run
     ``cost_analysis()`` against (obs/device.py)."""
-    scores = U[uidx] @ V.T  # [B, n_items]
+    # [B, n_items]
+    scores = jnp.matmul(U[uidx], V.T, precision=SCORE_PRECISION)
     return jax.lax.top_k(scores, k)
 
 

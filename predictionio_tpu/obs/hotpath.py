@@ -1,9 +1,8 @@
 """Solo-path host-stage attribution: where a single request's time goes.
 
-BENCH_r05 measured the solo serving path paying ~100 ms of host-side
-overhead around a 1.4 ms device cost (ROADMAP item 3d) — but the request
-latency histogram is one opaque number, so "optimize the solo path" had no
-starting breakdown.  This module decomposes every non-batched request into
+The request latency histogram is one opaque number, so "optimize the solo
+path" has no starting breakdown.  This module decomposes every non-batched
+request into
 named HOST stages, measured contiguously so they account for (almost) all
 of the request's wall time:
 
@@ -20,7 +19,7 @@ stage                     meaning
 ``compute``               device compute the engine marked
 ``d2h``                   device→host readback the engine marked
 ``dispatch``              the unattributed interior of the predict window:
-                          kernel-launch / dev-tunnel overhead on device
+                          kernel-launch / dispatch overhead on device
                           engines, host scoring on host-replica engines
 ``block_until_ready``     event-loop wakeup + future resolution after the
                           wave finished (micro-batched front end only)

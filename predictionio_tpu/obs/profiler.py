@@ -17,7 +17,6 @@ the gauges are current without a sampler thread.
 from __future__ import annotations
 
 import os
-import sys
 import tempfile
 import threading
 import time
@@ -26,6 +25,7 @@ from typing import Any
 
 from predictionio_tpu.obs import device as device_obs
 from predictionio_tpu.obs.metrics import REGISTRY, MetricsRegistry
+from predictionio_tpu.utils.runtime import backend_initialized
 
 #: upper bound on one capture; profiles are for debugging, not surveillance
 MAX_CAPTURE_SECONDS = 300.0
@@ -160,8 +160,9 @@ def sample_runtime_gauges(registry: MetricsRegistry | None = None) -> bool:
     transfer tallies the device-efficiency layer keeps
     (``pio_device_transfer_bytes{direction}``).  Every probe is
     individually fenced — telemetry must never break a scrape — and the
-    whole call is a no-op returning False unless jax is ALREADY imported in
-    this process: a scrape of the admin/dashboard/event/storage daemons
+    whole call is a no-op returning False unless this process has ALREADY
+    initialized a jax backend (importing jax is not enough: the event
+    server does): a scrape of the admin/dashboard/event/storage daemons
     must not trigger a multi-second backend init (or contend for the TPU
     the serving process exclusively holds) just to report empty gauges.
 
@@ -173,12 +174,10 @@ def sample_runtime_gauges(registry: MetricsRegistry | None = None) -> bool:
     cached values between walks.
     """
     reg = registry or REGISTRY
-    if "jax" not in sys.modules:
+    if not backend_initialized():
         return False
-    try:
-        import jax
-    except Exception:
-        return False
+    import jax
+
     t_start = time.perf_counter()
     try:
         arrs = jax.live_arrays()

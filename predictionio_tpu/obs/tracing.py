@@ -218,22 +218,19 @@ def install_jax_compile_listener() -> bool:
     from execute time — and counts them into ``pio_jax_compile_total`` so
     the device-efficiency layer (obs/device.py) can report cumulative
     compile activity next to its per-(fn, shapes) recompile attribution.
-    Idempotent; returns False when the monitoring API is unavailable (the
-    listener is additive-only, so failure is harmless).
+    Idempotent.
     """
     global _jax_listener_installed
     with _jax_listener_lock:
         if _jax_listener_installed:
             return True
-        try:
-            from jax import monitoring
-        except Exception:
-            return False
-        if not hasattr(monitoring, "register_event_duration_secs_listener"):
-            return False
+        from jax import monitoring
 
         def _on_duration(event: str, duration: float, **kwargs) -> None:
-            if "compile" not in event:
+            # trace + lowering + backend compile (which, on a persistent-
+            # cache hit, is the retrieval).  NOT the compilation_cache
+            # family: its "compile_time_saved_sec" is time NOT spent.
+            if not event.startswith("/jax/core/compile/"):
                 return
             try:
                 REGISTRY.histogram(
@@ -250,9 +247,37 @@ def install_jax_compile_listener() -> bool:
             except Exception:
                 pass  # telemetry must never break compilation
 
-        try:
-            monitoring.register_event_duration_secs_listener(_on_duration)
-        except Exception:
-            return False
+        def _on_cache_event(event: str, **kwargs) -> None:
+            # persistent-cache traffic: compile_requests_use_cache (every
+            # cacheable compile), cache_hits (retrieved), cache_misses
+            # (compiled, then written) — a warm second run shows no misses
+            if not event.startswith("/jax/compilation_cache/"):
+                return
+            REGISTRY.counter(
+                "pio_jax_compile_cache_events_total",
+                "Persistent compilation cache events by name",
+                labelnames=("event",),
+            ).labels(event.rsplit("/", 1)[1]).inc()
+
+        monitoring.register_event_duration_secs_listener(_on_duration)
+        monitoring.register_event_listener(_on_cache_event)
         _jax_listener_installed = True
         return True
+
+
+def jax_compile_stats() -> dict:
+    """What the listener has recorded so far: total compile seconds (trace
+    + lowering + backend compile or cache retrieval) and the persistent
+    cache's event counts."""
+    secs = REGISTRY.get("pio_jax_compile_seconds")
+    cache = REGISTRY.get("pio_jax_compile_cache_events_total")
+    return {
+        "compile_s": (
+            sum(child.sum for _, child in secs.series()) if secs else 0.0
+        ),
+        "cache": (
+            {labels[0]: int(child.value) for labels, child in cache.series()}
+            if cache
+            else {}
+        ),
+    }

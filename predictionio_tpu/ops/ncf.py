@@ -35,6 +35,12 @@ import numpy as np
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as PSpec
 
+from predictionio_tpu.ops.topk import SCORE_PRECISION
+
+#: the serving scorers' matmul: full f32, so the device ranks a query as
+#: the numpy host replica does (training keeps the default)
+_score_mm = partial(jnp.matmul, precision=SCORE_PRECISION)
+
 
 @dataclass(frozen=True)
 class NCFParams:
@@ -164,7 +170,7 @@ def score_all_items(params: dict, user_idx: jax.Array) -> jax.Array:
     a handful of [n_items, d] matmuls on the MXU.
     """
     if "out_w" not in params:  # pure GMF (mlp_layers=())
-        score = params["item_emb"] @ params["user_emb"][user_idx]
+        score = _score_mm(params["item_emb"], params["user_emb"][user_idx])
         score = score + params["out_b"][0]
         bias = params.get("item_bias")
         if bias is not None:
@@ -179,9 +185,9 @@ def score_all_items(params: dict, user_idx: jax.Array) -> jax.Array:
         axis=-1,
     )
     for layer in params["mlp"]:
-        h = jax.nn.relu(h @ layer["w"] + layer["b"])
+        h = jax.nn.relu(_score_mm(h, layer["w"]) + layer["b"])
     fused = jnp.concatenate([gmf, h], axis=-1)
-    score = (fused @ params["out_w"] + params["out_b"])[..., 0]
+    score = (_score_mm(fused, params["out_w"]) + params["out_b"])[..., 0]
     bias = params.get("item_bias")
     if bias is not None:
         score = score + bias
@@ -202,7 +208,7 @@ def score_users_vs_items(
     discriminates pure GMF by the absence of ``out_w``, as everywhere).
     """
     if "out_w" not in head:  # pure GMF (mlp_layers=())
-        scores = ue @ item_emb.T + head["out_b"][0]
+        scores = _score_mm(ue, item_emb.T) + head["out_b"][0]
         if item_bias is not None:
             scores = scores + item_bias[None, :]
         return scores
@@ -217,9 +223,9 @@ def score_users_vs_items(
         axis=-1,
     )
     for layer in head["mlp"]:
-        h = jax.nn.relu(h @ layer["w"] + layer["b"])
+        h = jax.nn.relu(_score_mm(h, layer["w"]) + layer["b"])
     fused = jnp.concatenate([gmf, h], axis=-1)
-    scores = (fused @ head["out_w"] + head["out_b"])[..., 0]
+    scores = (_score_mm(fused, head["out_w"]) + head["out_b"])[..., 0]
     if item_bias is not None:
         scores = scores + item_bias[None, :]
     return scores

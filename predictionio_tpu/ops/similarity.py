@@ -14,6 +14,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from predictionio_tpu.ops.topk import SCORE_PRECISION
+
 
 @partial(jax.jit, static_argnames=("k",))
 def cosine_topk(
@@ -33,7 +35,10 @@ def cosine_topk(
     )
     item_norm = jnp.maximum(jnp.linalg.norm(item_factors, axis=1), 1e-9)
     # [n_items, q] cosine matrix via one matmul, summed over query vectors
-    scores = (item_factors @ qn.T).sum(axis=1) / item_norm
+    scores = (
+        jnp.matmul(item_factors, qn.T, precision=SCORE_PRECISION).sum(axis=1)
+        / item_norm
+    )
     scores = jnp.where(exclude_mask, -jnp.inf, scores)
     return jax.lax.top_k(scores, k)
 
@@ -46,6 +51,6 @@ def dot_topk(
     k: int,
 ):
     """Dot-product scoring with masked top-k (the known-user serving path)."""
-    scores = item_factors @ user_vec
+    scores = jnp.matmul(item_factors, user_vec, precision=SCORE_PRECISION)
     scores = jnp.where(exclude_mask, -jnp.inf, scores)
     return jax.lax.top_k(scores, k)

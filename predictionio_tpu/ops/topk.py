@@ -53,6 +53,16 @@ BATCH_BLOCK = 128
 #: very deep k's belong on the materialized-row path (counted as fallbacks)
 MAX_FUSED_K = 128
 
+#: precision of EVERY serving score contraction on the device, in this
+#: kernel and in the XLA programs beside it (ALS, NCF, similarity): full
+#: f32.  The TPU default contracts f32 operands in ONE bf16 MXU pass — on a
+#: v5e scores came out ~1e-3 relative off the f32 value, enough to reorder
+#: near-equal items, so the same query ranked differently on the device
+#: than on the host replica.  The contraction is over the rank (tens of
+#: terms) and the work is reading the item table: the extra passes are
+#: noise.  A string so this module stays importable without jax.
+SCORE_PRECISION = "highest"
+
 #: retired-entry / padding sentinel id — a power of two, exactly
 #: representable in f32, and above the 2^24 packed-id ceiling every catalog
 #: already honors (models/ncf/engine._packable_n_items)
@@ -166,6 +176,7 @@ def _make_fused_topk_kernel(k: int, bc: int, tile: int):
         # the only score slab that ever exists: [bc, tile], never [bc, N]
         scores = jax.lax.dot_general(
             q, vt, dimension_numbers=(((1,), (1,)), ((), ())),
+            precision=SCORE_PRECISION,
             preferred_element_type=jnp.float32,
         )
         neg = jnp.float32(-jnp.inf)
@@ -293,6 +304,9 @@ def fused_topk_batch(
     # n_items — asserted by the no-full-row tests (single-device AND
     # per-shard, where this records each shard's local launch)
     LAST_KERNEL_SHAPES[name] = {
+        # 1 = the pallas interpreter ran the body (the CPU-test rule
+        # above), 0 = Mosaic compiled it for the chip
+        "interpret": int(interpret),
         "rows_tile": int(min(TILE_ROWS, n_rows)),
         "batch": int(b),
         "batch_block": int(bc),

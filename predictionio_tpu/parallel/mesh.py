@@ -43,34 +43,6 @@ class MeshConfig:
         return cls(axes=dict(d["axes"]))
 
 
-def shard_map_compat(f, mesh: Mesh, in_specs, out_specs, check: bool = True):
-    """``shard_map`` across the JAX API migration.
-
-    Newer releases expose top-level ``jax.shard_map(..., check_vma=)``;
-    0.4.x has ``jax.experimental.shard_map.shard_map(..., check_rep=)``.
-    ``check=False`` disables the replication/vma static check under either
-    spelling (needed when outputs are all_gather'ed to replicated values the
-    analysis cannot prove).
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(
-                f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_vma=check,
-            )
-        except TypeError:  # jax ~0.6: top-level but still check_rep
-            return sm(
-                f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_rep=check,
-            )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check
-    )
-
-
 def make_mesh(
     config: MeshConfig | None = None, devices: Sequence[jax.Device] | None = None
 ) -> Mesh:
@@ -240,17 +212,14 @@ def shard_attribution(tree: Any) -> dict[str, dict[str, float]]:
     """
     out: dict[str, dict[str, float]] = {}
     for leaf in jax.tree_util.tree_leaves(tree):
-        shards = getattr(leaf, "addressable_shards", None)
-        if shards is None:
+        if not isinstance(leaf, jax.Array):
             continue
         try:
-            for shard in shards:
+            for shard in leaf.addressable_shards:
                 d = shard.device
                 label = f"{d.platform}:{d.id}"
                 entry = out.setdefault(label, {"bytes": 0.0, "shards": 0})
-                entry["bytes"] += float(
-                    getattr(shard.data, "nbytes", 0) or 0
-                )
+                entry["bytes"] += float(shard.data.nbytes)
                 entry["shards"] += 1
         except Exception:
             continue  # deleted/donated buffers mid-walk: skip the leaf

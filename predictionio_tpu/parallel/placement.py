@@ -45,7 +45,6 @@ from predictionio_tpu.parallel.mesh import (
     MeshConfig,
     make_mesh,
     pad_to_multiple,
-    shard_map_compat,
 )
 
 #: ShardPlan wire-format version (rides inside model blobs and generation
@@ -324,9 +323,9 @@ def build_sharded_topk(
         return jnp.stack([mv, mi.astype(jnp.float32)])
 
     return jax.jit(
-        shard_map_compat(
+        jax.shard_map(
             body, mesh=mesh, in_specs=in_specs, out_specs=out_spec,
-            check=False,  # outputs ARE replicated post-merge; vma can't prove
+            check_vma=False,  # outputs ARE replicated post-merge; vma can't prove
         )
     )
 

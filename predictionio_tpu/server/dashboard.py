@@ -139,6 +139,11 @@ def _quality_html(quality: QualityMonitor, registry: MetricsRegistry) -> str:
     )
 
 
+def _pct(frac: float | None) -> str:
+    """A utilization cell; a device off the peak table reports none."""
+    return "n/a" if frac is None else f"{frac:.2%}"
+
+
 def _efficiency_html(registry: MetricsRegistry) -> str:
     """Device-efficiency panel: achieved-vs-peak per jitted entry point
     (the /efficiency.json surface, human-shaped) with trend sparklines
@@ -157,9 +162,9 @@ def _efficiency_html(registry: MetricsRegistry) -> str:
             f"<tr><td>{html.escape(fn)}</td>"
             f"<td>{entry['calls']}</td>"
             f"<td>{entry['achieved_gbps']:.3f}</td>"
-            f"<td>{entry['utilization_hbm']:.2%}</td>"
+            f"<td>{_pct(entry.get('utilization_hbm'))}</td>"
             f"<td>{entry['achieved_tflops']:.4f}</td>"
-            f"<td>{entry['utilization_mxu']:.2%}</td>"
+            f"<td>{_pct(entry.get('utilization_mxu'))}</td>"
             f"<td>{html.escape(entry.get('source', '?'))}</td>"
             f"<td>{html.escape(spark_gbps)}</td></tr>"
         )

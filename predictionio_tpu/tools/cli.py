@@ -611,6 +611,69 @@ def _preflight_lint_advisory() -> None:
         print(f"pre-flight lint skipped: {e}", file=sys.stderr)
 
 
+def _device_startup(verb: str) -> None:
+    """Start-of-verb set-up for the verbs that compile: one compile cache
+    (utils/runtime.py), compile seconds into ``pio_jax_compile_seconds``,
+    and — once — which platform / device kind / how many devices this
+    process got, so a run that landed on the wrong device says so in its
+    first log line (`pio status` prints the same)."""
+    import logging
+
+    from predictionio_tpu.obs.tracing import install_jax_compile_listener
+    from predictionio_tpu.utils.runtime import (
+        configure_compile_cache,
+        describe_devices,
+    )
+
+    cache_dir = configure_compile_cache()
+    install_jax_compile_listener()
+    dev = describe_devices()
+    logging.getLogger("predictionio_tpu.cli").info(
+        "pio %s on %s (%s) x%d; compile cache %s",
+        verb, dev["platform"], dev["device_kind"], dev["device_count"],
+        cache_dir,
+        extra={**dev, "verb": verb, "compile_cache_dir": cache_dir},
+    )
+
+
+def _log_device_report(verb: str) -> None:
+    """End-of-verb summary for the one-shot verbs (a server answers the
+    same questions on /metrics and /explain.json): what compiling cost and
+    whether the persistent cache served it, peak device memory, and which
+    top-k kernels launched — compiled by Mosaic or interpreted — with how
+    many full-row fallbacks."""
+    import logging
+
+    import jax
+
+    from predictionio_tpu.obs.metrics import REGISTRY
+    from predictionio_tpu.obs.tracing import jax_compile_stats
+    from predictionio_tpu.ops.topk import LAST_KERNEL_SHAPES
+
+    # the CPU backend keeps no allocator statistics (memory_stats() is None)
+    stats = [d.memory_stats() or {} for d in jax.local_devices()]
+    fallbacks = REGISTRY.get("pio_topk_full_row_fallback_total")
+    compiled = jax_compile_stats()
+    report = {
+        "verb": verb,
+        "compile_s": round(compiled["compile_s"], 3),
+        "compile_cache": compiled["cache"],
+        "peak_bytes_in_use": max(
+            (s.get("peak_bytes_in_use", 0) for s in stats), default=0
+        ),
+        "bytes_limit": stats[0].get("bytes_limit"),
+        "topk_kernels": dict(LAST_KERNEL_SHAPES),
+        "topk_full_row_fallbacks": int(
+            sum(c.value for _, c in fallbacks.series()) if fallbacks else 0
+        ),
+    }
+    logging.getLogger("predictionio_tpu.cli").info(
+        "pio %s device report: compile %.1fs, peak device memory %d bytes",
+        verb, report["compile_s"], report["peak_bytes_in_use"],
+        extra={"device_report": report},
+    )
+
+
 def do_train(args) -> int:
     from predictionio_tpu.core.base import EngineContext
     from predictionio_tpu.core.workflow import WorkflowParams, run_train
@@ -619,6 +682,7 @@ def do_train(args) -> int:
     # distributed bootstrap FIRST: jax.distributed.initialize must run
     # before anything (engine imports included) can initialize the backend
     initialize_distributed()
+    _device_startup("train")
     factory_name, engine, variant = _resolve_engine(args)
     if _dase_preflight(factory_name, engine, skip=args.no_check):
         return 1
@@ -643,6 +707,7 @@ def do_train(args) -> int:
         engine_variant=variant.get("variant", args.variant),
         engine_factory=factory_name,
     )
+    _log_device_report("train")
     if instance is not None:
         print(f"Training completed. Engine instance: {instance.id}")
     return 0
@@ -654,6 +719,7 @@ def do_eval(args) -> int:
     from predictionio_tpu.eval.evaluation import resolve_evaluation
     from predictionio_tpu.eval.evaluator import MetricEvaluator
 
+    _device_startup("eval")
     _load_engine_modules()
     evaluation = resolve_evaluation(
         args.evaluation, json.loads(args.params) if args.params else None
@@ -782,6 +848,7 @@ def do_deploy(args) -> int:
         create_prediction_server,
     )
 
+    _device_startup("deploy")
     if getattr(args, "app_specs", None):
         return _deploy_multi_tenant(args, args.app_specs)
     _load_engine_modules()
@@ -866,6 +933,7 @@ def do_undeploy(args) -> int:
 def do_batchpredict(args) -> int:
     from predictionio_tpu.core.batch_predict import run_batch_predict
 
+    _device_startup("batchpredict")
     _load_engine_modules()
     factory, engine_id, engine_version, engine_variant = _engine_coords(args)
     n = run_batch_predict(
@@ -878,6 +946,7 @@ def do_batchpredict(args) -> int:
         engine_version=engine_version,
         engine_variant=engine_variant,
     )
+    _log_device_report("batchpredict")
     print(f"Wrote {n} predictions to {args.output}")
     return 0
 

@@ -10,6 +10,9 @@ from __future__ import annotations
 
 import asyncio
 import json
+import os
+import subprocess
+import sys
 import time
 
 import jax
@@ -44,13 +47,27 @@ class TestPeakTable:
     def test_longest_prefix_wins(self):
         assert device_peaks("tpu v4 chip").hbm_gbps == 1228.0
         assert device_peaks("tpu v5 lite").hbm_gbps == 819.0
-        assert device_peaks("tpu v7x").source == "tpu"  # unknown tpu class
         assert device_peaks("cpu").source == "cpu"
 
-    def test_unknown_kind_falls_back_to_cpu_row(self):
-        p = device_peaks("quantum abacus")
-        cpu = device_peaks("cpu")
-        assert (p.hbm_gbps, p.tflops) == (cpu.hbm_gbps, cpu.tflops)
+    def test_unknown_kind_has_no_peaks(self):
+        # a device the table does not list is not assumed to be a v5e (or
+        # a CPU): no peaks, so no utilization is reported against it
+        for kind in ("tpu v7x", "quantum abacus"):
+            p = device_peaks(kind)
+            assert (p.hbm_gbps, p.tflops, p.source) == (0.0, 0.0, "unknown")
+
+    def test_unknown_kind_reports_no_utilization(self):
+        reg = MetricsRegistry()
+        eff = EfficiencyTracker(
+            registry=reg, peaks=device_peaks("quantum abacus")
+        )
+        eff.record_cost("f", flops=1e9, nbytes=1e9)
+        eff.observe("f", 0.5)
+        assert not list(reg.get("pio_device_utilization_frac").series())
+        entry = eff.snapshot()["functions"]["f"]
+        assert entry["achieved_gbps"] > 0
+        assert "utilization_hbm" not in entry
+        assert eff.snapshot()["peaks"]["source"] == "unknown"
 
     def test_env_overrides(self, monkeypatch):
         monkeypatch.setenv("PIO_DEVICE_PEAK_GBPS", "123.5")
@@ -390,6 +407,7 @@ class TestRuntimeGaugeSatellites:
     def test_compile_cache_growth_counter(self):
         from predictionio_tpu.obs.profiler import sample_runtime_gauges
 
+        jax.devices()  # gauge sampling requires an initialized backend
         reg = MetricsRegistry()
         assert sample_runtime_gauges(reg) is True  # seeds the last-seen size
 
@@ -406,6 +424,7 @@ class TestRuntimeGaugeSatellites:
         from predictionio_tpu.obs.profiler import sample_runtime_gauges
 
         device_obs.note_transfer("d2h", 4096, registry=MetricsRegistry())
+        jax.devices()  # gauge sampling requires an initialized backend
         reg = MetricsRegistry()
         sample_runtime_gauges(reg)
         gauge = reg.get("pio_device_transfer_bytes").labels("d2h")
@@ -452,6 +471,52 @@ class TestEfficiencyRoute:
         app = _obs_app(debug_routes=False)
         resp = app.handle(Request("GET", "/efficiency.json", {}, {}))
         assert resp.status == 404
+
+
+_EVENT_SERVER_SCRAPE = r"""
+import json, sys
+from predictionio_tpu.data.storage.config import StorageConfig, StorageRuntime
+from predictionio_tpu.obs.metrics import MetricsRegistry
+from predictionio_tpu.server.event_server import create_event_server_app
+from predictionio_tpu.server.httpd import Request
+from predictionio_tpu.utils.runtime import backend_initialized
+
+assert "jax" in sys.modules  # the event server does import it
+storage = StorageRuntime(StorageConfig.from_env({"PIO_HOME": sys.argv[1]}))
+app = create_event_server_app(
+    storage, registry=MetricsRegistry(), obs_access_key="k"
+)
+out = {}
+for path in ("/efficiency.json", "/metrics"):
+    resp = app.handle(Request("GET", path, {"accessKey": "k"}, {}))
+    out[path] = resp.status
+    if path == "/efficiency.json" and resp.status == 200:
+        out["platform"] = resp.body["platform"]
+out["initialized"] = backend_initialized()
+print(json.dumps(out))
+"""
+
+
+class TestScrapeLeavesTheChipAlone:
+    def test_event_server_scrape_initializes_no_backend(self, tmp_path):
+        """One process per chip: the event server imports jax and computes
+        nothing, so its observability endpoints must not initialize a
+        backend — on a chip host that would claim the chip `pio deploy`
+        needs, and under JAX_PLATFORMS=tpu with no chip (this child) it
+        raises, i.e. a 500 from a scrape."""
+        env = dict(os.environ, JAX_PLATFORMS="tpu")
+        proc = subprocess.run(
+            [sys.executable, "-c", _EVENT_SERVER_SCRAPE, str(tmp_path)],
+            env=env, capture_output=True, text=True, timeout=120,
+            cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        )
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        assert json.loads(proc.stdout.strip().splitlines()[-1]) == {
+            "/efficiency.json": 200,
+            "/metrics": 200,
+            "platform": "cpu",
+            "initialized": False,
+        }
 
 
 # ---------------------------------------------------------------------------

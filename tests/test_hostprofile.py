@@ -694,6 +694,7 @@ class TestRuntimeGaugeCostGuard:
 
         from predictionio_tpu.obs import profiler as profiler_mod
 
+        jax.devices()  # gauge sampling requires an initialized backend
         calls = {"n": 0}
         real = jax.local_devices
 
@@ -712,10 +713,11 @@ class TestRuntimeGaugeCostGuard:
         assert calls["n"] == 2
 
     def test_scrape_cost_is_self_metered(self):
-        import jax  # noqa: F401 — gauge sampling requires jax in sys.modules
+        import jax
 
         from predictionio_tpu.obs import profiler as profiler_mod
 
+        jax.devices()  # gauge sampling requires an initialized backend
         reg = MetricsRegistry()
         assert profiler_mod.sample_runtime_gauges(reg) is True
         fam = reg.get("pio_runtime_sample_seconds")
@@ -1009,7 +1011,7 @@ class TestAcceptanceE2E:
         snap = _get_json(base, "/hotpath.json")
         assert snap["requests"] >= 40
         assert snap["coverage_frac"] >= 0.95, snap
-        # the solo path decomposes into the documented taxonomy
+        # the solo path decomposes into the documented stage table
         assert {"parse", "route", "serialize"} <= set(snap["stages"])
         assert "dispatch" in snap["stages"] or "compute" in snap["stages"]
         # every stage row carries the quantile table

@@ -345,10 +345,12 @@ class TestProfiler:
             p.start(10_000)
 
     def test_sample_runtime_gauges_populates_registry(self):
-        import jax  # noqa: F401 — the populated path requires jax loaded;
-        # without this the function deliberately no-ops (returns False),
-        # and test-selection order must not decide which path runs
+        import jax
 
+        # the populated path requires an initialized backend; without one
+        # the function deliberately no-ops (returns False), and
+        # test-selection order must not decide which path runs
+        jax.devices()
         reg = MetricsRegistry()
         assert profiler_mod.sample_runtime_gauges(reg) is True
         assert reg.get("pio_jax_live_buffer_count") is not None

@@ -318,6 +318,101 @@ class TestShardedTrainingState:
             assert e["bytes"] == pytest.approx(2 * table_bytes / 4)
 
 
+class TestGlobalTrainEntry:
+    def test_daemon_fed_global_train_matches_direct(self, tmp_path):
+        """The multi-host data plane with the storage daemon in the loop,
+        in one process: events -> loopback storage daemon -> entity-hash
+        shard scan over HTTP (RemotePEvents.iter_shards) -> COO columns ->
+        global sharded arrays -> SPMD train (train_als_global).  The
+        storage plane may reorder rows but must not change the solution.
+        (The two-process form is
+        test_distributed.py::test_two_process_remote_daemon_train_parity.)"""
+        from datetime import datetime, timezone
+
+        from predictionio_tpu.data.event import Event
+        from predictionio_tpu.data.storage.remote_backend import (
+            RemoteClient,
+            RemoteLEvents,
+            RemotePEvents,
+        )
+        from predictionio_tpu.ops.als import ALSParams, train_als_global
+        from predictionio_tpu.parallel.mesh import (
+            default_mesh,
+            global_data_array,
+        )
+        from predictionio_tpu.server.storage_server import StorageServer
+
+        rng = np.random.default_rng(0)
+        nnz, num_users, num_items, chunk = 2048, 64, 48, 256
+        ru = rng.integers(0, num_users, nnz).astype(np.int32)
+        ri = rng.integers(0, num_items, nnz).astype(np.int32)
+        rr = rng.uniform(1.0, 5.0, nnz).astype(np.float32)
+        daemon = StorageServer(
+            tmp_path, host="127.0.0.1", port=0
+        ).start_background()
+        try:
+            url = f"http://127.0.0.1:{daemon.port}"
+            le = RemoteLEvents(RemoteClient(url))
+            le.init(1)
+            t0 = datetime(2024, 1, 1, tzinfo=timezone.utc)
+            le.insert_batch(
+                [
+                    Event(
+                        event="rate", entity_type="user", entity_id=f"u{a}",
+                        target_entity_type="item", target_entity_id=f"i{b}",
+                        properties={"rating": float(c)}, event_time=t0,
+                    )
+                    for a, b, c in zip(ru, ri, rr)
+                ],
+                1,
+            )
+            pe = RemotePEvents(RemoteClient(url))
+            us, is_, rs = [], [], []
+            for _, frame in pe.iter_shards(
+                1, shards=list(range(pe.n_shards(1)))
+            ):
+                sel = frame.where_event("rate")
+                us.append(
+                    np.array([int(s[1:]) for s in sel.entity_id], np.int32)
+                )
+                is_.append(
+                    np.array(
+                        [int(s[1:]) for s in sel.target_entity_id], np.int32
+                    )
+                )
+                rs.append(
+                    sel.property_column("rating", default=0.0).astype(
+                        np.float32
+                    )
+                )
+        finally:
+            daemon.shutdown()
+        fed = [np.concatenate(us), np.concatenate(is_), np.concatenate(rs)]
+        assert len(fed[0]) == nnz
+
+        mesh = default_mesh()
+        n_dev = mesh.devices.size
+
+        def train(cols):
+            (gu, gi, gr), valid = balance_local_chunks(cols, chunk * n_dev)
+            return train_als_global(
+                global_data_array(mesh, gu),
+                global_data_array(mesh, gi),
+                global_data_array(mesh, gr),
+                global_data_array(mesh, valid),
+                num_users,
+                num_items,
+                mesh,
+                params=ALSParams(rank=8, num_iterations=1, chunk_size=chunk),
+            )
+
+        got, direct = train(fed), train([ru, ri, rr])
+        assert np.isfinite(got.user_factors).all()
+        np.testing.assert_allclose(
+            got.user_factors, direct.user_factors, rtol=2e-4, atol=2e-4
+        )
+
+
 # ---------------------------------------------------------------------------
 # engine-level sharded serving (the acceptance e2e)
 
@@ -577,9 +672,27 @@ class TestBenchShardedGate:
             {**base, "sharded_devices": 8}, {**base, "sharded_devices": 2}
         )
         assert code == 2 and "sharded_devices" in report["error"]
+        # same count, chips vs the virtual CPU mesh: not the same measurement
+        code, report = compare_bench(
+            {**base, "sharded_devices": 4, "sharded_platform": "tpu"},
+            {**base, "sharded_devices": 4, "sharded_platform": "cpu"},
+        )
+        assert code == 2 and "sharded_platform" in report["error"]
         # absent on both (no sharded section): not a mismatch
         code, _ = compare_bench(dict(base), dict(base))
         assert code == 0
+
+    def test_accelerator_parent_short_of_devices_raises(self, monkeypatch):
+        """A chip run never fills sharded_* from a CPU child: with fewer
+        than N accelerator devices the section fails."""
+        import types
+
+        import bench
+
+        chip = types.SimpleNamespace(platform="tpu")
+        monkeypatch.setattr(jax, "devices", lambda *a: [chip])
+        with pytest.raises(RuntimeError, match="holds 1 tpu device"):
+            bench.bench_sharded_section(4, 0.01)
 
     def test_sharded_metrics_are_gated(self):
         from predictionio_tpu.obs.device import (

@@ -1,0 +1,245 @@
+"""The chip's programs, compiled for a v5e without a chip.
+
+libtpu can AOT-compile for a named topology from the CPU sandbox
+(``jax.experimental.topologies``), so whether today's Mosaic and XLA accept
+every Pallas kernel and the whole fused ALS train program — and how much HBM
+XLA plans for it — is a tier-1 question, answered here instead of on chip
+budget.  Nothing runs: these tests see compile errors and memory plans, not
+numerics (``python chip_smoke.py`` on the chip checks those against numpy).
+One installation, one topology: no skip.
+"""
+
+from __future__ import annotations
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
+
+from predictionio_tpu.ops import als, als_pallas
+from predictionio_tpu.ops.topk import fused_topk_batch
+
+#: one v5e chip's HBM (Google Cloud "TPU v5e": 16 GB)
+V5E_HBM_BYTES = 16 * 10**9
+
+# the MovieLens-20M shape chip_smoke.py trains at
+NNZ, NUM_USERS, NUM_ITEMS = 20_000_000, 138_493, 26_744
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    assert len(topo.devices) == 4
+    return topo
+
+
+def _spec_on(sharding):
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    return sds
+
+
+def _compile(jitted, *specs):
+    return jitted.trace(*specs).lower(lowering_platforms=("tpu",)).compile()
+
+
+@pytest.mark.parametrize(
+    "batch,k",
+    [(8, 10), (32, 10), (512, 10), (1024, 10), (32, 16), (8, 128)],
+)
+def test_fused_topk_batch_compiles(v5e, batch, k):
+    """The serving top-k kernel at the wave sizes serving pads to and the
+    bulk sizes batchpredict sends, over the ML-20M item table."""
+    sds = _spec_on(SingleDeviceSharding(v5e.devices[0]))
+    fn = jax.jit(lambda q, t: fused_topk_batch(q, t, k, interpret=False))
+    _compile(fn, sds((batch, 10)), sds((NUM_ITEMS, 10)))
+
+
+@pytest.mark.parametrize("rank", [10, 32])
+def test_als_accumulators_compile(v5e, rank):
+    """Both Pallas normal-equation accumulators: the single-grid fused
+    kernel and the chunk-scan one the OOM ladder falls back to."""
+    sds = _spec_on(SingleDeviceSharding(v5e.devices[0]))
+    T = als_pallas.T
+    nt, nb, n_other = 64, 8, 4096
+    i32 = jnp.int32
+
+    fused = jax.jit(
+        lambda plan, oth, wrv, f: als_pallas.segment_stats_fused(
+            plan, oth, wrv, f, nt, nb
+        )
+    )
+    _compile(
+        fused,
+        (sds((nt,), i32), sds((nt,), i32), sds((nt, T // 128, 128), i32)),
+        sds((nt, T), i32), sds((nt, 3, T)), sds((n_other, rank)),
+    )
+
+    chunks, tpc = 2, 32
+    chunked = jax.jit(
+        lambda plan, oth, rat, val, f: als_pallas.segment_stats_pallas(
+            plan, oth, rat, val, f, False, 1.0, tpc, nb
+        )
+    )
+    _compile(
+        chunked,
+        (
+            sds((chunks, tpc), i32), sds((chunks, tpc), i32),
+            sds((chunks, tpc, T // 128, 128), i32), sds((chunks, nb)),
+        ),
+        sds((chunks, tpc * T), i32), sds((chunks, tpc * T)),
+        sds((chunks, tpc * T)), sds((n_other, rank)),
+    )
+
+
+@pytest.mark.parametrize("rank", [10, 32])
+def test_fused_als_train_program_fits_the_chip(v5e, rank):
+    """The WHOLE fused train program (all iterations in one fori_loop) at
+    ML-20M shapes: it must compile, and XLA's own memory plan — temps plus
+    the staged streams it takes as arguments — must fit one v5e.  The plan
+    shape is the worst case of ``build_plan``'s block padding (every block
+    wastes a whole tile), so real data plans slightly under this."""
+    sds = _spec_on(SingleDeviceSharding(v5e.devices[0]))
+    T = als_pallas.T
+    users_pad = (NUM_USERS + 127) // 128 * 128
+    items_pad = (NUM_ITEMS + 127) // 128 * 128
+    nb_u, nb_i = users_pad // als_pallas.S, items_pad // als_pallas.S
+    nt_u, nt_i = NNZ // T + nb_u, NNZ // T + nb_i
+    steps = als._make_pallas_step(
+        (nt_u, nb_u, nt_i, nb_i), als.ALSParams(rank=rank),
+        users_pad, items_pad, fused=True,
+    )
+    i32 = jnp.int32
+
+    def side(nt):
+        return (
+            (sds((nt,), i32), sds((nt,), i32), sds((nt, T // 128, 128), i32)),
+            sds((nt, T), i32), sds((nt, T)), sds((nt, T)),
+        )
+
+    compiled = _compile(
+        steps, *side(nt_u), *side(nt_i),
+        sds((users_pad, rank)), sds((items_pad, rank)), sds((), i32),
+    )
+    mem = compiled.memory_analysis()
+    planned = mem.temp_size_in_bytes + mem.argument_size_in_bytes
+    assert planned < V5E_HBM_BYTES, (
+        f"rank {rank}: XLA plans {planned / 1e9:.1f} GB "
+        f"({mem.temp_size_in_bytes / 1e9:.1f} GB of temps) on a 16 GB chip"
+    )
+
+
+def test_ncf_wave_program_compiles(v5e):
+    """``_score_topk_batch`` — the ``ncf.device_wave`` program — at the one
+    padded shape serving uses (32 x k 16) over the flagship's tables."""
+    from predictionio_tpu.models.ncf.engine import _score_topk_batch
+
+    sds = _spec_on(SingleDeviceSharding(v5e.devices[0]))
+    params = {
+        "user_emb": sds((NUM_USERS, 10)),
+        "item_emb": sds((NUM_ITEMS, 10)),
+        "item_bias": sds((NUM_ITEMS,)),
+        "out_b": sds((1,)),
+    }
+    _compile(_score_topk_batch, params, sds((32,), jnp.int32), NUM_ITEMS, 16)
+
+
+def test_serving_score_contractions_are_full_f32():
+    """Every device serving scorer beside the fused kernel — the NCF wave
+    (pure GMF and MLP tower), ALS's materialized-row top-k, the similarity
+    templates — contracts at HIGHEST.  The TPU runs a DEFAULT f32 dot as
+    one bf16 pass (scores ~1e-3 off, ranks differ from the host replica);
+    the CPU computes full f32 either way, so CPU numerics cannot see a
+    regression here: the program lowered for the TPU is read instead."""
+    from predictionio_tpu.models.ncf.engine import _score_topk_batch
+    from predictionio_tpu.models.recommendation.engine import (
+        _device_score_topk,
+    )
+    from predictionio_tpu.ops.ncf import (
+        NCFParams,
+        init_ncf,
+        score_users_vs_items,
+    )
+    from predictionio_tpu.ops.similarity import cosine_topk, dot_topk
+
+    def dots(jitted, *specs):
+        text = jitted.trace(*specs).lower(lowering_platforms=("tpu",)).as_text()
+        found = [ln for ln in text.splitlines() if "stablehlo.dot_general" in ln]
+        assert found, "no contraction in the lowered program"
+        return found
+
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)  # noqa: E731
+    users = jax.ShapeDtypeStruct((32,), jnp.int32)
+    mask = jax.ShapeDtypeStruct((NUM_ITEMS,), jnp.bool_)
+    towers = [
+        jax.eval_shape(
+            lambda p=p: init_ncf(jax.random.PRNGKey(0), 64, NUM_ITEMS, p)
+        )
+        for p in (
+            NCFParams(embed_dim=10, mlp_layers=()),
+            NCFParams(embed_dim=8, mlp_layers=(16, 8)),
+        )
+    ]
+    programs = [
+        dots(_score_topk_batch, tower, users, NUM_ITEMS, 16)
+        for tower in towers
+    ] + [
+        # the per-shard scorer of factor-sharded NCF serving
+        dots(
+            jax.jit(score_users_vs_items),
+            {k: v for k, v in tower.items() if k in ("mlp", "out_w", "out_b")},
+            f32(32, tower["user_emb"].shape[1]),
+            tower["item_emb"],
+        )
+        for tower in towers
+    ] + [
+        dots(_device_score_topk, f32(64, 10), f32(NUM_ITEMS, 10), users, 16),
+        dots(dot_topk, f32(10), f32(NUM_ITEMS, 10), mask, 16),
+        dots(cosine_topk, f32(3, 10), f32(NUM_ITEMS, 10), mask, 16),
+    ]
+    for lines in programs:
+        for ln in lines:
+            assert "precision = [HIGHEST, HIGHEST]" in ln, ln
+
+
+def test_per_shard_fused_topk_compiles_on_four_chips(v5e):
+    """The sharded serving kernel as ``ALSAlgorithm._sharded_topk`` builds
+    it: each of 4 devices runs the fused top-k over ITS quarter of the item
+    table inside shard_map, then the k winners all_gather and merge."""
+    from predictionio_tpu.parallel.placement import (
+        ShardPlan,
+        build_sharded_topk,
+    )
+
+    mesh = Mesh(np.array(v5e.devices), ("model",))
+    plan = ShardPlan.model_parallel(
+        ["item_factors"], rows={"item_factors": NUM_ITEMS}
+    )
+    rows_pad = (NUM_ITEMS + 3) // 4 * 4
+    batch, k = 32, 16
+
+    def fused_local(item_local, q, kc, limit):
+        packed = fused_topk_batch(
+            q, item_local, kc, limit=limit, interpret=False
+        )
+        return packed[0], packed[1].astype(jnp.int32)
+
+    kernel = build_sharded_topk(
+        mesh, plan, lambda item_local, q: q @ item_local.T,
+        ["item_factors"], n_items=NUM_ITEMS, k=k,
+        name="aot.sharded_topk", local_topk_fn=fused_local,
+    )
+    table = jax.ShapeDtypeStruct(
+        (rows_pad, 10), jnp.float32,
+        sharding=NamedSharding(mesh, PartitionSpec("model", None)),
+    )
+    queries = jax.ShapeDtypeStruct(
+        (batch, 10), jnp.float32,
+        sharding=NamedSharding(mesh, PartitionSpec()),
+    )
+    _compile(kernel, table, queries)
