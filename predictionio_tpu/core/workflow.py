@@ -55,12 +55,29 @@ def _now() -> datetime:
 def _stage_breakdown(root, compile_delta_s: float | None = None) -> dict:
     """Per-stage seconds from the run's span tree + the compile split.
 
-    ``compile_delta_s`` is the growth of ``pio_jax_compile_seconds`` over
-    this run — stage wall time minus it approximates pure execute time.
+    The root's children (the DASE stages) by name, as ever; then every
+    deeper span by its own name, seconds accumulated over same-named spans.
+    Same-named spans of several threads ran side by side (the two sides of
+    ``als.stage``): their value is the longest thread's, and ``parallel``
+    lists those names.  ``compile_delta_s`` is the growth of
+    ``pio_jax_compile_seconds`` over this run — stage wall time minus it
+    approximates pure execute time.
     """
     out = {
         name: round(secs, 4) for name, secs in root.breakdown().items()
     }
+    by_thread: dict[str, dict[int, float]] = {}
+    spans = [g for c in root.children for g in c.children]
+    while spans:
+        s = spans.pop()
+        spans.extend(s.children)
+        threads = by_thread.setdefault(s.name, {})
+        threads[s.thread_id] = threads.get(s.thread_id, 0.0) + s.duration_s
+    for name, threads in by_thread.items():
+        out.setdefault(name, round(max(threads.values()), 4))
+    parallel = sorted(n for n, t in by_thread.items() if len(t) > 1)
+    if parallel:
+        out["parallel"] = parallel
     out["total"] = round(root.duration_s, 4)
     if compile_delta_s is not None:
         out["jax_compile"] = round(compile_delta_s, 4)

@@ -1982,6 +1982,13 @@ class ParquetLEvents(LEvents):
         return iter(_table_to_events(_sort_limit(t, flt)))
 
 
+def _bytes_read_total() -> float:
+    """``pio_eventstore_bytes_read_total`` over every scan kind."""
+    return sum(
+        child.value for _, child in _metrics()["bytes_read"].series()
+    )
+
+
 class ParquetPEvents(PEvents):
     """Bulk columnar DAO (the HBPEvents/JDBCPEvents role): per-shard
     EventFrames for memory-bounded scans and multi-host shard ranges."""
@@ -2026,13 +2033,32 @@ class ParquetPEvents(PEvents):
         channel_id: int | None = None,
         filter: EventFilter | None = None,
     ) -> EventFrame:
-        tables = [
-            t for _, t in self.store.scan_shards(app_id, channel_id, filter)
-        ]
+        from predictionio_tpu.obs.tracing import trace
+
+        with trace("eventstore.scan") as span:
+            read0 = _bytes_read_total()
+            tables = [
+                t
+                for _, t in self.store.scan_shards(app_id, channel_id, filter)
+            ]
+            span.tags = {
+                "rows": sum(t.num_rows for t in tables),
+                "shards": len(tables),
+                "bytes_read": int(_bytes_read_total() - read0),
+            }
         if not tables:
             return EventFrame.from_events([])
-        t = _sort_limit(pa.concat_tables(tables), filter)
-        return _table_to_frame(t)
+        # each span also releases the input it made redundant, so that the
+        # spans add up to the read: freeing 20 M rows is not free
+        with trace("eventstore.sort") as span:
+            t = _sort_limit(pa.concat_tables(tables), filter)
+            span.tags = {"rows": t.num_rows}
+            del tables
+        with trace("eventstore.decode") as span:
+            span.tags = {"rows": t.num_rows, "columns": t.num_columns}
+            frame = _table_to_frame(t)
+            del t
+        return frame
 
     def write(
         self, frame: EventFrame, app_id: int, channel_id: int | None = None

@@ -110,10 +110,14 @@ def _score_topk(params, user_idx, n_items: int, k: int):
     indices) instead of a (scores, indices) pair: fetching two separate
     outputs costs two device->host transfers, the packed layout one.  f32
     holds item ids exactly up to 2^24."""
-    scores = score_all_items(params, user_idx)
-    masked = jnp.where(jnp.arange(scores.shape[0]) < n_items, scores, -jnp.inf)
-    s, i = jax.lax.top_k(masked, k)
-    return jnp.stack([s, i.astype(jnp.float32)])
+    with jax.named_scope("ncf.score"):
+        scores = score_all_items(params, user_idx)
+    with jax.named_scope("ncf.topk"):
+        masked = jnp.where(
+            jnp.arange(scores.shape[0]) < n_items, scores, -jnp.inf
+        )
+        s, i = jax.lax.top_k(masked, k)
+        return jnp.stack([s, i.astype(jnp.float32)])
 
 
 @partial(jax.jit, static_argnames=("n_items", "k"))
@@ -127,12 +131,14 @@ def _score_topk_batch(params, user_idx, n_items: int, k: int):
     [2, B, k] f32 (scores, indices) for the same one-transfer reason as
     ``_score_topk``.
     """
-    scores = jax.vmap(lambda u: score_all_items(params, u))(user_idx)
-    masked = jnp.where(
-        jnp.arange(scores.shape[1])[None, :] < n_items, scores, -jnp.inf
-    )
-    s, i = jax.lax.top_k(masked, k)
-    return jnp.stack([s, i.astype(jnp.float32)])
+    with jax.named_scope("ncf.score"):
+        scores = jax.vmap(lambda u: score_all_items(params, u))(user_idx)
+    with jax.named_scope("ncf.topk"):
+        masked = jnp.where(
+            jnp.arange(scores.shape[1])[None, :] < n_items, scores, -jnp.inf
+        )
+        s, i = jax.lax.top_k(masked, k)
+        return jnp.stack([s, i.astype(jnp.float32)])
 
 
 def _packable_n_items(model: "NCFModel") -> int:

@@ -36,6 +36,7 @@ from predictionio_tpu.core.warmstart import align_warm_factors, find_warm_start
 from predictionio_tpu.data.bimap import BiMap
 from predictionio_tpu.obs import device as device_obs
 from predictionio_tpu.obs import provenance
+from predictionio_tpu.obs.tracing import trace
 from predictionio_tpu.ops.als import ALSParams, ALSState, train_als
 from predictionio_tpu.ops.topk import (
     SCORE_PRECISION,
@@ -144,16 +145,22 @@ class RatingsDataSource(DataSource):
             target_entity_type="item",
             event_names=["rate", "buy"],
         )
-        ratings = frame.property_column("rating", default=np.nan)
-        # buy events carry no rating property -> fixed implicit rating
-        is_buy = frame.event == "buy"
-        ratings = np.where(is_buy, self.params.buy_rating, ratings)
-        keep = ~np.isnan(ratings)
-        return TrainingData(
-            users=frame.entity_id[keep],
-            items=frame.target_entity_id[keep],
-            ratings=ratings[keep].astype(np.float32),
-        )
+        with trace("datasource.columns") as span:
+            ratings = frame.property_column("rating", default=np.nan)
+            # buy events carry no rating property -> fixed implicit rating
+            is_buy = frame.event == "buy"
+            ratings = np.where(is_buy, self.params.buy_rating, ratings)
+            keep = ~np.isnan(ratings)
+            td = TrainingData(
+                users=frame.entity_id[keep],
+                items=frame.target_entity_id[keep],
+                ratings=ratings[keep].astype(np.float32),
+            )
+            span.tags = {"rows_in": len(keep), "rows_kept": len(td.ratings)}
+            # the frame's other columns go here, inside the span that made
+            # them redundant: freeing 20 M decoded rows is not free
+            del frame, is_buy
+        return td
 
     def read_training(self, ctx: EngineContext) -> TrainingData:
         return self._read(ctx)
@@ -202,13 +209,19 @@ class RatingsPreparator(Preparator):
         pass
 
     def prepare(self, ctx: EngineContext, td: TrainingData) -> PreparedData:
-        user_vocab = BiMap.from_keys(td.users)
-        item_vocab = BiMap.from_keys(td.items)
+        with trace("prepare.vocab") as span:
+            user_vocab = BiMap.from_keys(td.users)
+            item_vocab = BiMap.from_keys(td.items)
+            span.tags = {"users": len(user_vocab), "items": len(item_vocab)}
+        with trace("prepare.index") as span:
+            user_idx = user_vocab.to_index_array(td.users).astype(np.int32)
+            item_idx = item_vocab.to_index_array(td.items).astype(np.int32)
+            span.tags = {"rows": len(user_idx)}
         return PreparedData(
             user_vocab=user_vocab,
             item_vocab=item_vocab,
-            user_idx=user_vocab.to_index_array(td.users).astype(np.int32),
-            item_idx=item_vocab.to_index_array(td.items).astype(np.int32),
+            user_idx=user_idx,
+            item_idx=item_idx,
             ratings=td.ratings,
         )
 
@@ -253,7 +266,7 @@ class ALSModel:
     shards: Any = None
 
     def sanity_check(self):
-        uf = np.asarray(self.user_factors)
+        uf = self.host_factors()[0]
         if not np.isfinite(uf).all():
             raise SanityCheckError("ALS user factors contain non-finite values")
 
@@ -310,12 +323,17 @@ class ALSAlgorithm(Algorithm):
             mesh=ctx.mesh if ctx.mesh.devices.size > 1 else None,
             init_factors=self._warm_start_init(ctx, pd),
         )
-        return ALSModel(
+        model = ALSModel(
             user_factors=state.user_factors,
             item_factors=state.item_factors,
             user_vocab=pd.user_vocab,
             item_vocab=pd.item_vocab,
         )
+        # the factors' one way back to the host: the sanity check and the
+        # persisted model both read this replica
+        with trace("als.fetch") as span:
+            span.tags = {"bytes": sum(f.nbytes for f in model.host_factors())}
+        return model
 
     def _warm_start_init(
         self, ctx: EngineContext, pd: PreparedData
@@ -680,8 +698,8 @@ class ALSAlgorithm(Algorithm):
     # -- persistence ---------------------------------------------------------
     def make_persistent_model(self, ctx: EngineContext, model: ALSModel):
         out = {
-            "user_factors": np.asarray(jax.device_get(model.user_factors)),
-            "item_factors": np.asarray(jax.device_get(model.item_factors)),
+            "user_factors": model.host_factors()[0],
+            "item_factors": model.host_factors()[1],
             "user_vocab": model.user_vocab.to_state(),
             "item_vocab": model.item_vocab.to_state(),
         }

@@ -10,7 +10,8 @@ onto a server:
   GET  /logs.json           recent structured log records (?request_id=&
                             limit=&level=)
   GET  /debug/flight.json   flight recorder: N slowest + errored requests
-  POST /debug/profile       start a jax.profiler capture (?seconds=N&dir=)
+  POST /debug/profile       start a jax.profiler capture (?seconds=N&dir=;
+                            &python=1 adds the Python tracer)
   GET  /debug/profile       capture status (running / last)
   GET  /quality.json        online model quality: per-variant metrics +
                             drift state (servers constructed with a
@@ -592,7 +593,13 @@ def add_observability_routes(
         except ValueError:
             return json_response(400, {"message": "seconds must be a number"})
         try:
-            started = PROFILER.start(seconds, req.query.get("dir"))
+            started = PROFILER.start(
+                seconds,
+                req.query.get("dir"),
+                # Python frames cost the server what the capture measures:
+                # off unless the operator asks (?python=1)
+                python_tracer=req.query.get("python") == "1",
+            )
         except ValueError as e:
             return json_response(400, {"message": str(e)})
         except ProfilerBusy as e:

@@ -1,9 +1,10 @@
 """On-demand JAX profiling + runtime gauges for a live server.
 
-``POST /debug/profile?seconds=N`` starts a ``jax.profiler`` trace capture on
-a running server without restarting it — the "grab a profile of the slow
-fleet member right now" workflow (DrJAX's profiling emphasis; the Spark job
-UI role in the reference).  ``start_trace`` runs on the request thread (it
+``POST /debug/profile?seconds=N`` starts a ``jax.profiler`` trace capture
+(device planes + the program's spans; ``&python=1`` adds Python frames, at
+the server's expense) on a running server without restarting it — the "grab
+a profile of the slow fleet member right now" workflow (DrJAX's profiling
+emphasis; the Spark job UI role in the reference).  ``start_trace`` runs on the request thread (it
 only arms collection, and a failure must surface as the HTTP status); the
 capture *wait* and ``stop_trace`` run on a dedicated background thread so
 the request thread answers immediately — a stalled profiler must never hold
@@ -39,11 +40,21 @@ class ProfilerBusy(RuntimeError):
     """A capture is already in flight (jax allows one trace at a time)."""
 
 
-def _start_trace(out_dir: str) -> None:
-    """Indirection point (tests stub these; jax imports stay lazy)."""
+def _start_trace(out_dir: str, python_tracer: bool = False) -> None:
+    """Indirection point (tests stub these; jax imports stay lazy).
+
+    Host tracer at level 1: the program's spans (``obs/tracing.trace``
+    opens a ``TraceAnnotation`` per span while a session is open) are in
+    the capture beside the device planes.  The Python tracer is OFF unless
+    asked for: it hooks every call of every thread, and on a live server
+    it cut 480 answers/s to 155-240 and timed requests out (PERF.md
+    section 6) — the capture then measures its own cost."""
     import jax
 
-    jax.profiler.start_trace(out_dir)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 1 if python_tracer else 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(out_dir, profiler_options=opts)
 
 
 def _stop_trace() -> None:
@@ -65,7 +76,12 @@ class ProfilerController:
         self._last: dict[str, Any] | None = None
         self._wakeup = threading.Event()
 
-    def start(self, seconds: float, out_dir: str | None = None) -> dict[str, Any]:
+    def start(
+        self,
+        seconds: float,
+        out_dir: str | None = None,
+        python_tracer: bool = False,
+    ) -> dict[str, Any]:
         if not 0 < seconds <= MAX_CAPTURE_SECONDS:
             raise ValueError(
                 f"seconds must be in (0, {MAX_CAPTURE_SECONDS:g}]"
@@ -81,10 +97,11 @@ class ProfilerController:
             self._running = {
                 "dir": out_dir,
                 "seconds": seconds,
+                "python_tracer": python_tracer,
                 "started": time.time(),
             }
         try:
-            _start_trace(out_dir)
+            _start_trace(out_dir, python_tracer)
         except Exception as e:
             with self._lock:
                 self._running = None
@@ -98,7 +115,10 @@ class ProfilerController:
             name="pio-profiler",
             daemon=True,
         ).start()
-        return {"profiling": True, "seconds": seconds, "dir": out_dir}
+        return {
+            "profiling": True, "seconds": seconds, "dir": out_dir,
+            "python_tracer": python_tracer,
+        }
 
     def _finish(self, seconds: float, out_dir: str) -> None:
         # paced by an Event, not a sleep poll: interruptible and lint-clean

@@ -16,11 +16,28 @@ header and the matching ``/logs.json`` lines.  The HTTP front ends open one
 cheap unrecorded root span per request (``record=False``: ring only, no
 histogram); the second-scale stages — DASE train stages, JAX compiles, batch
 predict, eval folds — use recorded spans.
+
+**The profiler's clock.**  While a ``jax.profiler`` session is open, each
+span also opens a ``jax.profiler.TraceAnnotation`` of its name, so the
+program's spans land on the ``/host:CPU`` plane of the capture, beside the
+device planes and on their clock: a device-idle gap is then named for the
+span the host was in.  The annotation opens and closes with the ``Span`` (no
+second clock, no second span type), only in a process that has ALREADY
+imported ``jax`` (a span never imports it), and only while
+``TraceAnnotation.is_enabled()``: an untraced process pays one flag read per
+span.
+
+**Other threads.**  A thread (or executor worker) starts with an empty span
+stack.  ``trace(name, parent=span)`` opens a span there as a child of
+``span``: it gets a stack of its own, inherits the parent's request and trace
+ids, and attaches to ``span.children`` when it closes — the parent's own
+stack is never shared between threads.
 """
 
 from __future__ import annotations
 
 import contextvars
+import sys
 import threading
 import time
 from collections import deque
@@ -46,6 +63,32 @@ _stack_var: contextvars.ContextVar[list["Span"] | None] = (
     contextvars.ContextVar("pio_span_stack", default=None)
 )
 
+#: ``jax.profiler.TraceAnnotation``, looked up the first time a span opens in
+#: a process that has imported jax; never imported from here
+_trace_annotation: Any = None
+
+
+def _open_annotation(name: str) -> Any:
+    """The span's annotation on the profiler's clock, entered — or None when
+    jax is not loaded, no profiler session is open, or anything fails
+    (telemetry must never break the traced block)."""
+    global _trace_annotation
+    cls = _trace_annotation
+    try:
+        if cls is None:
+            jax = sys.modules.get("jax")
+            if jax is None:
+                return None
+            cls = _trace_annotation = jax.profiler.TraceAnnotation
+        if not cls.is_enabled():
+            return None
+        annotation = cls(name)
+        annotation.__enter__()
+        return annotation
+    except Exception:
+        return None
+
+
 #: ring of the most recent finished root spans (as dicts), newest last
 _ring: deque[dict[str, Any]] = deque(maxlen=256)
 _ring_lock = threading.Lock()
@@ -57,7 +100,7 @@ class Span:
     __slots__ = (
         "name", "start_s", "duration_s", "children", "error",
         "request_id", "tags", "span_id", "parent_id", "trace_id",
-        "start_ts",
+        "start_ts", "thread_id",
     )
 
     def __init__(self, name: str):
@@ -79,6 +122,9 @@ class Span:
         self.parent_id: str | None = None
         self.trace_id: str | None = None
         self.start_ts: float = 0.0
+        #: the thread the span ran on: same-named spans of several threads
+        #: ran side by side, and their seconds do not add up to wall time
+        self.thread_id: int = 0
 
     def to_dict(self) -> dict[str, Any]:
         d: dict[str, Any] = {
@@ -112,9 +158,17 @@ class trace:
     keeps a ROOT span out of the recent-traces ring (for high-volume
     infrastructure spans like storage round trips that would otherwise
     evict real request traces from ``/traces.json``) — cross-process
-    fragment collection is unaffected by either."""
+    fragment collection is unaffected by either.
 
-    __slots__ = ("span", "_registry", "_record", "_ring")
+    ``parent=span`` opens the span as a child of ``span`` from ANOTHER
+    thread than the one ``span`` is open on (a pool worker): the block gets
+    a span stack of its own, so nothing is shared with the parent's thread
+    but the finished child, appended to ``span.children`` at exit."""
+
+    __slots__ = (
+        "span", "_registry", "_record", "_ring", "_parent", "_token",
+        "_annotation",
+    )
 
     def __init__(
         self,
@@ -122,37 +176,61 @@ class trace:
         registry: MetricsRegistry | None = None,
         record: bool = True,
         ring: bool = True,
+        parent: Span | None = None,
     ):
         self.span = Span(name)
         self._registry = registry or REGISTRY
         self._record = record
         self._ring = ring
+        self._parent = parent
+        self._token = None
+        self._annotation = None
 
     def __enter__(self) -> Span:
-        stack = _stack_var.get()
-        if stack is None:
-            stack = []
-            _stack_var.set(stack)
         span = self.span
-        span.request_id = get_request_id()
-        span.trace_id = get_trace_id()
+        parent = self._parent
         span.span_id = new_span_id()
-        if not stack:
-            # a ROOT span parents to the cross-process caller (the span id
-            # adopted from X-Pio-Parent-Span); children parent in-tree
-            span.parent_id = get_parent_span()
-        stack.append(span)
+        if parent is not None:
+            # this thread's own stack, with the span as its bottom: spans
+            # nested in the block become its children, never the parent's
+            self._token = _stack_var.set([span])
+            span.request_id = parent.request_id
+            span.trace_id = parent.trace_id
+            span.parent_id = parent.span_id
+        else:
+            stack = _stack_var.get()
+            if stack is None:
+                stack = []
+                _stack_var.set(stack)
+            span.request_id = get_request_id()
+            span.trace_id = get_trace_id()
+            if not stack:
+                # a ROOT span parents to the cross-process caller (the span
+                # id adopted from X-Pio-Parent-Span); children parent in-tree
+                span.parent_id = get_parent_span()
+            stack.append(span)
+        span.thread_id = threading.get_ident()
+        self._annotation = _open_annotation(span.name)
         span.start_ts = time.time()
         span.start_s = time.perf_counter()
         return span
 
     def __exit__(self, exc_type, exc, tb) -> None:
         self.span.duration_s = time.perf_counter() - self.span.start_s
+        if self._annotation is not None:
+            try:
+                self._annotation.__exit__(exc_type, exc, tb)
+            except Exception:
+                pass  # telemetry must never break the traced block
         if exc is not None:
             self.span.error = f"{type(exc).__name__}: {exc}"
         stack = _stack_var.get() or []
         stack.pop()
-        if stack:
+        if self._parent is not None:
+            _stack_var.reset(self._token)
+            # list.append is atomic: two workers may close at once
+            self._parent.children.append(self.span)
+        elif stack:
             stack[-1].children.append(self.span)
         else:
             if self._ring:

@@ -35,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as PSpec
 
+from predictionio_tpu.obs.tracing import trace
 from predictionio_tpu.parallel.mesh import pad_to_multiple
 
 log = logging.getLogger("predictionio_tpu.ops.als")
@@ -236,27 +237,30 @@ def _half_step(
     layout, where factors persist 1/n_dev per device between iterations and
     only the transient all-gather inside the NEXT half-step materializes a
     full table."""
-    a_weight, rhs = confidence_weights(
-        rating, valid, p.implicit_prefs, p.alpha, other_factors.dtype
-    )
-    # other_factors is replicated, so the Gram needs no collective.
-    gram = other_factors.T @ other_factors if p.implicit_prefs else None
-    acc = _segment_stats(
-        seg_idx, other_idx, other_factors, a_weight, rhs, valid,
-        num_seg_pad, p.chunk_size, axis,
-    )
+    with jax.named_scope("als.weights"):
+        a_weight, rhs = confidence_weights(
+            rating, valid, p.implicit_prefs, p.alpha, other_factors.dtype
+        )
     k = other_factors.shape[1]
-    if axis:
-        # one psum over the flat stats (A | b | counts packed together)
-        acc = jax.lax.psum(acc, axis)
-        n_dev = jax.lax.axis_size(axis)
-        slice_size = num_seg_pad // n_dev
-        start = jax.lax.axis_index(axis) * slice_size
-        acc = jax.lax.dynamic_slice_in_dim(acc, start, slice_size)
-    A = acc[:, : k * k].reshape(-1, k, k)
-    b = acc[:, k * k : k * k + k]
-    counts = acc[:, -1]
-    x = _solve_factors(A, b, counts, p.reg, p.scale_reg_with_count, gram)
+    with jax.named_scope("als.accumulate"):
+        acc = _segment_stats(
+            seg_idx, other_idx, other_factors, a_weight, rhs, valid,
+            num_seg_pad, p.chunk_size, axis,
+        )
+        if axis:
+            # one psum over the flat stats (A | b | counts packed together)
+            acc = jax.lax.psum(acc, axis)
+            n_dev = jax.lax.axis_size(axis)
+            slice_size = num_seg_pad // n_dev
+            start = jax.lax.axis_index(axis) * slice_size
+            acc = jax.lax.dynamic_slice_in_dim(acc, start, slice_size)
+    with jax.named_scope("als.solve"):
+        # other_factors is replicated, so the Gram needs no collective.
+        gram = other_factors.T @ other_factors if p.implicit_prefs else None
+        A = acc[:, : k * k].reshape(-1, k, k)
+        b = acc[:, k * k : k * k + k]
+        counts = acc[:, -1]
+        x = _solve_factors(A, b, counts, p.reg, p.scale_reg_with_count, gram)
     if axis and gather_output:
         return jax.lax.all_gather(x, axis, axis=0, tiled=True)
     return x
@@ -317,22 +321,29 @@ def _make_pallas_step(
         )
         return _solve_factors(A, b, counts, p.reg, p.scale_reg_with_count, gram)
 
-    def half(plan_args, oth, wrv_or_rat, val, other_factors, tpc, n_blocks,
-             num_seg_pad):
-        if fused:
-            # wrv_or_rat is the precomputed [nt, 3, T] weight stack; val
-            # is unused (folded into wrv once per dispatch)
-            acc = als_pallas.segment_stats_fused(
-                plan_args, oth, wrv_or_rat, other_factors, tpc, n_blocks,
-                precision=p.pallas_precision,
-            )[:num_seg_pad]
-        else:
-            acc = als_pallas.segment_stats_pallas(
-                plan_args, oth, wrv_or_rat, val, other_factors,
-                p.implicit_prefs, p.alpha, tpc, n_blocks,
-                precision=p.pallas_precision,
-            )[:num_seg_pad]
-        return solve(acc, other_factors)
+    # named scopes: every device operation of the step carries the name of
+    # its part in its metadata (``als.user_half/als.accumulate/...``), so a
+    # reduction of the device trace finds the parts after any refactor
+    def half(side, plan_args, oth, wrv_or_rat, val, other_factors, tpc,
+             n_blocks, num_seg_pad):
+        with jax.named_scope(f"als.{side}_half"):
+            with jax.named_scope("als.accumulate"):
+                if fused:
+                    # wrv_or_rat is the precomputed [nt, 3, T] weight
+                    # stack; val is unused (folded into wrv once per
+                    # dispatch)
+                    acc = als_pallas.segment_stats_fused(
+                        plan_args, oth, wrv_or_rat, other_factors, tpc,
+                        n_blocks, precision=p.pallas_precision,
+                    )[:num_seg_pad]
+                else:
+                    acc = als_pallas.segment_stats_pallas(
+                        plan_args, oth, wrv_or_rat, val, other_factors,
+                        p.implicit_prefs, p.alpha, tpc, n_blocks,
+                        precision=p.pallas_precision,
+                    )[:num_seg_pad]
+            with jax.named_scope("als.solve"):
+                return solve(acc, other_factors)
 
     def prep(rat, val):
         """Per-dispatch (NOT per-iteration) weight precompute for the
@@ -340,7 +351,8 @@ def _make_pallas_step(
         in-body instead."""
         if not fused:
             return rat
-        return als_pallas.make_wrv(rat, val, p.implicit_prefs, p.alpha)
+        with jax.named_scope("als.weights"):
+            return als_pallas.make_wrv(rat, val, p.implicit_prefs, p.alpha)
 
     if single_step:
 
@@ -349,9 +361,9 @@ def _make_pallas_step(
                   i_plan, i_oth, i_rat, i_val, U, V, n_iters):
             del n_iters  # one iteration per dispatch, caller loops
             u_w, i_w = prep(u_rat, u_val), prep(i_rat, i_val)
-            U = half(u_plan, u_oth, u_w, u_val, V, tpcu, nbu,
+            U = half("user", u_plan, u_oth, u_w, u_val, V, tpcu, nbu,
                      num_users_pad)
-            V = half(i_plan, i_oth, i_w, i_val, U, tpci, nbi,
+            V = half("item", i_plan, i_oth, i_w, i_val, U, tpci, nbi,
                      num_items_pad)
             return U, V
 
@@ -368,9 +380,9 @@ def _make_pallas_step(
 
             def body(_, uv):
                 U, V = uv
-                U = half(u_plan, u_oth, u_w, u_val, V, tpcu, nbu,
+                U = half("user", u_plan, u_oth, u_w, u_val, V, tpcu, nbu,
                          num_users_pad)
-                V = half(i_plan, i_oth, i_w, i_val, U, tpci, nbi,
+                V = half("item", i_plan, i_oth, i_w, i_val, U, tpci, nbi,
                          num_items_pad)
                 return U, V
 
@@ -486,60 +498,66 @@ def _train_pallas_mode(user_idx, item_idx, rating, num_users, num_items,
     num_users_pad = max((num_users + 127) // 128 * 128, 128)
     num_items_pad = max((num_items + 127) // 128 * 128, 128)
 
-    def stage(seg, oth, num_seg_pad, num_oth_pad):
-        base_plan = als_pallas.build_plan(
-            np.asarray(seg, np.int64), num_seg_pad
-        )
-        if mode == "fused":
-            plan = base_plan
-            perm, pad_mask = plan.dest_perm, plan.pad_mask
-            # [nt, T], minor dim 1024: layout-clean on device (no T(8,128)
-            # minor-dim padding possible)
-            shape2 = (plan.n_tiles, als_pallas.T)
-        else:
-            plan = als_pallas.chunk_plan(base_plan)
-            perm, pad_mask = plan.dest_perm, plan.pad_mask
-            shape2 = (plan.n_chunks, plan.tiles_per_chunk * als_pallas.T)
-        oth_p = np.asarray(oth, np.int32)[perm]
-        rat_p = np.asarray(rating, np.float32)[perm]
-        oth_p[pad_mask] = 0
-        rat_p[pad_mask] = 0.0
-        # Transfer-lean uploads: ship the narrowest encoding and widen on
-        # device.  seg3 ids are < S=128 -> int8 (4x); the opposite-entity
-        # index fits uint16 below 64Ki rows (2x); validity is DERIVED from
-        # seg3 (padding rows carry -1), so it costs zero transfer.
-        seg3_dev = jnp.asarray(plan.seg3.astype(np.int8)).astype(jnp.int32)
-        if num_oth_pad <= 0xFFFF:
-            oth_dev = jnp.asarray(
-                oth_p.astype(np.uint16).reshape(shape2)
-            ).astype(jnp.int32)
-        else:
-            oth_dev = jnp.asarray(oth_p.reshape(shape2))
-        val_dev = (
-            (seg3_dev.reshape(shape2) >= 0).astype(jnp.float32)
-        )
-        if mode == "fused":
-            dev_plan_args = (
-                jnp.asarray(plan.block_map),
-                jnp.asarray(plan.first),
-                seg3_dev,
+    def stage(side, seg, oth, num_seg_pad, num_oth_pad, parent):
+        """One scatter direction, on a pool thread: plan (sorts), permute,
+        upload — each a child span of ``parent`` (``als.stage``)."""
+        with trace("als.stage.plan", parent=parent) as span:
+            span.tags = {"side": side}
+            plan = als_pallas.build_plan(
+                np.asarray(seg, np.int64), num_seg_pad
             )
-        else:
-            dev_plan_args = (
-                jnp.asarray(plan.block_map),
-                jnp.asarray(plan.first),
-                seg3_dev,
-                jnp.asarray(plan.visited),
-            )
-        return (plan, dev_plan_args, oth_dev,
-                jnp.asarray(rat_p.reshape(shape2)), val_dev)
+            if mode == "fused":
+                # [nt, T], minor dim 1024: layout-clean on device (no
+                # T(8,128) minor-dim padding possible)
+                shape2 = (plan.n_tiles, als_pallas.T)
+            else:
+                plan = als_pallas.chunk_plan(plan)
+                shape2 = (plan.n_chunks, plan.tiles_per_chunk * als_pallas.T)
+        with trace("als.stage.permute", parent=parent) as span:
+            span.tags = {"side": side}
+            perm, pad_mask = plan.dest_perm, plan.pad_mask
+            oth_p = np.asarray(oth, np.int32)[perm]
+            rat_p = np.asarray(rating, np.float32)[perm]
+            oth_p[pad_mask] = 0
+            rat_p[pad_mask] = 0.0
+        with trace("als.stage.upload", parent=parent) as span:
+            span.tags = {"side": side, "upload_bytes": 0}
 
-    cache_key = (
-        _data_fingerprint(user_idx, item_idx, rating),
-        num_users_pad,
-        num_items_pad,
-        mode,
-    )
+            def upload(host: np.ndarray):
+                span.tags["upload_bytes"] += host.nbytes
+                return jnp.asarray(host)
+
+            # Transfer-lean uploads: ship the narrowest encoding and widen
+            # on device.  seg3 ids are < S=128 -> int8 (4x); the
+            # opposite-entity index fits uint16 below 64Ki rows (2x);
+            # validity is DERIVED from seg3 (padding rows carry -1), so it
+            # costs zero transfer.
+            seg3_dev = upload(plan.seg3.astype(np.int8)).astype(jnp.int32)
+            if num_oth_pad <= 0xFFFF:
+                oth_dev = upload(
+                    oth_p.astype(np.uint16).reshape(shape2)
+                ).astype(jnp.int32)
+            else:
+                oth_dev = upload(oth_p.reshape(shape2))
+            val_dev = (
+                (seg3_dev.reshape(shape2) >= 0).astype(jnp.float32)
+            )
+            dev_plan_args = (
+                upload(plan.block_map), upload(plan.first), seg3_dev,
+            )
+            if mode != "fused":
+                dev_plan_args += (upload(plan.visited),)
+            rat_dev = upload(rat_p.reshape(shape2))
+        return plan, dev_plan_args, oth_dev, rat_dev, val_dev
+
+    with trace("als.fingerprint") as span:
+        span.tags = {
+            "bytes": sum(
+                np.asarray(a).nbytes for a in (user_idx, item_idx, rating)
+            )
+        }
+        fingerprint = _data_fingerprint(user_idx, item_idx, rating)
+    cache_key = (fingerprint, num_users_pad, num_items_pad, mode)
     staged = _STAGE_CACHE.get(cache_key)
     if staged is None:
         # evict BEFORE staging: holding the old dataset's device streams
@@ -550,17 +568,25 @@ def _train_pallas_mode(user_idx, item_idx, rating, num_users, num_items,
         # nearly halve the cold-train host staging wall time
         from concurrent.futures import ThreadPoolExecutor
 
-        import time as _time
-
-        t0 = _time.perf_counter()
-        with ThreadPoolExecutor(2) as pool:
-            fu = pool.submit(stage, user_idx, item_idx, num_users_pad,
-                             num_items_pad)
-            fi = pool.submit(stage, item_idx, user_idx, num_items_pad,
-                             num_users_pad)
-            staged = (fu.result(), fi.result())
-        LAST_PLAN_INFO["stage_s"] = round(_time.perf_counter() - t0, 2)
+        with trace("als.stage") as stage_span:
+            with ThreadPoolExecutor(2) as pool:
+                fu = pool.submit(stage, "user", user_idx, item_idx,
+                                 num_users_pad, num_items_pad, stage_span)
+                fi = pool.submit(stage, "item", item_idx, user_idx,
+                                 num_items_pad, num_users_pad, stage_span)
+                staged = (fu.result(), fi.result())
+            stage_span.tags = {
+                "upload_bytes": sum(
+                    c.tags["upload_bytes"]
+                    for c in stage_span.children
+                    if c.name == "als.stage.upload"
+                )
+            }
+        LAST_PLAN_INFO["stage_s"] = round(stage_span.duration_s, 2)
+        LAST_PLAN_INFO["upload_bytes"] = stage_span.tags["upload_bytes"]
         _STAGE_CACHE[cache_key] = staged
+    else:
+        LAST_PLAN_INFO["upload_bytes"] = 0  # staged streams reused
     (up, u_plan, u_oth, u_rat, u_val), (ip, i_plan, i_oth, i_rat, i_val) = (
         staged
     )
@@ -588,25 +614,32 @@ def _train_pallas_mode(user_idx, item_idx, rating, num_users, num_items,
         per_iter=per_iter,
     )
 
-    U, V = _init_factors(p, num_users_pad, num_items_pad, num_users,
-                         num_items, dtype)
+    with trace("als.init"):
+        U, V = _init_factors(p, num_users_pad, num_items_pad, num_users,
+                             num_items, dtype)
     steps = _make_pallas_step(
         (tiles_u, up.n_blocks, tiles_i, ip.n_blocks),
         p, num_users_pad, num_items_pad, fused, single_step=per_iter,
     )
-    import time as _time
-
-    t0 = _time.perf_counter()
-    if per_iter:
-        for _ in range(p.num_iterations):
+    with trace("als.device_loop") as loop_span:
+        # the trip count each dispatch carries, times the dispatches
+        loop_span.tags = {
+            "iterations": p.num_iterations, "mode": mode,
+            "per_iter": per_iter,
+        }
+        if per_iter:
+            for _ in range(p.num_iterations):
+                U, V = steps(u_plan, u_oth, u_rat, u_val,
+                             i_plan, i_oth, i_rat, i_val, U, V, jnp.int32(1))
+        else:
             U, V = steps(u_plan, u_oth, u_rat, u_val,
-                         i_plan, i_oth, i_rat, i_val, U, V, jnp.int32(1))
-    else:
-        U, V = steps(u_plan, u_oth, u_rat, u_val,
-                     i_plan, i_oth, i_rat, i_val, U, V,
-                     jnp.int32(p.num_iterations))
-    jax.block_until_ready((U, V))
-    wall_s = _time.perf_counter() - t0
+                         i_plan, i_oth, i_rat, i_val, U, V,
+                         jnp.int32(p.num_iterations))
+        jax.block_until_ready((U, V))
+    wall_s = loop_span.duration_s
+    LAST_PLAN_INFO.update(
+        iterations=p.num_iterations, loop_s=round(wall_s, 4)
+    )
     _record_pallas_efficiency(wall_s, p)
     _log_train_path("als.pallas_step", wall_s, mode=mode, per_iter=per_iter)
     return ALSState(user_factors=U[:num_users], item_factors=V[:num_items])
@@ -681,15 +714,23 @@ def _make_train_step(
         if shard_state and axis:
             # factors arrive as this device's row slice: gather the full
             # opposite table transiently, return only the solved slice
-            Vf = jax.lax.all_gather(V, axis, axis=0, tiled=True)
-            U = _half_step(u_idx, i_idx, rating, valid, Vf, num_users_pad,
-                           p, axis, gather_output=False)
-            Uf = jax.lax.all_gather(U, axis, axis=0, tiled=True)
-            V = _half_step(i_idx, u_idx, rating, valid, Uf, num_items_pad,
-                           p, axis, gather_output=False)
+            with jax.named_scope("als.user_half"):
+                Vf = jax.lax.all_gather(V, axis, axis=0, tiled=True)
+                U = _half_step(u_idx, i_idx, rating, valid, Vf,
+                               num_users_pad, p, axis, gather_output=False)
+            with jax.named_scope("als.item_half"):
+                Uf = jax.lax.all_gather(U, axis, axis=0, tiled=True)
+                V = _half_step(i_idx, u_idx, rating, valid, Uf,
+                               num_items_pad, p, axis, gather_output=False)
             return U, V
-        U = _half_step(u_idx, i_idx, rating, valid, V, num_users_pad, p, axis)
-        V = _half_step(i_idx, u_idx, rating, valid, U, num_items_pad, p, axis)
+        with jax.named_scope("als.user_half"):
+            U = _half_step(
+                u_idx, i_idx, rating, valid, V, num_users_pad, p, axis
+            )
+        with jax.named_scope("als.item_half"):
+            V = _half_step(
+                i_idx, u_idx, rating, valid, U, num_items_pad, p, axis
+            )
         return U, V
 
     if mesh is None:
@@ -816,47 +857,74 @@ def train_als(
     num_users_pad = max(math.ceil(num_users / lane) * lane, lane)
     num_items_pad = max(math.ceil(num_items / lane) * lane, lane)
 
-    # clamp the chunk so small datasets aren't padded to a huge multiple
-    # (one scan step is enough when nnz/device fits a single chunk)
-    per_dev = max((len(user_idx) + n_dev - 1) // n_dev, 1)
-    if per_dev < p.chunk_size:
-        p = dataclasses.replace(
-            p, chunk_size=max(1 << max(per_dev - 1, 1).bit_length(), 256)
-        )
-    chunk_total = p.chunk_size * n_dev
-    u, n_real = pad_to_multiple(np.asarray(user_idx, np.int32), chunk_total)
-    i, _ = pad_to_multiple(np.asarray(item_idx, np.int32), chunk_total)
-    r, _ = pad_to_multiple(np.asarray(rating, np.float32), chunk_total)
-    valid = np.zeros(len(u), np.float32)
-    valid[:n_real] = 1.0
-    # padding rows scatter into a real segment with weight 0 — harmless
-    u[n_real:] = 0
-    i[n_real:] = 0
-
-    U0, V0 = _init_factors(p, num_users_pad, num_items_pad, num_users, num_items, dtype)
-    if init_factors is not None:
-        Uw, Vw = init_factors
-        if Uw.shape != (num_users, p.rank) or Vw.shape != (num_items, p.rank):
-            raise ValueError(
-                f"init_factors shapes {Uw.shape}/{Vw.shape} do not match "
-                f"({num_users}, {p.rank})/({num_items}, {p.rank})"
+    # host staging of the COO streams, under the span names of the pallas
+    # path (one side here: both half-steps read the same padded stream)
+    with trace("als.stage") as stage_span:
+        with trace("als.stage.plan"):
+            # clamp the chunk so small datasets aren't padded to a huge
+            # multiple (one scan step is enough when nnz/device fits a
+            # single chunk)
+            per_dev = max((len(user_idx) + n_dev - 1) // n_dev, 1)
+            if per_dev < p.chunk_size:
+                p = dataclasses.replace(
+                    p,
+                    chunk_size=max(
+                        1 << max(per_dev - 1, 1).bit_length(), 256
+                    ),
+                )
+            chunk_total = p.chunk_size * n_dev
+        with trace("als.stage.permute"):
+            u, n_real = pad_to_multiple(
+                np.asarray(user_idx, np.int32), chunk_total
             )
-        U0 = U0.at[:num_users].set(jnp.asarray(Uw, dtype))
-        V0 = V0.at[:num_items].set(jnp.asarray(Vw, dtype))
+            i, _ = pad_to_multiple(np.asarray(item_idx, np.int32), chunk_total)
+            r, _ = pad_to_multiple(np.asarray(rating, np.float32), chunk_total)
+            valid = np.zeros(len(u), np.float32)
+            valid[:n_real] = 1.0
+            # padding rows scatter into a real segment with weight 0 —
+            # harmless
+            u[n_real:] = 0
+            i[n_real:] = 0
+        with trace("als.stage.upload"):
+            upload_bytes = u.nbytes + i.nbytes + r.nbytes + valid.nbytes
+            if mesh is not None:
+                coo_sh = NamedSharding(mesh, PSpec("data"))
+                u = jax.device_put(u, coo_sh)
+                i = jax.device_put(i, coo_sh)
+                r = jax.device_put(r, coo_sh)
+                valid = jax.device_put(valid, coo_sh)
+            else:
+                # once, not once per iteration as arguments of the step
+                u, i, r, valid = (jnp.asarray(a) for a in (u, i, r, valid))
+        stage_span.tags = {"upload_bytes": upload_bytes}
+    LAST_PLAN_INFO.update(
+        stage_s=round(stage_span.duration_s, 2), upload_bytes=upload_bytes
+    )
 
-    if mesh is not None:
-        coo_sh = NamedSharding(mesh, PSpec("data"))
-        # sharded factor state (ROADMAP item 1): the tables and everything
-        # derived from them persist row-sharded over the mesh, so the
-        # per-device factor footprint drops as devices grow — each step
-        # all-gathers the opposite table transiently for its COO gathers
-        factor_sh = NamedSharding(mesh, PSpec("data", None))
-        u = jax.device_put(u, coo_sh)
-        i = jax.device_put(i, coo_sh)
-        r = jax.device_put(r, coo_sh)
-        valid = jax.device_put(valid, coo_sh)
-        U0 = jax.device_put(U0, factor_sh)
-        V0 = jax.device_put(V0, factor_sh)
+    with trace("als.init"):
+        U0, V0 = _init_factors(
+            p, num_users_pad, num_items_pad, num_users, num_items, dtype
+        )
+        if init_factors is not None:
+            Uw, Vw = init_factors
+            if Uw.shape != (num_users, p.rank) or Vw.shape != (
+                num_items, p.rank
+            ):
+                raise ValueError(
+                    f"init_factors shapes {Uw.shape}/{Vw.shape} do not "
+                    f"match ({num_users}, {p.rank})/({num_items}, {p.rank})"
+                )
+            U0 = U0.at[:num_users].set(jnp.asarray(Uw, dtype))
+            V0 = V0.at[:num_items].set(jnp.asarray(Vw, dtype))
+        if mesh is not None:
+            # sharded factor state (ROADMAP item 1): the tables and
+            # everything derived from them persist row-sharded over the
+            # mesh, so the per-device factor footprint drops as devices
+            # grow — each step all-gathers the opposite table transiently
+            # for its COO gathers
+            factor_sh = NamedSharding(mesh, PSpec("data", None))
+            U0 = jax.device_put(U0, factor_sh)
+            V0 = jax.device_put(V0, factor_sh)
 
     step = _make_train_step(
         mesh, num_users_pad, num_items_pad, p, shard_state=mesh is not None
@@ -896,22 +964,30 @@ def train_als(
         bool(os.environ.get("PIO_TRAIN_STEP_TIMELINE"))
         and get_trace_id() is not None
     )
-    t0 = _time.perf_counter()
-    U, V = U0, V0
-    for it in range(p.num_iterations):
-        t_step = _time.time()
-        U, V = step(u, i, r, valid, U, V)
-        if emit_steps:
-            jax.block_until_ready(V)
-            record_fragment(
-                f"als.train_step[{it}]",
-                t_step,
-                _time.time() - t_step,
-                track=f"train:{n_dev}dev",
-                tags={"iteration": it, "devices": n_dev},
-            )
-    U = jax.block_until_ready(U)
-    wall_s = _time.perf_counter() - t0
+    with trace("als.device_loop") as loop_span:
+        # one iteration a dispatch, ``num_iterations`` dispatches
+        loop_span.tags = {
+            "iterations": p.num_iterations, "mode": "scatter",
+            "per_iter": True,
+        }
+        U, V = U0, V0
+        for it in range(p.num_iterations):
+            t_step = _time.time()
+            U, V = step(u, i, r, valid, U, V)
+            if emit_steps:
+                jax.block_until_ready(V)
+                record_fragment(
+                    f"als.train_step[{it}]",
+                    t_step,
+                    _time.time() - t_step,
+                    track=f"train:{n_dev}dev",
+                    tags={"iteration": it, "devices": n_dev},
+                )
+        U = jax.block_until_ready(U)
+    wall_s = loop_span.duration_s
+    LAST_PLAN_INFO.update(
+        iterations=p.num_iterations, loop_s=round(wall_s, 4)
+    )
     if eff.cached_cost("als.train_step", sig) is None:
         # settle the residue of the concurrent capture (usually zero: the
         # analysis compile raced the real compile + N iterations)

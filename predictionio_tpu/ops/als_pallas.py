@@ -191,6 +191,7 @@ def make_segment_accum(
         out_shape=jax.ShapeDtypeStruct((n_blocks * S, width), jnp.float32),
         grid_spec=grid_spec,
         interpret=interpret,
+        name="als_segment_accum",
     )
 
 
@@ -341,6 +342,7 @@ def make_fused_accum(
         out_shape=jax.ShapeDtypeStruct((n_blocks * width, S), jnp.float32),
         grid_spec=grid_spec,
         interpret=interpret,
+        name="als_fused_accum",
     )
 
 

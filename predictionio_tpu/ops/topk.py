@@ -248,6 +248,7 @@ def _fused_topk_call(
         out_shape=jax.ShapeDtypeStruct((2, nb * bc, k), jnp.float32),
         grid_spec=grid_spec,
         interpret=interpret,
+        name="fused_topk",
     )
 
 
@@ -317,7 +318,9 @@ def fused_topk_batch(
     call = _fused_topk_call(
         nb, nt, bc, rank, k, TILE_ROWS, n_rows, interpret
     )
-    packed = call(limit_arr, q, t)
+    # the caller's name for this launch, on every device operation of it
+    with jax.named_scope(name):
+        packed = call(limit_arr, q, t)
     if pad_b:
         packed = packed[:, :b]
     return packed

@@ -282,10 +282,11 @@ class TestFlightRecorder:
 @pytest.fixture()
 def stub_profiler(monkeypatch):
     """Replace the jax trace hooks and reset the process controller."""
-    calls = {"start": [], "stop": 0}
+    calls = {"start": [], "python": [], "stop": 0}
 
-    def fake_start(out_dir):
+    def fake_start(out_dir, python_tracer=False):
         calls["start"].append(out_dir)
+        calls["python"].append(python_tracer)
 
     def fake_stop():
         calls["stop"] += 1
@@ -328,7 +329,7 @@ class TestProfiler:
         assert stub_profiler["stop"] == 1
 
     def test_unsupported_surfaces_and_unlocks(self, stub_profiler, monkeypatch):
-        def broken(out_dir):
+        def broken(out_dir, python_tracer=False):
             raise RuntimeError("no profiler on this backend")
 
         monkeypatch.setattr(profiler_mod, "_start_trace", broken)
@@ -427,7 +428,7 @@ class TestObservabilityRoutes:
         assert r.status == 200 and r.body["last"]["error"] is None
 
     def test_profile_route_501_when_unsupported(self, stub_profiler, monkeypatch):
-        def broken(out_dir):
+        def broken(out_dir, python_tracer=False):
             raise RuntimeError("CPU wheel without profiler")
 
         monkeypatch.setattr(profiler_mod, "_start_trace", broken)

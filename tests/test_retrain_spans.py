@@ -1,0 +1,531 @@
+"""The retrain's span tree (ISSUE 24): one tree from the store scan to the
+persisted model, the same spans on the profiler's clock, children opened from
+other threads, and what reaches whoever reads ``stages`` / ``LAST_PLAN_INFO``.
+
+Everything here is counts, names and structure; seconds are only compared
+with each other (children against their parent)."""
+
+from __future__ import annotations
+
+import functools
+import logging
+import subprocess
+import sys
+import threading
+
+import numpy as np
+import pytest
+
+from predictionio_tpu.core import EngineContext, EngineParams
+from predictionio_tpu.core.workflow import _stage_breakdown, run_train
+from predictionio_tpu.data.storage.base import EventFrame
+from predictionio_tpu.data.storage.config import StorageConfig, StorageRuntime
+from predictionio_tpu.models.recommendation import (
+    ALSAlgorithmParams,
+    DataSourceParams,
+    recommendation_engine,
+)
+from predictionio_tpu.obs import tracing
+from predictionio_tpu.obs.tracing import Span, recent_traces, trace
+from predictionio_tpu.ops import als, als_pallas
+from predictionio_tpu.parallel.mesh import MeshConfig
+from predictionio_tpu.tools import commands
+
+#: ISSUE 24, table (2): the spans of a retrain, whichever train path ran ...
+SPANS = {
+    "eventstore.scan", "eventstore.sort", "eventstore.decode",
+    "datasource.columns", "prepare.vocab", "prepare.index",
+    "als.stage", "als.stage.plan", "als.stage.permute", "als.stage.upload",
+    "als.init", "als.device_loop", "als.fetch",
+    "train.persist.save_models",
+}
+#: ... and the one only the Pallas path has (its staging cache's key)
+PALLAS_ONLY = {"als.fingerprint"}
+#: the root's children: what ``stages`` held before the tree went deeper
+DASE = {
+    "train.datasource.read", "train.preparator.prepare",
+    "train.algorithm.als", "train.persist.save_models",
+}
+ITERATIONS = 3
+N_USERS, N_ITEMS, NNZ = 120, 40, 2000
+
+
+@pytest.fixture()
+def parquet_storage(tmp_path):
+    """A throwaway parquet event store holding one app's ratings."""
+    home = tmp_path / "pio_home"
+    rt = StorageRuntime(StorageConfig.from_env({
+        "PIO_HOME": str(home),
+        "PIO_STORAGE_SOURCES_PARQUET_TYPE": "parquet",
+        "PIO_STORAGE_SOURCES_PARQUET_PATH": str(home / "events_parquet"),
+        "PIO_STORAGE_SOURCES_PARQUET_NSHARDS": "4",
+        "PIO_STORAGE_REPOSITORIES_EVENTDATA_SOURCE": "PARQUET",
+    }))
+    app = commands.app_new(rt, "spans").app
+    rng = np.random.default_rng(24)
+    users = rng.integers(0, N_USERS, NNZ)
+    items = rng.integers(0, N_ITEMS, NNZ)
+
+    def const(value: str) -> np.ndarray:
+        col = np.empty(NNZ, object)
+        col[:] = value
+        return col
+
+    rt.p_events().write(
+        EventFrame(
+            event=const("rate"),
+            entity_type=const("user"),
+            entity_id=np.array([f"u{u}" for u in users], object),
+            target_entity_type=const("item"),
+            target_entity_id=np.array([f"i{i}" for i in items], object),
+            event_time_ms=1_700_000_000_000 + np.arange(NNZ, dtype=np.int64),
+            properties=np.array(
+                [f'{{"rating": {1 + (u + i) % 5}.0}}'
+                 for u, i in zip(users, items)],
+                object,
+            ),
+        ),
+        app_id=app.id,
+    )
+    yield rt
+    rt.close()
+
+
+class _Stages(logging.Handler):
+    def __init__(self):
+        super().__init__(logging.INFO)
+        self.stages = None
+
+    def emit(self, record):
+        if hasattr(record, "stages"):
+            self.stages = record.stages
+
+
+def _retrain(storage, devices=1):
+    """(stages extra of the workflow's log record, the root span's dict) of
+    one ``run_train`` over a mesh of ``devices`` of the virtual CPU devices."""
+    seen = _Stages()
+    log = logging.getLogger("predictionio_tpu.workflow")
+    log.addHandler(seen)
+    level = log.level
+    log.setLevel(logging.INFO)
+    try:
+        inst = run_train(
+            recommendation_engine(),
+            EngineParams(
+                datasource=("ratings", DataSourceParams(app_name="spans")),
+                preparator=("ratings", None),
+                algorithms=(
+                    ("als", ALSAlgorithmParams(
+                        rank=4, num_iterations=ITERATIONS)),
+                ),
+                serving=("first", None),
+            ),
+            ctx=EngineContext(
+                storage=storage,
+                mesh_config=MeshConfig(axes={"data": devices}),
+            ),
+            storage=storage,
+        )
+    finally:
+        log.removeHandler(seen)
+        log.setLevel(level)
+    assert inst.status == "COMPLETED"
+    root = next(
+        t for t in recent_traces(5) if t.get("request_id") == inst.id
+    )
+    return seen.stages, root
+
+
+@pytest.fixture()
+def pallas_on_cpu(monkeypatch):
+    """The chip's train path under the Pallas interpreter: the steering a
+    CPU test needs lives here, not in an option of the program."""
+    monkeypatch.setattr(als, "_use_pallas", lambda p: True)
+    monkeypatch.setattr(
+        als_pallas, "segment_stats_fused",
+        functools.partial(als_pallas.segment_stats_fused, interpret=True),
+    )
+    als._STEP_CACHE.clear()
+    als._STAGE_CACHE.clear()
+    yield
+    als._STEP_CACHE.clear()
+    als._STAGE_CACHE.clear()
+
+
+def _walk(node, depth=0):
+    yield node, depth
+    for child in node.get("children", ()):
+        yield from _walk(child, depth + 1)
+
+
+def _check_tree(stages, root, expected, mode):
+    # every span of the table, by its own name, beside the DASE stages
+    assert expected | DASE <= set(stages)
+    assert stages["total"] > 0 and "jax_compile" in stages
+    # the root's children: the same keys with the same meaning as before
+    children = {c["name"]: c["duration_s"] for c in root["children"]}
+    assert set(children) == DASE
+    for name, secs in children.items():
+        assert stages[name] == pytest.approx(secs, abs=1e-4)
+    # deeper spans accumulate by name: none sits directly under the root
+    deeper = {n["name"] for n, depth in _walk(root) if depth >= 2}
+    assert expected - DASE <= deeper
+    # on one thread children sum to at most their parent, and to all of it
+    # (5 % + 5 ms) where the block is nothing but spans
+    by_name = {n["name"]: n for n, _ in _walk(root)}
+    for parent in ("train.datasource.read", "train.preparator.prepare"):
+        node = by_name[parent]
+        inner = sum(c["duration_s"] for c in node["children"])
+        assert inner <= node["duration_s"]
+        assert node["duration_s"] - inner <= 0.05 * node["duration_s"] + 0.005
+    # counts at the boundaries
+    assert by_name["eventstore.scan"]["rows"] == NNZ
+    assert by_name["eventstore.scan"]["shards"] == 4
+    assert by_name["eventstore.scan"]["bytes_read"] > 0
+    assert by_name["eventstore.decode"]["rows"] == NNZ
+    assert by_name["datasource.columns"]["rows_kept"] == NNZ
+    assert by_name["prepare.vocab"]["users"] <= N_USERS
+    assert by_name["prepare.index"]["rows"] == NNZ
+    loop = by_name["als.device_loop"]
+    assert loop["iterations"] == ITERATIONS and loop["mode"] == mode
+    assert by_name["als.fetch"]["bytes"] > 0
+    assert by_name["als.stage"]["upload_bytes"] > 0
+    # the counters the benchmark reads beside the spans
+    info = als.LAST_PLAN_INFO
+    assert info["iterations"] == ITERATIONS
+    assert info["loop_s"] == pytest.approx(loop["duration_s"], abs=1e-3)
+    assert info["upload_bytes"] == by_name["als.stage"]["upload_bytes"]
+    assert info["stage_s"] == round(by_name["als.stage"]["duration_s"], 2)
+
+
+@pytest.mark.parametrize("devices", [1, 8])
+def test_scatter_retrain_holds_the_span_tree(parquet_storage, devices):
+    stages, root = _retrain(parquet_storage, devices)
+    _check_tree(stages, root, SPANS, "scatter")
+    # one thread staged: nothing ran side by side
+    assert "parallel" not in stages
+    stage = next(n for n, _ in _walk(root) if n["name"] == "als.stage")
+    assert sum(c["duration_s"] for c in stage["children"]) <= stage["duration_s"]
+
+
+def test_pallas_retrain_holds_the_span_tree(parquet_storage, pallas_on_cpu):
+    stages, root = _retrain(parquet_storage)
+    _check_tree(stages, root, SPANS | PALLAS_ONLY, "fused")
+    # the two sides staged on two threads: each name's value is the longer
+    # side, the breakdown says which names those are, and no side outlasts
+    # the parent
+    staged = ["als.stage.permute", "als.stage.plan", "als.stage.upload"]
+    assert stages["parallel"] == staged
+    stage = next(n for n, _ in _walk(root) if n["name"] == "als.stage")
+    sides = {}
+    for child in stage["children"]:
+        sides.setdefault(child["name"], {})[child["side"]] = child["duration_s"]
+    for name in staged:
+        assert set(sides[name]) == {"user", "item"}
+        assert stages[name] == pytest.approx(
+            max(sides[name].values()), abs=1e-4)
+        assert stages[name] <= stage["duration_s"] + 1e-4
+    assert stage["upload_bytes"] == sum(
+        c["upload_bytes"] for c in stage["children"]
+        if c["name"] == "als.stage.upload"
+    )
+
+
+def test_staged_streams_reused_upload_nothing(pallas_on_cpu):
+    rng = np.random.default_rng(3)
+    u = rng.integers(0, 50, 600)
+    i = rng.integers(0, 20, 600)
+    r = rng.random(600).astype(np.float32)
+    p = als.ALSParams(rank=4, num_iterations=2)
+    als.train_als(u, i, r, 50, 20, p)
+    assert als.LAST_PLAN_INFO["upload_bytes"] > 0
+    with trace("again") as root:
+        als.train_als(u, i, r, 50, 20, p)
+    assert als.LAST_PLAN_INFO["upload_bytes"] == 0
+    assert als.LAST_PLAN_INFO["iterations"] == 2
+    names = {c.name for c in root.children}
+    assert "als.fingerprint" in names and "als.stage" not in names
+
+
+def test_breakdown_keeps_stage_values_and_takes_the_longest_thread():
+    root = Span("workflow.run_train")
+    root.duration_s = 10.0
+    stage = Span("train.algorithm.als")
+    stage.duration_s = 6.0
+    root.children.append(stage)
+    for name, thread, secs in [
+        ("als.stage.plan", 1, 2.0), ("als.stage.plan", 2, 3.0),
+        ("als.tick", 1, 0.5), ("als.tick", 1, 0.25),
+        # a deeper span under a stage's own name never replaces the stage
+        ("train.algorithm.als", 1, 1.0),
+    ]:
+        s = Span(name)
+        s.duration_s, s.thread_id = secs, thread
+        stage.children.append(s)
+    out = _stage_breakdown(root, 0.0)
+    assert out["train.algorithm.als"] == 6.0
+    assert out["als.stage.plan"] == 3.0  # the longer side, not the sum
+    assert out["als.tick"] == 0.75  # same thread: accumulated
+    assert out["parallel"] == ["als.stage.plan"]
+    assert out["total"] == 10.0
+
+
+# -- (b) children from other threads ----------------------------------------
+
+
+def test_spans_from_worker_threads_attach_to_the_given_parent():
+    from predictionio_tpu.obs.logging import (
+        reset_request_context,
+        set_request_context,
+    )
+
+    tokens = set_request_context("req-24", "trace-24")
+    started = threading.Barrier(2, timeout=10)
+    seen = {}
+
+    def work(side):
+        # a pool worker: no span stack, no request context of its own
+        assert tracing.current_span() is None
+        with trace("side", parent=parent) as span:
+            span.tags = {"side": side}
+            started.wait()  # both sides are open at the same time
+            with trace("inner"):
+                seen[side] = tracing.current_span().name
+        assert tracing.current_span() is None
+
+    try:
+        with trace("outer") as outer:
+            with trace("parent") as parent:
+                threads = [
+                    threading.Thread(target=work, args=(s,)) for s in "ab"
+                ]
+                for t in threads:
+                    t.start()
+                for t in threads:
+                    t.join(timeout=10)
+                assert not any(t.is_alive() for t in threads)
+                # the parent's own stack is untouched by the workers
+                assert tracing.current_span() is parent
+            assert tracing.current_span() is outer
+    finally:
+        reset_request_context(tokens)
+    assert seen == {"a": "inner", "b": "inner"}
+    assert sorted(c.tags["side"] for c in parent.children) == ["a", "b"]
+    assert outer.children == [parent]
+    for child in parent.children:
+        assert child.parent_id == parent.span_id
+        assert child.trace_id == "trace-24" and child.request_id == "req-24"
+        assert child.span_id and child.span_id != parent.span_id
+        assert child.thread_id != parent.thread_id
+        assert [g.name for g in child.children] == ["inner"]
+    assert len({c.span_id for c in parent.children}) == 2
+
+
+def test_a_span_with_a_given_parent_is_not_a_root():
+    before = len(recent_traces(256))
+    root = Span("finished-elsewhere")
+    done = []
+
+    def work():
+        with trace("child", parent=root):
+            pass
+        done.append(tracing.current_span())
+
+    t = threading.Thread(target=work)
+    t.start()
+    t.join(timeout=10)
+    assert not t.is_alive() and done == [None]
+    # it attaches to the parent it was given and never lands in the ring of
+    # root spans, though the thread's own stack held nothing above it
+    assert [c.name for c in root.children] == ["child"]
+    assert len(recent_traces(256)) == before
+
+
+# -- (c) the profiler's clock ------------------------------------------------
+
+
+def test_a_span_imports_nothing_where_jax_is_not_loaded():
+    code = (
+        "import sys\n"
+        "from predictionio_tpu.obs import tracing\n"
+        # the package's own imports may have loaded jax: forget it, as in a
+        # process that never imported it
+        "for m in [m for m in sys.modules\n"
+        "          if m.split('.')[0] in ('jax', 'jaxlib')]:\n"
+        "    del sys.modules[m]\n"
+        "before = set(sys.modules)\n"
+        "with tracing.trace('outer'):\n"
+        "    with tracing.trace('inner', record=False):\n"
+        "        pass\n"
+        "new = set(sys.modules) - before\n"
+        "assert not new, sorted(new)\n"
+        "assert tracing._trace_annotation is None\n"
+        "print('ok')\n"
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        timeout=120,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip() == "ok"
+
+
+def test_untraced_spans_open_no_annotation():
+    import jax  # noqa: F401  (loaded, but no profiler session is open)
+
+    with trace("quiet") as span:
+        assert tracing._open_annotation("probe") is None
+    assert span.duration_s >= 0
+
+
+def test_spans_land_on_the_profilers_host_plane(parquet_storage, tmp_path):
+    import jax
+
+    from benchmark import trace_reduce
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path / "trace"), profiler_options=opts)
+    try:
+        _retrain(parquet_storage)
+    finally:
+        jax.profiler.stop_trace()
+    data = jax.profiler.ProfileData.from_file(
+        trace_reduce.find_xplane(str(tmp_path / "trace"))
+    )
+    # the reducer's own host-plane loop: every event with a duration
+    host = {}
+    for plane in data.planes:
+        if plane.name == trace_reduce.HOST_PLANE:
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.duration_ns > 0:
+                        host[ev.name] = host.get(ev.name, 0) + ev.duration_ns
+    assert {"train.datasource.read", "eventstore.decode"} <= set(host)
+    assert SPANS | DASE | {"workflow.run_train"} <= set(host)
+    # one clock: a child's annotation lies inside its parent's
+    assert host["eventstore.decode"] <= host["train.datasource.read"]
+    assert host["train.datasource.read"] <= host["workflow.run_train"]
+
+
+# -- /debug/profile ----------------------------------------------------------
+
+
+@pytest.mark.parametrize(
+    "query,python_level", [({}, 0), ({"python": "0"}, 0), ({"python": "1"}, 1)]
+)
+def test_debug_profile_leaves_the_python_tracer_to_the_operator(
+    monkeypatch, query, python_level
+):
+    import jax
+
+    from predictionio_tpu.obs import profiler as profiler_mod
+    from predictionio_tpu.obs.http import add_observability_routes
+    from predictionio_tpu.obs.metrics import MetricsRegistry
+    from predictionio_tpu.server.httpd import HTTPApp, Request
+
+    started = []
+    monkeypatch.setattr(
+        jax.profiler, "start_trace",
+        lambda out_dir, profiler_options=None: started.append(
+            (out_dir, profiler_options)),
+    )
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    controller = profiler_mod.ProfilerController()
+    monkeypatch.setattr(profiler_mod, "PROFILER", controller)
+    monkeypatch.setattr("predictionio_tpu.obs.http.PROFILER", controller)
+    app = HTTPApp("profile-options")
+    add_observability_routes(app, MetricsRegistry(), access_key="pk")
+    r = app.handle(Request(
+        "POST", "/debug/profile",
+        {"seconds": "0.05", "accessKey": "pk", **query}, {},
+    ))
+    assert r.status == 202 and r.body["python_tracer"] is bool(python_level)
+    (_, opts), = started
+    # the program's annotations are in the capture; Python frames only when
+    # the operator asked for them
+    assert opts.host_tracer_level == 1
+    assert opts.python_tracer_level == python_level
+    controller._wakeup.set()
+    for _ in range(200):
+        if not controller.status()["running"]:
+            break
+        threading.Event().wait(0.01)
+    assert controller.status()["last"]["error"] is None
+
+
+# -- (d) stable names on the device ------------------------------------------
+
+
+def _scopes(jaxpr, found):
+    for eqn in jaxpr.eqns:
+        found.add(str(eqn.source_info.name_stack))
+        for sub in _subjaxprs(eqn):
+            _scopes(sub, found)
+
+
+def _subjaxprs(eqn):
+    for value in eqn.params.values():
+        values = value if isinstance(value, (list, tuple)) else [value]
+        for v in values:
+            inner = getattr(v, "jaxpr", v)
+            if hasattr(inner, "eqns"):
+                yield inner
+
+
+def test_scatter_step_carries_the_scope_names():
+    import jax
+    import jax.numpy as jnp
+
+    p = als.ALSParams(rank=4, chunk_size=256)
+    step = als._make_train_step(None, 64, 32, p)
+    n = 512
+    jaxpr = jax.make_jaxpr(step)(
+        jnp.zeros(n, jnp.int32), jnp.zeros(n, jnp.int32),
+        jnp.zeros(n, jnp.float32), jnp.ones(n, jnp.float32),
+        jnp.ones((64, 4), jnp.float32), jnp.ones((32, 4), jnp.float32),
+    )
+    found = set()
+    _scopes(jaxpr.jaxpr, found)
+    for side in ("als.user_half", "als.item_half"):
+        for part in ("als.weights", "als.accumulate", "als.solve"):
+            assert any(f"{side}/{part}" in s for s in found), (side, part)
+
+
+def test_fused_step_carries_the_scope_names_and_the_kernels_name():
+    import jax
+    import jax.numpy as jnp
+
+    T = als_pallas.T
+    nt, nb, users_pad, items_pad, k = 4, 1, 128, 128, 4
+    als._STEP_CACHE.clear()
+    steps = als._make_pallas_step(
+        (nt, nb, nt, nb), als.ALSParams(rank=k), users_pad, items_pad,
+        fused=True,
+    )
+    als._STEP_CACHE.clear()
+    i32, f32 = jnp.int32, jnp.float32
+
+    def side():
+        return (
+            (jnp.zeros(nt, i32), jnp.ones(nt, i32),
+             jnp.zeros((nt, T // 128, 128), i32)),
+            jnp.zeros((nt, T), i32), jnp.ones((nt, T), f32),
+            jnp.ones((nt, T), f32),
+        )
+
+    jaxpr = jax.make_jaxpr(steps)(
+        *side(), *side(), jnp.ones((users_pad, k), f32),
+        jnp.ones((items_pad, k), f32), jnp.int32(2),
+    )
+    found = set()
+    _scopes(jaxpr.jaxpr, found)
+    assert any("als.weights" in s for s in found)
+    for side_name in ("als.user_half", "als.item_half"):
+        for part in ("als.accumulate", "als.solve"):
+            assert any(f"{side_name}/{part}" in s for s in found), (
+                side_name, part)
+    text = str(jaxpr)
+    assert "als_fused_accum" in text
