@@ -6,15 +6,65 @@ string entity ids to dense integer indices so models can use array layouts;
 string array plus a hash dict, built from any iterable or numpy array, and is
 TPU-friendly: ``to_index_array`` vectorizes the forward lookup for columnar
 event batches.
+
+One algorithm, first-seen dedup, and two ways to run it, chosen from the
+argument alone.  A one-dimensional numpy object array whose rows share few
+distinct objects (an event column decoded through its dictionary: 20 M rows
+over 165 k interned strings) is factorized by POINTER in one vectorized pass
+(``ptr_factorize``), and Python hashes each distinct object once: the same
+object is the same dict key whatever it holds (``None``, a NaN, ``1`` beside
+``1.0``), and equal keys held by distinct objects merge in the dict as they do
+in the loop, so vocabulary and indices are the loop's, element for element.
+Anything else — lists, generators, ``U`` arrays, object arrays whose rows are
+mostly distinct objects, where the pass would save no hashing — takes the
+loop a row.  (Not ``pandas.factorize`` by VALUE: its string table compares
+C strings, so ``"a"`` and ``"a\0b"`` would share an index.)
 """
 
 from __future__ import annotations
 
-from typing import Generic, Hashable, Iterable, Iterator, Mapping, Sequence, TypeVar
+from typing import (
+    Generic,
+    Hashable,
+    Iterable,
+    Iterator,
+    Mapping,
+    NamedTuple,
+    Sequence,
+    TypeVar,
+)
 
 import numpy as np
 
 K = TypeVar("K", bound=Hashable)
+
+
+def _distinct(keys) -> tuple[np.ndarray, list] | None:
+    """(int64 code of every row, the distinct objects in first-seen order)
+    from one pointer-level pass over an object column, or None where the
+    caller runs the loop: not a 1-d object array, empty, or rows that are
+    mostly distinct objects."""
+    if type(keys) is not np.ndarray or keys.dtype != object or keys.ndim != 1:
+        return None
+    from predictionio_tpu.data.storage.base import ptr_factorize
+
+    f = ptr_factorize(keys)
+    if f is None:
+        return None
+    return f[0], f[1].tolist()
+
+
+class Factorized(NamedTuple):
+    """What ``BiMap.factorize`` made of one column."""
+
+    vocab: "BiMap"
+    #: int64 index of every row in ``vocab``
+    codes: np.ndarray
+    #: ``factorize``: one vectorized pass over the rows, Python over the
+    #: distinct objects; ``loop``: Python over every row
+    path: str
+    #: how many keys Python hashed to build the vocabulary
+    hashed: int
 
 
 class BiMap(Generic[K]):
@@ -38,11 +88,32 @@ class BiMap(Generic[K]):
     @classmethod
     def from_keys(cls, keys: Iterable[K]) -> "BiMap[K]":
         """Build a vocab from keys in first-seen order (deduplicating)."""
+        f = _distinct(keys)
         forward: dict[K, int] = {}
-        for k in keys:
+        for k in (keys if f is None else f[1]):
             if k not in forward:
                 forward[k] = len(forward)
         return cls.__new__(cls)._init_unchecked(forward)
+
+    @classmethod
+    def factorize(cls, keys: Iterable[K]) -> Factorized:
+        """Vocabulary in first-seen order AND every key's index in it, from
+        one pass over ``keys``: ``from_keys`` + ``to_index_array`` for a
+        column that is needed both ways."""
+        f = _distinct(keys)
+        if f is None:
+            if not isinstance(keys, (Sequence, np.ndarray)):
+                keys = list(keys)  # a generator is read once
+            vocab = cls.from_keys(keys)
+            return Factorized(
+                vocab, vocab.to_index_array(keys), "loop", len(keys)
+            )
+        codes, distinct = f
+        vocab = cls.from_keys(distinct)
+        if len(vocab) < len(distinct):
+            # equal keys held by distinct objects shared an entry
+            codes = vocab.to_index_array(distinct)[codes]
+        return Factorized(vocab, codes, "factorize", len(distinct))
 
     @classmethod
     def string_int(cls, keys: Iterable[str]) -> "BiMap[str]":
@@ -81,6 +152,9 @@ class BiMap(Generic[K]):
         self, keys: Sequence[K] | np.ndarray, missing: int = -1
     ) -> np.ndarray:
         """Vectorized forward lookup; unknown keys map to ``missing``."""
+        f = _distinct(keys)
+        if f is not None:
+            return self.to_index_array(f[1], missing)[f[0]]
         get = self._forward.get
         return np.fromiter(
             (get(k, missing) for k in keys), dtype=np.int64, count=len(keys)

@@ -12,6 +12,7 @@ lambda=0.01/seed=3 mirror the template's engine.json.
 
 from __future__ import annotations
 
+import logging
 import time
 from dataclasses import dataclass, field
 from functools import partial
@@ -47,6 +48,8 @@ from predictionio_tpu.ops.topk import (
     note_full_row_fallback,
 )
 from predictionio_tpu.parallel import device_cache
+
+log = logging.getLogger(__name__)
 
 # ---------------------------------------------------------------------------
 # Data types
@@ -210,13 +213,33 @@ class RatingsPreparator(Preparator):
 
     def prepare(self, ctx: EngineContext, td: TrainingData) -> PreparedData:
         with trace("prepare.vocab") as span:
-            user_vocab = BiMap.from_keys(td.users)
-            item_vocab = BiMap.from_keys(td.items)
-            span.tags = {"users": len(user_vocab), "items": len(item_vocab)}
+            users = BiMap.factorize(td.users)
+            items = BiMap.factorize(td.items)
+            user_vocab, item_vocab = users.vocab, items.vocab
+            span.tags = tags = {
+                # "loop" if either column fell back to Python over every row
+                "path": (
+                    "factorize"
+                    if users.path == items.path == "factorize"
+                    else "loop"
+                ),
+                "rows": len(users.codes),
+                "users": len(user_vocab),
+                "items": len(item_vocab),
+                # keys Python hashed: distinct objects, or every row
+                "user_keys_hashed": users.hashed,
+                "item_keys_hashed": items.hashed,
+            }
         with trace("prepare.index") as span:
-            user_idx = user_vocab.to_index_array(td.users).astype(np.int32)
-            item_idx = item_vocab.to_index_array(td.items).astype(np.int32)
-            span.tags = {"rows": len(user_idx)}
+            user_idx = users.codes.astype(np.int32)
+            item_idx = items.codes.astype(np.int32)
+            span.tags = {"rows": len(user_idx), "path": tags["path"]}
+            # the int64 codes go inside the span that made them redundant
+            del users, items
+        log.info(
+            "prepared %(rows)d rows by %(path)s: %(users)d users, %(items)d "
+            "items", tags, extra={"prepare": tags},
+        )
         return PreparedData(
             user_vocab=user_vocab,
             item_vocab=item_vocab,

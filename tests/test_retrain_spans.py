@@ -185,8 +185,15 @@ def _check_tree(stages, root, expected, mode):
     assert by_name["eventstore.scan"]["bytes_read"] > 0
     assert by_name["eventstore.decode"]["rows"] == NNZ
     assert by_name["datasource.columns"]["rows_kept"] == NNZ
-    assert by_name["prepare.vocab"]["users"] <= N_USERS
+    vocab = by_name["prepare.vocab"]
+    assert vocab["users"] <= N_USERS and vocab["items"] <= N_ITEMS
+    # the store decodes ids through their dictionary: one object an id, so
+    # the Preparator hashed each id once and says which way it went
+    assert vocab["path"] == "factorize" and vocab["rows"] == NNZ
+    assert vocab["user_keys_hashed"] == vocab["users"]
+    assert vocab["item_keys_hashed"] == vocab["items"]
     assert by_name["prepare.index"]["rows"] == NNZ
+    assert by_name["prepare.index"]["path"] == "factorize"
     loop = by_name["als.device_loop"]
     assert loop["iterations"] == ITERATIONS and loop["mode"] == mode
     assert by_name["als.fetch"]["bytes"] > 0
@@ -230,6 +237,91 @@ def test_pallas_retrain_holds_the_span_tree(parquet_storage, pallas_on_cpu):
         c["upload_bytes"] for c in stage["children"]
         if c["name"] == "als.stage.upload"
     )
+
+
+# -- the Preparator: one factorize pass a column, or the loop a row ----------
+
+PREPARE_ROWS, PREPARE_USERS, PREPARE_ITEMS = 200_000, 5_000, 800
+
+
+def _ids(prefix, k, seed):
+    """PREPARE_ROWS ids over ``k`` distinct ones, one str object each: what
+    the event store's dictionary decode hands the Preparator."""
+    vocab = np.empty(k, object)
+    vocab[:] = [f"{prefix}{j}" for j in range(k)]
+    return vocab[np.random.default_rng(seed).integers(0, k, PREPARE_ROWS)]
+
+
+def _with_none(col):
+    col = col.copy()
+    col[[7, 70_000, 199_999]] = None
+    return col
+
+
+def _boxed(col):
+    # a row-by-row decoder: every row a str object of its own
+    out = np.empty(len(col), object)
+    out[:] = [str(k) + "" for k in col.astype("U")]
+    return out
+
+
+#: users column -> the way the Preparator must say it went
+PREPARE_CASES = {
+    "interned": (lambda u: u, "factorize", PREPARE_USERS),
+    # a pointer is a pointer: None is one more distinct object
+    "none-user-id": (_with_none, "factorize", PREPARE_USERS + 1),
+    "U-dtype": (lambda u: u.astype("U"), "loop", PREPARE_ROWS),
+    "object-a-row": (_boxed, "loop", PREPARE_ROWS),
+}
+
+
+@pytest.mark.parametrize("case", PREPARE_CASES)
+def test_preparator_equals_the_loop_and_says_which_way_it_went(case, caplog):
+    from predictionio_tpu.models.recommendation.engine import (
+        RatingsPreparator,
+        TrainingData,
+    )
+
+    make, path, hashed = PREPARE_CASES[case]
+    td = TrainingData(
+        users=make(_ids("u", PREPARE_USERS, 25)),
+        items=_ids("i", PREPARE_ITEMS, 26),
+        ratings=np.ones(PREPARE_ROWS, np.float32),
+    )
+    with caplog.at_level(logging.INFO, "predictionio_tpu"):
+        with trace("root", ring=False) as root:
+            pd = RatingsPreparator().prepare(EngineContext(), td)
+
+    # the loop a row, written out, is the oracle
+    for col, vocab, idx in (
+        (td.users, pd.user_vocab, pd.user_idx),
+        (td.items, pd.item_vocab, pd.item_idx),
+    ):
+        forward = {}
+        for k in col:
+            if k not in forward:
+                forward[k] = len(forward)
+        assert list(vocab.items()) == list(forward.items())
+        assert idx.dtype == np.int32
+        np.testing.assert_array_equal(
+            idx, np.fromiter((forward[k] for k in col), np.int32, len(col))
+        )
+    assert pd.ratings is td.ratings
+
+    vocab, index = root.children
+    assert (vocab.name, index.name) == ("prepare.vocab", "prepare.index")
+    assert vocab.tags == {
+        "path": path,
+        "rows": PREPARE_ROWS,
+        "users": len(pd.user_vocab),
+        "items": PREPARE_ITEMS,
+        "user_keys_hashed": hashed,
+        "item_keys_hashed": PREPARE_ITEMS,
+    }
+    assert index.tags == {"rows": PREPARE_ROWS, "path": path}
+    # ... and in the retrain's log, for whoever has no trace
+    (record,) = [r for r in caplog.records if hasattr(r, "prepare")]
+    assert record.prepare == vocab.tags
 
 
 def test_staged_streams_reused_upload_nothing(pallas_on_cpu):
