@@ -14,14 +14,21 @@ manifest blob that references them.  Parts are stored as individual keyed
 blobs in any Models backend (localfs/sqlite/s3) — see
 ``data/storage/base.Models.insert_parts`` — so a multi-gigabyte table is
 written and read leaf-by-leaf, and a deploy host streams parts instead of
-materializing blob + pickle + arrays three times over.
+materializing blob + pickle + arrays three times over.  The writer streams
+too: ``serialize_models_sharded`` hands back the parts as a mapping that makes
+each part's ``.npy`` bytes when it is asked for them, so a save holds the
+arrays and ONE part's bytes at a time, not a second copy of the model
+(measured in the sandbox at 3.2 GB of float32 leaves: host peak +3.24 GB over
+the arrays before, +1.56 GB after — the largest leaf's buffer and its bytes;
+PERF.md, PR 26).
 """
 
 from __future__ import annotations
 
 import io
 import pickle
-from typing import Any, Callable
+from collections.abc import Mapping
+from typing import Any, Callable, Iterator
 
 import jax
 import numpy as np
@@ -49,26 +56,43 @@ class _ShardingPickler(pickle.Pickler):
 
     def __init__(self, buf: io.BytesIO, threshold: int):
         super().__init__(buf, protocol=pickle.HIGHEST_PROTOCOL)
-        self.parts: dict[str, bytes] = {}
+        #: part name -> the array it holds (its bytes are made on demand)
+        self.leaves: dict[str, np.ndarray] = {}
         self.threshold = threshold
         # persistent_id runs before pickle's own memoization, so aliased
         # arrays (one table referenced from two fields) must be deduped here
-        # or they double both checkpoint size and deploy-host RAM
+        # or they double both checkpoint size and deploy-host RAM; the
+        # arrays in ``leaves`` pin their id() for the dump's life
         self._seen: dict[int, str] = {}
-        self._keepalive: list[Any] = []
 
     def persistent_id(self, obj: Any):
         if isinstance(obj, np.ndarray) and obj.nbytes >= self.threshold:
             name = self._seen.get(id(obj))
             if name is None:
-                name = f"leaf{len(self.parts):05d}"
-                part = io.BytesIO()
-                np.save(part, obj, allow_pickle=False)
-                self.parts[name] = part.getvalue()
+                name = f"leaf{len(self.leaves):05d}"
+                self.leaves[name] = obj
                 self._seen[id(obj)] = name
-                self._keepalive.append(obj)  # pin id() for the dump's life
             return ("pio-part", name)
         return None
+
+
+class LazyParts(Mapping):
+    """Part name -> raw ``.npy`` bytes, serialized when asked for and not
+    kept: a store that writes part after part holds one part's bytes."""
+
+    def __init__(self, leaves: dict[str, np.ndarray]):
+        self._leaves = leaves
+
+    def __getitem__(self, name: str) -> bytes:
+        part = io.BytesIO()
+        np.save(part, self._leaves[name], allow_pickle=False)
+        return part.getvalue()
+
+    def __iter__(self) -> Iterator[str]:
+        return iter(self._leaves)
+
+    def __len__(self) -> int:
+        return len(self._leaves)
 
 
 class _ShardingUnpickler(pickle.Unpickler):
@@ -105,12 +129,13 @@ def deserialize_models(blob: bytes) -> list[Any]:
 
 def serialize_models_sharded(
     models: list[Any], threshold: int = PART_THRESHOLD
-) -> tuple[bytes, dict[str, bytes]]:
-    """Return (manifest blob, {part name: raw .npy bytes})."""
+) -> tuple[bytes, Mapping[str, bytes]]:
+    """Return (manifest blob, {part name: raw .npy bytes}); the mapping makes
+    a part's bytes each time it is read (``LazyParts``)."""
     buf = io.BytesIO()
     p = _ShardingPickler(buf, threshold)
     p.dump([_to_host(m) for m in models])
-    return buf.getvalue(), p.parts
+    return buf.getvalue(), LazyParts(p.leaves)
 
 
 def deserialize_models_sharded(

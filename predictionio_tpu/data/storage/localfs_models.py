@@ -40,7 +40,10 @@ class LocalFSModels(base.Models):
         fd = os.open(str(tmp), os.O_WRONLY | os.O_CREAT | os.O_EXCL, 0o644)
         try:
             try:
-                os.write(fd, blob)
+                # one write() moves at most 2 GiB - 4 KiB of a larger blob
+                view = memoryview(blob)
+                while len(view):
+                    view = view[os.write(fd, view):]
                 os.fsync(fd)
             finally:
                 os.close(fd)

@@ -7,6 +7,10 @@ Parity targets (reference examples/):
   - ecommerce: ALS + business-rule filters (scala-parallel-ecommercerecommendation)
   - ncf: deep two-tower/NCF with sharded embeddings (pypio deep-rec config)
   - external: serve externally-trained models through DASE (e2 PythonEngine)
+  - sequence: next-item prediction over each entity's ordered history with
+    the Olmo-Hybrid block — gated delta-rule linear attention 3:1 with full
+    attention (huggingface.co/allenai/Olmo-Hybrid-7B config.json;
+    arXiv:2412.06464); its kernels load when it trains, not at import
 
 Importing this package registers every bundled engine factory (the reflective
 EngineFactory discovery analog, workflow/WorkflowUtils.scala:47).
@@ -18,5 +22,6 @@ from predictionio_tpu.models import (  # noqa: F401
     external,
     ncf,
     recommendation,
+    sequence,
     similarproduct,
 )

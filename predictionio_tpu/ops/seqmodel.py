@@ -1,0 +1,627 @@
+"""A hybrid sequence model: gated delta-rule linear attention among full
+attention (the Olmo-Hybrid block), trainable on packed rows of tokens.
+
+Layers follow ``layer_types`` (``"linear_attention"`` / ``"full_attention"``),
+each a token mixer and a SwiGLU MLP with the OLMo 2/3 residual form
+``x + RMSNorm(f(x))``:
+
+* linear attention: q, k, v projections, each through a causal depthwise
+  convolution and SiLU; q, k L2-normalised per head; ``beta = 2 sigmoid(.)``
+  (negative eigenvalues) or ``sigmoid(.)``; ``g = -exp(A_log) softplus(. +
+  dt_bias)``; the gated delta rule (``ops/gdn.py``); per-head RMSNorm of the
+  output gated by ``SiLU(W_g x)``; output projection.
+* full attention: per-head RMSNorm on q and k, no positional encoding,
+  causal softmax within the segment; on a TPU the blocked Pallas kernel of
+  ``jax.experimental.pallas.ops.tpu.flash_attention`` (no T x T score matrix),
+  elsewhere its ``jax.numpy`` form.
+
+**The share.**  A deployment divides every layer over ``chips`` chips; this
+process holds one share of it: ``heads`` of the attention heads, ``mlp_cols``
+of the MLP's columns, ``vocab_rows`` rows of the embedding and the head,
+starting at ``vocab_start``.  Every function computes the part of the result
+its share gives (the sum over its heads of ``o_h W_o[h]``, the sum over its
+MLP columns, logits and loss over its vocabulary rows; an id outside its rows
+embeds to zero).  Nothing stands in for the other shares or their exchange.
+
+**Precision.**  Master weights, gradients and Adam moments float32.  The large
+matrix products take bfloat16 inputs and accumulate in float32, in the forward
+and both backward products (``mm``).  Residual stream, norms, the
+convolution, the decay projections and everything of the delta rule float32.
+
+**Training.**  ``train_steps`` dispatches, for each optimiser step, the rows of
+the step one at a time (forward, per-layer recomputation, backward; gradients
+accumulated in place) and then AdamW; buffers are donated from program to
+program and nothing returns to the host between steps.  Each row also hands
+back the first layer's delta-rule output along a seeded vector (``trunk``):
+what the training record holds the rule's state precision by.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from predictionio_tpu.ops import gdn
+
+HIGHEST = jax.lax.Precision.HIGHEST
+
+LINEAR = "linear_attention"
+FULL = "full_attention"
+
+#: segment id of a row's padding (real segments count from 0)
+PAD_SEGMENT = -1
+
+#: what the large matrix products round their inputs to (the configuration's
+#: stated precision; tests set float32 to compare with the plain reference
+#: to rounding error)
+MATMUL_DTYPE = jnp.bfloat16
+
+
+@dataclasses.dataclass(frozen=True)
+class SeqConfig:
+    """Widths as published, counts as HELD by this share."""
+
+    hidden: int
+    layer_types: tuple[str, ...]
+    #: full-attention heads held, and their size
+    heads: int
+    head_dim: int
+    #: linear-attention heads held (keys and values alike), and their sizes
+    lin_heads: int
+    lin_key_dim: int
+    lin_value_dim: int
+    conv_width: int
+    #: MLP columns held
+    mlp_cols: int
+    #: vocabulary rows held: ids ``vocab_start .. vocab_start + vocab_rows``
+    vocab_rows: int
+    vocab_start: int = 0
+    eps: float = 1e-6
+    neg_eigval: bool = True
+    chunk: int = 64
+    #: token block of the loss: logits of this many tokens at a time
+    loss_block: int = 2048
+    #: sequential pass of the delta rule: None = by backend (ops/gdn.py)
+    gdn_impl: str | None = None
+    #: full attention: None = by backend; "dense" = jax.numpy
+    attn_impl: str | None = None
+
+    def __post_init__(self):
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        bad = set(self.layer_types) - {LINEAR, FULL}
+        if bad:
+            raise ValueError(f"unknown layer types {sorted(bad)}")
+
+
+@dataclasses.dataclass(frozen=True)
+class AdamW:
+    lr: float = 3e-4
+    b1: float = 0.9
+    b2: float = 0.95
+    eps: float = 1e-8
+    weight_decay: float = 0.1
+
+
+#: tensors AdamW does not decay: norms, the decay's parameters, the convolution
+NO_DECAY = ("norm", "a_log", "dt_bias", "conv")
+
+
+def decays(name: str) -> bool:
+    return not any(part in name for part in NO_DECAY)
+
+
+# ---------------------------------------------------------------------------
+# parameters
+
+
+def param_shapes(cfg: SeqConfig) -> dict[str, tuple[int, ...]]:
+    """Flat name -> shape of every held tensor, in a fixed order."""
+    D = cfg.hidden
+    shapes: dict[str, tuple[int, ...]] = {"embed": (cfg.vocab_rows, D)}
+    for i, kind in enumerate(cfg.layer_types):
+        p = f"layer{i}."
+        if kind == LINEAR:
+            qk = cfg.lin_heads * cfg.lin_key_dim
+            vv = cfg.lin_heads * cfg.lin_value_dim
+            shapes.update({
+                p + "q": (D, qk), p + "k": (D, qk), p + "v": (D, vv),
+                p + "g": (D, vv), p + "a": (D, cfg.lin_heads),
+                p + "b": (D, cfg.lin_heads),
+                p + "conv_q": (cfg.conv_width, qk),
+                p + "conv_k": (cfg.conv_width, qk),
+                p + "conv_v": (cfg.conv_width, vv),
+                p + "a_log": (cfg.lin_heads,), p + "dt_bias": (cfg.lin_heads,),
+                p + "o_norm": (cfg.lin_value_dim,), p + "o": (vv, D),
+            })
+        else:
+            hd = cfg.heads * cfg.head_dim
+            shapes.update({
+                p + "q": (D, hd), p + "k": (D, hd), p + "v": (D, hd),
+                p + "q_norm": (cfg.head_dim,), p + "k_norm": (cfg.head_dim,),
+                p + "o": (hd, D),
+            })
+        shapes.update({
+            p + "mixer_norm": (D,),
+            p + "gate": (D, cfg.mlp_cols), p + "up": (D, cfg.mlp_cols),
+            p + "down": (cfg.mlp_cols, D), p + "mlp_norm": (D,),
+        })
+    shapes["final_norm"] = (D,)
+    shapes["head"] = (cfg.vocab_rows, D)
+    return shapes
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _init_tensor(leaf: str, shape: tuple, conv_width: int, key):
+    if leaf.endswith("norm"):
+        return jnp.ones(shape, jnp.float32)
+    if leaf.startswith("conv"):
+        bound = 1.0 / math.sqrt(conv_width)
+        return jax.random.uniform(key, shape, jnp.float32, -bound, bound)
+    if leaf == "a_log":
+        return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1e-3, 16.0))
+    if leaf == "dt_bias":
+        dt = jnp.exp(jax.random.uniform(
+            key, shape, jnp.float32, math.log(1e-3), math.log(1e-1)))
+        dt = jnp.maximum(dt, 1e-4)
+        return dt + jnp.log(-jnp.expm1(-dt))
+    return 0.02 * jax.random.normal(key, shape, jnp.float32)
+
+
+def init_params(cfg: SeqConfig, seed: int) -> dict[str, jax.Array]:
+    """Seeded weights of the held share.  Tensor number n (the order of
+    ``param_shapes``) draws from ``fold_in(PRNGKey(seed), n)`` at its held
+    shape: matrices normal(0, 0.02); norm weights 1; convolution taps
+    uniform(-1/sqrt(width), 1/sqrt(width)) (a depthwise Conv1d's default);
+    ``a_log = log(uniform(0.001, 16))`` and ``dt_bias`` the inverse softplus
+    of ``exp(uniform(log 0.001, log 0.1))`` floored at 1e-4 (the Gated
+    DeltaNet release's initialisation; its ``uniform(0, 16)`` floored so that
+    the logarithm is finite).  One small program a tensor: the random bits of
+    one tensor are the only temporary."""
+    base = jax.random.PRNGKey(seed)
+    return {
+        name: _init_tensor(
+            name.rsplit(".", 1)[-1], shape, cfg.conv_width,
+            jax.random.fold_in(base, n))
+        for n, (name, shape) in enumerate(param_shapes(cfg).items())
+    }
+
+
+def layer_params(params: dict, i: int) -> dict:
+    p = f"layer{i}."
+    return {k[len(p):]: v for k, v in params.items() if k.startswith(p)}
+
+
+# ---------------------------------------------------------------------------
+# pieces
+
+
+@jax.custom_vjp
+def mm(x, w):
+    """``x @ w`` with bfloat16 inputs and float32 accumulation, in the forward
+    and in both products of the backward."""
+    return jnp.matmul(
+        x.astype(MATMUL_DTYPE), w.astype(MATMUL_DTYPE),
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _mm_fwd(x, w):
+    return mm(x, w), (x, w)
+
+
+def _mm_bwd(res, g):
+    x, w = res
+    gb = g.astype(MATMUL_DTYPE)
+    dx = jnp.matmul(
+        gb, w.astype(MATMUL_DTYPE).T, preferred_element_type=jnp.float32)
+    x2 = x.reshape(-1, x.shape[-1]).astype(MATMUL_DTYPE)
+    dw = jnp.matmul(
+        x2.T, gb.reshape(-1, gb.shape[-1]), preferred_element_type=jnp.float32)
+    return dx.astype(x.dtype), dw.astype(w.dtype)
+
+
+mm.defvjp(_mm_fwd, _mm_bwd)
+
+
+def mm_f32(x, w):
+    return jnp.matmul(x, w, precision=HIGHEST, preferred_element_type=jnp.float32)
+
+
+def rmsnorm(x, w, eps):
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * w
+
+
+def causal_conv(x, w, seg):
+    """Depthwise causal convolution over time, zero history at a segment's
+    start.  x: [B, T, ch]; w: [width, ch] (tap ``width - 1`` is the current
+    token); seg: [B, T]."""
+    width = w.shape[0]
+    y = x * w[width - 1]
+    for s in range(1, width):
+        xs = jnp.pad(x, ((0, 0), (s, 0), (0, 0)))[:, : x.shape[1]]
+        ss = jnp.pad(seg, ((0, 0), (s, 0)), constant_values=gdn.NO_SEGMENT)[
+            :, : seg.shape[1]]
+        y = y + jnp.where((ss == seg)[..., None], xs, 0.0) * w[width - 1 - s]
+    return y
+
+
+def embed(cfg: SeqConfig, table, tokens):
+    """Rows of the held slice; an id another share holds embeds to zero."""
+    with jax.named_scope("seq.embed"):
+        idx = tokens - cfg.vocab_start
+        held = (idx >= 0) & (idx < cfg.vocab_rows)
+        rows = jnp.take(table, jnp.where(held, idx, 0), axis=0)
+        return jnp.where(held[..., None], rows, 0.0)
+
+
+#: a linear layer's tensors that are split by head along their LAST axis, the
+#: one split along its first, and the one every head shares
+_BY_HEAD_COLS = ("q", "k", "v", "g", "a", "b", "conv_q", "conv_k", "conv_v",
+                 "a_log", "dt_bias")
+
+
+def _head_group(p: dict, lo: int, hi: int, heads: int) -> dict:
+    """The tensors of heads ``lo .. hi`` of a linear layer."""
+    def cols(w):
+        per = w.shape[-1] // heads
+        return w[..., lo * per : hi * per]
+
+    out = {n: cols(p[n]) for n in _BY_HEAD_COLS}
+    per = p["o"].shape[0] // heads
+    out["o"] = p["o"][lo * per : hi * per]
+    out["o_norm"] = p["o_norm"]
+    return out
+
+
+def delta_inputs(cfg: SeqConfig, p: dict, x, seg):
+    """What the delta rule of the heads in ``p`` reads -> (q, k [B, T, H, dk],
+    v [B, T, H, dv], g, beta [B, T, H]) and the output gate's projection."""
+    B, T, _ = x.shape
+    H, dk, dv = p["a_log"].shape[0], cfg.lin_key_dim, cfg.lin_value_dim
+    with jax.named_scope("gdn.proj"):
+        q, k, v, gate = (mm(x, p[n]) for n in ("q", "k", "v", "g"))
+        a, b = mm_f32(x, p["a"]), mm_f32(x, p["b"])
+    with jax.named_scope("gdn.conv"):
+        q, k, v = (
+            jax.nn.silu(causal_conv(t, p[n], seg))
+            for t, n in ((q, "conv_q"), (k, "conv_k"), (v, "conv_v"))
+        )
+        q, k = (t.reshape(B, T, H, dk) for t in (q, k))
+        q, k = (
+            t * jax.lax.rsqrt(jnp.sum(t * t, -1, keepdims=True) + 1e-6)
+            for t in (q, k)
+        )
+        q = q * dk ** -0.5
+        beta = jax.nn.sigmoid(b) * (2.0 if cfg.neg_eigval else 1.0)
+        g = -jnp.exp(p["a_log"]) * jax.nn.softplus(a + p["dt_bias"])
+    return q, k, v.reshape(B, T, H, dv), g, beta, gate.reshape(B, T, H, dv)
+
+
+#: seed of the probes the training record holds
+PROBE_SEED = 1
+
+
+def delta_probe_vector(dv: int):
+    """The seeded direction each head's delta-rule output is recorded along:
+    standard normal [dv] from ``fold_in(PRNGKey(PROBE_SEED), 2**20)`` (the
+    gradient probes fold in a tensor's number, far below)."""
+    return jax.random.normal(
+        jax.random.fold_in(jax.random.PRNGKey(PROBE_SEED), 2 ** 20), (dv,),
+        jnp.float32)
+
+
+def _linear_heads(cfg: SeqConfig, p: dict, x, seg):
+    """``sum_h o_h W_o[h]`` over the heads whose tensors ``p`` holds, and the
+    delta rule's own output along the probe vector [B, T, H]: what the rule
+    made of ITS inputs, before any later rounding."""
+    B, T, _ = x.shape
+    q, k, v, g, beta, gate = delta_inputs(cfg, p, x, seg)
+    o = gdn.gated_delta_rule(q, k, v, g, beta, seg, cfg.chunk, cfg.gdn_impl)
+    probe = jax.lax.stop_gradient(
+        jnp.einsum("bthv,v->bth", o, delta_probe_vector(o.shape[-1]),
+                   precision=HIGHEST))
+    with jax.named_scope("gdn.proj"):
+        o = rmsnorm(o, p["o_norm"], cfg.eps) * jax.nn.silu(gate)
+        return mm(o.reshape(B, T, -1), p["o"]), probe
+
+
+def linear_attention(cfg: SeqConfig, p: dict, x, seg):
+    """The share's part of a gated delta-rule layer's output (before the
+    residual norm), the sum over its heads of ``o_h W_o[h]``, and the rule's
+    output along the probe vector [B, T, H].  The heads go through in groups
+    of ``gdn.heads_per_block`` (the heads one step of the kernel's grid works
+    on side by side: 5 of 15), one group after another, each recomputed in its
+    own backward pass: what a group keeps live is that share of the layer's."""
+    H = p["a_log"].shape[0]
+    per = gdn.heads_per_block(H)
+    f = jax.checkpoint(functools.partial(_linear_heads, cfg))
+    with jax.named_scope("seq.gdn"):
+        y, probes = None, []
+        for lo in range(0, H, per):
+            part, probe = f(_head_group(p, lo, lo + per, H), x, seg)
+            y = part if y is None else y + part
+            probes.append(probe)
+        return y, jnp.concatenate(probes, axis=-1)
+
+
+def _dense_attention(q, k, v, seg, scale):
+    """[B, H, T, d] -> causal softmax attention within the segment."""
+    T = q.shape[2]
+    s = jnp.einsum(
+        "bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32) * scale
+    mask = jnp.tril(jnp.ones((T, T), bool)) & (
+        seg[:, None, :, None] == seg[:, None, None, :])
+    p = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), axis=-1)
+    return jnp.einsum(
+        "bhqk,bhkd->bhqd", p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+
+
+def full_attention(cfg: SeqConfig, p: dict, x, seg):
+    """The share's part of a full-attention layer's output."""
+    B, T, _ = x.shape
+    d = cfg.head_dim
+    H = p["q"].shape[1] // d
+    with jax.named_scope("seq.attn"):
+        q, k, v = (mm(x, p[n]).reshape(B, T, H, d) for n in ("q", "k", "v"))
+        q = rmsnorm(q, p["q_norm"], cfg.eps)
+        k = rmsnorm(k, p["k_norm"], cfg.eps)
+        q, k, v = (
+            t.transpose(0, 2, 1, 3).astype(MATMUL_DTYPE) for t in (q, k, v))
+        impl = cfg.attn_impl or (
+            "flash" if jax.default_backend() == "tpu" else "dense")
+        if impl == "flash":
+            from jax.experimental.pallas.ops.tpu import flash_attention as fa
+
+            o = fa.flash_attention(
+                q, k, v, segment_ids=fa.SegmentIds(q=seg, kv=seg),
+                causal=True, sm_scale=d ** -0.5,
+            )
+        else:
+            o = _dense_attention(q, k, v, seg, d ** -0.5)
+        o = o.astype(jnp.float32).transpose(0, 2, 1, 3).reshape(B, T, H * d)
+        return mm(o, p["o"])
+
+
+def mlp(cfg: SeqConfig, p: dict, x):
+    """The share's part of the MLP's output: the sum over its columns."""
+    with jax.named_scope("seq.mlp"):
+        return mm(jax.nn.silu(mm(x, p["gate"])) * mm(x, p["up"]), p["down"])
+
+
+def layer(cfg: SeqConfig, kind: str, p: dict, x, seg):
+    """-> (x after the layer, the delta rule's probe [B, T, H]; no heads where
+    the layer is full attention)."""
+    if kind == LINEAR:
+        y, probe = linear_attention(cfg, p, x, seg)
+    else:
+        y, probe = full_attention(cfg, p, x, seg), jnp.zeros(x.shape[:2] + (0,))
+    x = x + rmsnorm(y, p["mixer_norm"], cfg.eps)
+    return x + rmsnorm(mlp(cfg, p, x), p["mlp_norm"], cfg.eps), probe
+
+
+def trunk(cfg: SeqConfig, params: dict, x, seg, remat: bool = False):
+    """The layers and the final norm over embedded rows x [B, T, D] -> (the
+    normalised hidden states, the FIRST layer's delta-rule probe: its inputs
+    are three products of exact embedding rows, so the training record can
+    hold the rule to the token-by-token recurrence there; deeper layers read a
+    residual stream that already carries every earlier rounding).  With
+    ``remat`` each layer is recomputed in the backward pass, so that only the
+    residual stream between layers is kept."""
+    first = None
+    for i, kind in enumerate(cfg.layer_types):
+        f = functools.partial(layer, cfg, kind)
+        if remat:
+            f = jax.checkpoint(f)
+        x, probe = f(layer_params(params, i), x, seg)
+        first = probe if first is None else first
+    return rmsnorm(x, params["final_norm"], cfg.eps), first
+
+
+def hidden_states(cfg: SeqConfig, params: dict, tokens, seg):
+    """Final normalised hidden states [B, T, D] of packed rows."""
+    return trunk(cfg, params, embed(cfg, params["embed"], tokens), seg)[0]
+
+
+# ---------------------------------------------------------------------------
+# loss
+
+
+def next_item_targets(tokens, seg):
+    """Targets and weights of next-item prediction: position t predicts token
+    t + 1 where that is the same (real) segment."""
+    nxt = jnp.pad(tokens[:, 1:], ((0, 0), (0, 1)))
+    nseg = jnp.pad(seg[:, 1:], ((0, 0), (0, 1)), constant_values=gdn.NO_SEGMENT)
+    weight = ((nseg == seg) & (seg != PAD_SEGMENT)).astype(jnp.float32)
+    return nxt, weight
+
+
+def cross_entropy(cfg: SeqConfig, h, head, targets, weight, dhead):
+    """Sum over tokens of ``weight * cross-entropy(h @ head^T, target)`` over
+    the held vocabulary rows AND its gradients, a block of tokens at a time:
+    the logits of a block exist once, their gradient is made beside them, and
+    the head's gradient is added into ``dhead`` -> (loss, dh, dhead)."""
+    shape = h.shape
+    T = math.prod(shape[:-1])
+    blk = min(cfg.loss_block, T)
+    if T % blk:
+        raise ValueError(f"{T} tokens are not a multiple of the loss block {blk}")
+    n = T // blk
+    w16 = head.astype(MATMUL_DTYPE)
+    local = targets.reshape(n, blk) - cfg.vocab_start
+
+    def block(carry, x):
+        loss, dw = carry
+        hx, tx, wx = x
+        h16 = hx.astype(MATMUL_DTYPE)
+        logits = jnp.matmul(h16, w16.T, preferred_element_type=jnp.float32)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        hit = jnp.arange(logits.shape[-1])[None, :] == tx[:, None]
+        picked = jnp.sum(jnp.where(hit, logits, 0.0), axis=-1)
+        loss = loss + jnp.sum(wx * (lse - picked))
+        dlog = ((jnp.exp(logits - lse[:, None]) - hit) * wx[:, None]).astype(
+            MATMUL_DTYPE)
+        dh = jnp.matmul(dlog, w16, preferred_element_type=jnp.float32)
+        dw = dw + jnp.matmul(dlog.T, h16, preferred_element_type=jnp.float32)
+        return (loss, dw), dh
+
+    with jax.named_scope("seq.loss"):
+        (loss, dhead), dh = jax.lax.scan(
+            block, (jnp.float32(0.0), dhead),
+            (h.reshape(n, blk, shape[-1]), local, weight.reshape(n, blk)),
+        )
+    return loss, dh.reshape(shape), dhead
+
+
+def row_grads(cfg: SeqConfig, params: dict, tokens, seg, gsum: dict):
+    """Forward, per-layer recomputation and backward of packed rows
+    [B, T] -> (sum of the next-item cross-entropy over their real positions,
+    how many those are, ``gsum`` + the sum's gradient, the first layer's
+    delta-rule probe [B, T, H]).  The two vocabulary
+    tables' gradients are added into ``gsum`` in place (a scatter of the
+    embedded rows' gradient, the loss's own accumulation), never held beside
+    it."""
+    inner = {k: v for k, v in params.items() if k not in ("embed", "head")}
+    x0 = embed(cfg, params["embed"], tokens)
+    h, vjp, probe = jax.vjp(
+        lambda p, x: trunk(cfg, p, x, seg, remat=True), inner, x0, has_aux=True)
+    targets, weight = next_item_targets(tokens, seg)
+    loss, dh, dhead = cross_entropy(
+        cfg, h, params["head"], targets, weight, gsum["head"])
+    dinner, dx0 = vjp(dh)
+    with jax.named_scope("seq.embed"):
+        idx = tokens - cfg.vocab_start
+        held = (idx >= 0) & (idx < cfg.vocab_rows)
+        dembed = gsum["embed"].at[jnp.where(held, idx, 0)].add(
+            jnp.where(held[..., None], dx0, 0.0))
+    out = {k: gsum[k] + g for k, g in dinner.items()}
+    out["embed"], out["head"] = dembed, dhead
+    return loss, jnp.sum(weight), out, probe
+
+
+# ---------------------------------------------------------------------------
+# training
+
+
+def init_state(cfg: SeqConfig, seed: int) -> tuple[dict, dict]:
+    """(state, acc): the weights with Adam's two moments and the step count,
+    and the accumulator of one optimiser step (the gradients' sum, the loss's
+    sum, the positions counted; zero between steps).  16 bytes a parameter."""
+    params = init_params(cfg, seed)
+    zeros = lambda: {k: jnp.zeros_like(v) for k, v in params.items()}  # noqa: E731
+    state = {"params": params, "m": zeros(), "v": zeros(),
+             "t": jnp.zeros((), jnp.int32)}
+    acc = {"g": zeros(), "loss": jnp.float32(0.0), "count": jnp.float32(0.0)}
+    return state, acc
+
+
+def accumulate_row(cfg: SeqConfig, state: dict, acc: dict, tokens, seg):
+    """One packed row [T] through forward, recomputation and backward, added
+    into the step's accumulator -> (state, acc, the row's delta-rule probe
+    [T, H]).  The state goes in and comes out untouched: the moments are this
+    program's arguments only so that the compiler plans its temporaries beside
+    ALL that is resident (it fits a program into the memory its own arguments
+    leave)."""
+    loss, count, g, probe = row_grads(
+        cfg, state["params"], tokens[None], seg[None], acc["g"])
+    return state, {
+        "g": g, "loss": acc["loss"] + loss, "count": acc["count"] + count
+    }, probe[0]
+
+
+def grad_probe(n: int, g):
+    """One seeded linear functional of tensor number n's gradient: ``r^T g``
+    for a vector, ``r_rows^T G r_cols`` for a matrix, the r's standard normal
+    from ``fold_in(PRNGKey(PROBE_SEED), n)`` (split in two for a matrix).  Its
+    error is the gradient's own error, undamped and unamplified: what a norm
+    cannot show and an Adam step blows up."""
+    key = jax.random.fold_in(jax.random.PRNGKey(PROBE_SEED), n)
+    if g.ndim == 1:
+        return jnp.sum(g * jax.random.normal(key, g.shape, jnp.float32))
+    kr, kc = jax.random.split(key)
+    rows = jax.random.normal(kr, (g.shape[0],), jnp.float32)
+    cols = jax.random.normal(kc, (g.shape[1],), jnp.float32)
+    return jnp.sum(rows * mm_f32(g, cols))
+
+
+def apply_step(opt: AdamW, state: dict, acc: dict):
+    """AdamW from the accumulated step -> (state, the accumulator zeroed, the
+    step's record: loss, positions, the global gradient norm, and per tensor
+    the gradient's norm and its seeded probe)."""
+    scale = 1.0 / jnp.maximum(acc["count"], 1.0)
+    gsum = acc["g"]
+    sq = {k: jnp.sum(g * g) for k, g in gsum.items()}
+    record = {
+        "loss": acc["loss"] * scale,
+        "tokens": acc["count"],
+        "grad_norm": jnp.sqrt(sum(sq.values())) * scale,
+        "tensor_grad_norm": {k: jnp.sqrt(v) * scale for k, v in sq.items()},
+        "tensor_grad_probe": {
+            k: grad_probe(n, g) * scale for n, (k, g) in enumerate(gsum.items())},
+    }
+    t = state["t"] + 1
+    tf = t.astype(jnp.float32)
+    c1 = 1.0 - opt.b1 ** tf
+    c2 = 1.0 - opt.b2 ** tf
+    new_p, new_m, new_v = {}, {}, {}
+    with jax.named_scope("seq.adamw"):
+        for name, p in state["params"].items():
+            g = gsum[name] * scale
+            m = opt.b1 * state["m"][name] + (1.0 - opt.b1) * g
+            v = opt.b2 * state["v"][name] + (1.0 - opt.b2) * g * g
+            step = (m / c1) / (jnp.sqrt(v / c2) + opt.eps)
+            if decays(name):
+                step = step + opt.weight_decay * p
+            new_p[name], new_m[name], new_v[name] = p - opt.lr * step, m, v
+        zeroed = jax.tree.map(jnp.zeros_like, acc)
+    return {"params": new_p, "m": new_m, "v": new_v, "t": t}, zeroed, record
+
+
+@functools.lru_cache(maxsize=8)
+def train_programs(cfg: SeqConfig, opt: AdamW):
+    """The two jitted programs of a training run: a row into the accumulator,
+    and the optimiser step; state and accumulator are donated to both.  Each
+    is straight-line: state carried through a device loop is copied by the
+    compiler (3 GB a copy at the published widths), buffers handed from one
+    program to the next are not."""
+    return (
+        jax.jit(functools.partial(accumulate_row, cfg), donate_argnums=(0, 1)),
+        jax.jit(functools.partial(apply_step, opt), donate_argnums=(0, 1)),
+    )
+
+
+def train_steps(cfg: SeqConfig, opt: AdamW, state: dict, acc: dict, tokens, seg):
+    """``tokens``, ``seg``: [steps, rows, T] int32 on the device.  Every row
+    and every optimiser step is dispatched at once (nothing is fetched in
+    between, so the host never waits for a step) -> (state, acc, the records
+    of the steps, the delta-rule probes [T, H] of the FIRST step's rows: those
+    are made from the seeded initial weights; all still on the device)."""
+    accumulate, apply = train_programs(cfg, opt)
+    records, probes = [], []
+    for s in range(tokens.shape[0]):
+        for r in range(tokens.shape[1]):
+            state, acc, probe = accumulate(state, acc, tokens[s, r], seg[s, r])
+            if s == 0:
+                probes.append(probe)
+        state, acc, record = apply(state, acc)
+        records.append(record)
+    return state, acc, records, probes
+
+
+# ---------------------------------------------------------------------------
+# serving (a plain full forward: no cache)
+
+
+@functools.partial(jax.jit, static_argnums=(0,))
+def last_hidden(cfg: SeqConfig, params: dict, tokens, seg, last):
+    """Final hidden state [B, D] at position ``last[b]`` of each row."""
+    h = hidden_states(cfg, params, tokens, seg)
+    return jnp.take_along_axis(h, last[:, None, None], axis=1)[:, 0]
+
+
+def num_params(cfg: SeqConfig) -> int:
+    return int(sum(np.prod(s) for s in param_shapes(cfg).values()))

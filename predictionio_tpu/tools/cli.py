@@ -1196,6 +1196,22 @@ _TEMPLATE_VARIANTS = {
                         "numEpochs": 5}}
         ],
     },
+    # one of two chips' share of one period of the Olmo-Hybrid-7B layer
+    # (published widths; 801 M parameters: a 16 GB chip trains it)
+    "sequence": {
+        "engineFactory": "sequence",
+        "datasource": {"params": {"appName": "MyApp", "eventNames": ["rate"]}},
+        "preparator": {"params": {"rowLen": 8192, "maxLen": 8192,
+                                  "rowsPerStep": 4, "vocabSize": 50176}},
+        "algorithms": [
+            {"name": "gdn",
+             "params": {"hiddenSize": 3840, "numAttentionHeads": 15,
+                        "headDim": 128, "linearNumHeads": 15,
+                        "linearKeyHeadDim": 96, "linearValueHeadDim": 192,
+                        "intermediateSize": 5504, "vocabSize": 50176,
+                        "rowsPerStep": 4, "stepsPerRetrain": 4}}
+        ],
+    },
 }
 
 

@@ -44,3 +44,18 @@ def storage(tmp_path):
     rt = reset_storage(cfg)
     yield rt
     rt.close()
+
+
+@pytest.fixture(autouse=True)
+def _no_console_handler_on_a_closed_stream():
+    """A ``pio`` verb run in-process leaves its console handler on the
+    captured stderr of the test that ran it; once that capture is closed every
+    later log line of the worker prints a traceback (milliseconds each, inside
+    whatever span is open).  Drop such handlers before a test starts."""
+    import logging
+
+    root = logging.getLogger()
+    for h in list(root.handlers):
+        if getattr(getattr(h, "stream", None), "closed", False):
+            root.removeHandler(h)
+    yield

@@ -604,6 +604,9 @@ def _marker_instances(storage, factory="lifecycle-marker-test"):
         engine, params("A"), ctx=EngineContext(storage=storage),
         storage=storage, engine_factory=factory,
     )
+    # "latest COMPLETED" orders by startTime in whole milliseconds, and a
+    # marker train takes 0.2 ms: B has to start in a later one than A
+    time.sleep(0.002)
     inst_b = run_train(
         engine, params("B"), ctx=EngineContext(storage=storage),
         storage=storage, engine_factory=factory,

@@ -1,0 +1,260 @@
+"""The sequence engine behind the DASE contract (ISSUE 26): time order from
+the store, the Preparator's vocabulary and packing, ``pio train`` -> persisted
+model -> ``load_models`` -> ``predict``, and the spans a retrain opens."""
+
+from __future__ import annotations
+
+import dataclasses
+import json
+import logging
+import subprocess
+import sys
+
+import numpy as np
+import pytest
+
+from predictionio_tpu.core import EngineContext
+from predictionio_tpu.core.engine import resolve_engine_factory
+from predictionio_tpu.core.persistence import load_models
+from predictionio_tpu.core.workflow import run_train
+from predictionio_tpu.data.storage.base import EventFrame
+from predictionio_tpu.data.storage.config import StorageConfig, StorageRuntime
+from predictionio_tpu.models.recommendation.engine import Query
+from predictionio_tpu.models.sequence import engine as seq
+from predictionio_tpu.tools import commands
+
+N_USERS, N_ITEMS, NNZ = 24, 100, 700
+
+VARIANT = {
+    "datasource": {"params": {"appName": "seq"}},
+    "preparator": {"params": {
+        "rowLen": 64, "maxLen": 64, "rowsPerStep": 2, "vocabSize": 128}},
+    "algorithms": [{"name": "gdn", "params": {
+        "hiddenSize": 64, "numAttentionHeads": 2, "headDim": 16,
+        "linearNumHeads": 2, "linearKeyHeadDim": 8, "linearValueHeadDim": 16,
+        "intermediateSize": 16, "vocabSize": 128, "rowsPerStep": 2,
+        "stepsPerRetrain": 2}}],
+}
+
+#: ISSUE 26, table 7: the spans the engine adds to a retrain's tree
+SPANS = ("datasource.sequences", "prepare.vocab", "prepare.pack", "seq.init",
+         "seq.device_loop", "seq.fetch")
+
+
+def _events(rng):
+    users = rng.integers(0, N_USERS, NNZ)
+    items = rng.integers(0, N_ITEMS, NNZ)
+    # distinct instants, and a few shared ones: ties keep the write order
+    times = 1_700_000_000_000 + np.sort(rng.integers(0, NNZ // 2, NNZ))
+    return users, items, times
+
+
+@pytest.fixture()
+def store(tmp_path):
+    """A parquet event store whose events were WRITTEN in shuffled order."""
+    home = tmp_path / "pio_home"
+    rt = StorageRuntime(StorageConfig.from_env({
+        "PIO_HOME": str(home),
+        "PIO_STORAGE_SOURCES_PARQUET_TYPE": "parquet",
+        "PIO_STORAGE_SOURCES_PARQUET_PATH": str(home / "events_parquet"),
+        "PIO_STORAGE_SOURCES_PARQUET_NSHARDS": "4",
+        "PIO_STORAGE_REPOSITORIES_EVENTDATA_SOURCE": "PARQUET",
+    }))
+    app = commands.app_new(rt, "seq").app
+    rng = np.random.default_rng(26)
+    users, items, times = _events(rng)
+    # shuffle whole instants, so that the write order within an instant (the
+    # store's tie-break) stays what the test knows
+    instants = np.unique(times)
+    rank = rng.permutation(len(instants))[np.searchsorted(instants, times)]
+    order = np.argsort(rank, kind="stable")
+
+    def const(value: str) -> np.ndarray:
+        col = np.empty(NNZ, object)
+        col[:] = value
+        return col
+
+    rt.p_events().write(
+        EventFrame(
+            event=const("rate"), entity_type=const("user"),
+            entity_id=np.array([f"u{u}" for u in users[order]], object),
+            target_entity_type=const("item"),
+            target_entity_id=np.array([f"i{i}" for i in items[order]], object),
+            event_time_ms=times[order],
+            properties=const('{"rating": 4.0}'),
+        ),
+        app_id=app.id,
+    )
+    yield rt, (users, items, times)
+    rt.close()
+
+
+def test_datasource_returns_each_entitys_events_in_time_order(store):
+    rt, (users, items, times) = store
+    td = seq.SequenceDataSource(
+        seq.SequenceDataSourceParams(app_name="seq")
+    ).read_training(EngineContext(storage=rt))
+    assert len(td.items) == NNZ and td.offsets[-1] == NNZ
+    assert sorted(td.entities) == sorted({f"u{u}" for u in users})
+    for e, name in enumerate(td.entities):
+        got = td.items[td.order[td.offsets[e] : td.offsets[e + 1]]].tolist()
+        mine = np.flatnonzero(users == int(name[1:]))
+        assert got == [f"i{i}" for i in items[mine]], name  # (time, write order)
+
+
+def test_preparator_packs_the_most_recent_events(store):
+    rt, (users, items, _) = store
+    ctx = EngineContext(storage=rt)
+    td = seq.SequenceDataSource(
+        seq.SequenceDataSourceParams(app_name="seq")).read_training(ctx)
+    pd = seq.SequencePreparator(seq.SequencePreparatorParams(
+        row_len=32, max_len=16, rows_per_step=4, vocab_size=128, vocab_start=10)
+    ).prepare(ctx, td)
+    assert pd.tokens.shape == pd.segments.shape and len(pd.tokens) % 4 == 0
+    assert pd.tokens.shape[1] == 32
+    for e, name in enumerate(td.entities):
+        mine = [f"i{i}" for i in items[users == int(name[1:])]][-16:]
+        row, col = np.nonzero(pd.segments == e)
+        assert len(set(row)) == 1 and (np.diff(col) == 1).all()  # one run
+        ids = pd.tokens[row, col] - 10
+        assert [pd.item_vocab.inverse(int(j)) for j in ids] == mine
+    real = pd.segments != seq.PAD_SEGMENT
+    assert real.sum() == sum(
+        min(16, int((users == u).sum())) for u in np.unique(users))
+    assert (pd.tokens[~real] == 0).all()
+
+
+def test_more_items_than_vocabulary_rows_is_an_error(store):
+    rt, _ = store
+    ctx = EngineContext(storage=rt)
+    td = seq.SequenceDataSource(
+        seq.SequenceDataSourceParams(app_name="seq")).read_training(ctx)
+    with pytest.raises(ValueError, match="do not fit the vocabulary of 64"):
+        seq.SequencePreparator(seq.SequencePreparatorParams(
+            row_len=64, max_len=64, vocab_size=64)).prepare(ctx, td)
+
+
+@pytest.mark.parametrize("name,value", [
+    ("vocab_start", 64), ("vocab_size", 256), ("rows_per_step", 1)])
+def test_rows_packed_for_another_share_are_refused(store, name, value):
+    """The share and the step size are written twice in engine.json (the
+    Preparator's and the algorithm's): rows made for one are not trained by
+    an algorithm configured with another (ids from another ``vocabStart``
+    would embed to zero without a word)."""
+    rt, _ = store
+    ctx = EngineContext(storage=rt)
+    engine = resolve_engine_factory("sequence")()
+    params = engine.params_from_json(VARIANT)
+    _, prep, (algo,), _ = engine.instantiate(params)
+    td = seq.SequenceDataSource(
+        seq.SequenceDataSourceParams(app_name="seq")).read_training(ctx)
+    pd = prep.prepare(ctx, td)
+    assert (pd.vocab_start, pd.vocab_size, pd.rows_per_step) == (0, 128, 2)
+    other = seq.SequenceAlgorithm(dataclasses.replace(algo.params, **{name: value}))
+    with pytest.raises(ValueError, match="the Preparator packed rows for"):
+        other.train(ctx, pd)
+
+
+class _Stages(logging.Handler):
+    def __init__(self):
+        super().__init__(logging.INFO)
+        self.stages = None
+
+    def emit(self, record):
+        if hasattr(record, "stages"):
+            self.stages = record.stages
+
+
+@pytest.fixture()
+def trained(store):
+    rt, data = store
+    seen = _Stages()
+    log = logging.getLogger("predictionio_tpu.workflow")
+    log.addHandler(seen)
+    level = log.level
+    log.setLevel(logging.INFO)
+    try:
+        engine = resolve_engine_factory("sequence")()
+        params = engine.params_from_json(VARIANT)
+        instance = run_train(
+            engine, params, engine_factory="sequence", storage=rt,
+            ctx=EngineContext(storage=rt))
+    finally:
+        log.removeHandler(seen)
+        log.setLevel(level)
+    assert instance.status == "COMPLETED"
+    return rt, data, engine, params, instance, seen.stages
+
+
+def test_train_persist_load_predict_round_trip(trained):
+    rt, (users, items, _), engine, params, instance, _ = trained
+    (data,) = load_models(rt.models(), instance.id)
+    record = data["training_record"]
+    assert len(record["loss"]) == 2 and np.isfinite(record["loss"]).all()
+    assert record["loss"][0] == pytest.approx(np.log(128), rel=0.02)
+    assert set(record["tensor_grad_norm"]) == set(data["params"])
+    assert all(v.dtype == np.float32 for v in data["params"].values())
+    algo = engine.instantiate(params)[2][0]
+    model = algo.load_persistent_model(EngineContext(storage=rt), data)
+    seen = {f"i{i}" for i in items}
+    answer = algo.predict(model, Query(user=f"u{users[0]}", num=5))
+    assert len(answer.item_scores) == 5
+    scores = [s.score for s in answer.item_scores]
+    assert scores == sorted(scores, reverse=True)
+    assert {s.item for s in answer.item_scores} <= seen  # never a padding row
+    assert algo.predict(model, Query(user="nobody", num=5)).item_scores == ()
+    # the answer is the head over the history's last hidden state
+    from predictionio_tpu.ops import seqmodel
+
+    e = model.entity_vocab[f"u{users[0]}"]
+    hist = model.history_tokens[model.history_offsets[e] : model.history_offsets[e + 1]]
+    tokens = np.zeros((1, 64), np.int32)
+    segments = np.full((1, 64), seq.PAD_SEGMENT, np.int32)
+    tokens[0, : len(hist)], segments[0, : len(hist)] = hist, 0
+    h = seqmodel.hidden_states(
+        model.config, data["params"], tokens, segments)[0, len(hist) - 1]
+    want = np.asarray(data["params"]["head"] @ h)[: len(model.item_vocab)]
+    top = np.argsort(-want, kind="stable")[:5]
+    assert [s.item for s in answer.item_scores] == [
+        model.item_vocab.inverse(int(j)) for j in top]
+    np.testing.assert_allclose(scores, want[top], rtol=2e-2, atol=2e-3)
+
+
+def test_every_span_of_the_engine_appears_once_in_stages(trained):
+    stages = trained[-1]
+    for name in SPANS + ("train.algorithm.gdn", "train.persist.save_models",
+                         "train.datasource.read", "train.preparator.prepare"):
+        assert name in stages and stages[name] >= 0, name
+    assert "parallel" not in stages  # nothing ran side by side
+    for part, whole in (("datasource.sequences", "train.datasource.read"),
+                        ("prepare.pack", "train.preparator.prepare"),
+                        ("seq.device_loop", "train.algorithm.gdn")):
+        assert stages[part] <= stages[whole] + 1e-3
+
+
+def test_template_scaffolds_the_engine(tmp_path):
+    from predictionio_tpu.tools.cli import build_parser
+
+    args = build_parser().parse_args(
+        ["template", "get", "sequence", str(tmp_path / "engine")])
+    assert args.fn(args) == 0
+    variant = json.loads((tmp_path / "engine" / "engine.json").read_text())
+    assert variant["engineFactory"] == "sequence"
+    engine = resolve_engine_factory("sequence")()
+    params = engine.params_from_json(variant)
+    assert params.algorithms[0][1].linear_key_head_dim == 96  # published widths
+
+
+def test_importing_the_engines_loads_no_kernel_code():
+    """``pio train`` of another engine pays nothing for this one: its Pallas
+    and attention code load when it trains."""
+    code = (
+        "import sys, predictionio_tpu.models\n"
+        "bad = [m for m in sys.modules if m.startswith(('jax.experimental.pallas',"
+        " 'predictionio_tpu.ops.gdn', 'predictionio_tpu.ops.seqmodel'))]\n"
+        "print(bad)\n"
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip() == "[]"

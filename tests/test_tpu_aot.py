@@ -243,3 +243,59 @@ def test_per_shard_fused_topk_compiles_on_four_chips(v5e):
         sharding=NamedSharding(mesh, PartitionSpec()),
     )
     _compile(kernel, table, queries)
+
+
+# the sequence engine's kernels at Olmo-Hybrid's published widths (one of two
+# chips' share: 15 heads of d_k 96 / d_v 192, rows of 8192 tokens in chunks
+# of 64; five heads a group as the layer runs them)
+GDN = dict(heads=5, chunks=128, chunk=64, dk=96, dv=192)
+
+
+def _gdn_parts(sds):
+    h, nc, c, dk, dv = (GDN[k] for k in ("heads", "chunks", "chunk", "dk", "dv"))
+    return (
+        sds((1, h, nc, c, dk)), sds((1, h, nc, c, dv)), sds((1, h, nc, c, dk)),
+        sds((1, h, nc, c, c)), sds((1, h, nc, c, dk)), sds((1, h, nc)),
+    )
+
+
+@pytest.mark.parametrize("passes", ["forward", "forward_backward"])
+def test_gdn_chunk_kernels_compile(v5e, passes):
+    """The delta rule's sequential pass: ``gdn_chunk_fwd`` alone, and with
+    ``gdn_chunk_bwd`` under its ``custom_vjp`` (key / value sizes that are no
+    multiple of the 128 lanes: Mosaic pads them, and must accept that)."""
+    from predictionio_tpu.ops import gdn
+
+    sds = _spec_on(SingleDeviceSharding(v5e.devices[0]))
+    if passes == "forward":
+        fn = jax.jit(lambda *p: gdn.chunk_pallas(*p, False))
+    else:
+        fn = jax.jit(jax.grad(
+            lambda *p: gdn.chunk_pallas(*p, False).sum(), argnums=tuple(range(6))))
+    # the shape one call site of the layer has: a group of the 15 held heads
+    assert gdn.heads_per_block(15) == GDN["heads"]
+    text = _compile(fn, *_gdn_parts(sds)).as_text()
+    assert "gdn_chunk_fwd" in text
+    assert ("gdn_chunk_bwd" in text) == (passes == "forward_backward")
+
+
+def test_segment_masked_flash_attention_compiles(v5e):
+    """The full-attention layers' library kernel as ``ops/seqmodel`` calls
+    it: 15 heads of 128 over a row of 8192 with segment ids, forward and
+    backward (no 8192 x 8192 score matrix in the program's temporaries)."""
+    import dataclasses
+
+    from predictionio_tpu.ops import seqmodel
+
+    sds = _spec_on(SingleDeviceSharding(v5e.devices[0]))
+    cfg = seqmodel.SeqConfig(
+        hidden=3840, layer_types=(seqmodel.FULL,), heads=15, head_dim=128,
+        lin_heads=15, lin_key_dim=96, lin_value_dim=192, conv_width=4,
+        mlp_cols=5504, vocab_rows=50176, attn_impl="flash")
+    shapes = {
+        k[len("layer0."):]: sds(s) for k, s in seqmodel.param_shapes(cfg).items()
+        if k.startswith("layer0.")}
+    fn = jax.jit(jax.grad(lambda p, x, seg: seqmodel.full_attention(
+        cfg, p, x, seg).sum(), argnums=(0, 1)))
+    compiled = _compile(fn, shapes, sds((1, 8192, 3840)), sds((1, 8192), jnp.int32))
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 10**9
