@@ -11,7 +11,9 @@ framework's Spark-replacement seam.
 from __future__ import annotations
 
 import abc
+import functools
 import hashlib
+import inspect
 import json
 from dataclasses import dataclass, field, replace
 from datetime import datetime, timezone
@@ -881,13 +883,45 @@ class PEvents(abc.ABC):
         reach into backend internals for it."""
         return 1
 
+    def __init_subclass__(cls, **kwargs):
+        """A backend whose ``find`` takes only the filter gets the two
+        consumer arguments here, ignored: the full, time-sorted frame it
+        returns satisfies any projection and any order."""
+        super().__init_subclass__(**kwargs)
+        find = cls.__dict__.get("find")
+        if find is None or "ordered" in inspect.signature(find).parameters:
+            return
+
+        @functools.wraps(find)
+        def full_sorted(
+            self, app_id, channel_id=None, filter=None, columns=None,
+            ordered=True,
+        ):
+            return find(self, app_id, channel_id, filter)
+
+        cls.find = full_sorted
+
     @abc.abstractmethod
     def find(
         self,
         app_id: int,
         channel_id: int | None = None,
         filter: EventFilter | None = None,
-    ) -> EventFrame: ...
+        columns: Sequence[str] | None = None,
+        ordered: bool = True,
+    ) -> EventFrame:
+        """Every event the filter admits, as one frame.
+
+        The last two arguments describe the CONSUMER, and a backend may
+        ignore either: ``columns`` names the EventFrame columns it will
+        read (None = all; ``event`` always comes), ``ordered=False`` says
+        it does not need the rows by ``event_time_ms``.  A backend may
+        return more than was asked (extra columns, sorted rows), never
+        less: a column not asked for may be None, and unordered rows still
+        come in an order that two reads of one unchanged store repeat.  A
+        filter with ``limit`` or ``reversed`` is answered in time order
+        whatever ``ordered`` says.  The defaults return what ``find``
+        always returned."""
 
     @abc.abstractmethod
     def write(

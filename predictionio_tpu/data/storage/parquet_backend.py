@@ -2018,10 +2018,13 @@ class ParquetPEvents(PEvents):
         shards: Sequence[int] | None = None,
         columns: Sequence[str] | None = None,
     ) -> Iterator[tuple[int, EventFrame]]:
-        """One EventFrame per shard.  Rows within a shard are unordered
-        (training consumers are order-free; ``find`` sorts).  ``columns``
-        projects the read down to the named EventFrame columns — absent
-        optional columns come back as None (``event`` is always read)."""
+        """One EventFrame per shard, shard index ascending.  Rows within a
+        shard are in the shard table's own order (write-hot head, then the
+        compacted segment), not time order: training consumers are
+        order-free, and ``find`` sorts unless told ``ordered=False``, when
+        it returns these same rows end to end.  ``columns`` projects the
+        read down to the named EventFrame columns — absent optional
+        columns come back as None (``event`` is always read)."""
         for k, t in self.store.scan_shards(
             app_id, channel_id, filter, shards, columns=columns
         ):
@@ -2032,27 +2035,53 @@ class ParquetPEvents(PEvents):
         app_id: int,
         channel_id: int | None = None,
         filter: EventFilter | None = None,
+        columns: Sequence[str] | None = None,
+        ordered: bool = True,
     ) -> EventFrame:
+        """The ``PEvents.find`` contract, acted on: ``columns`` projects
+        the parquet read itself (absent columns come back as None) and
+        ``ordered=False`` skips the sort.  Unordered rows come shard index
+        ascending, each shard in its table's own row order (write-hot head
+        before the compacted segment), so two reads of one store give the
+        same frame.  ``limit`` / ``reversed`` need the order whatever the
+        caller says; an ordered projection reads the sort keys too."""
         from predictionio_tpu.obs.tracing import trace
 
+        ordered = ordered or (
+            filter is not None
+            and (filter.limit is not None or filter.reversed)
+        )
+        if ordered and columns is not None:
+            columns = (*columns, "event_time_ms", "seq")
         with trace("eventstore.scan") as span:
             read0 = _bytes_read_total()
             tables = [
                 t
-                for _, t in self.store.scan_shards(app_id, channel_id, filter)
+                for _, t in self.store.scan_shards(
+                    app_id, channel_id, filter, columns=columns
+                )
             ]
-            span.tags = {
+            span.tags = tags = {
                 "rows": sum(t.num_rows for t in tables),
                 "shards": len(tables),
                 "bytes_read": int(_bytes_read_total() - read0),
+                "columns": max((t.num_columns for t in tables), default=0),
+                "ordered": ordered,
             }
+        # ... and in the log, for whoever has no trace
+        log.info(
+            "bulk read: %(rows)d rows of %(shards)d shards, %(columns)d "
+            "columns, ordered=%(ordered)s", tags, extra={"bulk_read": tags},
+        )
         if not tables:
             return EventFrame.from_events([])
         # each span also releases the input it made redundant, so that the
         # spans add up to the read: freeing 20 M rows is not free
         with trace("eventstore.sort") as span:
-            t = _sort_limit(pa.concat_tables(tables), filter)
-            span.tags = {"rows": t.num_rows}
+            t = pa.concat_tables(tables)
+            if ordered:
+                t = _sort_limit(t, filter)
+            span.tags = {"rows": t.num_rows, "sorted": ordered}
             del tables
         with trace("eventstore.decode") as span:
             span.tags = {"rows": t.num_rows, "columns": t.num_columns}

@@ -61,7 +61,12 @@ class PEventStore:
         event_names: Sequence[str] | None = None,
         target_entity_type: str | None = None,
         target_entity_id: str | None = None,
+        columns: Sequence[str] | None = None,
+        ordered: bool = True,
     ) -> EventFrame:
+        """``columns`` and ``ordered`` describe what the DataSource reads
+        of the frame (``PEvents.find``): a backend may use them to read
+        less; the defaults are the full frame in time order."""
         app_id, channel_id = resolve_app(app_name, channel_name, self.storage)
         return self.storage.p_events().find(
             app_id,
@@ -75,6 +80,8 @@ class PEventStore:
                 target_entity_type=target_entity_type,
                 target_entity_id=target_entity_id,
             ),
+            columns=columns,
+            ordered=ordered,
         )
 
     def aggregate_properties(

@@ -141,12 +141,18 @@ class RatingsDataSource(DataSource):
         self.params = params or DataSourceParams()
 
     def _read(self, ctx: EngineContext) -> TrainingData:
+        # what is read below, and ALS takes its ratings in any order
+        asked = {
+            "columns": ("entity_id", "target_entity_id", "properties"),
+            "ordered": False,
+        }
         frame = ctx.p_event_store.find(
             self.params.app_name,
             channel_name=self.params.channel_name,
             entity_type="user",
             target_entity_type="item",
             event_names=["rate", "buy"],
+            **asked,
         )
         with trace("datasource.columns") as span:
             ratings = frame.property_column("rating", default=np.nan)
@@ -159,10 +165,18 @@ class RatingsDataSource(DataSource):
                 items=frame.target_entity_id[keep],
                 ratings=ratings[keep].astype(np.float32),
             )
-            span.tags = {"rows_in": len(keep), "rows_kept": len(td.ratings)}
+            span.tags = tags = {
+                "rows_in": len(keep), "rows_kept": len(td.ratings),
+            }
             # the frame's other columns go here, inside the span that made
             # them redundant: freeing 20 M decoded rows is not free
             del frame, is_buy
+        log.info(
+            "read %d ratings of %d events (asked the store for %s, "
+            "ordered=%s)", tags["rows_kept"], tags["rows_in"],
+            ", ".join(asked["columns"]), asked["ordered"],
+            extra={"read": {**asked, **tags}},
+        )
         return td
 
     def read_training(self, ctx: EngineContext) -> TrainingData:

@@ -449,6 +449,263 @@ class TestPushdown:
         ] == [eid]
 
 
+#: what a training consumer reads of a frame (RatingsDataSource's three)
+ASKED = ("entity_id", "target_entity_id", "properties")
+NOT_ASKED = (
+    "entity_type", "target_entity_type", "event_time_ms", "event_id",
+    "tags", "pr_id", "creation_time_ms",
+)
+
+
+def asked_rows(frame: EventFrame) -> list[tuple]:
+    """The frame's rows as the consumer sees them, in the frame's order."""
+    return [
+        (frame.event[i], *(getattr(frame, c)[i] for c in ASKED))
+        for i in range(len(frame))
+    ]
+
+
+@pytest.fixture()
+def mixed_store(tmp_path):
+    """Four shards, each with a compacted segment AND a write-hot head;
+    id-bearing rows some of which were upserted after the fold, tombstones
+    on both sides of it, and bulk (null-id) rows on both sides of it."""
+    client, le, pe = store_at(tmp_path / "pq")
+    le.init(1)
+    rated = lambda j: {"rating": float(1 + j % 5)}  # noqa: E731
+    ids = le.insert_batch(
+        [mk("rate", f"u{j % 11}", j, target=f"i{j % 7}", props=rated(j))
+         for j in range(60)],
+        1,
+    )
+    pe.write(bulk_frame(300, seed=1), 1)
+    le.delete(ids[3], 1)  # folded into the compacted segment
+    pe.compact(1)
+    le.insert_batch(  # upserts: same ids (and users: an id's shard), later
+        [mk("rate", f"u{j % 11}", 100 + j, target=f"i{(j + 1) % 7}",
+            props={"rating": 0.5}, eid=ids[j]) for j in (5, 6, 17, 40)],
+        1,
+    )
+    le.insert_batch(
+        [mk("buy", f"u{j % 11}", 200 + j, target=f"i{j % 5}")
+         for j in range(12)],
+        1,
+    )
+    le.delete(ids[8], 1)  # newer than the watermark
+    le.delete(ids[17], 1)  # a tombstone over an upserted id
+    pe.write(bulk_frame(200, t0=500, seed=2), 1)
+    st = pe.status(1)
+    assert st["segments_hot"] > 0 and st["segments_compacted"] == 4
+    yield client, le, pe
+    client.close()
+
+
+class TestConsumerStatedRead:
+    """``PEvents.find(columns=, ordered=)`` (ISSUE 27): the consumer says
+    what it reads and whether it needs time order; the parquet backend
+    then reads less, every other backend ignores both."""
+
+    FILTERS = {
+        "none": None,
+        "events": EventFilter(event_names=("rate", "buy")),
+        "training": EventFilter(
+            entity_type="user", target_entity_type="item",
+            event_names=("rate", "buy"),
+        ),
+        "window": EventFilter(start_time=t(20), until_time=t(400)),
+        "target": EventFilter(target_entity_id="i2"),
+        "entity": EventFilter(entity_type="user", entity_id="u3"),
+    }
+
+    @pytest.mark.parametrize("name", list(FILTERS))
+    def test_unordered_projection_is_the_same_multiset(
+        self, mixed_store, name
+    ):
+        _, _, pe = mixed_store
+        flt = self.FILTERS[name]
+        want = pe.find(1, filter=flt)
+        got = pe.find(1, filter=flt, columns=ASKED, ordered=False)
+        assert len(want) > 0
+        assert sorted(asked_rows(got)) == sorted(asked_rows(want))
+        for col in NOT_ASKED:
+            assert getattr(got, col) is None, col
+
+    def test_unordered_rows_come_shard_by_shard_and_repeat(self, mixed_store):
+        from predictionio_tpu.data.storage.base import frame_shard_of
+
+        _, _, pe = mixed_store
+        a = pe.find(1, columns=(*ASKED, "entity_type"), ordered=False)
+        b = pe.find(1, columns=(*ASKED, "entity_type"), ordered=False)
+        assert asked_rows(a) == asked_rows(b)
+        shard = frame_shard_of(a.entity_type, a.entity_id, 4)
+        assert (np.diff(shard) >= 0).all() and len(set(shard)) == 4
+        # ... and within a shard in its table's own order: the rows of
+        # iter_shards, one shard after the other
+        per_shard = [
+            row
+            for _, f in pe.iter_shards(1, columns=ASKED)
+            for row in asked_rows(f)
+        ]
+        assert asked_rows(a) == per_shard
+        # a full frame read without order is the same rows, every column
+        full = pe.find(1, ordered=False)
+        assert asked_rows(full) == asked_rows(a)
+        assert full.event_time_ms is not None and full.event_id is not None
+        assert (np.diff(full.event_time_ms) < 0).any()  # not time order
+
+    @pytest.mark.parametrize(
+        "flt",
+        [
+            EventFilter(limit=25),
+            EventFilter(reversed=True),
+            EventFilter(limit=9, reversed=True, event_names=("rate",)),
+            EventFilter(limit=0),
+        ],
+        ids=["limit", "reversed", "limit-reversed", "limit-0"],
+    )
+    def test_limit_and_reversed_come_back_ordered(self, mixed_store, flt):
+        _, _, pe = mixed_store
+        want = pe.find(1, filter=flt)
+        got = pe.find(1, filter=flt, columns=ASKED, ordered=False)
+        assert asked_rows(got) == asked_rows(want)  # row for row
+        if len(want):
+            # the sort key it was ordered by came along: more than asked
+            assert (got.event_time_ms == want.event_time_ms).all()
+
+    def test_ordered_projection_keeps_the_default_order(self, mixed_store):
+        _, _, pe = mixed_store
+        want = pe.find(1)
+        got = pe.find(1, columns=ASKED)
+        assert asked_rows(got) == asked_rows(want)
+        assert (got.event_time_ms == want.event_time_ms).all()
+        assert (np.diff(got.event_time_ms) >= 0).all()
+        assert got.event_id is None and got.entity_type is None
+
+    def test_the_default_call_is_the_full_sorted_frame(self, mixed_store):
+        import dataclasses
+
+        _, le, pe = mixed_store
+        frame = pe.find(1)
+        explicit = pe.find(1, None, None, None, True)
+        for f in dataclasses.fields(EventFrame):
+            a, b = getattr(frame, f.name), getattr(explicit, f.name)
+            assert a is not None and a.tolist() == b.tolist(), f.name
+        assert (np.diff(frame.event_time_ms) >= 0).all()
+        # the row path agrees on what is live: upserts won, tombstones hid
+        assert sorted(x for x in frame.event_id if x) == sorted(
+            e.event_id for e in le.find(1) if e.event_id
+        )
+        assert len(frame) == 500 + 60 + 12 - 3  # three ids deleted
+
+    def test_spans_say_what_the_read_did(self, mixed_store, caplog):
+        import logging
+
+        from predictionio_tpu.obs.tracing import recent_traces, trace
+
+        _, _, pe = mixed_store
+        caplog.set_level(logging.INFO, "predictionio_tpu.data.parquet")
+        tags = {}
+        for key, kwargs in {
+            "default": {},
+            "training": {"columns": ASKED, "ordered": False},
+            "limit": {"filter": EventFilter(limit=5), "ordered": False},
+        }.items():
+            with trace(f"test.read.{key}"):
+                pe.find(1, **kwargs)
+            root = recent_traces(1)[0]
+            assert [c["name"] for c in root["children"]] == [
+                "eventstore.scan", "eventstore.sort", "eventstore.decode"]
+            tags[key] = {c["name"]: c for c in root["children"]}
+        scan = {k: v["eventstore.scan"] for k, v in tags.items()}
+        assert (scan["default"]["columns"], scan["default"]["ordered"]) == (
+            12, True)
+        # event + the three asked for; the merge keys were read and dropped
+        assert (scan["training"]["columns"], scan["training"]["ordered"]) == (
+            4, False)
+        assert scan["limit"]["ordered"] is True
+        assert tags["default"]["eventstore.sort"]["sorted"] is True
+        assert tags["training"]["eventstore.sort"]["sorted"] is False
+        assert tags["limit"]["eventstore.sort"]["sorted"] is True
+        assert tags["training"]["eventstore.decode"]["columns"] == 4
+        assert scan["training"]["rows"] == scan["default"]["rows"]
+        # the same tags in the log, one record a bulk read
+        logged = [r.bulk_read for r in caplog.records
+                  if hasattr(r, "bulk_read")]
+        assert [(r["columns"], r["ordered"]) for r in logged] == [
+            (12, True), (4, False), (12, True)]
+        assert logged[1]["rows"] == scan["training"]["rows"]
+
+    def test_empty_store_and_no_match(self, tmp_path):
+        client, le, pe = store_at(tmp_path / "pq")
+        le.init(1)
+        assert len(pe.find(1, columns=ASKED, ordered=False)) == 0
+        pe.write(bulk_frame(40), 1)
+        none = pe.find(
+            1, filter=EventFilter(event_names=("nope",)), columns=ASKED,
+            ordered=False,
+        )
+        assert len(none) == 0
+        client.close()
+
+    def test_backends_that_ignore_the_arguments_get_them(self):
+        """A backend written against the old contract (``find`` takes the
+        filter and nothing else) still answers a caller that states its
+        columns and order: with everything, sorted."""
+        from predictionio_tpu.data.storage.base import PEvents
+
+        calls = []
+
+        class Narrow(PEvents):
+            def find(self, app_id, channel_id=None, filter=None):
+                calls.append((app_id, channel_id, filter))
+                return "everything, sorted"
+
+            def write(self, frame, app_id, channel_id=None): ...
+
+            def delete(self, event_ids, app_id, channel_id=None): ...
+
+        class Wide(Narrow):
+            def find(self, app_id, channel_id=None, filter=None,
+                     columns=None, ordered=True):
+                return (columns, ordered)
+
+        flt = EventFilter(limit=3)
+        n = Narrow()
+        assert n.find(7, None, flt, columns=ASKED, ordered=False) == (
+            "everything, sorted")
+        assert n.find(7, filter=flt) == "everything, sorted"
+        assert calls == [(7, None, flt)] * 2
+        assert Narrow.find.__name__ == "find"
+        assert Wide().find(7, columns=ASKED, ordered=False) == (ASKED, False)
+
+    def test_sqlite_accepts_both_and_returns_the_full_frame(self, tmp_path):
+        import dataclasses
+
+        from predictionio_tpu.data.storage.sqlite_backend import (
+            SQLiteClient,
+            SQLiteLEvents,
+            SQLitePEvents,
+        )
+
+        client = SQLiteClient(tmp_path / "pio.sqlite")
+        le = SQLiteLEvents(client)
+        pe = SQLitePEvents(client, le)
+        le.init(1)
+        le.insert_batch(
+            [mk("rate", f"u{j % 5}", 50 - j, target=f"i{j % 3}",
+                props={"rating": float(j % 5)}) for j in range(30)],
+            1,
+        )
+        want = pe.find(1)
+        got = pe.find(1, columns=ASKED, ordered=False)
+        for f in dataclasses.fields(EventFrame):
+            assert getattr(got, f.name).tolist() == (
+                getattr(want, f.name).tolist()), f.name
+        assert (np.diff(got.event_time_ms) >= 0).all()
+        few = pe.find(1, None, EventFilter(limit=4), ASKED, False)
+        assert asked_rows(few) == asked_rows(want)[:4]
+
+
 class TestBackpressure:
     def test_saturated_ingest_sheds_503_with_retry_after(self, tmp_path):
         from predictionio_tpu.data.storage.config import (

@@ -185,6 +185,12 @@ def _check_tree(stages, root, expected, mode):
     assert by_name["eventstore.scan"]["bytes_read"] > 0
     assert by_name["eventstore.decode"]["rows"] == NNZ
     assert by_name["datasource.columns"]["rows_kept"] == NNZ
+    # the training read states what it uses (ISSUE 27): `event` and three
+    # columns of twelve, in no order
+    assert by_name["eventstore.scan"]["columns"] == 4
+    assert by_name["eventstore.scan"]["ordered"] is False
+    assert by_name["eventstore.sort"]["sorted"] is False
+    assert by_name["eventstore.decode"]["columns"] == 4
     vocab = by_name["prepare.vocab"]
     assert vocab["users"] <= N_USERS and vocab["items"] <= N_ITEMS
     # the store decodes ids through their dictionary: one object an id, so
@@ -237,6 +243,135 @@ def test_pallas_retrain_holds_the_span_tree(parquet_storage, pallas_on_cpu):
         c["upload_bytes"] for c in stage["children"]
         if c["name"] == "als.stage.upload"
     )
+
+
+# -- the training read: three columns and no order (ISSUE 27) ---------------
+
+
+def test_read_spans_keep_their_names_and_say_what_the_read_did(
+    parquet_storage, caplog
+):
+    """The four spans the benchmark's ``scan_s`` / ``sort_s`` / ``decode_s``
+    / ``columns_s`` read are all in ``stages`` on the order-free path, the
+    tags say the mechanism engaged, and the DataSource's log says what it
+    asked for."""
+    with caplog.at_level(logging.INFO, "predictionio_tpu"):
+        stages, root = _retrain(parquet_storage)
+    for name in ("eventstore.scan", "eventstore.sort", "eventstore.decode",
+                 "datasource.columns"):
+        assert stages[name] >= 0, name
+    read = next(
+        c for c in root["children"] if c["name"] == "train.datasource.read")
+    assert [c["name"] for c in read["children"]] == [
+        "eventstore.scan", "eventstore.sort", "eventstore.decode",
+        "datasource.columns"]
+    scan, sort, decode, _ = read["children"]
+    assert (scan["ordered"], scan["columns"]) == (False, 4)
+    assert (sort["sorted"], sort["rows"]) == (False, NNZ)
+    assert decode["columns"] == 4
+    (store,) = [r.bulk_read for r in caplog.records
+                if hasattr(r, "bulk_read")]
+    assert (store["ordered"], store["columns"], store["rows"]) == (
+        False, 4, NNZ)
+    (record,) = [r for r in caplog.records if hasattr(r, "read")]
+    assert record.read == {
+        "columns": ("entity_id", "target_entity_id", "properties"),
+        "ordered": False, "rows_in": NNZ, "rows_kept": NNZ,
+    }
+
+
+def _old_read(monkeypatch):
+    """The DataSource's read as it was: the full frame in time order."""
+    from predictionio_tpu.data.store import PEventStore
+
+    find = PEventStore.find
+    monkeypatch.setattr(
+        PEventStore, "find",
+        lambda self, *a, columns=None, ordered=True, **kw: find(
+            self, *a, **kw),
+    )
+
+
+def test_new_read_hands_the_preparator_the_same_ratings(
+    parquet_storage, monkeypatch
+):
+    """Same events in, the same ratings per (user, item) out, through a
+    compacted segment and a hot head with ``buy`` events and events that
+    are not ratings; only the order rows are first seen in differs, and
+    that order is the store's (shard, then row), read after read."""
+    from predictionio_tpu.data.storage.base import frame_shard_of
+    from predictionio_tpu.models.recommendation.engine import (
+        RatingsDataSource,
+        RatingsPreparator,
+    )
+
+    rt = parquet_storage
+    app_id = rt.apps().get_by_name("spans").id
+    pe = rt.p_events()
+    pe.compact(app_id)
+    n = 300
+
+    def const(value):
+        col = np.empty(n, object)
+        col[:] = value
+        return col
+
+    rng = np.random.default_rng(27)
+    event = const("buy")
+    event[::3] = "view"  # not a rating: the filter leaves it out
+    props = const("")
+    props[1::3] = '{"rating": "n/a"}'  # a buy's rating is the fixed one
+    pe.write(
+        EventFrame(
+            event=event, entity_type=const("user"),
+            entity_id=np.array(
+                [f"u{u}" for u in rng.integers(0, N_USERS + 9, n)], object),
+            target_entity_type=const("item"),
+            target_entity_id=np.array(
+                [f"i{i}" for i in rng.integers(0, N_ITEMS + 3, n)], object),
+            event_time_ms=1_600_000_000_000 + np.arange(n, dtype=np.int64),
+            properties=props,
+        ),
+        app_id=app_id,
+    )
+    ctx = EngineContext(storage=rt)
+    ds = RatingsDataSource(DataSourceParams(app_name="spans", buy_rating=3.5))
+    new = ds.read_training(ctx)
+    again = ds.read_training(ctx)
+    with monkeypatch.context() as m:
+        _old_read(m)
+        old = ds.read_training(ctx)
+
+    def triples(td):
+        return sorted(zip(td.users, td.items, td.ratings.tolist()))
+
+    assert len(new.ratings) == NNZ + 200 and 3.5 in new.ratings
+    assert triples(new) == triples(old)
+    assert not (new.users == old.users).all()  # another order ...
+    for a, b in ((new.users, again.users), (new.items, again.items),
+                 (new.ratings, again.ratings)):
+        assert (a == b).all()  # ... and the same one on every read
+    shard = frame_shard_of(
+        np.full(len(new.users), "user", object), new.users, 4)
+    assert (np.diff(shard) >= 0).all()
+
+    prep = RatingsPreparator()
+    pd_new, pd_old = prep.prepare(ctx, new), prep.prepare(ctx, old)
+    assert sorted(pd_new.user_vocab) == sorted(pd_old.user_vocab)
+    assert sorted(pd_new.item_vocab) == sorted(pd_old.item_vocab)
+    # ids are handed out as first seen in the read's own order
+    first_seen = list(dict.fromkeys(new.users))
+    assert list(pd_new.user_vocab) == first_seen
+    assert list(pd_new.user_vocab) != list(pd_old.user_vocab)
+
+    def decoded(pd):
+        return sorted(zip(
+            (pd.user_vocab.inverse(int(u)) for u in pd.user_idx),
+            (pd.item_vocab.inverse(int(i)) for i in pd.item_idx),
+            pd.ratings.tolist(),
+        ))
+
+    assert decoded(pd_new) == decoded(pd_old) == triples(old)
 
 
 # -- the Preparator: one factorize pass a column, or the loop a row ----------

@@ -102,6 +102,65 @@ def test_datasource_returns_each_entitys_events_in_time_order(store):
         assert got == [f"i{i}" for i in items[mine]], name  # (time, write order)
 
 
+def test_equal_timestamps_keep_the_stores_order_to_the_element(tmp_path):
+    """Every event at ONE instant, written as two frames: the read's order
+    is then all the store's tie-break (write, then shard, then row), and
+    the DataSource's output is held to it element by element.  This engine
+    calls ``find`` with the defaults; the order-free read of ISSUE 27 must
+    never reach it."""
+    from predictionio_tpu.data.storage.base import frame_shard_of
+
+    home = tmp_path / "pio_home"
+    rt = StorageRuntime(StorageConfig.from_env({
+        "PIO_HOME": str(home),
+        "PIO_STORAGE_SOURCES_PARQUET_TYPE": "parquet",
+        "PIO_STORAGE_SOURCES_PARQUET_PATH": str(home / "events_parquet"),
+        "PIO_STORAGE_SOURCES_PARQUET_NSHARDS": "4",
+        "PIO_STORAGE_REPOSITORIES_EVENTDATA_SOURCE": "PARQUET",
+    }))
+    app = commands.app_new(rt, "seq").app
+    rng = np.random.default_rng(27)
+    n = 240
+    users = np.array([f"u{u}" for u in rng.integers(0, 9, n)], object)
+    items = np.array([f"i{i}" for i in rng.integers(0, 50, n)], object)
+
+    def const(value: str, rows: int) -> np.ndarray:
+        col = np.empty(rows, object)
+        col[:] = value
+        return col
+
+    for part in (slice(0, 150), slice(150, n)):
+        rows = len(users[part])
+        rt.p_events().write(
+            EventFrame(
+                event=const("rate", rows), entity_type=const("user", rows),
+                entity_id=users[part], target_entity_type=const("item", rows),
+                target_entity_id=items[part],
+                event_time_ms=np.full(rows, 1_700_000_000_000, np.int64),
+                properties=const("", rows),
+            ),
+            app_id=app.id,
+        )
+    shard = frame_shard_of(const("user", n), users, 4)
+    written = np.arange(n) >= 150
+    # stable by (write, shard): rows keep their place within a shard's write
+    read_order = np.lexsort((shard, written))
+    try:
+        td = seq.SequenceDataSource(
+            seq.SequenceDataSourceParams(app_name="seq")
+        ).read_training(EngineContext(storage=rt))
+    finally:
+        rt.close()
+    assert td.items.tolist() == items[read_order].tolist()
+    read_users = users[read_order]
+    assert td.entities.tolist() == list(dict.fromkeys(read_users))
+    for e, name in enumerate(td.entities):
+        mine = np.flatnonzero(read_users == name)
+        assert td.order[td.offsets[e] : td.offsets[e + 1]].tolist() == (
+            mine.tolist()), name
+    assert td.offsets[-1] == n
+
+
 def test_preparator_packs_the_most_recent_events(store):
     rt, (users, items, _) = store
     ctx = EngineContext(storage=rt)
@@ -230,6 +289,27 @@ def test_every_span_of_the_engine_appears_once_in_stages(trained):
                         ("prepare.pack", "train.preparator.prepare"),
                         ("seq.device_loop", "train.algorithm.gdn")):
         assert stages[part] <= stages[whole] + 1e-3
+
+
+def test_the_sequence_read_asks_for_time_order(trained):
+    """The engine that NEEDS the store's order runs the ordered path: its
+    retrain's ``eventstore.scan`` says ``ordered: true``, every column."""
+    from predictionio_tpu.obs.tracing import recent_traces
+
+    instance, stages = trained[-2:]
+    root = next(
+        t for t in recent_traces(5) if t.get("request_id") == instance.id)
+    read = next(
+        c for c in root["children"] if c["name"] == "train.datasource.read")
+    by_name = {c["name"]: c for c in read["children"]}
+    assert list(by_name) == ["eventstore.scan", "eventstore.sort",
+                             "eventstore.decode", "datasource.sequences"]
+    assert by_name["eventstore.scan"]["ordered"] is True
+    assert by_name["eventstore.scan"]["columns"] == 12
+    assert by_name["eventstore.sort"]["sorted"] is True
+    assert by_name["eventstore.sort"]["rows"] == NNZ
+    for name in by_name:
+        assert stages[name] >= 0, name
 
 
 def test_template_scaffolds_the_engine(tmp_path):

@@ -557,6 +557,39 @@ class TestFacades:
         with pytest.raises(AppNotFoundError):
             PEventStore(storage).find("nope")
 
+    def test_find_takes_what_the_consumer_states(self, storage):
+        """Every backend answers ``find(columns=, ordered=)`` with at least
+        what was asked: the parquet store projects and skips the sort, the
+        others hand back the full sorted frame they always did."""
+        app_id = storage.apps().insert(App(id=0, name="shop"))
+        le = storage.l_events()
+        le.init(app_id)
+        le.insert_batch(
+            [mk("rate", f"u{j % 4}", 40 - j, target=f"i{j % 3}",
+                props={"rating": float(j % 5)}) for j in range(24)],
+            app_id,
+        )
+        store = PEventStore(storage)
+        asked = ("entity_id", "target_entity_id", "properties")
+
+        def rows(frame):
+            return [
+                (frame.entity_id[i], frame.target_entity_id[i], r)
+                for i, r in enumerate(frame.property_column("rating"))
+            ]
+
+        want = store.find("shop", event_names=["rate"])
+        assert (np.diff(want.event_time_ms) >= 0).all() and len(want) == 24
+        got = store.find(
+            "shop", event_names=["rate"], columns=asked, ordered=False)
+        assert sorted(rows(got)) == sorted(rows(want))
+        assert got.event.tolist() == ["rate"] * 24
+        again = store.find(
+            "shop", event_names=["rate"], columns=asked, ordered=False)
+        assert rows(again) == rows(got)  # a repeatable order
+        projected = store.find("shop", event_names=["rate"], columns=asked)
+        assert rows(projected) == rows(want)  # ordered unless said otherwise
+
     def test_localfs_models(self, tmp_path):
         from predictionio_tpu.data.storage.localfs_models import LocalFSModels
 
