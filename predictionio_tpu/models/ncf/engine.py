@@ -101,35 +101,17 @@ class NCFAlgorithmParams:
 
 
 @partial(jax.jit, static_argnames=("n_items", "k"))
-def _score_topk(params, user_idx, n_items: int, k: int):
-    """Serving hot path as ONE compiled program: score every item, mask
-    table padding rows, top-k (the recommendation template's
-    _topk_for_user pattern).
-
-    Returns ONE packed [2, k] f32 array (row 0 = scores, row 1 = item
-    indices) instead of a (scores, indices) pair: fetching two separate
-    outputs costs two device->host transfers, the packed layout one.  f32
-    holds item ids exactly up to 2^24."""
-    with jax.named_scope("ncf.score"):
-        scores = score_all_items(params, user_idx)
-    with jax.named_scope("ncf.topk"):
-        masked = jnp.where(
-            jnp.arange(scores.shape[0]) < n_items, scores, -jnp.inf
-        )
-        s, i = jax.lax.top_k(masked, k)
-        return jnp.stack([s, i.astype(jnp.float32)])
-
-
-@partial(jax.jit, static_argnames=("n_items", "k"))
 def _score_topk_batch(params, user_idx, n_items: int, k: int):
     """A whole micro-batch wave in ONE dispatch: [B] users -> top-k each.
 
     One device round trip per wave instead of per query — under
     concurrency the dispatch overhead amortizes B-fold (the reason the
     MicroBatcher exists).  Callers pad ``user_idx`` to a power of two so
-    at most log2(max_batch) variants ever compile.  Output is packed
-    [2, B, k] f32 (scores, indices) for the same one-transfer reason as
-    ``_score_topk``.
+    at most log2(max_batch) variants ever compile.  Output is ONE packed
+    [2, B, k] f32 array (row 0 = scores, row 1 = item indices) instead of a
+    (scores, indices) pair: fetching two separate outputs costs two
+    device->host transfers, the packed layout one.  f32 holds item ids
+    exactly up to 2^24.
     """
     with jax.named_scope("ncf.score"):
         scores = jax.vmap(lambda u: score_all_items(params, u))(user_idx)

@@ -635,8 +635,7 @@ class ALSAlgorithm(Algorithm):
                     device_obs.device_label(packed)
                 )
                 # pallas bodies are opaque to XLA cost_analysis: the
-                # analytic roofline stands in (same as the ALS train
-                # kernel's source="plan")
+                # analytic roofline stands in
                 device_obs.note_wave_cost(
                     "als.fused_topk",
                     fused_topk_roofline(

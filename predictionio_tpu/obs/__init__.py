@@ -8,7 +8,7 @@ ring (logging.py), a flight recorder for the slowest/errored requests
 (slo.py), on-demand jax.profiler capture (profiler.py), online model-quality
 monitoring — prediction log, feedback joins, drift detection (quality.py) —
 device-efficiency attribution — XLA cost/roofline capture, recompile-storm
-detection, wave-timeline splits, the bench perf-regression gate (device.py)
+detection, wave-timeline splits (device.py)
 — HTTP exposition for all of it (http.py), a sniffer plugin proving the
 plugin seams can consume the registry (plugin.py), and the watch loop that
 turns it all into autonomous detection: a declarative alert rules engine
@@ -37,7 +37,6 @@ from predictionio_tpu.obs.device import (
     DevicePeaks,
     EfficiencyTracker,
     RecompileTracker,
-    compare_bench,
     device_peaks,
     device_snapshot,
     jit_cost_analysis,
@@ -123,7 +122,6 @@ __all__ = [
     "Span",
     "annotate",
     "clear_traces",
-    "compare_bench",
     "configure_logging",
     "current_cost",
     "current_span",

@@ -1,11 +1,9 @@
 """Device-efficiency observability: roofline attribution from real XLA
-costs, recompile accounting, and the perf-regression gate.
+costs and recompile accounting.
 
 The serving/training substrate already times everything (spans, MicroBatcher
-waves) but none of those numbers say how well the *device* is used: BENCH_r05
-achieves 25 GB/s of an ~819 GB/s HBM peak and the repo's only roofline math
-is ad-hoc arithmetic inside bench.py.  This module is the runtime
-counterpart:
+waves) but none of those numbers say how well the *device* is used.  This
+module is the runtime counterpart:
 
 - :func:`jit_cost_analysis` captures ``lowered.compile().cost_analysis()``
   (FLOPs, bytes accessed) for a jitted entry point — the XLA cost model's
@@ -24,11 +22,7 @@ counterpart:
 - a contextvar *wave timeline* (:func:`wave_timeline` / :func:`wave_stage`)
   lets engines split a MicroBatcher wave's opaque ``device_s`` into
   host-gather / H2D / device-compute / D2H, so a slow query is attributable
-  to transfer vs compute vs queue;
-- :func:`als_plan_roofline` is the pallas-plan HBM/MXU arithmetic that used
-  to live in bench.py, and :func:`compare_bench` is the
-  ``pio bench --compare`` regression gate over two BENCH json lines
-  (``schema_version``-checked).
+  to transfer vs compute vs queue.
 
 Import-light by design: servers that never touch an accelerator (event
 ingest, admin, dashboard) import this module through ``obs.http`` — nothing
@@ -779,273 +773,6 @@ def split_breakdown(
     marked = sum(stages.get(name, 0.0) for name in WAVE_STAGES)
     out["other"] = round(max(device_s - marked, 0.0), 6)
     return out
-
-
-# ---------------------------------------------------------------------------
-# ALS pallas-plan roofline (moved out of bench.py so bench consumes it)
-
-
-def als_plan_roofline(plan: Mapping[str, Any]) -> dict[str, float] | None:
-    """HBM bytes and MXU flop-equivalents per ALS iteration from the staged
-    pallas plan (``ops.als.LAST_PLAN_INFO``) — the analytic roofline for the
-    kernel the XLA cost model cannot see inside (pallas bodies are opaque to
-    ``cost_analysis``).  Returns per-iteration ``gb`` / ``tflop_eq`` or None
-    when the plan is missing the required fields."""
-    required = ("width", "rank", "precision", "rows_user", "rows_item",
-                "blocks_user", "blocks_item")
-    if not all(k in plan for k in required):
-        return None
-    width = plan["width"]
-    passes = {"hilo": 2, "bf16": 1, "highest": 6}.get(plan["precision"])
-    if passes is None:
-        return None
-    row_b = width * 4
-    k_pad = (plan["rank"] + 7) // 8 * 8  # sublane round-up
-    gb = 0.0
-    fl = 0.0
-    for side in ("user", "item"):
-        rows = plan[f"rows_{side}"]
-        if plan.get("mode") == "fused":
-            # transposed gather write+read of cv_t [nt, k_pad, T] + wrv
-            # [nt, 8, T] read + seg3 + one output write per block
-            # (VMEM-carried: no accumulator re-reads)
-            gb += rows * (2 * k_pad * 4 + 8 * 4 + 4) / 1e9
-            gb += plan[f"blocks_{side}"] * 128 * row_b / 1e9
-        else:
-            # gather factors + write flat rows + kernel read
-            gb += rows * (512 + 2 * row_b) / 1e9
-            # per-chunk accumulator read-modify-write
-            gb += (
-                plan[f"chunks_{side}"] * plan[f"blocks_{side}"] * 128
-                * row_b * 3
-            ) / 1e9
-        fl += 2.0 * rows * 128 * width * passes / 1e12
-    return {"gb_per_iter": gb, "tflop_eq_per_iter": fl}
-
-
-# ---------------------------------------------------------------------------
-# bench schema + perf-regression gate
-
-#: BENCH json schema: v2 introduced the roofline/utilization fields and the
-#: compare gate; v3 adds the ``--devices N`` sharded section (flat
-#: ``sharded_*`` metrics + the ``sharded_devices`` config echo the gate
-#: refuses to cross-compare); v4 adds the ``--fleet N`` router section
-#: (``fleet_*`` metrics + the ``fleet_replicas`` config echo, same
-#: cross-compare refusal); v5 adds the solo async-dispatch e2e number
-#: (``serving_solo_e2e_p50_ms`` — wall INCLUDING dispatch, the PR 12
-#: target), ``factor_cache_hit_rate``, and the fused-topk roofline block;
-#: v6 grows the event-store section (``--events-scale``): throughput
-#: rates (``events_write_mb_s``/``events_scan_mb_s``), the per-user
-#: history latency (``events_user_history_p50_ms`` — the serving-path
-#: point read), and the post-compaction backlog echo
-#: (``events_compaction_backlog``), plus the ``events_scale_m`` config
-#: echo the gate refuses to cross-compare; v7 adds the ``cost_attribution``
-#: block: per-query attributed device cost for the ALS and NCF serving
-#: paths (``cost_als_device_us_per_query`` / ``cost_ncf_device_us_per_query``),
-#: metering overhead (``cost_metering_overhead_pct`` — serving p50 with the
-#: ledger billing vs without), and the attribution coverage fraction
-#: (``cost_attribution_coverage_frac`` — attributed device-seconds over
-#: measured device-seconds, 1.0 when conservation holds), plus the
-#: event-visibility freshness p99 echo (``events_visibility_lag_p99_s``);
-#: v8 adds the ``fleet_day`` section (``bench.py --fleet N --day``): a
-#: scripted mini production day replayed through the real multi-replica
-#: topology — worst-phase tail latency (``fleet_day_p99_ms``), shed and
-#: retry-elsewhere rates over the whole day (``fleet_day_shed_rate`` /
-#: ``fleet_day_retry_rate``), total attributed device cost
-#: (``fleet_day_device_s``), the verdict booleans as diagnostics, and the
-#: ``fleet_day_scenario`` config echo the gate refuses to cross-compare
-#: (a calm day vs one with a mid-peak SIGKILL is not the same
-#: measurement); v9 grows the ``fleet_day`` section with the two-tenant
-#: isolation run (``replay.tenant_day``): the noisy-neighbor verdict
-#: (``fleet_day_tenant_isolation_pass``), the innocent tenant's
-#: availability under a neighbor's 10× quota flood
-#: (``fleet_day_tenant_victim_availability``) and its tail latency
-#: (``fleet_day_tenant_victim_p99_ms``).  ``pio bench --compare``
-#: refuses version-less or older files.
-BENCH_SCHEMA_VERSION = 9
-
-#: regression-gateable BENCH metrics and which direction is better.  Only
-#: keys present in BOTH files are compared; everything else (configuration
-#: echoes, section diagnostics) is ignored by the gate.
-BENCH_GATE_METRICS: dict[str, str] = {
-    # headline + latency: lower is better
-    "value": "lower",
-    "train_cold_s": "lower",
-    "als_rank32_iter_s": "lower",
-    "serving_p50_ms": "lower",
-    "serving_p50_concurrent32_ms": "lower",
-    "serving_p99_concurrent32_ms": "lower",
-    # solo end-to-end WALL including dispatch through the pipelined async
-    # path
-    "serving_solo_e2e_p50_ms": "lower",
-    "ncf_serving_p50_ms": "lower",
-    "ncf_solo_device_ms": "lower",
-    "ncf_wave32_pipelined_ms": "lower",
-    "ncf_pretrain_s": "lower",
-    "events20m_write_s": "lower",
-    "events20m_scan_s": "lower",
-    # event-store data plane (schema v6): throughput up, serving-path
-    # history reads down, post-compaction backlog down
-    "events_write_mb_s": "higher",
-    "events_scan_mb_s": "higher",
-    "events_user_history_p50_ms": "lower",
-    "events_compaction_backlog": "lower",
-    # throughput / quality / roofline: higher is better
-    "vs_baseline": "higher",
-    "map_at_10": "higher",
-    "precision_at_10": "higher",
-    "ncf_map_at_10": "higher",
-    "ncf_precision_at_10": "higher",
-    "ncf_epochs_per_s": "higher",
-    "roofline_achieved_gb_s": "higher",
-    "roofline_achieved_tflop_s": "higher",
-    # repeat-entity factor-cache effectiveness + fused-topk roofline
-    "factor_cache_hit_rate": "higher",
-    "fused_topk_achieved_gb_s": "higher",
-    "fused_topk_hbm_utilization_frac": "higher",
-    # sharded section (bench --devices N): lower is better
-    "sharded_train_s": "lower",
-    "sharded_serving_p50_ms": "lower",
-    "sharded_serving_p99_ms": "lower",
-    # fleet section (bench --fleet N): the router hop must stay cheap
-    "fleet_router_p50_ms": "lower",
-    "fleet_router_p99_ms": "lower",
-    "fleet_router_overhead_ms": "lower",
-    # cost-attribution section (schema v7): the metering tax must stay
-    # negligible, attribution must stay conservative (coverage ~1.0), and
-    # the freshness signal must not quietly decay
-    "cost_metering_overhead_pct": "lower",
-    "cost_attribution_coverage_frac": "higher",
-    "events_visibility_lag_p99_s": "lower",
-    # production-day section (schema v8, bench --fleet N --day): the whole
-    # scripted day must not get slower, sheddier, retry-happier or more
-    # expensive release over release
-    "fleet_day_p99_ms": "lower",
-    "fleet_day_shed_rate": "lower",
-    "fleet_day_retry_rate": "lower",
-    "fleet_day_device_s": "lower",
-    # two-tenant isolation run (schema v9): an innocent neighbor's
-    # availability and tail under a co-tenant's quota flood must not decay
-    "fleet_day_tenant_victim_availability": "higher",
-    "fleet_day_tenant_victim_p99_ms": "lower",
-}
-
-
-def compare_bench(
-    current: Mapping[str, Any],
-    previous: Mapping[str, Any],
-    tolerance_pct: float = 10.0,
-) -> tuple[int, dict[str, Any]]:
-    """The ``pio bench --compare`` gate: exit-code, report.
-
-    0 = no gateable metric regressed beyond ``tolerance_pct``;
-    1 = at least one did (the CI gate trips);
-    2 = either file is missing ``schema_version`` or carries an old one —
-    version-less BENCH lines predate the gate and must not silently pass.
-    """
-    report: dict[str, Any] = {
-        "tolerance_pct": tolerance_pct,
-        "schema_version": BENCH_SCHEMA_VERSION,
-        "checked": 0,
-        "regressions": [],
-        "improvements": [],
-    }
-    for name, d in (("current", current), ("previous", previous)):
-        sv = d.get("schema_version")
-        if sv != BENCH_SCHEMA_VERSION:
-            report["error"] = (
-                f"{name} bench json has schema_version={sv!r}; this gate "
-                f"needs {BENCH_SCHEMA_VERSION} (re-run bench.py to produce "
-                "a comparable line)"
-            )
-            return 2, report
-    # the headline "metric" key encodes the run configuration (scale
-    # suffix): gating a full-scale run against a scale-0.1 file would
-    # produce a confident 10x "regression" — refuse instead
-    cur_metric, prev_metric = current.get("metric"), previous.get("metric")
-    if cur_metric != prev_metric:
-        report["error"] = (
-            f"bench configurations differ: current metric={cur_metric!r} "
-            f"vs previous {prev_metric!r} — these runs are not comparable"
-        )
-        return 2, report
-    # sharded-section config: an 8-device sharded run gated against a
-    # 2-device file — or N chips against an N-virtual-device CPU rehearsal
-    # — would "regress" by construction: refuse, like the scale-suffix
-    # check above (absent-on-both means no sharded section ran)
-    cur_dev = (current.get("sharded_devices"), current.get("sharded_platform"))
-    prev_dev = (
-        previous.get("sharded_devices"), previous.get("sharded_platform")
-    )
-    if cur_dev != prev_dev:
-        report["error"] = (
-            "sharded sections differ: current (sharded_devices, "
-            f"sharded_platform)={cur_dev!r} vs previous {prev_dev!r} — "
-            "re-run bench with the same --devices on the same platform to "
-            "compare"
-        )
-        return 2, report
-    # fleet-section config: router latency over 2 replicas vs 8 is not the
-    # same measurement — refuse mismatched --fleet runs like --devices
-    cur_fleet = current.get("fleet_replicas")
-    prev_fleet = previous.get("fleet_replicas")
-    if cur_fleet != prev_fleet:
-        report["error"] = (
-            f"fleet sections differ: current fleet_replicas={cur_fleet!r} "
-            f"vs previous {prev_fleet!r} — re-run bench with the same "
-            "--fleet to compare"
-        )
-        return 2, report
-    # production-day section config: fleet_day_* numbers only compare when
-    # the scripted day was the same script — a calm day vs one with a
-    # mid-peak SIGKILL "regresses" by construction
-    cur_day = current.get("fleet_day_scenario")
-    prev_day = previous.get("fleet_day_scenario")
-    if cur_day != prev_day:
-        report["error"] = (
-            f"production-day sections differ: current fleet_day_scenario="
-            f"{cur_day!r} vs previous {prev_day!r} — re-run bench with the "
-            "same --day scenario to compare"
-        )
-        return 2, report
-    # event-store section config: a 100M-row write rate vs a 20M one is
-    # not the same measurement — refuse mismatched --events-scale runs
-    cur_ev = current.get("events_scale_m")
-    prev_ev = previous.get("events_scale_m")
-    if cur_ev != prev_ev:
-        report["error"] = (
-            f"event-store sections differ: current events_scale_m="
-            f"{cur_ev!r} vs previous {prev_ev!r} — re-run bench with the "
-            "same --events-scale to compare"
-        )
-        return 2, report
-    for key in sorted(BENCH_GATE_METRICS):
-        direction = BENCH_GATE_METRICS[key]
-        prev, cur = previous.get(key), current.get(key)
-        if (
-            not isinstance(prev, (int, float))
-            or not isinstance(cur, (int, float))
-            or isinstance(prev, bool)
-            or isinstance(cur, bool)
-            or prev == 0
-        ):
-            continue
-        change_pct = (cur - prev) / abs(prev) * 100.0
-        worse = change_pct > 0 if direction == "lower" else change_pct < 0
-        entry = {
-            "metric": key,
-            "previous": prev,
-            "current": cur,
-            "change_pct": round(change_pct, 3),
-            "better": direction,
-        }
-        report["checked"] += 1
-        if abs(change_pct) <= tolerance_pct:
-            continue
-        (report["regressions"] if worse else report["improvements"]).append(
-            entry
-        )
-    return (1 if report["regressions"] else 0), report
 
 
 # ---------------------------------------------------------------------------

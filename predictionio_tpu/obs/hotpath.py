@@ -249,8 +249,7 @@ class HotPathTracker:
 
 
 def render_hotpath_text(snap: Mapping[str, Any]) -> str:
-    """One-screen stage table over a ``/hotpath.json`` body — the
-    ``# serving_hotpath`` lines in bench logs."""
+    """One-screen stage table over a ``/hotpath.json`` body."""
     lines = [
         f"requests: {snap.get('requests', 0)}   "
         f"coverage: {snap.get('coverage_frac', 0.0):.1%}   "

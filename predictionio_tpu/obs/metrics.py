@@ -186,7 +186,7 @@ class Histogram:
 def quantile_from_buckets(
     bounds: Iterable[float], counts: list[int], total: int, q: float
 ) -> float:
-    """Shared bucket→quantile math (also used by bench.py snapshots)."""
+    """Shared bucket→quantile math (snapshots, obs.hotpath, obs.verdict)."""
     bounds = list(bounds)
     if total <= 0:
         return 0.0
@@ -501,24 +501,6 @@ class MetricsRegistry:
             return current
         return subtract_snapshots(current, prev)
 
-    def histogram_quantiles(
-        self, name: str, qs: Iterable[float] = (0.50, 0.95, 0.99)
-    ) -> dict[str, Any]:
-        """Per-series quantiles for one histogram family (bench snapshots)."""
-        fam = self.get(name)
-        if fam is None or fam.kind != "histogram":
-            return {}
-        out: dict[str, Any] = {}
-        for lv, child in fam.series():
-            counts, _, count = child.snapshot()
-            key = ",".join(f"{n}={v}" for n, v in zip(fam.labelnames, lv)) or "_"
-            out[key] = {"count": count}
-            for q in qs:
-                out[key][f"p{int(q * 100)}"] = quantile_from_buckets(
-                    fam.buckets, counts, count, q
-                )
-        return out
-
 
 def subtract_snapshots(
     current: Mapping[str, Any], previous: Mapping[str, Any]
@@ -607,10 +589,3 @@ REGISTRY = MetricsRegistry()
 
 def default_registry() -> MetricsRegistry:
     return REGISTRY
-
-
-def render_json_line(registry: MetricsRegistry, names: Iterable[str]) -> str:
-    """One-line JSON snapshot of selected histogram families (bench.py)."""
-    return json.dumps(
-        {n: registry.histogram_quantiles(n) for n in names}, sort_keys=True
-    )

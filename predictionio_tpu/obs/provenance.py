@@ -14,8 +14,7 @@ Two capture levels:
 
 - **cheap** (always on): everything replay needs — bounded dicts and
   counts, no per-item filter contents.  Budget: tens of microseconds on
-  the solo path (bench section ``provenance_capture``; tier-1 bounds p50
-  below 50 µs).
+  the solo path (tier-1 bounds p50 below 50 µs).
 - **deep** (opt-in per request via the ``X-Pio-Explain: 1`` header): adds
   filter item lists, wave-mate request ids, and the post-extraction query.
 

@@ -61,11 +61,6 @@ class ALSParams:
     #: "hilo" (2-pass, ~2^-16 rel err — default), "highest" (6-pass exact),
     #: "bf16" (1-pass, ~2^-8)
     pallas_precision: str = "hilo"
-    #: single-device pallas dispatch: "auto" picks the single-grid fused
-    #: kernel (packed rows built in VMEM, no chunk-scan accumulator
-    #: traffic) when the packed stream fits comfortably in HBM, else the
-    #: chunked scan; "fused"/"chunked" force a path
-    pallas_mode: str = "auto"
 
 
 @dataclass
@@ -103,9 +98,7 @@ def _segment_stats(
     item skew), the item half-step drops 2669 ms -> 578 ms vs the
     [chunk, k, k] layout.  Skew now *helps* rather than hurts: the lowering
     combines duplicate indices within a chunk, so hot segments cost one
-    HBM read-modify-write (bench epoch: skewed 1.8 s vs uniform 7.5 s —
-    the worst case is unique-index uniform data, and it stays within
-    budget).
+    HBM read-modify-write; the worst case is unique-index uniform data.
     """
     n = seg_idx.shape[0]
     k = other_factors.shape[1]
@@ -267,7 +260,7 @@ def _half_step(
 
 
 #: compiled-step cache: repeated train_als calls with the same mesh/shapes/
-#: program params (bench warmup then timed run; retrain-on-deploy) must not
+#: program params (a warm-up then a timed run; retrain-on-deploy) must not
 #: pay a second trace+compile — num_iterations and seed don't enter the
 #: compiled program, so they are excluded from the key.  Bounded (FIFO) so a
 #: long-lived retraining server on growing data can't pin dead executables.
@@ -278,11 +271,7 @@ _STEP_CACHE_MAX = 8
 def _use_pallas(p: "ALSParams") -> bool:
     """Single-device TPU runs route the normal-equation accumulation through
     the scatter-free pallas MXU kernel (ops/als_pallas.py) when the flat row
-    fits its 128-lane width; PIO_ALS_NO_PALLAS=1 forces the scatter path."""
-    import os
-
-    if os.environ.get("PIO_ALS_NO_PALLAS"):
-        return False
+    fits its 128-lane width."""
     if p.rank > 32:  # row_width(32) = 1152 lanes; wider is untested
         return False
     # a backend that fails to initialise raises here: an error, not a
@@ -392,15 +381,15 @@ def _make_pallas_step(
     return steps
 
 
-#: diagnostics from the most recent _train_pallas staging (bench roofline
-#: reporting): padded row counts and block counts per scatter direction
+#: diagnostics from the most recent _train_pallas staging (the benchmark's
+#: plan_info reader): padded row counts and block counts per scatter direction
 LAST_PLAN_INFO: dict = {}
 
 #: single-entry staging cache: the host sort/permute + device upload of the
 #: COO streams depends only on the DATA, not on hyperparameters or the
-#: iteration count — retraining on the same ratings (bench repeats, the
-#: deploy-retrain path, hyperparameter sweeps) reuses the staged device
-#: arrays, the way Spark caches a partitioned RDD across ALS iterations.
+#: iteration count — retraining on the same ratings (the deploy-retrain
+#: path, hyperparameter sweeps) reuses the staged device arrays, the way
+#: Spark caches a partitioned RDD across ALS iterations.
 #: Keyed by a full content hash (sha1 of the raw arrays, ~1 s at 20M rows vs
 #: ~13 s restaging); bounded to ONE dataset so stale streams don't pin HBM.
 _STAGE_CACHE: dict = {}
@@ -430,6 +419,32 @@ def _is_oom_error(e: Exception) -> bool:
     )
 
 
+def _first_rung(nnz: int, rank: int) -> str:
+    """The ladder's first rung, from an estimate of the fused path's HBM.
+
+    The fused single-grid kernel streams the transposed gather output
+    ([nt, k, T] f32) per half-step; any rank runs fused (wide ranks add
+    width slabs, not VMEM), so the only reason to start on the chunk-scan
+    is the gather transient crowding HBM."""
+    from predictionio_tpu.ops import als_pallas
+
+    est_rows = int(nnz * 1.06) + als_pallas.T  # ~pad factor
+    # Fused-path HBM budget: the transposed gather output cv_t
+    # [k, nt, T] (k padded to the next sublane multiple of 8) is the
+    # big per-half-step transient, the staged wrv [3->8, nt, T] stacks
+    # live for the whole train, and XLA may keep ~2 transients alive
+    # across the double-buffered halves.  (The transposed orientation
+    # keeps minor dims at 1024, so T(8,128) layout padding cannot
+    # exceed the sublane round-up.)
+    k_pad = (rank + 7) // 8 * 8
+    fused_bytes = est_rows * 4 * (2 * k_pad + 2 * 8)
+    # budget ~half of a v5e's 16G HBM for the staged streams + the
+    # per-half-step gather transient (leaves room for XLA
+    # double-buffering and the accumulator); the OOM ladder catches
+    # an underestimate by falling back to chunked
+    return "fused" if fused_bytes <= 8 << 30 else "chunked"
+
+
 def _train_pallas(user_idx, item_idx, rating, num_users, num_items,
                   p: ALSParams, dtype) -> "ALSState":
     """Single-device TPU train via the scatter-free pallas accumulator.
@@ -439,30 +454,7 @@ def _train_pallas(user_idx, item_idx, rating, num_users, num_items,
     — the chunk scan drops the whole-stream packed transients; per-
     iteration dispatch drops the fori_loop's loop-carried remat copies):
     one OOM must cost a retry, not the train."""
-    from predictionio_tpu.ops import als_pallas
-
-    # mode select: the fused single-grid kernel streams the transposed
-    # gather output ([nt, k, T] f32) per half-step; any rank runs fused
-    # (wide ranks add width slabs, not VMEM), so the only reason to fall
-    # back to the chunk-scan is the gather transient crowding HBM
-    mode = p.pallas_mode
-    if mode == "auto":
-        est_rows = int(len(user_idx) * 1.06) + als_pallas.T  # ~pad factor
-        # Fused-path HBM budget: the transposed gather output cv_t
-        # [k, nt, T] (k padded to the next sublane multiple of 8) is the
-        # big per-half-step transient, the staged wrv [3->8, nt, T] stacks
-        # live for the whole train, and XLA may keep ~2 transients alive
-        # across the double-buffered halves.  (The transposed orientation
-        # keeps minor dims at 1024, so T(8,128) layout padding cannot
-        # exceed the sublane round-up.)
-        k_pad = (p.rank + 7) // 8 * 8
-        fused_bytes = est_rows * 4 * (2 * k_pad + 2 * 8)
-        # budget ~half of a v5e's 16G HBM for the staged streams + the
-        # per-half-step gather transient (leaves room for XLA
-        # double-buffering and the accumulator); the OOM ladder catches
-        # an underestimate by falling back to chunked
-        mode = "fused" if fused_bytes <= 8 << 30 else "chunked"
-
+    mode = _first_rung(len(user_idx), p.rank)
     ladder = [(mode, False)]
     if mode == "fused":
         ladder.append(("chunked", False))
@@ -640,7 +632,6 @@ def _train_pallas_mode(user_idx, item_idx, rating, num_users, num_items,
     LAST_PLAN_INFO.update(
         iterations=p.num_iterations, loop_s=round(wall_s, 4)
     )
-    _record_pallas_efficiency(wall_s, p)
     _log_train_path("als.pallas_step", wall_s, mode=mode, per_iter=per_iter)
     return ALSState(user_factors=U[:num_users], item_factors=V[:num_items])
 
@@ -650,38 +641,6 @@ def _log_train_path(path: str, wall_s: float, **detail) -> None:
     name (``als.pallas_step`` / ``als.train_step``)."""
     fields = {"als_path": path, "wall_s": round(wall_s, 3), **detail}
     log.info("ALS train ran %s %s", path, detail, extra=fields)
-
-
-def _record_pallas_efficiency(wall_s: float, p: ALSParams) -> None:
-    """Place the pallas train on the live roofline: the kernel body is
-    opaque to XLA's ``cost_analysis``, so the per-iteration HBM/MXU cost
-    comes from the staged plan's analytic arithmetic
-    (``obs.device.als_plan_roofline`` — the same math bench.py reports) and
-    joins the measured dispatch wall clock."""
-    from predictionio_tpu.obs import device as device_obs
-
-    per_iter_cost = device_obs.als_plan_roofline(LAST_PLAN_INFO)
-    if per_iter_cost is None:
-        return
-    sig = (
-        LAST_PLAN_INFO.get("mode"),
-        LAST_PLAN_INFO.get("rows_user"),
-        LAST_PLAN_INFO.get("rows_item"),
-        p.rank,
-    )
-    eff = device_obs.default_efficiency()
-    eff.record_cost(
-        "als.pallas_step",
-        flops=per_iter_cost["tflop_eq_per_iter"] * 1e12,
-        nbytes=per_iter_cost["gb_per_iter"] * 1e9,
-        signature=sig,
-        source="plan",
-    )
-    eff.observe(
-        "als.pallas_step",
-        wall_s / max(p.num_iterations, 1),
-        signature=sig,
-    )
 
 
 def _make_train_step(
@@ -938,9 +897,9 @@ def train_als(
     # with the measured wall clock.  The capture is deferred BEFORE the
     # loop so its out-of-band analysis compile runs concurrently with the
     # training dispatches instead of adding a second synchronous compile
-    # to the cold-train wall time bench's regression gate tracks; the
-    # factor shapes are part of the key (same COO, different rank or
-    # entity count is a different program with a different cost)
+    # to the cold-train wall time; the factor shapes are part of the key
+    # (same COO, different rank or entity count is a different program
+    # with a different cost)
     eff = device_obs.default_efficiency()
     sig = device_obs.signature_of(u, i, r, valid, U0, V0)
     eff.capture_cost(
@@ -948,10 +907,10 @@ def train_als(
         signature=sig, defer=True,
     )
     # per-iteration timeline track: with PIO_TRAIN_STEP_TIMELINE=1 and a
-    # bound trace id (`pio bench --devices N` step-timeline mode, an
-    # operator chasing step jitter), each solve iteration becomes one
-    # device-track fragment in the distributed timeline.  Costs one
-    # host-device block per iteration, so it needs the EXPLICIT opt-in —
+    # bound trace id (an operator chasing step jitter), each solve
+    # iteration becomes one device-track fragment in the distributed
+    # timeline.  Costs one host-device block per iteration, so it needs
+    # the EXPLICIT opt-in —
     # a trace id alone is not enough, because run_train binds the engine
     # instance id as every training run's correlation (and thus trace) id,
     # and production retrains must keep the fully async dispatch loop.

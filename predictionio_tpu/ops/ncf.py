@@ -78,7 +78,7 @@ class NCFParams:
     #: equations (reg=0.01 there).
     weight_decay: float = 0.0
     #: confidence weight on observed interactions for loss="wals" (the
-    #: iALS alpha; the recommendation templates' bench config uses 2.0)
+    #: iALS alpha; the recommendation templates use 2.0)
     alpha: float = 2.0
     seed: int = 3
 
@@ -263,9 +263,9 @@ def full_softmax_loss(params: dict, user_idx, pos_idx, valid,
 
     This is the objective sampled-negative SGD approximates (and the
     reason implicit ALS — whole-catalog weighted least squares — beat the
-    sampled NCF configs by ~35% MAP on the bench data).  With the
-    pure-GMF head the logits are ONE [b, d] @ [d, n_items] matmul, so
-    "exact" is also the MXU-shaped choice.  Requires init with
+    sampled NCF configs by ~35% MAP in a run that predates the ledger).
+    With the pure-GMF head the logits are ONE [b, d] @ [d, n_items] matmul,
+    so "exact" is also the MXU-shaped choice.  Requires init with
     ``mlp_layers=()``."""
     if "out_w" in params:
         raise ValueError(
@@ -300,7 +300,7 @@ def wals_loss(params: dict, user_idx, pos_idx, valid, inv_count,
     once, and carries the user's whole-catalog term scaled by
     ``inv_count = 1/|P_u|`` so a user appearing |P_u| times contributes it
     exactly once per epoch.  This is the objective that made implicit ALS
-    beat every sampled NCF config by ~35% MAP on the bench protocol — here
+    beat every sampled NCF config by ~35% MAP before the ledger — here
     it trains the same factorization by AdamW instead of alternating
     exact solves, on logits that are one [b, d] @ [d, n_items] matmul.
     Requires the pure-GMF head (``mlp_layers=()``)."""

@@ -28,7 +28,7 @@ when the kernel runs inside the PR 8 ``build_sharded_topk`` shard_map.
 
 Shapes off the fused menu (``k`` past :data:`MAX_FUSED_K`) fall back to the
 materialized-row kernels and are COUNTED: ``pio_topk_full_row_fallback_
-total`` plus a logged ``(batch, k)`` shape, so a bench run claiming zero
+total`` plus a logged ``(batch, k)`` shape, so a run claiming zero
 fallbacks is a checkable fact.
 """
 
@@ -133,9 +133,9 @@ def note_full_row_fallback(
 ) -> None:
     """Count (and name) one full-score-row fallback: a top-k that had to
     materialize the whole ``[batch, n_items]`` row because its shape is off
-    the fused menu.  The bench gate drives this to zero; any non-zero count
-    names the offending (wave, k) shape in the log (once per distinct
-    shape — the counter carries the per-dispatch cardinality)."""
+    the fused menu.  Any non-zero count names the offending (wave, k) shape
+    in the log (once per distinct shape — the counter carries the
+    per-dispatch cardinality)."""
     from predictionio_tpu.obs.metrics import REGISTRY
 
     REGISTRY.counter(

@@ -228,7 +228,7 @@ def invalidate_model_caches(models: Iterable[Any], reason: str) -> int:
 
 
 def stats() -> dict[str, float]:
-    """Process-cumulative cache counters (bench + tests read deltas)."""
+    """Process-cumulative cache counters (tests read deltas)."""
     hits = REGISTRY.counter(
         "pio_factor_cache_hits_total",
         "Factor-cache lookups served without a gather",

@@ -2,7 +2,7 @@
 
 ``workload`` is the one traffic generator (seeded open-loop schedules,
 the closed-loop keep-alive measure loop, and the asyncio concurrent
-client BENCH uses); ``scenario`` is the declarative scripted-day format;
+client); ``scenario`` is the declarative scripted-day format;
 ``day`` drives the real fleet topology through a scenario and hands the
 evidence to :mod:`predictionio_tpu.obs.verdict`.
 """

@@ -48,8 +48,8 @@ def seed_demo_home(
     seed: int = 5,
 ) -> str:
     """Events + one trained recommendation generation in a fresh
-    PIO_HOME — the fixture the mini-day tests and ``bench.py --day``
-    share.  Returns the engine instance id."""
+    PIO_HOME — the fixture of the mini-day tests.  Returns the engine
+    instance id."""
     import numpy as np
 
     from predictionio_tpu.core.base import EngineContext

@@ -19,8 +19,7 @@ in :func:`predictionio_tpu.obs.verdict.evaluate_day`, whose
    tenant's instance set.
 
 Everything is in-process and CPU-only (stub engines, no storage, no
-training), so the same run serves tier-1 tests and the ``fleet_day``
-bench section (docs/robustness.md#multi-tenancy).
+training), so tier-1 runs it whole (docs/robustness.md#multi-tenancy).
 """
 
 from __future__ import annotations

@@ -1,7 +1,6 @@
 """The one traffic generator.
 
-Three consumers share this module so there is a single definition of
-"send /queries.json traffic and measure it":
+One definition of "send /queries.json traffic and measure it":
 
 - the **production-day harness** (``pio day``) uses :class:`OpenLoopRunner`
   over seeded :class:`PhaseSchedule` s — open-loop paced arrivals with
@@ -10,12 +9,10 @@ Three consumers share this module so there is a single definition of
   outcome record per request (status, latency, replica/instance/variant
   headers, request id) that the verdict engine joins against scraped
   telemetry;
-- BENCH's ``--fleet`` section uses :func:`measure_closed_loop`, the
-  sequential keep-alive loop it used to hand-roll inline;
-- BENCH's concurrent serving section runs this module as a subprocess
-  (``python -m predictionio_tpu.replay.workload PORT CONNS PER_CONN
-  NUM_USERS ROUNDS``), the asyncio load client that used to live in a
-  ``-c`` script string.
+- :func:`measure_closed_loop` is the sequential keep-alive loop, and
+  ``python -m predictionio_tpu.replay.workload PORT CONNS PER_CONN
+  NUM_USERS ROUNDS`` the asyncio load client, for a server started by
+  hand.
 
 Determinism contract: a schedule is a pure function of (phase
 parameters, seed).  Same seed ⇒ byte-identical arrival times, kinds and
@@ -338,7 +335,7 @@ class OpenLoopRunner:
 
 
 # ---------------------------------------------------------------------------
-# the closed-loop measure loop (BENCH --fleet)
+# the closed-loop measure loop
 # ---------------------------------------------------------------------------
 
 
@@ -379,7 +376,7 @@ def measure_closed_loop(
 
 
 # ---------------------------------------------------------------------------
-# the asyncio concurrent client (BENCH serving section; `-m` entry point)
+# the asyncio concurrent client (`-m` entry point)
 # ---------------------------------------------------------------------------
 
 
@@ -442,10 +439,7 @@ def run_load_rounds(
 
 def main(argv: list[str]) -> int:
     """``python -m predictionio_tpu.replay.workload PORT CONNS PER_CONN
-    NUM_USERS ROUNDS`` — one JSON result line per round, the protocol
-    BENCH's serving section consumes.  Spawned ONCE before the parent
-    deprioritizes itself, so the client never inherits a degraded
-    priority."""
+    NUM_USERS ROUNDS`` — one JSON result line per round."""
     port, conns, per_conn, num_users, rounds = (int(a) for a in argv[:5])
     for res in run_load_rounds(port, conns, per_conn, num_users, rounds):
         print(json.dumps(res), flush=True)

@@ -1,5 +1,6 @@
 """Process-level JAX runtime set-up shared by the `pio` verbs that compile
-(train / deploy / batchpredict / eval) and ``bench.py``: where compiled
+(train / deploy / batchpredict / eval) and the benchmark's chip checks
+(``benchmark/tests/*_chip.py``): where compiled
 programs are cached, which device the process actually got, and whether it
 has touched one at all."""
 

@@ -59,3 +59,24 @@ def _no_console_handler_on_a_closed_stream():
         if getattr(getattr(h, "stream", None), "closed", False):
             root.removeHandler(h)
     yield
+
+
+@pytest.fixture()
+def pallas_on_cpu(monkeypatch):
+    """The chip's train path under the Pallas interpreter: the steering a
+    CPU test needs lives here, not in an option of the program."""
+    import functools
+
+    from predictionio_tpu.ops import als, als_pallas
+
+    monkeypatch.setattr(als, "_use_pallas", lambda p: True)
+    for kernel in ("segment_stats_fused", "segment_stats_pallas"):
+        monkeypatch.setattr(
+            als_pallas, kernel,
+            functools.partial(getattr(als_pallas, kernel), interpret=True),
+        )
+    als._STEP_CACHE.clear()
+    als._STAGE_CACHE.clear()
+    yield
+    als._STEP_CACHE.clear()
+    als._STAGE_CACHE.clear()

@@ -86,6 +86,31 @@ class TestMeshConfig:
             make_mesh(MeshConfig({"data": 16}))
 
 
+#: how far a Pallas rung's factors may sit from the scatter step's, as a
+#: share of the largest factor.  Read over the 18 cases below under the
+#: interpreter: 9e-6 ... 8e-5, and 4.5e-4 ... 7.3e-4 at rank 20 explicit
+#: (20 x 20 systems of users with a handful of ratings at reg 0.01); the
+#: reason for any gap is beside the assertion
+RUNG_TOL = 2e-3
+
+
+@pytest.mark.parametrize("backend,rank,pallas", [
+    ("tpu", 10, True), ("tpu", 32, True), ("tpu", 33, False),
+    ("cpu", 10, False),
+])
+def test_pallas_is_chosen_from_backend_and_rank(
+    monkeypatch, backend, rank, pallas
+):
+    """The one choice between the Pallas kernel and the scatter step, from
+    what the process can observe and nothing a user sets."""
+    import jax
+
+    from predictionio_tpu.ops.als import _use_pallas
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert _use_pallas(ALSParams(rank=rank)) is pallas
+
+
 class TestOOMFallbackLadder:
     """HBM exhaustion degrades fused -> chunked -> per-iteration instead of
     killing the train."""
@@ -112,7 +137,8 @@ class TestOOMFallbackLadder:
             return "sentinel-state"
 
         monkeypatch.setattr(als_mod, "_train_pallas_mode", fake_mode)
-        p = als_mod.ALSParams(rank=4, pallas_mode="fused")
+        monkeypatch.setattr(als_mod, "_first_rung", lambda nnz, rank: "fused")
+        p = als_mod.ALSParams(rank=4)
         with pytest.warns(RuntimeWarning):
             out = als_mod._train_pallas(
                 np.zeros(4, np.int64), np.zeros(4, np.int64),
@@ -130,12 +156,61 @@ class TestOOMFallbackLadder:
             raise ValueError("genuine bug")
 
         monkeypatch.setattr(als_mod, "_train_pallas_mode", fake_mode)
-        p = als_mod.ALSParams(rank=4, pallas_mode="chunked")
+        monkeypatch.setattr(
+            als_mod, "_first_rung", lambda nnz, rank: "chunked"
+        )
+        p = als_mod.ALSParams(rank=4)
         with pytest.raises(ValueError, match="genuine bug"):
             als_mod._train_pallas(
                 np.zeros(4, np.int64), np.zeros(4, np.int64),
                 np.ones(4, np.float32), 4, 4, p, np.float32,
             )
+
+    @pytest.mark.parametrize("nnz,rank,rung", [
+        # ML-20M: 3.8 GiB and 6.3 GiB of the estimate's 8 GiB budget
+        (20_000_263, 10, "fused"),
+        (20_000_263, 32, "fused"),
+        (100_000_000, 10, "chunked"),  # 19 GiB
+    ])
+    def test_first_rung_follows_the_estimate(self, nnz, rank, rung):
+        from predictionio_tpu.ops.als import _first_rung
+
+        assert _first_rung(nnz, rank) == rung
+
+    @pytest.mark.parametrize("rank", [4, 10, 20])  # 20 > _SOA_MAX_RANK
+    @pytest.mark.parametrize("implicit", [False, True],
+                             ids=["explicit", "implicit"])
+    @pytest.mark.parametrize("mode,per_iter", [
+        ("fused", False), ("chunked", False), ("chunked", True),
+    ], ids=["fused", "chunked", "chunked_per_iter"])
+    def test_every_rung_trains_what_the_scatter_step_trains(
+        self, pallas_on_cpu, monkeypatch, mode, per_iter, implicit, rank
+    ):
+        """A whole train through each rung of the ladder, under the Pallas
+        interpreter, against the scatter step from the same seed: a
+        fallback that has never run is not a fallback."""
+        from predictionio_tpu.ops import als as als_mod
+
+        rng = np.random.default_rng(28)
+        nu, ni, n = 50, 20, 600
+        ui, ii = rng.integers(0, nu, n), rng.integers(0, ni, n)
+        r = rng.integers(1, 6, n).astype(np.float32)
+        p = ALSParams(rank=rank, num_iterations=3, implicit_prefs=implicit)
+        got = als_mod._train_pallas_mode(
+            ui, ii, r, nu, ni, p, jnp.float32, mode, per_iter
+        )
+        assert als_mod.LAST_PLAN_INFO["mode"] == mode
+        assert als_mod.LAST_PLAN_INFO["per_iter"] is per_iter
+        monkeypatch.setattr(als_mod, "_use_pallas", lambda p: False)
+        want = train_als(ui, ii, r, nu, ni, p)
+        # not bit-equal: the kernel accumulates in two bf16 passes
+        # ("hilo", ~2^-16 relative an entry) in another summation order
+        # than the f32 scatter, and three rounds of solves at reg 0.01
+        # carry that through; a wrong rung is off by O(1), not by 1e-3
+        for side in ("user_factors", "item_factors"):
+            a = np.asarray(getattr(got, side))
+            b = np.asarray(getattr(want, side))
+            assert np.abs(a - b).max() <= RUNG_TOL * np.abs(b).max(), side
 
 
 class TestSolveFactors:

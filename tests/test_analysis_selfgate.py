@@ -382,37 +382,6 @@ def test_trace_assemble_smoke():
         )
 
 
-def test_bench_compare_smoke():
-    """Tier-1 smoke of the perf-regression gate: a synthetic current/prev
-    pair drives `pio bench --compare` through the real CLI — deterministic,
-    CPU-only, no bench run needed.  The full exit contract lives in
-    tests/test_device_obs.py; this anchors the CI-gateable entry point."""
-    import json
-    import tempfile
-
-    from predictionio_tpu.tools.cli import main
-
-    from predictionio_tpu.obs.device import BENCH_SCHEMA_VERSION
-
-    with tempfile.TemporaryDirectory() as tmp:
-        prev = Path(tmp) / "prev.json"
-        cur = Path(tmp) / "cur.json"
-        prev.write_text(
-            json.dumps({"schema_version": BENCH_SCHEMA_VERSION, "value": 5.0})
-            + "\n"
-        )
-        cur.write_text(
-            json.dumps({"schema_version": BENCH_SCHEMA_VERSION, "value": 8.0})
-            + "\n"
-        )
-        assert main(["bench", "--compare", str(prev), str(cur)]) == 1
-        cur.write_text(
-            json.dumps({"schema_version": BENCH_SCHEMA_VERSION, "value": 5.1})
-            + "\n"
-        )
-        assert main(["bench", "--compare", str(prev), str(cur)]) == 0
-
-
 def test_profiler_capture_runs_off_request_thread():
     """PIO-CONC-aware gate for /debug/profile: the profiler module must be
     free of concurrency findings (no busy-waits, no blocking calls hidden in

@@ -1,8 +1,7 @@
 """Device-efficiency observability (obs/device.py): XLA cost capture on the
 CPU backend, peak-table overrides, the recompile-storm detector, the
-MicroBatcher wave-timeline split, /efficiency.json gating, and the
-`pio bench --compare` perf-regression gate — including the acceptance e2e
-on a real (tiny) NCF engine: nonzero achieved-vs-peak utilization from real
+MicroBatcher wave-timeline split and /efficiency.json gating — including
+the acceptance e2e on a real (tiny) NCF engine: nonzero achieved-vs-peak utilization from real
 ``cost_analysis()``, a shape-churning query stream trips
 ``pio_recompile_storm_total`` while stable traffic does not."""
 
@@ -22,11 +21,8 @@ import pytest
 
 from predictionio_tpu.obs import device as device_obs
 from predictionio_tpu.obs.device import (
-    BENCH_SCHEMA_VERSION,
     EfficiencyTracker,
     RecompileTracker,
-    als_plan_roofline,
-    compare_bench,
     device_peaks,
     jit_cost_analysis,
     signature_of,
@@ -517,181 +513,6 @@ class TestScrapeLeavesTheChipAlone:
             "platform": "cpu",
             "initialized": False,
         }
-
-
-# ---------------------------------------------------------------------------
-# ALS plan roofline (the math bench.py now imports)
-
-
-class TestAlsPlanRoofline:
-    PLAN = {
-        "rank": 10,
-        "width": 128,
-        "precision": "hilo",
-        "mode": "fused",
-        "rows_user": 1000,
-        "rows_item": 1000,
-        "blocks_user": 8,
-        "blocks_item": 8,
-        "chunks_user": 1,
-        "chunks_item": 1,
-    }
-
-    def test_fused_plan_math(self):
-        per = als_plan_roofline(self.PLAN)
-        # hand-checked: per side, rows*(2*16*4 + 32 + 4) bytes + 8*128*512
-        expected_gb = 2 * (1000 * 164 + 8 * 128 * 512) / 1e9
-        expected_fl = 2 * (2.0 * 1000 * 128 * 128 * 2) / 1e12
-        assert per["gb_per_iter"] == pytest.approx(expected_gb)
-        assert per["tflop_eq_per_iter"] == pytest.approx(expected_fl)
-
-    def test_chunked_plan_math(self):
-        plan = dict(self.PLAN, mode="chunked")
-        per = als_plan_roofline(plan)
-        expected_gb = 2 * (
-            1000 * (512 + 2 * 512) + 1 * 8 * 128 * 512 * 3
-        ) / 1e9
-        assert per["gb_per_iter"] == pytest.approx(expected_gb)
-
-    def test_incomplete_plan_returns_none(self):
-        assert als_plan_roofline({}) is None
-        assert als_plan_roofline({"width": 128}) is None
-        assert als_plan_roofline(dict(self.PLAN, precision="???")) is None
-
-
-# ---------------------------------------------------------------------------
-# bench compare gate
-
-
-def _bench(v=5.0, **kw):
-    d = {"schema_version": BENCH_SCHEMA_VERSION, "value": v}
-    d.update(kw)
-    return d
-
-
-class TestCompareBench:
-    def test_within_tolerance_exits_zero(self):
-        code, report = compare_bench(_bench(5.2), _bench(5.0), 10.0)
-        assert code == 0 and report["regressions"] == []
-        assert report["checked"] >= 1
-
-    def test_regression_exits_one(self):
-        code, report = compare_bench(_bench(7.0), _bench(5.0), 10.0)
-        assert code == 1
-        assert report["regressions"][0]["metric"] == "value"
-        assert report["regressions"][0]["change_pct"] == pytest.approx(40.0)
-
-    def test_higher_is_better_direction(self):
-        code, report = compare_bench(
-            _bench(5.0, map_at_10=0.02), _bench(5.0, map_at_10=0.03), 10.0
-        )
-        assert code == 1  # quality DROP is the regression
-        assert report["regressions"][0]["metric"] == "map_at_10"
-        # and a quality RISE is an improvement, not a regression
-        code, report = compare_bench(
-            _bench(5.0, map_at_10=0.04), _bench(5.0, map_at_10=0.03), 10.0
-        )
-        assert code == 0
-        assert [i["metric"] for i in report["improvements"]] == ["map_at_10"]
-
-    def test_missing_schema_exits_two(self):
-        code, report = compare_bench({"value": 5.0}, _bench(5.0))
-        assert code == 2 and "schema_version" in report["error"]
-        code, report = compare_bench(_bench(5.0), {"value": 5.0})
-        assert code == 2
-
-    def test_old_schema_exits_two(self):
-        old = {"schema_version": 1, "value": 5.0}
-        assert compare_bench(old, _bench(5.0))[0] == 2
-
-    def test_mismatched_run_configuration_exits_two(self):
-        """A full-scale run gated against a scale-0.1 file would produce a
-        confident 10x 'regression' — the metric key encodes the config and
-        a mismatch is a usage error, not a verdict."""
-        cur = _bench(5.0, metric="als_ml20m_train_time")
-        prev = _bench(0.5, metric="als_ml20m_train_time_scale0.1")
-        code, report = compare_bench(cur, prev)
-        assert code == 2 and "not comparable" in report["error"]
-
-    def test_non_numeric_and_missing_keys_skipped(self):
-        code, report = compare_bench(
-            _bench(5.0, serving_p50_ms="n/a"),
-            _bench(5.0, serving_p50_ms=0.1, ncf_epochs_per_s=3.0),
-            10.0,
-        )
-        assert code == 0  # unparseable/absent metrics are not regressions
-
-
-class TestBenchCompareCLI:
-    """`pio bench --compare` exit contract through the real CLI."""
-
-    def _write(self, tmp_path, name, obj):
-        p = tmp_path / name
-        p.write_text(json.dumps(obj) + "\n")
-        return str(p)
-
-    def _run(self, argv):
-        from predictionio_tpu.tools.cli import main
-
-        return main(argv)
-
-    def test_within_tolerance_exit_zero(self, tmp_path, capsys):
-        prev = self._write(tmp_path, "prev.json", _bench(5.0))
-        cur = self._write(tmp_path, "cur.json", _bench(5.2))
-        assert self._run(["bench", "--compare", prev, cur]) == 0
-        out = json.loads(capsys.readouterr().out)
-        assert out["regressions"] == []
-
-    def test_regression_exit_one(self, tmp_path, capsys):
-        prev = self._write(tmp_path, "prev.json", _bench(5.0))
-        cur = self._write(tmp_path, "cur.json", _bench(9.0))
-        assert self._run(["bench", "--compare", prev, cur]) == 1
-        assert "PERF REGRESSION" in capsys.readouterr().err
-
-    def test_tolerance_flag_loosens_the_gate(self, tmp_path):
-        prev = self._write(tmp_path, "prev.json", _bench(5.0))
-        cur = self._write(tmp_path, "cur.json", _bench(6.0))  # +20%
-        assert self._run(["bench", "--compare", prev, cur]) == 1
-        assert (
-            self._run(
-                ["bench", "--compare", prev, cur, "--tolerance", "25"]
-            )
-            == 0
-        )
-
-    def test_versionless_previous_exit_two(self, tmp_path):
-        prev = self._write(tmp_path, "prev.json", {"value": 5.0})
-        cur = self._write(tmp_path, "cur.json", _bench(5.0))
-        assert self._run(["bench", "--compare", prev, cur]) == 2
-
-    def test_unreadable_file_exit_two(self, tmp_path):
-        cur = self._write(tmp_path, "cur.json", _bench(5.0))
-        assert (
-            self._run(
-                ["bench", "--compare", str(tmp_path / "missing.json"), cur]
-            )
-            == 2
-        )
-
-    def test_garbage_file_exit_two(self, tmp_path):
-        prev = self._write(tmp_path, "prev.json", _bench(5.0))
-        garbage = tmp_path / "garbage.json"
-        garbage.write_text("not json at all\n")
-        assert (
-            self._run(["bench", "--compare", prev, str(garbage)]) == 2
-        )
-
-    def test_log_noise_around_the_json_line_is_tolerated(self, tmp_path):
-        """bench.py output redirected to a file can carry stray lines;
-        the LAST parseable JSON object wins."""
-        prev = self._write(tmp_path, "prev.json", _bench(5.0))
-        noisy = tmp_path / "noisy.json"
-        noisy.write_text(
-            "# platform=cpu devices=1\n"
-            + json.dumps(_bench(5.1))
-            + "\n"
-        )
-        assert self._run(["bench", "--compare", prev, str(noisy)]) == 0
 
 
 # ---------------------------------------------------------------------------

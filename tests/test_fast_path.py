@@ -769,7 +769,7 @@ class TestPipelinedServingE2E:
 
 
 # ---------------------------------------------------------------------------
-# hotpath overlap accounting + bench gate directions
+# hotpath overlap accounting
 
 
 class TestOverlapAccounting:
@@ -784,43 +784,3 @@ class TestOverlapAccounting:
         snap = t.snapshot()
         assert snap["coverage_frac"] == 1.0  # clamped, never 1.5
         assert snap["overlap_frac"] == pytest.approx(0.5)
-
-    def test_bench_gate_directions_for_new_metrics(self):
-        from predictionio_tpu.obs.device import (
-            BENCH_SCHEMA_VERSION,
-            compare_bench,
-        )
-
-        def line(**kw):
-            return {
-                "schema_version": BENCH_SCHEMA_VERSION,
-                "metric": "m",
-                **kw,
-            }
-
-        # solo e2e regressing (higher) trips the gate
-        code, report = compare_bench(
-            line(serving_solo_e2e_p50_ms=2.0),
-            line(serving_solo_e2e_p50_ms=1.0),
-        )
-        assert code == 1
-        assert report["regressions"][0]["metric"] == "serving_solo_e2e_p50_ms"
-        # hit rate regressing (lower) trips the gate
-        code, report = compare_bench(
-            line(factor_cache_hit_rate=0.2), line(factor_cache_hit_rate=0.9)
-        )
-        assert code == 1
-        # both improving: clean pass
-        code, _ = compare_bench(
-            line(
-                serving_solo_e2e_p50_ms=0.5,
-                factor_cache_hit_rate=0.95,
-                fused_topk_hbm_utilization_frac=0.3,
-            ),
-            line(
-                serving_solo_e2e_p50_ms=5.0,
-                factor_cache_hit_rate=0.5,
-                fused_topk_hbm_utilization_frac=0.1,
-            ),
-        )
-        assert code == 0

@@ -889,7 +889,7 @@ class TestRouterTraceLane:
 
         import numpy as np
 
-        from bench import _SERVER_SCRIPT
+        from serving_fixture import _SERVER_SCRIPT
         from predictionio_tpu.obs import timeline as tlm
 
         blob = tmp_path / "m.npz"

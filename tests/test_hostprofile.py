@@ -921,7 +921,7 @@ class TestCLIVerbs:
 def _bench_style_deployed():
     """A real DeployedEngine over the ALS recommendation template, no
     storage daemon — the bench serving topology."""
-    from bench import build_als_model
+    from serving_fixture import build_als_model
     from predictionio_tpu.core.base import FirstServing
     from predictionio_tpu.models.recommendation.engine import ALSAlgorithm
     from predictionio_tpu.server.prediction_server import DeployedEngine

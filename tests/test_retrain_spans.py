@@ -7,7 +7,6 @@ with each other (children against their parent)."""
 
 from __future__ import annotations
 
-import functools
 import logging
 import subprocess
 import sys
@@ -135,22 +134,6 @@ def _retrain(storage, devices=1):
         t for t in recent_traces(5) if t.get("request_id") == inst.id
     )
     return seen.stages, root
-
-
-@pytest.fixture()
-def pallas_on_cpu(monkeypatch):
-    """The chip's train path under the Pallas interpreter: the steering a
-    CPU test needs lives here, not in an option of the program."""
-    monkeypatch.setattr(als, "_use_pallas", lambda p: True)
-    monkeypatch.setattr(
-        als_pallas, "segment_stats_fused",
-        functools.partial(als_pallas.segment_stats_fused, interpret=True),
-    )
-    als._STEP_CACHE.clear()
-    als._STAGE_CACHE.clear()
-    yield
-    als._STEP_CACHE.clear()
-    als._STAGE_CACHE.clear()
 
 
 def _walk(node, depth=0):
@@ -473,6 +456,25 @@ def test_staged_streams_reused_upload_nothing(pallas_on_cpu):
     assert als.LAST_PLAN_INFO["iterations"] == 2
     names = {c.name for c in root.children}
     assert "als.fingerprint" in names and "als.stage" not in names
+
+
+def test_pallas_train_puts_no_host_clock_on_the_roofline(
+    parquet_storage, pallas_on_cpu, caplog
+):
+    """The Pallas kernel's share of the roofline is the benchmark's, read
+    off the device trace: the running process publishes none from the host
+    clock of ``als.device_loop``.  What names the path and what the
+    benchmark's plan reader takes stay."""
+    from predictionio_tpu.obs.device import default_efficiency
+
+    with caplog.at_level(logging.INFO, "predictionio_tpu.ops.als"):
+        _retrain(parquet_storage)
+    assert "als.pallas_step" not in default_efficiency().snapshot()["functions"]
+    (record,) = [r for r in caplog.records if hasattr(r, "als_path")]
+    assert record.als_path == "als.pallas_step"
+    assert {"loop_s", "stage_s", "upload_bytes", "mode"} <= set(
+        als.LAST_PLAN_INFO
+    )
 
 
 def test_breakdown_keeps_stage_values_and_takes_the_longest_thread():

@@ -653,68 +653,6 @@ class TestMicroBatcherSharded:
 
 
 # ---------------------------------------------------------------------------
-# bench gate: the sharded section's config-mismatch handling
-
-
-class TestBenchShardedGate:
-    def test_device_count_mismatch_refused(self):
-        from predictionio_tpu.obs.device import (
-            BENCH_SCHEMA_VERSION,
-            compare_bench,
-        )
-
-        base = {
-            "schema_version": BENCH_SCHEMA_VERSION,
-            "metric": "m",
-            "value": 1.0,
-        }
-        code, report = compare_bench(
-            {**base, "sharded_devices": 8}, {**base, "sharded_devices": 2}
-        )
-        assert code == 2 and "sharded_devices" in report["error"]
-        # same count, chips vs the virtual CPU mesh: not the same measurement
-        code, report = compare_bench(
-            {**base, "sharded_devices": 4, "sharded_platform": "tpu"},
-            {**base, "sharded_devices": 4, "sharded_platform": "cpu"},
-        )
-        assert code == 2 and "sharded_platform" in report["error"]
-        # absent on both (no sharded section): not a mismatch
-        code, _ = compare_bench(dict(base), dict(base))
-        assert code == 0
-
-    def test_accelerator_parent_short_of_devices_raises(self, monkeypatch):
-        """A chip run never fills sharded_* from a CPU child: with fewer
-        than N accelerator devices the section fails."""
-        import types
-
-        import bench
-
-        chip = types.SimpleNamespace(platform="tpu")
-        monkeypatch.setattr(jax, "devices", lambda *a: [chip])
-        with pytest.raises(RuntimeError, match="holds 1 tpu device"):
-            bench.bench_sharded_section(4, 0.01)
-
-    def test_sharded_metrics_are_gated(self):
-        from predictionio_tpu.obs.device import (
-            BENCH_SCHEMA_VERSION,
-            compare_bench,
-        )
-
-        base = {
-            "schema_version": BENCH_SCHEMA_VERSION,
-            "metric": "m",
-            "value": 1.0,
-            "sharded_devices": 8,
-        }
-        code, report = compare_bench(
-            {**base, "sharded_train_s": 5.0},
-            {**base, "sharded_train_s": 4.0},
-        )
-        assert code == 1
-        assert report["regressions"][0]["metric"] == "sharded_train_s"
-
-
-# ---------------------------------------------------------------------------
 # generation-manifest round trip (per-part checksums + ShardPlan + fallback)
 
 
