@@ -491,13 +491,17 @@ def _train_pallas_mode(user_idx, item_idx, rating, num_users, num_items,
     num_items_pad = max((num_items + 127) // 128 * 128, 128)
 
     def stage(side, seg, oth, num_seg_pad, num_oth_pad, parent):
-        """One scatter direction, on a pool thread: plan (sorts), permute,
-        upload — each a child span of ``parent`` (``als.stage``)."""
+        """One scatter direction, on a pool thread: plan (radix order +
+        block layout), permute, upload — each a child span of ``parent``
+        (``als.stage``)."""
         with trace("als.stage.plan", parent=parent) as span:
-            span.tags = {"side": side}
-            plan = als_pallas.build_plan(
-                np.asarray(seg, np.int64), num_seg_pad
-            )
+            span.tags = {
+                "side": side,
+                "rows": len(seg),
+                "sort_passes": als_pallas.radix_passes(num_seg_pad),
+            }
+            plan = als_pallas.build_plan(np.asarray(seg), num_seg_pad)
+            span.tags["padded_rows"] = plan.padded_len
             if mode == "fused":
                 # [nt, T], minor dim 1024: layout-clean on device (no
                 # T(8,128) minor-dim padding possible)
@@ -556,8 +560,9 @@ def _train_pallas_mode(user_idx, item_idx, rating, num_users, num_items,
         # while uploading the new ones would transiently double HBM use
         _STAGE_CACHE.clear()
         # the two scatter directions stage concurrently: the work is
-        # numpy radix sorts + permutes (GIL-released), so two threads
-        # nearly halve the cold-train host staging wall time
+        # numpy sorts (a radix sort on 16-bit digits: als_pallas._stable_order)
+        # + copies + permutes (GIL-released), so two threads nearly halve
+        # the cold-train host staging wall time
         from concurrent.futures import ThreadPoolExecutor
 
         with trace("als.stage") as stage_span:

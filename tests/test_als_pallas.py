@@ -40,6 +40,167 @@ def test_out_of_range_segment_rejected():
         ap.build_plan(np.array([-1, 5]), 384)
 
 
+def _reference_plan(seg: np.ndarray, num_seg_pad: int) -> ap.SegmentPlan:
+    """``build_plan`` as it stood until PR 29, after its input checks (a
+    32-bit merge sort, then gathers and scatters through the order): the
+    plan the linear-time one has to return, element for element and dtype
+    for dtype."""
+    S, T = ap.S, ap.T
+    order = np.argsort(seg.astype(np.int32), kind="stable")
+    seg_sorted = seg[order]
+    n_blocks = num_seg_pad // S
+    blk = seg_sorted // S
+    counts = np.bincount(blk, minlength=n_blocks)
+    padded_counts = np.maximum((counts + T - 1) // T * T, T)
+    starts = np.concatenate([[0], np.cumsum(padded_counts)[:-1]])
+    P = int(padded_counts.sum())
+    within = np.arange(len(seg)) - np.concatenate(
+        [[0], np.cumsum(counts)[:-1]]
+    )[blk]
+    dest = starts[blk] + within
+    seg_local = np.full(P, -1, np.int32)
+    seg_local[dest] = (seg_sorted - blk * S).astype(np.int32)
+    nt = P // T
+    block_map = np.repeat(
+        np.arange(n_blocks, dtype=np.int32), padded_counts // T
+    )
+    first = np.zeros(nt, np.int32)
+    first[starts // T] = 1
+    dest_perm = np.zeros(P, np.int64)
+    dest_perm[dest] = order
+    return ap.SegmentPlan(
+        seg3=seg_local.reshape(nt, T // 128, 128),
+        dest_perm=dest_perm,
+        pad_mask=seg_local < 0,
+        block_map=block_map,
+        first=first,
+        n_blocks=n_blocks,
+        n_tiles=nt,
+        padded_len=P,
+    )
+
+
+def _uniform(rng, n, num_seg_pad):
+    return rng.integers(0, num_seg_pad, n)
+
+
+def _zipf(rng, n, num_seg_pad):
+    # a few heavy segments scattered over the id range, a long thin tail
+    ids = rng.permutation(num_seg_pad)
+    return ids[np.minimum(rng.zipf(1.3, n) - 1, num_seg_pad - 1)]
+
+
+def _one_segment(rng, n, num_seg_pad):
+    return np.full(n, num_seg_pad - 3)
+
+
+def _exact_tiles(rng, n, num_seg_pad):
+    # the last block holds exactly 2 T rows (no padding slot in it), the
+    # first T + 1 (one row into its second tile); every block between them
+    # is empty
+    seg = np.concatenate([
+        rng.integers(num_seg_pad - ap.S, num_seg_pad, 2 * ap.T),
+        rng.integers(0, ap.S, ap.T + 1),
+    ])
+    return seg[rng.permutation(len(seg))]
+
+
+def _no_rows(rng, n, num_seg_pad):
+    return np.zeros(0, np.int64)
+
+
+def _as_int32(seg):
+    return seg.astype(np.int32)
+
+
+def _as_int64(seg):
+    return seg.astype(np.int64)
+
+
+def _as_strided_view(seg):
+    # every other element of a buffer twice as long: not contiguous
+    buf = np.zeros(2 * len(seg), np.int32)
+    buf[::2] = seg
+    view = buf[::2]
+    assert len(view) < 2 or not view.flags.c_contiguous
+    return view
+
+
+@pytest.mark.parametrize("given", [_as_int32, _as_int64, _as_strided_view])
+@pytest.mark.parametrize(
+    "ids", [_uniform, _zipf, _one_segment, _exact_tiles, _no_rows]
+)
+@pytest.mark.parametrize("num_seg_pad", [384, 65_536, 65_664, 1_000_064])
+def test_plan_equals_the_sorted_and_gathered_plan(
+    monkeypatch, num_seg_pad, ids, given
+):
+    """The plan laid out from counts and a 16-bit radix order IS the plan
+    the 32-bit sort and the gathers through its order gave: every field,
+    values and dtypes, with ids on both sides of the 16-bit digit (65,664),
+    blocks with no rows (4,000 rows over up to 7,813 blocks), a block whose
+    count is a multiple of ``T``, and no rows at all."""
+    # the counts come a slice of rows at a time: three slices here, the
+    # last one short
+    monkeypatch.setattr(ap, "_COUNT_ROWS", 1500)
+    rng = np.random.default_rng(num_seg_pad)
+    seg = given(ids(rng, 4000, num_seg_pad))
+    plan = ap.build_plan(seg, num_seg_pad)
+    want = _reference_plan(seg, num_seg_pad)
+    for field in ("seg3", "dest_perm", "pad_mask", "block_map", "first"):
+        got, ref = getattr(plan, field), getattr(want, field)
+        assert got.dtype == ref.dtype, field
+        assert np.array_equal(got, ref), field  # shapes too
+    for field in ("n_blocks", "n_tiles", "padded_len"):
+        got, ref = getattr(plan, field), getattr(want, field)
+        assert type(got) is type(ref) and got == ref, field
+
+
+@pytest.mark.parametrize(
+    "num_seg_pad, passes",
+    [(128, 1), (65_536, 1), (65_664, 2), (1_000_064, 2), (1 << 31, 2)],
+)
+def test_radix_passes_follow_the_id_width(num_seg_pad, passes):
+    assert ap.radix_passes(num_seg_pad) == passes
+
+
+@pytest.mark.parametrize("num_users, passes", [(65_536, 1), (65_664, 2)])
+def test_plan_span_says_rows_and_sort_passes(pallas_on_cpu, num_users, passes):
+    """``als.stage.plan`` carries what the plan did on each side: the rows
+    it laid out, the slots they take, and the 16-bit sorts the order cost
+    (one up to 65,536 segments, two above)."""
+    from predictionio_tpu.obs.tracing import trace
+    from predictionio_tpu.ops import als
+
+    rng = np.random.default_rng(4)
+    n, num_items = 600, 20
+    u = rng.integers(0, num_users, n).astype(np.int32)
+    u[0] = num_users - 1  # a row on the far side of the 16-bit digit
+    i = rng.integers(0, num_items, n).astype(np.int32)
+    r = rng.random(n).astype(np.float32)
+    with trace("train") as root:
+        als.train_als(
+            u, i, r, num_users, num_items,
+            als.ALSParams(rank=2, num_iterations=1),
+        )
+    (stage,) = [c for c in root.children if c.name == "als.stage"]
+    tags = {
+        c.tags["side"]: c.tags
+        for c in stage.children if c.name == "als.stage.plan"
+    }
+    assert tags == {
+        "user": {
+            "side": "user", "rows": n, "sort_passes": passes,
+            "padded_rows": ap.build_plan(u, num_users).padded_len,
+        },
+        "item": {
+            "side": "item", "rows": n, "sort_passes": 1,
+            "padded_rows": ap.build_plan(i, 128).padded_len,
+        },
+    }
+    assert als.LAST_PLAN_INFO["rows_user"] == tags["user"]["padded_rows"]
+    assert als.LAST_PLAN_INFO["rows_item"] == tags["item"]["padded_rows"]
+
+
 def _accum_vs_numpy(precision):
     rng = np.random.default_rng(1)
     n, nseg = 5000, 256
