@@ -5,11 +5,14 @@ order (the other templates drop order: a rating is a rating), the Preparator
 turns them into rows of tokens — item vocabulary in first-seen order,
 histories cut to their most recent ``maxLen`` events and packed first-fit
 decreasing into rows of ``rowLen`` with segment ids — and the algorithm
-trains the hybrid block of ``ops/seqmodel.py`` (gated delta-rule linear
-attention among full attention; Olmo-Hybrid's layer, config.json at
-huggingface.co/allenai/Olmo-Hybrid-7B) by next-item cross-entropy with AdamW:
-``stepsPerRetrain`` optimiser steps of ``rowsPerStep`` rows, one pass in packed
-order, from a seeded initialisation.
+trains one of the hybrid blocks of ``ops/seqmodel.py`` — gated delta-rule
+linear attention among full attention (Olmo-Hybrid's layer, config.json at
+huggingface.co/allenai/Olmo-Hybrid-7B), or Mamba-2 state-space heads beside
+grouped-query attention on one normed input (Falcon-H1's,
+huggingface.co/tiiuae/Falcon-H1-34B-Instruct) — by next-item cross-entropy
+with AdamW: ``stepsPerRetrain`` optimiser steps of ``rowsPerStep`` rows, one
+pass in packed order, from a seeded initialisation.  ``layerTypes`` says
+which block; each kind reads its own sizes.
 
 The persisted model is the float32 weights, the vocabulary, the histories
 (for serving) and a small training record.  ``predict`` answers ``{user,
@@ -273,8 +276,14 @@ class SequencePreparator(Preparator):
 
 @dataclass(frozen=True)
 class SequenceAlgorithmParams:
-    """Widths as published; head, column and row counts as HELD (defaults:
-    one of two chips' share of the Olmo-Hybrid-7B layer, one period deep)."""
+    """Widths as published; head, group, column and row counts as HELD
+    (defaults: one of two chips' share of the Olmo-Hybrid-7B layer, one period
+    deep).  ``layer_types`` names each layer's kind, and the kind fixes the
+    block's form: ``linear_attention`` / ``full_attention`` (post-norm, one
+    mixer a layer; the ``linear_*`` sizes, ``num_attention_heads``) or
+    ``parallel_ssm_attention`` (pre-norm, a state-space and an attention mixer
+    side by side: the ``mamba_*`` sizes, ``num_key_value_heads``,
+    ``rope_theta`` and muP's forward multipliers, 1 where a model has none)."""
 
     hidden_size: int = 3840
     layer_types: tuple[str, ...] = (
@@ -300,8 +309,47 @@ class SequenceAlgorithmParams:
     adam_eps: float = 1e-8
     weight_decay: float = 0.1
     seed: int = 3
+    #: the parallel block: KV heads held (None: one a query head), rotary base
+    num_key_value_heads: int | None = None
+    rope_theta: float = 10000.0
+    #: its state space: heads and B / C groups held, a head's channels, the
+    #: state's size, the convolution's width, the chunk of the scan
+    mamba_n_heads: int = 0
+    mamba_n_groups: int = 1
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_d_conv: int = 4
+    mamba_chunk_size: int = 128
+    #: muP's forward multipliers, by their published names
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_multipliers: tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
+    ssm_out_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    #: gate, down
+    mlp_multipliers: tuple[float, ...] = (1.0, 1.0)
 
     params_aliases = {
+        "numKeyValueHeads": "num_key_value_heads",
+        "ropeTheta": "rope_theta",
+        "mambaNHeads": "mamba_n_heads",
+        "mambaNGroups": "mamba_n_groups",
+        "mambaDHead": "mamba_d_head",
+        "mambaDState": "mamba_d_state",
+        "mambaDConv": "mamba_d_conv",
+        "mambaChunkSize": "mamba_chunk_size",
+        "embeddingMultiplier": "embedding_multiplier",
+        "lmHeadMultiplier": "lm_head_multiplier",
+        "ssmInMultiplier": "ssm_in_multiplier",
+        "ssmMultipliers": "ssm_multipliers",
+        "ssmOutMultiplier": "ssm_out_multiplier",
+        "attentionInMultiplier": "attention_in_multiplier",
+        "attentionOutMultiplier": "attention_out_multiplier",
+        "keyMultiplier": "key_multiplier",
+        "mlpMultipliers": "mlp_multipliers",
         "hiddenSize": "hidden_size",
         "layerTypes": "layer_types",
         "numAttentionHeads": "num_attention_heads",
@@ -332,9 +380,11 @@ class SequenceModel:
     history_offsets: np.ndarray
     history_tokens: np.ndarray
     #: per step: loss, tokens, grad_norm; per tensor and step:
-    #: tensor_grad_norm, tensor_grad_probe; delta_rule_probe [rows a step,
-    #: row_len, heads]: the first layer's delta-rule output along a seeded
-    #: vector, for the first step's rows (``ops/seqmodel.trunk``)
+    #: tensor_grad_norm, tensor_grad_probe; and the first layer's recurrence
+    #: along a seeded vector for the first step's rows [rows a step, row_len,
+    #: heads] (``ops/seqmodel.trunk``), under the name of its kind
+    #: (``seqmodel.PROBE_NAME``): delta_rule_probe (the delta rule's output)
+    #: or ssd_probe (the state space's ``S_t C_t``)
     training_record: dict
     config: Any = None
 
@@ -352,9 +402,10 @@ class SequenceAlgorithm(Algorithm):
         self.params = params or SequenceAlgorithmParams()
 
     def seq_config(self):
-        from predictionio_tpu.ops.seqmodel import SeqConfig
+        from predictionio_tpu.ops.seqmodel import MuP, SeqConfig
 
         p = self.params
+        gate, down = p.mlp_multipliers
         return SeqConfig(
             hidden=p.hidden_size, layer_types=tuple(p.layer_types),
             heads=p.num_attention_heads, head_dim=p.head_dim,
@@ -363,6 +414,18 @@ class SequenceAlgorithm(Algorithm):
             conv_width=p.linear_conv_kernel_dim, mlp_cols=p.intermediate_size,
             vocab_rows=p.vocab_size, vocab_start=p.vocab_start,
             eps=p.rms_norm_eps, neg_eigval=p.linear_allow_neg_eigval,
+            kv_heads=p.num_key_value_heads, rope_theta=p.rope_theta,
+            ssm_heads=p.mamba_n_heads, ssm_head_dim=p.mamba_d_head,
+            ssm_state=p.mamba_d_state, ssm_groups=p.mamba_n_groups,
+            ssm_conv_width=p.mamba_d_conv, ssm_chunk=p.mamba_chunk_size,
+            mup=MuP(
+                embedding=p.embedding_multiplier, lm_head=p.lm_head_multiplier,
+                ssm_in=p.ssm_in_multiplier, ssm_zones=tuple(p.ssm_multipliers),
+                ssm_out=p.ssm_out_multiplier,
+                attention_in=p.attention_in_multiplier,
+                attention_out=p.attention_out_multiplier, key=p.key_multiplier,
+                mlp_gate=gate, mlp_down=down,
+            ),
         )
 
     def train(self, ctx: EngineContext, pd: PackedSequences) -> SequenceModel:
@@ -407,12 +470,14 @@ class SequenceAlgorithm(Algorithm):
             span.tags = {
                 "steps": p.steps_per_retrain, "rows": need,
                 "tokens": int((pd.segments[:need] != PAD_SEGMENT).sum()),
+                "block": "+".join(dict.fromkeys(cfg.layer_types)),
             }
         with trace("seq.fetch") as span:
             params = {k: np.asarray(v) for k, v in state["params"].items()}
             record = jax.tree.map(
                 lambda *xs: np.stack([np.asarray(x) for x in xs]), *records)
-            record["delta_rule_probe"] = np.stack([np.asarray(x) for x in probes])
+            record[seqmodel.PROBE_NAME[cfg.layer_types[0]]] = np.stack(
+                [np.asarray(x) for x in probes])
             span.tags = {"bytes": int(sum(v.nbytes for v in params.values()))}
             del state
         log.info(
@@ -457,7 +522,7 @@ class SequenceAlgorithm(Algorithm):
                 continue
             hist = model.history_tokens[
                 model.history_offsets[e] : model.history_offsets[e + 1]]
-            length = max(1 << (len(hist) - 1).bit_length(), cfg.chunk)
+            length = max(1 << (len(hist) - 1).bit_length(), cfg.token_multiple)
             tokens = np.zeros((1, length), np.int32)
             seg = np.full((1, length), PAD_SEGMENT, np.int32)
             tokens[0, : len(hist)] = hist
@@ -465,6 +530,8 @@ class SequenceAlgorithm(Algorithm):
             h = seqmodel.last_hidden(
                 cfg, params, jnp.asarray(tokens), jnp.asarray(seg),
                 jnp.asarray([len(hist) - 1], jnp.int32))
+            if cfg.mup.lm_head != 1.0:
+                h = h * cfg.mup.lm_head
             pending.append(
                 (i, fused_topk_batch(h, params["head"], k, limit=n_items)))
         for i, packed in _fetch_winners(pending):
@@ -525,6 +592,7 @@ def sequence_engine() -> Engine:
     return Engine(
         SequenceDataSource,
         SequencePreparator,
-        {"gdn": SequenceAlgorithm},
+        # one algorithm under the name of each block's recurrence
+        {"gdn": SequenceAlgorithm, "ssd": SequenceAlgorithm},
         FirstServing,
     )

@@ -1,8 +1,9 @@
-"""A hybrid sequence model: gated delta-rule linear attention among full
-attention (the Olmo-Hybrid block), trainable on packed rows of tokens.
+"""Hybrid sequence models, trainable on packed rows of tokens: two blocks.
 
-Layers follow ``layer_types`` (``"linear_attention"`` / ``"full_attention"``),
-each a token mixer and a SwiGLU MLP with the OLMo 2/3 residual form
+Layers follow ``layer_types``; the block's FORM is a property of the kind.
+
+``"linear_attention"`` / ``"full_attention"`` (the Olmo-Hybrid block): one
+token mixer and a SwiGLU MLP a layer with the OLMo 2/3 residual form
 ``x + RMSNorm(f(x))``:
 
 * linear attention: q, k, v projections, each through a causal depthwise
@@ -15,6 +16,20 @@ each a token mixer and a SwiGLU MLP with the OLMo 2/3 residual form
   ``jax.experimental.pallas.ops.tpu.flash_attention`` (no T x T score matrix),
   elsewhere its ``jax.numpy`` form.
 
+``"parallel_ssm_attention"`` (the Falcon-H1 block): pre-norm, and TWO mixers
+that read one normed input ``h = RMSNorm(x)``, their outputs scaled and
+summed, ``x + ssm(h) + attn(h)``, then ``x + mlp(RMSNorm(x))``; muP's forward
+multipliers (``MuP``) where the published model has them:
+
+* state-space mixer: one projection to ``z | x | B | C | dt`` (each zone
+  times its multiplier), a causal depthwise convolution with a bias and SiLU
+  over ``x | B | C``, ``Delta = softplus(dt + dt_bias)``, Mamba-2's selective
+  state space in its chunked form (``ops/ssd.py``) with the ``D`` skip,
+  ``RMSNorm(y * SiLU(z))`` over each group's channels, output projection.
+* attention mixer: grouped-query attention (each KV head repeated for its
+  query heads into the same attention kernel), rotary positions over the
+  whole head that RESTART at every segment of a packed row, no q / k norm.
+
 **The share.**  A deployment divides every layer over ``chips`` chips; this
 process holds one share of it: ``heads`` of the attention heads, ``mlp_cols``
 of the MLP's columns, ``vocab_rows`` rows of the embedding and the head,
@@ -22,6 +37,10 @@ starting at ``vocab_start``.  Every function computes the part of the result
 its share gives (the sum over its heads of ``o_h W_o[h]``, the sum over its
 MLP columns, logits and loss over its vocabulary rows; an id outside its rows
 embeds to zero).  Nothing stands in for the other shares or their exchange.
+One statistic of the parallel block crosses chips, the gated norm's mean
+square (a group's channels may lie on several chips): ``gated_group_norm``
+takes the mapped axis to reduce it over, ``None`` on one chip, where the mean
+is over the channels held.
 
 **Precision.**  Master weights, gradients and Adam moments float32.  The large
 matrix products take bfloat16 inputs and accumulate in float32, in the forward
@@ -32,8 +51,9 @@ convolution, the decay projections and everything of the delta rule float32.
 the step one at a time (forward, per-layer recomputation, backward; gradients
 accumulated in place) and then AdamW; buffers are donated from program to
 program and nothing returns to the host between steps.  Each row also hands
-back the first layer's delta-rule output along a seeded vector (``trunk``):
-what the training record holds the rule's state precision by.
+back the first layer's recurrence (the delta rule's output, the state
+space's ``S_t C_t``) along a seeded vector (``trunk``): what the training
+record holds the carried state's precision by.
 """
 
 from __future__ import annotations
@@ -46,12 +66,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from predictionio_tpu.ops import gdn
+from predictionio_tpu.ops import gdn, ssd
 
 HIGHEST = jax.lax.Precision.HIGHEST
 
 LINEAR = "linear_attention"
 FULL = "full_attention"
+PARALLEL = "parallel_ssm_attention"
+
+#: what the training record calls the first layer's probe, by its kind
+PROBE_NAME = {LINEAR: "delta_rule_probe", FULL: "delta_rule_probe",
+              PARALLEL: "ssd_probe"}
 
 #: segment id of a row's padding (real segments count from 0)
 PAD_SEGMENT = -1
@@ -63,8 +88,40 @@ MATMUL_DTYPE = jnp.bfloat16
 
 
 @dataclasses.dataclass(frozen=True)
+class MuP:
+    """muP's forward multipliers as a published config carries them (Falcon-H1:
+    twelve in a layer, five of them a vector over the zones of the state-space
+    projection's output, and two at the embedding and the head).  1 changes
+    nothing, and then nothing is multiplied."""
+
+    embedding: float = 1.0
+    lm_head: float = 1.0
+    ssm_in: float = 1.0
+    #: the zones z, x, B, C, dt of the state-space projection's output
+    ssm_zones: tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
+    ssm_out: float = 1.0
+    attention_in: float = 1.0
+    attention_out: float = 1.0
+    key: float = 1.0
+    mlp_gate: float = 1.0
+    mlp_down: float = 1.0
+
+    def __post_init__(self):
+        object.__setattr__(self, "ssm_zones", tuple(float(m) for m in self.ssm_zones))
+        if len(self.ssm_zones) != 5:
+            raise ValueError("ssm_zones multiplies the five zones z, x, B, C, dt")
+
+
+def _scaled(x, m: float):
+    return x if m == 1.0 else x * m
+
+
+@dataclasses.dataclass(frozen=True)
 class SeqConfig:
-    """Widths as published, counts as HELD by this share."""
+    """Widths as published, counts as HELD by this share.  The ``lin_*``
+    sizes are read by ``"linear_attention"`` layers, ``heads`` / ``head_dim``
+    by both attention mixers, ``kv_heads``, ``rope_theta``, ``ssm_*`` and
+    ``mup`` by ``"parallel_ssm_attention"`` layers."""
 
     hidden: int
     layer_types: tuple[str, ...]
@@ -90,12 +147,40 @@ class SeqConfig:
     gdn_impl: str | None = None
     #: full attention: None = by backend; "dense" = jax.numpy
     attn_impl: str | None = None
+    #: KV heads held by the parallel block's attention (None: one a query head)
+    kv_heads: int | None = None
+    #: base of its rotary positions
+    rope_theta: float = 10000.0
+    #: state-space heads held, a head's channels, the state's size, the B / C
+    #: groups held (the heads divide evenly over them)
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv_width: int = 4
+    ssm_chunk: int = 128
+    #: sequential pass of the state space: None = by backend (ops/ssd.py)
+    ssm_impl: str | None = None
+    mup: MuP = MuP()
 
     def __post_init__(self):
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
-        bad = set(self.layer_types) - {LINEAR, FULL}
+        bad = set(self.layer_types) - {LINEAR, FULL, PARALLEL}
         if bad:
             raise ValueError(f"unknown layer types {sorted(bad)}")
+        if PARALLEL in self.layer_types:
+            if not (self.ssm_heads and self.ssm_head_dim and self.ssm_state):
+                raise ValueError(f"{PARALLEL} layers need the ssm_* sizes")
+            if self.ssm_heads % self.ssm_groups or self.heads % (
+                    self.kv_heads or self.heads):
+                raise ValueError("heads do not divide over their groups")
+
+    @property
+    def token_multiple(self) -> int:
+        """Row lengths are multiples of this (the recurrences' chunks)."""
+        return math.lcm(*(
+            self.ssm_chunk if kind == PARALLEL else self.chunk
+            for kind in self.layer_types))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -107,8 +192,9 @@ class AdamW:
     weight_decay: float = 0.1
 
 
-#: tensors AdamW does not decay: norms, the decay's parameters, the convolution
-NO_DECAY = ("norm", "a_log", "dt_bias", "conv")
+#: tensors AdamW does not decay: norms, the decays' parameters, the
+#: convolutions with their bias, the state space's skip
+NO_DECAY = ("norm", "a_log", "dt_bias", "conv", "ssm_d")
 
 
 def decays(name: str) -> bool:
@@ -138,6 +224,26 @@ def param_shapes(cfg: SeqConfig) -> dict[str, tuple[int, ...]]:
                 p + "a_log": (cfg.lin_heads,), p + "dt_bias": (cfg.lin_heads,),
                 p + "o_norm": (cfg.lin_value_dim,), p + "o": (vv, D),
             })
+        elif kind == PARALLEL:
+            ch = cfg.ssm_heads * cfg.ssm_head_dim
+            bc = cfg.ssm_groups * cfg.ssm_state
+            shapes.update({
+                p + "input_norm": (D,),
+                p + "ssm_in": (D, 2 * ch + 2 * bc + cfg.ssm_heads),
+                p + "ssm_conv": (cfg.ssm_conv_width, ch + 2 * bc),
+                p + "ssm_conv_bias": (ch + 2 * bc,),
+                p + "ssm_a_log": (cfg.ssm_heads,), p + "ssm_d": (cfg.ssm_heads,),
+                p + "ssm_dt_bias": (cfg.ssm_heads,), p + "ssm_norm": (ch,),
+                p + "ssm_out": (ch, D),
+                p + "q": (D, cfg.heads * cfg.head_dim),
+                p + "k": (D, (cfg.kv_heads or cfg.heads) * cfg.head_dim),
+                p + "v": (D, (cfg.kv_heads or cfg.heads) * cfg.head_dim),
+                p + "o": (cfg.heads * cfg.head_dim, D),
+                p + "pre_ff_norm": (D,),
+                p + "gate": (D, cfg.mlp_cols), p + "up": (D, cfg.mlp_cols),
+                p + "down": (cfg.mlp_cols, D),
+            })
+            continue
         else:
             hd = cfg.heads * cfg.head_dim
             shapes.update({
@@ -157,14 +263,16 @@ def param_shapes(cfg: SeqConfig) -> dict[str, tuple[int, ...]]:
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2))
 def _init_tensor(leaf: str, shape: tuple, conv_width: int, key):
-    if leaf.endswith("norm"):
+    if leaf.endswith("norm") or leaf == "ssm_d":
         return jnp.ones(shape, jnp.float32)
-    if leaf.startswith("conv"):
+    if "conv" in leaf:
         bound = 1.0 / math.sqrt(conv_width)
         return jax.random.uniform(key, shape, jnp.float32, -bound, bound)
     if leaf == "a_log":
         return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1e-3, 16.0))
-    if leaf == "dt_bias":
+    if leaf == "ssm_a_log":
+        return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0))
+    if leaf.endswith("dt_bias"):
         dt = jnp.exp(jax.random.uniform(
             key, shape, jnp.float32, math.log(1e-3), math.log(1e-1)))
         dt = jnp.maximum(dt, 1e-4)
@@ -175,20 +283,22 @@ def _init_tensor(leaf: str, shape: tuple, conv_width: int, key):
 def init_params(cfg: SeqConfig, seed: int) -> dict[str, jax.Array]:
     """Seeded weights of the held share.  Tensor number n (the order of
     ``param_shapes``) draws from ``fold_in(PRNGKey(seed), n)`` at its held
-    shape: matrices normal(0, 0.02); norm weights 1; convolution taps
-    uniform(-1/sqrt(width), 1/sqrt(width)) (a depthwise Conv1d's default);
-    ``a_log = log(uniform(0.001, 16))`` and ``dt_bias`` the inverse softplus
-    of ``exp(uniform(log 0.001, log 0.1))`` floored at 1e-4 (the Gated
-    DeltaNet release's initialisation; its ``uniform(0, 16)`` floored so that
-    the logarithm is finite).  One small program a tensor: the random bits of
+    shape: matrices normal(0, 0.02); norm weights 1; convolution taps and
+    bias uniform(-1/sqrt(width), 1/sqrt(width)) (a depthwise Conv1d's
+    default); ``a_log = log(uniform(0.001, 16))`` and ``dt_bias`` the inverse
+    softplus of ``exp(uniform(log 0.001, log 0.1))`` floored at 1e-4 (the
+    Gated DeltaNet release's initialisation; its ``uniform(0, 16)`` floored so
+    that the logarithm is finite); the state space's ``ssm_a_log =
+    log(uniform(1, 16))``, ``ssm_dt_bias`` as ``dt_bias`` and ``ssm_d = 1``
+    (the Mamba-2 release's).  One small program a tensor: the random bits of
     one tensor are the only temporary."""
     base = jax.random.PRNGKey(seed)
-    return {
-        name: _init_tensor(
-            name.rsplit(".", 1)[-1], shape, cfg.conv_width,
-            jax.random.fold_in(base, n))
-        for n, (name, shape) in enumerate(param_shapes(cfg).items())
-    }
+    out = {}
+    for n, (name, shape) in enumerate(param_shapes(cfg).items()):
+        leaf = name.rsplit(".", 1)[-1]
+        width = cfg.ssm_conv_width if leaf.startswith("ssm_") else cfg.conv_width
+        out[name] = _init_tensor(leaf, shape, width, jax.random.fold_in(base, n))
+    return out
 
 
 def layer_params(params: dict, i: int) -> dict:
@@ -256,7 +366,7 @@ def embed(cfg: SeqConfig, table, tokens):
         idx = tokens - cfg.vocab_start
         held = (idx >= 0) & (idx < cfg.vocab_rows)
         rows = jnp.take(table, jnp.where(held, idx, 0), axis=0)
-        return jnp.where(held[..., None], rows, 0.0)
+        return _scaled(jnp.where(held[..., None], rows, 0.0), cfg.mup.embedding)
 
 
 #: a linear layer's tensors that are split by head along their LAST axis, the
@@ -361,6 +471,26 @@ def _dense_attention(q, k, v, seg, scale):
         "bhqk,bhkd->bhqd", p.astype(v.dtype), v, preferred_element_type=jnp.float32)
 
 
+def _attend(cfg: SeqConfig, q, k, v, seg):
+    """[B, T, H, d] each -> causal softmax attention within the segment,
+    [B, T, H * d] float32: the library's blocked kernel or ``jax.numpy``."""
+    B, T, H, d = q.shape
+    q, k, v = (
+        t.transpose(0, 2, 1, 3).astype(MATMUL_DTYPE) for t in (q, k, v))
+    impl = cfg.attn_impl or (
+        "flash" if jax.default_backend() == "tpu" else "dense")
+    if impl == "flash":
+        from jax.experimental.pallas.ops.tpu import flash_attention as fa
+
+        o = fa.flash_attention(
+            q, k, v, segment_ids=fa.SegmentIds(q=seg, kv=seg),
+            causal=True, sm_scale=d ** -0.5,
+        )
+    else:
+        o = _dense_attention(q, k, v, seg, d ** -0.5)
+    return o.astype(jnp.float32).transpose(0, 2, 1, 3).reshape(B, T, H * d)
+
+
 def full_attention(cfg: SeqConfig, p: dict, x, seg):
     """The share's part of a full-attention layer's output."""
     B, T, _ = x.shape
@@ -370,32 +500,132 @@ def full_attention(cfg: SeqConfig, p: dict, x, seg):
         q, k, v = (mm(x, p[n]).reshape(B, T, H, d) for n in ("q", "k", "v"))
         q = rmsnorm(q, p["q_norm"], cfg.eps)
         k = rmsnorm(k, p["k_norm"], cfg.eps)
-        q, k, v = (
-            t.transpose(0, 2, 1, 3).astype(MATMUL_DTYPE) for t in (q, k, v))
-        impl = cfg.attn_impl or (
-            "flash" if jax.default_backend() == "tpu" else "dense")
-        if impl == "flash":
-            from jax.experimental.pallas.ops.tpu import flash_attention as fa
+        return mm(_attend(cfg, q, k, v, seg), p["o"])
 
-            o = fa.flash_attention(
-                q, k, v, segment_ids=fa.SegmentIds(q=seg, kv=seg),
-                causal=True, sm_scale=d ** -0.5,
-            )
-        else:
-            o = _dense_attention(q, k, v, seg, d ** -0.5)
-        o = o.astype(jnp.float32).transpose(0, 2, 1, 3).reshape(B, T, H * d)
-        return mm(o, p["o"])
+
+def segment_positions(seg):
+    """[B, T] segment ids -> each token's position within its segment (a
+    segment is one run of equal ids): 0 at every segment's first token."""
+    at = jnp.arange(seg.shape[1])
+    prev = jnp.pad(seg[:, :-1], ((0, 0), (1, 0)), constant_values=gdn.NO_SEGMENT)
+    return at - jax.lax.cummax(jnp.where(seg != prev, at, 0), axis=1)
+
+
+def rope(x, pos, theta: float):
+    """Rotary positions over the whole head, channel i paired with i + d/2.
+    x: [B, T, H, d] float32; pos: [B, T]."""
+    half = x.shape[-1] // 2
+    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angle = pos[..., None].astype(jnp.float32) * inv
+    cos, sin = jnp.cos(angle)[:, :, None], jnp.sin(angle)[:, :, None]
+    x1, x2 = x[..., :half], x[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+
+
+def grouped_query_attention(cfg: SeqConfig, p: dict, h, seg):
+    """The share's part of the parallel block's attention mixer: its query
+    heads on its KV heads (each repeated for the query heads it serves, into
+    the kernel ``full_attention`` calls), positions restarting at a segment."""
+    B, T, _ = h.shape
+    d, mup = cfg.head_dim, cfg.mup
+    with jax.named_scope("seq.attn"):
+        h = _scaled(h, mup.attention_in)
+        q, k, v = (mm(h, p[n]).reshape(B, T, -1, d) for n in ("q", "k", "v"))
+        with jax.named_scope("attn.rope"):
+            pos = segment_positions(seg)
+            q = rope(q, pos, cfg.rope_theta)
+            k = rope(_scaled(k, mup.key), pos, cfg.rope_theta)
+        rep = q.shape[2] // k.shape[2]
+        if rep > 1:
+            # the barrier keeps the repeat out of the attention's products:
+            # folded into them, XLA's CPU backend meets a bf16 dot it cannot run
+            k, v = jax.lax.optimization_barrier(
+                tuple(jnp.repeat(t, rep, axis=2) for t in (k, v)))
+        return _scaled(mm(_attend(cfg, q, k, v, seg), p["o"]), mup.attention_out)
+
+
+def gated_group_norm(y, z, w, eps, axis_name=None):
+    """``RMSNorm(y * SiLU(z))`` over the last axis, a group's channels HELD
+    here.  A group's channels may lie on several chips: ``axis_name`` is the
+    mapped axis over those chips, and the mean square is then taken over all
+    of them (one float a token and group crosses); ``None`` on one chip,
+    where the mean is over the channels held."""
+    g = y * jax.nn.silu(z)
+    ss, n = jnp.sum(g * g, axis=-1, keepdims=True), g.shape[-1]
+    if axis_name is not None:
+        ss, n = jax.lax.psum(ss, axis_name), n * jax.lax.psum(1, axis_name)
+    return g * jax.lax.rsqrt(ss / n + eps) * w
+
+
+def ssm_probe_vector(P: int):
+    """The seeded direction each head's state-space output is recorded
+    along: standard normal [P] from ``fold_in(PRNGKey(PROBE_SEED), 2**20 + 1)``."""
+    return jax.random.normal(
+        jax.random.fold_in(jax.random.PRNGKey(PROBE_SEED), 2 ** 20 + 1), (P,),
+        jnp.float32)
+
+
+def ssm_inputs(cfg: SeqConfig, p: dict, h, seg):
+    """What the state space reads -> (x [B, T, H, P], Delta [B, T, H],
+    B, C [B, T, G, N]) and the gate z [B, T, H * P]."""
+    Bsz, T, _ = h.shape
+    H, P, G, N = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state
+    mup = cfg.mup
+    with jax.named_scope("ssm.proj"):
+        u = mm(_scaled(h, mup.ssm_in), p["ssm_in"])
+        if any(m != 1.0 for m in mup.ssm_zones):
+            u = u * np.repeat(
+                np.asarray(mup.ssm_zones, np.float32),
+                (H * P, H * P, G * N, G * N, H))
+        z, xbc, dt = jnp.split(u, (H * P, 2 * H * P + 2 * G * N), axis=-1)
+    with jax.named_scope("ssm.conv"):
+        xbc = jax.nn.silu(causal_conv(xbc, p["ssm_conv"], seg) + p["ssm_conv_bias"])
+        x, b, c = jnp.split(xbc, (H * P, H * P + G * N), axis=-1)
+        dt = jax.nn.softplus(dt + p["ssm_dt_bias"])
+    return (x.reshape(Bsz, T, H, P), dt, b.reshape(Bsz, T, G, N),
+            c.reshape(Bsz, T, G, N), z)
+
+
+def state_space_mixer(cfg: SeqConfig, p: dict, h, seg, norm_axis=None):
+    """The share's part of the parallel block's state-space mixer (the sum
+    over its heads' rows of the output projection) and the recurrence's own
+    output ``S_t C_t`` along the probe vector [B, T, H], before the ``D`` skip
+    (which is alike on both sides of any comparison and 100 x larger at the
+    seeded weights) and any later rounding."""
+    Bsz, T, _ = h.shape
+    G = cfg.ssm_groups
+    with jax.named_scope("seq.ssm"):
+        x, dt, b, c, z = ssm_inputs(cfg, p, h, seg)
+        y = ssd.ssd(x, dt, -jnp.exp(p["ssm_a_log"]), b, c, seg, cfg.ssm_chunk,
+                    cfg.ssm_impl)
+        probe = jax.lax.stop_gradient(jnp.einsum(
+            "bthp,p->bth", y, ssm_probe_vector(y.shape[-1]), precision=HIGHEST))
+        with jax.named_scope("ssm.norm"):
+            y = y + p["ssm_d"][:, None] * x
+            y = gated_group_norm(
+                y.reshape(Bsz, T, G, -1), z.reshape(Bsz, T, G, -1),
+                p["ssm_norm"].reshape(G, -1), cfg.eps, norm_axis)
+        with jax.named_scope("ssm.proj"):
+            out = mm(y.reshape(Bsz, T, -1), p["ssm_out"])
+        return _scaled(out, cfg.mup.ssm_out), probe
 
 
 def mlp(cfg: SeqConfig, p: dict, x):
     """The share's part of the MLP's output: the sum over its columns."""
+    mup = cfg.mup
     with jax.named_scope("seq.mlp"):
-        return mm(jax.nn.silu(mm(x, p["gate"])) * mm(x, p["up"]), p["down"])
+        gate = jax.nn.silu(_scaled(mm(x, p["gate"]), mup.mlp_gate))
+        return _scaled(mm(gate * mm(x, p["up"]), p["down"]), mup.mlp_down)
 
 
 def layer(cfg: SeqConfig, kind: str, p: dict, x, seg):
-    """-> (x after the layer, the delta rule's probe [B, T, H]; no heads where
-    the layer is full attention)."""
+    """-> (x after the layer, the layer's probe [B, T, H]: the delta rule's
+    or the state space's; no heads where the layer is full attention)."""
+    if kind == PARALLEL:
+        h = rmsnorm(x, p["input_norm"], cfg.eps)
+        m, probe = state_space_mixer(cfg, p, h, seg)
+        x = x + m + grouped_query_attention(cfg, p, h, seg)
+        return x + mlp(cfg, p, rmsnorm(x, p["pre_ff_norm"], cfg.eps)), probe
     if kind == LINEAR:
         y, probe = linear_attention(cfg, p, x, seg)
     else:
@@ -406,9 +636,10 @@ def layer(cfg: SeqConfig, kind: str, p: dict, x, seg):
 
 def trunk(cfg: SeqConfig, params: dict, x, seg, remat: bool = False):
     """The layers and the final norm over embedded rows x [B, T, D] -> (the
-    normalised hidden states, the FIRST layer's delta-rule probe: its inputs
-    are three products of exact embedding rows, so the training record can
-    hold the rule to the token-by-token recurrence there; deeper layers read a
+    normalised hidden states, the FIRST layer's probe, the delta rule's or the
+    state space's: its inputs are products of exact embedding rows (normed
+    first, in the pre-norm block), so the training record can hold the
+    recurrence to its token-by-token form there; deeper layers read a
     residual stream that already carries every earlier rounding).  With
     ``remat`` each layer is recomputed in the backward pass, so that only the
     residual stream between layers is kept."""
@@ -442,7 +673,8 @@ def next_item_targets(tokens, seg):
 
 def cross_entropy(cfg: SeqConfig, h, head, targets, weight, dhead):
     """Sum over tokens of ``weight * cross-entropy(h @ head^T, target)`` over
-    the held vocabulary rows AND its gradients, a block of tokens at a time:
+    the held vocabulary rows (the logits times ``mup.lm_head``) AND its
+    gradients, a block of tokens at a time:
     the logits of a block exist once, their gradient is made beside them, and
     the head's gradient is added into ``dhead`` -> (loss, dh, dhead)."""
     shape = h.shape
@@ -452,19 +684,22 @@ def cross_entropy(cfg: SeqConfig, h, head, targets, weight, dhead):
         raise ValueError(f"{T} tokens are not a multiple of the loss block {blk}")
     n = T // blk
     w16 = head.astype(MATMUL_DTYPE)
+    scale = cfg.mup.lm_head
     local = targets.reshape(n, blk) - cfg.vocab_start
 
     def block(carry, x):
         loss, dw = carry
         hx, tx, wx = x
         h16 = hx.astype(MATMUL_DTYPE)
-        logits = jnp.matmul(h16, w16.T, preferred_element_type=jnp.float32)
+        logits = _scaled(
+            jnp.matmul(h16, w16.T, preferred_element_type=jnp.float32), scale)
         lse = jax.nn.logsumexp(logits, axis=-1)
         hit = jnp.arange(logits.shape[-1])[None, :] == tx[:, None]
         picked = jnp.sum(jnp.where(hit, logits, 0.0), axis=-1)
         loss = loss + jnp.sum(wx * (lse - picked))
-        dlog = ((jnp.exp(logits - lse[:, None]) - hit) * wx[:, None]).astype(
-            MATMUL_DTYPE)
+        dlog = _scaled(
+            (jnp.exp(logits - lse[:, None]) - hit) * wx[:, None], scale
+        ).astype(MATMUL_DTYPE)
         dh = jnp.matmul(dlog, w16, preferred_element_type=jnp.float32)
         dw = dw + jnp.matmul(dlog.T, h16, preferred_element_type=jnp.float32)
         return (loss, dw), dh
@@ -481,7 +716,7 @@ def row_grads(cfg: SeqConfig, params: dict, tokens, seg, gsum: dict):
     """Forward, per-layer recomputation and backward of packed rows
     [B, T] -> (sum of the next-item cross-entropy over their real positions,
     how many those are, ``gsum`` + the sum's gradient, the first layer's
-    delta-rule probe [B, T, H]).  The two vocabulary
+    probe [B, T, H]).  The two vocabulary
     tables' gradients are added into ``gsum`` in place (a scatter of the
     embedded rows' gradient, the loss's own accumulation), never held beside
     it."""
@@ -497,7 +732,7 @@ def row_grads(cfg: SeqConfig, params: dict, tokens, seg, gsum: dict):
         idx = tokens - cfg.vocab_start
         held = (idx >= 0) & (idx < cfg.vocab_rows)
         dembed = gsum["embed"].at[jnp.where(held, idx, 0)].add(
-            jnp.where(held[..., None], dx0, 0.0))
+            _scaled(jnp.where(held[..., None], dx0, 0.0), cfg.mup.embedding))
     out = {k: gsum[k] + g for k, g in dinner.items()}
     out["embed"], out["head"] = dembed, dhead
     return loss, jnp.sum(weight), out, probe
@@ -521,7 +756,7 @@ def init_state(cfg: SeqConfig, seed: int) -> tuple[dict, dict]:
 
 def accumulate_row(cfg: SeqConfig, state: dict, acc: dict, tokens, seg):
     """One packed row [T] through forward, recomputation and backward, added
-    into the step's accumulator -> (state, acc, the row's delta-rule probe
+    into the step's accumulator -> (state, acc, the row's first-layer probe
     [T, H]).  The state goes in and comes out untouched: the moments are this
     program's arguments only so that the compiler plans its temporaries beside
     ALL that is resident (it fits a program into the memory its own arguments
@@ -598,8 +833,8 @@ def train_steps(cfg: SeqConfig, opt: AdamW, state: dict, acc: dict, tokens, seg)
     """``tokens``, ``seg``: [steps, rows, T] int32 on the device.  Every row
     and every optimiser step is dispatched at once (nothing is fetched in
     between, so the host never waits for a step) -> (state, acc, the records
-    of the steps, the delta-rule probes [T, H] of the FIRST step's rows: those
-    are made from the seeded initial weights; all still on the device)."""
+    of the steps, the first-layer probes [T, H] of the FIRST step's rows:
+    those are made from the seeded initial weights; all still on the device)."""
     accumulate, apply = train_programs(cfg, opt)
     records, probes = [], []
     for s in range(tokens.shape[0]):

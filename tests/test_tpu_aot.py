@@ -299,3 +299,70 @@ def test_segment_masked_flash_attention_compiles(v5e):
         cfg, p, x, seg).sum(), argnums=(0, 1)))
     compiled = _compile(fn, shapes, sds((1, 8192, 3840)), sds((1, 8192), jnp.int32))
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 10**9
+
+
+# the Falcon-H1 block at its published widths (one of four chips' share: 8
+# state-space heads of 128 channels in one group, state 256, rows of 8192
+# tokens in chunks of 128; four heads a grid step)
+SSD = dict(heads=8, groups=1, chunks=64, chunk=128, p=128, n=256)
+
+
+def _ssd_parts(sds):
+    h, g, nc, c, p, n = (SSD[k] for k in ("heads", "groups", "chunks", "chunk", "p", "n"))
+    return (sds((1, g, nc, c, n)), sds((1, g, nc, c, n)), sds((1, h, nc, c, p)),
+            sds((1, h, nc)))
+
+
+@pytest.mark.parametrize("passes", ["forward", "forward_backward"])
+def test_ssd_chunk_kernels_compile(v5e, passes):
+    """The state space's sequential pass: ``ssd_chunk_fwd`` alone, and with
+    ``ssd_chunk_bwd`` under its ``custom_vjp`` (B and C one block a group,
+    their gradients summed over a grid step's heads inside the kernel)."""
+    from predictionio_tpu.ops import ssd
+
+    sds = _spec_on(SingleDeviceSharding(v5e.devices[0]))
+    if passes == "forward":
+        fn = jax.jit(lambda *p: ssd.chunk_pallas(*p, False))
+    else:
+        fn = jax.jit(jax.grad(
+            lambda *p: ssd.chunk_pallas(*p, False).sum(), argnums=tuple(range(4))))
+    assert ssd.heads_per_block(SSD["heads"] // SSD["groups"]) == 4
+    text = _compile(fn, *_ssd_parts(sds)).as_text()
+    assert "ssd_chunk_fwd" in text
+    assert ("ssd_chunk_bwd" in text) == (passes == "forward_backward")
+
+
+def test_falcon_h1_row_program_fits_beside_its_arguments(v5e):
+    """The training row of ``falcon-h1-34b-tp4.retrain`` as the chip compiles
+    it (flash attention, the SSD kernels; 8192 tokens, 769.6 M parameters at
+    16 bytes): the compiler plans its temporaries beside 12.31 GB of weights,
+    moments and gradient sums, under the 16,909,336,064 B the v5e's allocator
+    reports as its limit (PERF.md).  A plan, not a reading."""
+    import dataclasses
+    import json
+    from pathlib import Path
+
+    from predictionio_tpu.models.sequence import engine as seq
+    from predictionio_tpu.ops import seqmodel
+    from predictionio_tpu.utils.params import extract_params
+
+    body = json.loads((Path(__file__).resolve().parents[1] / "benchmark" / "configs"
+                       / "falcon-h1-34b-tp4.json").read_text())
+    algo = seq.SequenceAlgorithm(extract_params(
+        seq.SequenceAlgorithmParams, body["engine_json"]["algorithms"][0]["params"]))
+    cfg = dataclasses.replace(algo.seq_config(), attn_impl="flash", ssm_impl="pallas")
+    assert seqmodel.num_params(cfg) == body["share"]["parameters_held"] == 769_637_472
+    row_len = body["engine_json"]["preparator"]["params"]["rowLen"]
+    sds = _spec_on(SingleDeviceSharding(v5e.devices[0]))
+    params = {k: sds(s) for k, s in seqmodel.param_shapes(cfg).items()}
+    state = {"params": params, "m": params, "v": params, "t": sds((), jnp.int32)}
+    acc = {"g": params, "loss": sds(()), "count": sds(())}
+    accumulate, _ = seqmodel.train_programs(cfg, seqmodel.AdamW())
+    compiled = _compile(
+        accumulate, state, acc, sds((row_len,), jnp.int32), sds((row_len,), jnp.int32))
+    text = compiled.as_text()
+    assert "ssd_chunk_fwd" in text and "ssd_chunk_bwd" in text
+    plan = compiled.memory_analysis()
+    assert plan.argument_size_in_bytes == pytest.approx(16 * 769_637_472, rel=1e-3)
+    assert plan.alias_size_in_bytes >= 0.999 * plan.argument_size_in_bytes  # donated
+    assert plan.argument_size_in_bytes + plan.temp_size_in_bytes < 16_909_336_064
