@@ -15,12 +15,16 @@ blobs in any Models backend (localfs/sqlite/s3) — see
 ``data/storage/base.Models.insert_parts`` — so a multi-gigabyte table is
 written and read leaf-by-leaf, and a deploy host streams parts instead of
 materializing blob + pickle + arrays three times over.  The writer streams
-too: ``serialize_models_sharded`` hands back the parts as a mapping that makes
-each part's ``.npy`` bytes when it is asked for them, so a save holds the
-arrays and ONE part's bytes at a time, not a second copy of the model
-(measured in the sandbox at 3.2 GB of float32 leaves: host peak +3.24 GB over
-the arrays before, +1.56 GB after — the largest leaf's buffer and its bytes;
-PERF.md, PR 26).
+too: ``serialize_models_sharded`` hands back the parts as a mapping
+(``LazyParts``) that makes a part's ``.npy`` bytes when it is asked for them
+and writes a part straight into an open file when it is handed one.  A store
+that asks for bytes (the base ``insert_parts``: sqlite, S3, HDFS, remote)
+holds the arrays and ONE part's buffer and bytes at a time; the local
+filesystem store hands over files and holds the arrays alone (measured in the
+sandbox at 3.0 GB of float32 leaves, the largest 770 MB: host peak over the
+arrays +1.52 GB through the bytes, +0.001 GB through the files; PERF.md,
+PR 31.  Before ``LazyParts`` every part's bytes were held until the last was
+written: +3.24 GB at 3.2 GB; PERF.md, PR 26).
 """
 
 from __future__ import annotations
@@ -28,7 +32,7 @@ from __future__ import annotations
 import io
 import pickle
 from collections.abc import Mapping
-from typing import Any, Callable, Iterator
+from typing import Any, BinaryIO, Callable, Iterator
 
 import jax
 import numpy as np
@@ -78,15 +82,28 @@ class _ShardingPickler(pickle.Pickler):
 
 class LazyParts(Mapping):
     """Part name -> raw ``.npy`` bytes, serialized when asked for and not
-    kept: a store that writes part after part holds one part's bytes."""
+    kept: a store that writes part after part holds one part's bytes, and a
+    store that hands ``write_part`` an open file holds none."""
 
     def __init__(self, leaves: dict[str, np.ndarray]):
         self._leaves = leaves
 
     def __getitem__(self, name: str) -> bytes:
         part = io.BytesIO()
-        np.save(part, self._leaves[name], allow_pickle=False)
+        self.write_part(name, part)
         return part.getvalue()
+
+    def write_part(self, name: str, file: BinaryIO) -> None:
+        """Write the bytes ``self[name]`` would return into ``file``.  Onto a
+        real file ``np.save`` writes the header and then the array's buffer
+        from its own memory (``ndarray.tofile``, which releases the GIL);
+        into anything else it copies the array chunk by chunk."""
+        np.save(file, self._leaves[name], allow_pickle=False)
+
+    def part_nbytes(self, name: str) -> int:
+        """The part's size without its ``.npy`` header (what a store orders
+        its writes by, without making the bytes)."""
+        return self._leaves[name].nbytes
 
     def __iter__(self) -> Iterator[str]:
         return iter(self._leaves)

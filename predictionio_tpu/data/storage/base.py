@@ -239,6 +239,13 @@ class Models(abc.ABC):
     def insert_parts(
         self, instance_id: str, manifest: bytes, parts: Mapping[str, bytes]
     ) -> None:
+        self._drop_checkpoint_for_resave(instance_id)
+        for name, blob in parts.items():
+            self.insert(f"{instance_id}:part:{name}", blob)
+        # manifest last: readers treat its presence as "all parts written"
+        self.insert(f"{instance_id}:manifest", _manifest_blob(manifest, parts))
+
+    def _drop_checkpoint_for_resave(self, instance_id: str) -> None:
         # Instance ids are write-once in normal operation (run_train mints a
         # fresh id per training run).  Re-saving an existing id is still made
         # safe: drop the old manifest FIRST so concurrent readers see
@@ -250,10 +257,6 @@ class Models(abc.ABC):
             self.delete(f"{instance_id}:manifest")
             for name in _manifest_part_names(old):
                 self.delete(f"{instance_id}:part:{name}")
-        for name, blob in parts.items():
-            self.insert(f"{instance_id}:part:{name}", blob)
-        # manifest last: readers treat its presence as "all parts written"
-        self.insert(f"{instance_id}:manifest", _manifest_blob(manifest, parts))
 
     def get_manifest(self, instance_id: str) -> bytes | None:
         raw = self.get(f"{instance_id}:manifest")

@@ -17,6 +17,7 @@ from types import SimpleNamespace
 import numpy as np
 import pytest
 
+from predictionio_tpu.data.storage import localfs_models
 from predictionio_tpu.data.storage.localfs_models import LocalFSModels
 from predictionio_tpu.lifecycle import (
     CanaryDecider,
@@ -111,6 +112,204 @@ class TestLocalFSDurability:
         models.insert("x", b"two")
         tmp_names = [s for s in seen if ".tmp" in s]
         assert len(tmp_names) == len(set(tmp_names)) >= 2
+
+    # -- a multi-part checkpoint, several parts in flight (ISSUE 31) --------
+
+    @pytest.mark.parametrize("kind", ["arrays", "bytes"])
+    def test_insert_parts_flushes_in_commit_order(
+        self, models, monkeypatch, kind
+    ):
+        """Each part's file is fsynced before its rename; the directory is
+        fsynced after the last part's rename and before the manifest's; and
+        again after the manifest's rename, before ``insert_parts`` returns."""
+        parts = _checkpoint_parts(kind)
+        events = _spy_on_files(monkeypatch, models.root)
+        models.insert_parts("ck", b"manifest", parts)
+        done = len(events)
+        monkeypatch.undo()
+
+        def final(key):
+            return str(models._file(key))
+
+        def index(event):
+            (i,) = [i for i, e in enumerate(events) if e == event]
+            return i
+
+        renamed = {}
+        for name in parts:
+            dst = final(f"ck:part:{name}")
+            renamed[name] = index(("replace", dst))
+            (tmp,) = {
+                path for op, path in events
+                if op == "open" and path.startswith(dst + ".")
+            }
+            assert tmp.endswith(".tmp")
+            assert index(("fsync", tmp)) < renamed[name]
+        manifest = final("ck:manifest")
+        manifest_renamed = index(("replace", manifest))
+        (manifest_tmp,) = {
+            path for op, path in events
+            if op == "open" and path.startswith(manifest + ".")
+        }
+        assert index(("fsync", manifest_tmp)) < manifest_renamed
+        dir_syncs = [
+            i for i, e in enumerate(events) if e == ("fsync", str(models.root))
+        ]
+        assert any(
+            max(renamed.values()) < i < manifest_renamed for i in dir_syncs)
+        assert any(manifest_renamed < i < done for i in dir_syncs)
+        # and what was committed reads back whole
+        assert models.get_manifest("ck") == b"manifest"
+        for name in parts:
+            assert models.get_part("ck", name) == parts[name]
+
+    @pytest.mark.parametrize("resave", [False, True], ids=["fresh", "resave"])
+    @pytest.mark.parametrize("kind", ["arrays", "bytes"])
+    @pytest.mark.parametrize("stage", ["write", "fsync", "rename"])
+    def test_part_failure_leaves_no_manifest_and_no_tmp(
+        self, models, monkeypatch, stage, kind, resave
+    ):
+        """An error in ONE part's write, fsync or rename: every writer is
+        joined, no ``.tmp`` is left, the error is raised and no manifest is
+        written — over a live id too, whose old manifest went first, so a
+        reader never pairs the old part list with new bytes."""
+        if resave:
+            models.insert_parts(
+                "ck", b"old", {"leaf00000": b"o0", "leaf00007": b"o7"})
+        parts = _checkpoint_parts(kind)
+        victim = "leaf00002"
+        victim_final = str(models._file(f"ck:part:{victim}"))
+        events = _spy_on_files(monkeypatch, models.root)
+        fd_paths = events.fd_paths
+
+        def is_victim(fd):
+            return fd_paths.get(fd, "").startswith(victim_final + ".")
+
+        if stage == "write":
+            # the tmp file's writer, broken after its first bytes
+            class Broken:
+                def __init__(self, file):
+                    self.file = file
+
+                def write(self, data):
+                    self.file.write(bytes(data)[:5])
+                    raise OSError("injected write failure")
+
+                def __enter__(self):
+                    return self
+
+                def __exit__(self, *exc):
+                    return self.file.__exit__(*exc)
+
+            def breaking_open(fd, *a, **kw):
+                file = open(fd, *a, **kw)
+                return Broken(file) if is_victim(fd) else file
+
+            monkeypatch.setattr(
+                localfs_models, "open", breaking_open, raising=False)
+        elif stage == "fsync":
+            spied_fsync = os.fsync
+
+            def failing_fsync(fd):
+                if is_victim(fd):
+                    raise OSError("injected fsync failure")
+                return spied_fsync(fd)
+
+            monkeypatch.setattr(os, "fsync", failing_fsync)
+        else:
+            spied_replace = os.replace
+
+            def failing_replace(src, dst):
+                if str(dst) == victim_final:
+                    raise OSError("injected rename failure")
+                return spied_replace(src, dst)
+
+            monkeypatch.setattr(os, "replace", failing_replace)
+
+        threads_before = set(threading.enumerate())
+        with pytest.raises(OSError, match=f"injected {stage} failure"):
+            models.insert_parts("ck", b"manifest", parts)
+        monkeypatch.undo()
+        assert set(threading.enumerate()) <= threads_before
+        assert models.get_manifest("ck") is None
+        assert not any(
+            p.name.endswith(".tmp") for p in models.root.iterdir())
+        assert models.get_part("ck", victim) is None
+        # the manifest was never opened, let alone renamed
+        assert not any(
+            "ck:manifest" in path for op, path in events if op == "open")
+        # a re-save after the failure commits as ever
+        models.insert_parts("ck", b"manifest", parts)
+        assert models.get_manifest("ck") == b"manifest"
+        assert models.get_part("ck", victim) == parts[victim]
+        assert models.get_part("ck", "leaf00007") is None
+
+    def test_resave_drops_the_old_manifest_before_any_new_byte(
+        self, models, monkeypatch
+    ):
+        models.insert_parts("ck", b"old", {"leaf00000": b"old-bytes"})
+        manifest = models._file("ck:manifest")
+        real_open = os.open
+        seen = []
+
+        def checking_open(path, flags, *a, **kw):
+            if ":part:" in str(path):
+                seen.append(manifest.exists())
+            return real_open(path, flags, *a, **kw)
+
+        monkeypatch.setattr(os, "open", checking_open)
+        models.insert_parts("ck", b"new", _checkpoint_parts("arrays"))
+        assert seen and not any(seen)
+        assert models.get_manifest("ck") == b"new"
+
+
+class _FileEvents(list):
+    """("open" | "fsync" | "replace", path) in the order they happened."""
+
+    def __init__(self):
+        super().__init__()
+        self.fd_paths: dict[int, str] = {}
+
+
+def _spy_on_files(monkeypatch, root) -> _FileEvents:
+    """Record every ``os.open`` / ``os.fsync`` / ``os.replace`` under
+    ``root`` (list.append is atomic: the writers' threads share the list)."""
+    events = _FileEvents()
+    real_open, real_fsync, real_replace = os.open, os.fsync, os.replace
+
+    def spying_open(path, flags, *a, **kw):
+        fd = real_open(path, flags, *a, **kw)
+        if str(path).startswith(str(root)):
+            events.fd_paths[fd] = str(path)
+            events.append(("open", str(path)))
+        return fd
+
+    def spying_fsync(fd):
+        real_fsync(fd)
+        events.append(("fsync", events.fd_paths.get(fd, "?")))
+
+    def spying_replace(src, dst):
+        real_replace(src, dst)
+        events.append(("replace", str(dst)))
+
+    monkeypatch.setattr(os, "open", spying_open)
+    monkeypatch.setattr(os, "fsync", spying_fsync)
+    monkeypatch.setattr(os, "replace", spying_replace)
+    return events
+
+
+def _checkpoint_parts(kind: str):
+    """Seven parts of unequal sizes, as ``save_models`` hands them over
+    (``LazyParts`` over arrays) or as plain bytes (``replay/day.py``)."""
+    from predictionio_tpu.core.persistence import LazyParts
+
+    rng = np.random.default_rng(31)
+    lazy = LazyParts({
+        f"leaf{i:05d}": rng.standard_normal((rows, 16)).astype(np.float32)
+        for i, rows in enumerate([64, 2048, 256, 8, 1024, 512, 128])
+    })
+    return lazy if kind == "arrays" else {n: lazy[n] for n in lazy}
+
 
 
 # ---------------------------------------------------------------------------

@@ -50,16 +50,25 @@ N_USERS, N_ITEMS, NNZ = 120, 40, 2000
 
 
 @pytest.fixture()
-def parquet_storage(tmp_path):
-    """A throwaway parquet event store holding one app's ratings."""
+def parquet_storage(tmp_path, request):
+    """A throwaway parquet event store holding one app's ratings; asked for
+    with ``"localfs"`` (indirect), its models go to the local filesystem as
+    the benchmark's do."""
     home = tmp_path / "pio_home"
-    rt = StorageRuntime(StorageConfig.from_env({
+    env = {
         "PIO_HOME": str(home),
         "PIO_STORAGE_SOURCES_PARQUET_TYPE": "parquet",
         "PIO_STORAGE_SOURCES_PARQUET_PATH": str(home / "events_parquet"),
         "PIO_STORAGE_SOURCES_PARQUET_NSHARDS": "4",
         "PIO_STORAGE_REPOSITORIES_EVENTDATA_SOURCE": "PARQUET",
-    }))
+    }
+    if getattr(request, "param", None) == "localfs":
+        env.update({
+            "PIO_STORAGE_SOURCES_LOCALFS_TYPE": "localfs",
+            "PIO_STORAGE_SOURCES_LOCALFS_PATH": str(home / "models"),
+            "PIO_STORAGE_REPOSITORIES_MODELDATA_SOURCE": "LOCALFS",
+        })
+    rt = StorageRuntime(StorageConfig.from_env(env))
     app = commands.app_new(rt, "spans").app
     rng = np.random.default_rng(24)
     users = rng.integers(0, N_USERS, NNZ)
@@ -226,6 +235,45 @@ def test_pallas_retrain_holds_the_span_tree(parquet_storage, pallas_on_cpu):
         c["upload_bytes"] for c in stage["children"]
         if c["name"] == "als.stage.upload"
     )
+
+
+# -- the model store's write: parts in flight (ISSUE 31) --------------------
+
+
+@pytest.mark.parametrize("parquet_storage", ["localfs"], indirect=True)
+def test_persist_span_says_what_the_store_wrote(parquet_storage, monkeypatch):
+    """``train.persist.save_models`` keeps its name and its place (the
+    benchmark's ``persist_s`` / ``seq_persist_s`` read it by the prefix
+    ``train.persist``, which no other stage may share), is tagged with what
+    the local store wrote, and holds a ``persist.part`` child a part, opened
+    from the writers' threads."""
+    from predictionio_tpu.core import persistence
+    from predictionio_tpu.data.storage import localfs_models
+
+    # the factor tables of this tiny fit are under the part threshold
+    monkeypatch.setattr(persistence, "PART_THRESHOLD", 256)
+    stages, root = _retrain(parquet_storage)
+    assert [n for n in stages if n.startswith("train.persist")] == [
+        "train.persist.save_models"]
+    span = next(
+        c for c in root["children"] if c["name"] == "train.persist.save_models")
+    files = {
+        p.name: p.stat().st_size
+        for p in parquet_storage.models().root.iterdir() if ":part:" in p.name
+    }
+    assert len(files) >= 2
+    assert span["parts"] == span["streamed_parts"] == len(files)
+    assert span["bytes"] == sum(files.values())
+    assert span["writers"] == min(localfs_models.PART_WRITERS, len(files))
+    children = span["children"]
+    assert {c["name"] for c in children} == {"persist.part"}
+    assert {
+        f"pio_model_{root['request_id']}:part:{c['part']}.bin": c["bytes"]
+        for c in children
+    } == files
+    # the parts' seconds reach ``stages`` under their own name (the longest
+    # thread's, where several wrote): never more than the span that waited
+    assert stages["persist.part"] <= stages["train.persist.save_models"] + 1e-4
 
 
 # -- the training read: three columns and no order (ISSUE 27) ---------------
