@@ -61,23 +61,30 @@ def _stage_breakdown(root, compile_delta_s: float | None = None) -> dict:
     ``als.stage``): their value is the longest thread's, and ``parallel``
     lists those names.  ``compile_delta_s`` is the growth of
     ``pio_jax_compile_seconds`` over this run — stage wall time minus it
-    approximates pure execute time.
+    approximates pure execute time.  Every name so far is seconds; what the
+    spans COUNTED (a span's ``counters`` tag, a dict of numbers: the routed
+    layers' pairs) goes under the one key ``counters``, same names summed.
     """
     out = {
         name: round(secs, 4) for name, secs in root.breakdown().items()
     }
     by_thread: dict[str, dict[int, float]] = {}
+    counters: dict[str, float] = {}
     spans = [g for c in root.children for g in c.children]
     while spans:
         s = spans.pop()
         spans.extend(s.children)
         threads = by_thread.setdefault(s.name, {})
         threads[s.thread_id] = threads.get(s.thread_id, 0.0) + s.duration_s
+        for name, n in ((s.tags or {}).get("counters") or {}).items():
+            counters[name] = counters.get(name, 0) + n
     for name, threads in by_thread.items():
         out.setdefault(name, round(max(threads.values()), 4))
     parallel = sorted(n for n, t in by_thread.items() if len(t) > 1)
     if parallel:
         out["parallel"] = parallel
+    if counters:
+        out["counters"] = counters
     out["total"] = round(root.duration_s, 4)
     if compile_delta_s is not None:
         out["jax_compile"] = round(compile_delta_s, 4)

@@ -7,9 +7,12 @@ histories cut to their most recent ``maxLen`` events and packed first-fit
 decreasing into rows of ``rowLen`` with segment ids — and the algorithm
 trains one of the hybrid blocks of ``ops/seqmodel.py`` — gated delta-rule
 linear attention among full attention (Olmo-Hybrid's layer, config.json at
-huggingface.co/allenai/Olmo-Hybrid-7B), or Mamba-2 state-space heads beside
+huggingface.co/allenai/Olmo-Hybrid-7B), Mamba-2 state-space heads beside
 grouped-query attention on one normed input (Falcon-H1's,
-huggingface.co/tiiuae/Falcon-H1-34B-Instruct) — by next-item cross-entropy
+huggingface.co/tiiuae/Falcon-H1-34B-Instruct), or routed experts after
+global (no rotary) and sliding-window (rotary) attention layers, the router
+read before attention (SmallThinker's,
+huggingface.co/PowerInfer/SmallThinker-21BA3B-Instruct) — by next-item cross-entropy
 with AdamW: ``stepsPerRetrain`` optimiser steps of ``rowsPerStep`` rows, one
 pass in packed order, from a seeded initialisation.  ``layerTypes`` says
 which block; each kind reads its own sizes.
@@ -283,7 +286,10 @@ class SequenceAlgorithmParams:
     mixer a layer; the ``linear_*`` sizes, ``num_attention_heads``) or
     ``parallel_ssm_attention`` (pre-norm, a state-space and an attention mixer
     side by side: the ``mamba_*`` sizes, ``num_key_value_heads``,
-    ``rope_theta`` and muP's forward multipliers, 1 where a model has none)."""
+    ``rope_theta`` and muP's forward multipliers, 1 where a model has none) or
+    ``global_attention_moe`` / ``sliding_attention_moe`` (pre-norm, attention
+    then routed experts: the ``moe_*`` sizes with the experts HELD and the first
+    of them, ``sliding_window_size``, ``num_key_value_heads``, ``rope_theta``)."""
 
     hidden_size: int = 3840
     layer_types: tuple[str, ...] = (
@@ -331,8 +337,23 @@ class SequenceAlgorithmParams:
     key_multiplier: float = 1.0
     #: gate, down
     mlp_multipliers: tuple[float, ...] = (1.0, 1.0)
+    #: the routed blocks: the router's width (the published expert count), the
+    #: experts held here and the first of them, experts a token, an expert's
+    #: width, and the sliding layers' window (keys a query sees, itself included)
+    moe_num_primary_experts: int = 0
+    moe_experts_held: int = 0
+    moe_expert_start: int = 0
+    moe_num_active_primary_experts: int = 0
+    moe_ffn_hidden_size: int = 0
+    sliding_window_size: int = 0
 
     params_aliases = {
+        "moeNumPrimaryExperts": "moe_num_primary_experts",
+        "moeExpertsHeld": "moe_experts_held",
+        "moeExpertStart": "moe_expert_start",
+        "moeNumActivePrimaryExperts": "moe_num_active_primary_experts",
+        "moeFfnHiddenSize": "moe_ffn_hidden_size",
+        "slidingWindowSize": "sliding_window_size",
         "numKeyValueHeads": "num_key_value_heads",
         "ropeTheta": "rope_theta",
         "mambaNHeads": "mamba_n_heads",
@@ -384,7 +405,11 @@ class SequenceModel:
     #: along a seeded vector for the first step's rows [rows a step, row_len,
     #: heads] (``ops/seqmodel.trunk``), under the name of its kind
     #: (``seqmodel.PROBE_NAME``): delta_rule_probe (the delta rule's output)
-    #: or ssd_probe (the state space's ``S_t C_t``)
+    #: or ssd_probe (the state space's ``S_t C_t``).  A routed block: moe_probe
+    #: (the first layer's experts on its normed input, [.., 1]), ``choices``
+    #: [rows a step, routed layers, row_len, experts a token] for the same
+    #: rows, and per step and routed layer moe_pairs_total, moe_pairs_held,
+    #: moe_expert_pairs [.., experts held] (``ops/seqmodel.apply_step``)
     training_record: dict
     config: Any = None
 
@@ -426,6 +451,10 @@ class SequenceAlgorithm(Algorithm):
                 attention_out=p.attention_out_multiplier, key=p.key_multiplier,
                 mlp_gate=gate, mlp_down=down,
             ),
+            experts=p.moe_num_primary_experts, experts_held=p.moe_experts_held,
+            expert_start=p.moe_expert_start,
+            experts_per_token=p.moe_num_active_primary_experts,
+            expert_width=p.moe_ffn_hidden_size, window=p.sliding_window_size,
         )
 
     def train(self, ctx: EngineContext, pd: PackedSequences) -> SequenceModel:
@@ -476,9 +505,15 @@ class SequenceAlgorithm(Algorithm):
             params = {k: np.asarray(v) for k, v in state["params"].items()}
             record = jax.tree.map(
                 lambda *xs: np.stack([np.asarray(x) for x in xs]), *records)
-            record[seqmodel.PROBE_NAME[cfg.layer_types[0]]] = np.stack(
-                [np.asarray(x) for x in probes])
+            first = jax.tree.map(
+                lambda *xs: np.stack([np.asarray(x) for x in xs]), *probes)
+            if isinstance(first, dict):  # a routed block names its own
+                record.update(first)
+            else:
+                record[seqmodel.PROBE_NAME[cfg.layer_types[0]]] = first
             span.tags = {"bytes": int(sum(v.nbytes for v in params.values()))}
+            if "moe_expert_pairs" in record:
+                span.tags["counters"] = _routing_counters(record)
             del state
         log.info(
             "trained %d steps: loss %s", p.steps_per_retrain,
@@ -569,6 +604,25 @@ class SequenceAlgorithm(Algorithm):
         )
 
 
+def _routing_counters(record: dict) -> dict:
+    """The routed layers' counters of a training record as flat numbers (a
+    span's ``counters`` tag, the ``stages`` extra's ``counters``): the
+    retrain's sums, and a step and layer the pairs all tokens made, those of
+    the experts held (the pairs computed) and the busiest held expert's."""
+    pairs = record["moe_expert_pairs"]
+    out = {
+        "moe_experts_held": int(pairs.shape[-1]),
+        "moe_pairs_total": int(record["moe_pairs_total"].sum()),
+        "moe_pairs_held": int(record["moe_pairs_held"].sum()),
+    }
+    for s, l in np.ndindex(pairs.shape[:2]):
+        at = f".step{s}.layer{l}"
+        out["moe_pairs_total" + at] = int(record["moe_pairs_total"][s, l])
+        out["moe_pairs_held" + at] = int(record["moe_pairs_held"][s, l])
+        out["moe_expert_pairs_max" + at] = int(pairs[s, l].max())
+    return out
+
+
 def _fetch_winners(pending: list) -> list:
     """The wave's packed winners, to the host: once a wave, after every
     query's forward and top-k have been dispatched."""
@@ -593,6 +647,7 @@ def sequence_engine() -> Engine:
         SequenceDataSource,
         SequencePreparator,
         # one algorithm under the name of each block's recurrence
-        {"gdn": SequenceAlgorithm, "ssd": SequenceAlgorithm},
+        {"gdn": SequenceAlgorithm, "ssd": SequenceAlgorithm,
+         "moe": SequenceAlgorithm},
         FirstServing,
     )

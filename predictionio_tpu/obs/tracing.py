@@ -112,7 +112,9 @@ class Span:
         #: correlation id captured from the request context at entry
         self.request_id: str | None = None
         #: small free-form annotations (route, status, ...) — keep it small;
-        #: every root span's dict lands in the trace ring
+        #: every root span's dict lands in the trace ring.  What a span COUNTED
+        #: goes under the one tag ``counters`` (a dict of numbers): the train
+        #: workflow sums those into the ``stages`` extra's ``counters``
         self.tags: dict[str, Any] | None = None
         #: distributed-tracing identity (obs/disttrace.py): a per-span id,
         #: the cross-process parent (root spans adopt X-Pio-Parent-Span),

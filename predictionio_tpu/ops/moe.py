@@ -1,0 +1,351 @@
+"""A layer of routed experts, as ONE share of an expert-parallel deployment
+holds it.
+
+The router keeps its published width: every token's logits over ALL experts,
+the ``k`` largest, and the weights ``softmax`` over those ``k`` (float32,
+``Precision.HIGHEST``).  The share holds experts ``start .. start + held`` and
+computes the (token, expert) pairs of THOSE experts alone; what the other
+shares' experts would add is left out and the partial result goes on
+(``ops/seqmodel.py``, "The share").  On one chip the layer runs without its
+exchange: tensor-parallel attention over the same chips replicates the tokens,
+so every pair of a held expert is already here.
+
+**No pair is dropped.**  The pairs are laid out by expert in a buffer of
+``plan_rows`` rows, each expert's pairs from a row that is a multiple of
+``tile`` (an expert with no pair still gets one tile of zeros, so that its
+gradient is written).  The buffer is sized for the worst case, every token
+choosing ``min(k, held)`` held experts, so capacity is never a reason to drop
+a pair; the grouped products work through the tiles that hold pairs and skip
+the rest.  Padding tokens (``valid`` false) make no pair.
+
+**The products.**  One tile of pairs times its expert's matrix, bfloat16
+inputs and float32 accumulation, in the forward and in both products of the
+backward: ``moe_gmm`` (pairs x weights, and pairs x weights^T for the
+gradient of the pairs) and ``moe_tgmm`` (pairs^T x pairs, the weights'
+gradient, summed over an expert's tiles).  On a TPU they are the Pallas
+kernels below (the weight block stays in VMEM across an expert's tiles);
+elsewhere ``jax.numpy`` over the same tiles.
+
+    gate | up = xs @ [W_gate | W_up]      one product, K = hidden
+    y         = (act(gate) * up) @ W_down
+    out[t]    = sum over t's held pairs of  w * y
+
+``expert_ffn`` carries its own backward pass: gathers in both directions (a
+pair's row is written once), never a scatter-add of rows.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+HIGHEST = jax.lax.Precision.HIGHEST
+
+#: scoped VMEM the kernels may use: a weight block [2560, 1536] bf16 twice
+#: over, a tile of pairs and its result (the default of 16 MiB is less)
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+
+
+def act(g):
+    """The experts' gate activation: ReLU (the published ReGLU)."""
+    return jnp.maximum(g, 0.0)
+
+
+def act_grad(g):
+    return (g > 0).astype(g.dtype)
+
+
+def route(logits, k: int):
+    """[N, E] float32 logits -> (chosen experts [N, k] int32, their weights
+    [N, k]): the ``k`` largest logits, then ``softmax`` over those ``k`` (they
+    sum to one)."""
+    top, idx = jax.lax.top_k(logits, k)
+    return idx.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
+
+
+class Plan(NamedTuple):
+    """Where every held pair lies in the buffer of ``plan_rows`` rows."""
+
+    #: [N, k]: the row of pair (t, j); ``plan_rows`` where the pair's expert
+    #: is not held here (or the token is padding)
+    dest: jax.Array
+    #: [R]: the token a row reads; N on a row no pair lies on
+    row_token: jax.Array
+    #: [R / tile]: the held expert (0 ..) a tile belongs to
+    tile_group: jax.Array
+    #: [1]: tiles that hold pairs (or an empty expert's zeros), from the front
+    n_active: jax.Array
+    #: [held]: pairs of each held expert
+    counts: jax.Array
+
+
+def plan_rows(tokens: int, k: int, held: int, tile: int) -> int:
+    """Rows of the pair buffer: the worst case, every token choosing
+    ``min(k, held)`` held experts, and under ``tile`` rows of padding an
+    expert (a whole tile for an expert with no pair)."""
+    worst = tokens * min(k, held)
+    return -(-worst // tile) * tile + held * tile
+
+
+def make_plan(idx, valid, start: int, held: int, tile: int) -> Plan:
+    """idx: [N, k] chosen experts of each token; valid: [N] bool.  The held
+    pairs in token order within each expert, experts one after another from
+    tile-aligned rows."""
+    N, k = idx.shape
+    R = plan_rows(N, k, held, tile)
+    local = idx - start
+    mine = (local >= 0) & (local < held) & valid[:, None]
+    # chosen[t, e]: token t chose held expert e (a token's k experts differ)
+    chosen = jnp.sum(
+        (local[:, :, None] == jnp.arange(held)) & mine[:, :, None], axis=1,
+        dtype=jnp.int32)
+    before = jnp.cumsum(chosen, axis=0) - chosen
+    counts = jnp.sum(chosen, axis=0)
+    tiles = jnp.maximum(-(-counts // tile), 1)
+    ends = jnp.cumsum(tiles)
+    first_row = (ends - tiles) * tile
+    at = jnp.clip(local, 0, held - 1)
+    rank = jnp.take_along_axis(before, at, axis=1)
+    dest = jnp.where(mine, first_row[at] + rank, R).astype(jnp.int32)
+    row_token = jnp.full((R,), N, jnp.int32).at[dest.reshape(-1)].set(
+        jnp.repeat(jnp.arange(N, dtype=jnp.int32), k), mode="drop",
+        unique_indices=True)
+    tile_group = jnp.minimum(
+        jnp.searchsorted(ends, jnp.arange(R // tile), side="right"), held - 1
+    ).astype(jnp.int32)
+    return Plan(dest, row_token, tile_group, ends[-1:].astype(jnp.int32), counts)
+
+
+# ---------------------------------------------------------------------------
+# the grouped products
+
+
+def _impl(impl: str | None) -> str:
+    return impl or ("pallas" if jax.default_backend() == "tpu" else "xla")
+
+
+def _gmm_kernel(group_ref, active_ref, lhs_ref, rhs_ref, out_ref, *, transpose_rhs):
+    del group_ref
+
+    @pl.when(pl.program_id(0) < active_ref[0])
+    def _():
+        dims = (((1,), (1 if transpose_rhs else 0,)), ((), ()))
+        out_ref[...] = jax.lax.dot_general(
+            lhs_ref[...], rhs_ref[...], dims,
+            preferred_element_type=jnp.float32)
+
+
+def gmm(lhs, rhs, plan: Plan, *, transpose_rhs: bool = False, name: str,
+        impl: str | None = None):
+    """``lhs`` [R, K] times, tile by tile, its expert's ``rhs`` [held, K, N]
+    (or [held, N, K] with ``transpose_rhs``) -> [R, N] float32.  Rows of the
+    tiles past ``plan.n_active`` are not written (nothing reads them)."""
+    R, K = lhs.shape
+    n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+    tiles = plan.tile_group.shape[0]
+    tm = R // tiles
+    impl = _impl(impl)
+    if impl == "xla":
+        w = rhs[plan.tile_group]
+        out = jnp.einsum(
+            "itk,ink->itn" if transpose_rhs else "itk,ikn->itn",
+            lhs.reshape(tiles, tm, K), w, preferred_element_type=jnp.float32)
+        live = jnp.arange(tiles) < plan.n_active[0]
+        return jnp.where(live[:, None, None], out, 0.0).reshape(R, n)
+    def last(i, active):
+        return jnp.minimum(i, active[0] - 1)
+
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, transpose_rhs=transpose_rhs),
+        out_shape=jax.ShapeDtypeStruct((R, n), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(tiles,),
+            in_specs=[
+                pl.BlockSpec((tm, K), lambda i, g, a: (last(i, a), 0)),
+                pl.BlockSpec((None,) + rhs.shape[1:],
+                             lambda i, g, a: (g[last(i, a)], 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((tm, n), lambda i, g, a: (last(i, a), 0)),
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * R * K * n, transcendentals=0,
+            bytes_accessed=lhs.size * lhs.dtype.itemsize + 4 * R * n
+            + rhs.size * rhs.dtype.itemsize),
+        interpret=impl == "interpret",
+        name=name,
+    )(plan.tile_group, plan.n_active, lhs, rhs)
+
+
+def _tgmm_kernel(group_ref, active_ref, lhs_ref, rhs_ref, out_ref):
+    i = pl.program_id(1)
+
+    @pl.when(i < active_ref[0])
+    def _():
+        part = jax.lax.dot_general(
+            lhs_ref[...], rhs_ref[...], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        first = (i == 0) | (group_ref[i] != group_ref[jnp.maximum(i - 1, 0)])
+
+        @pl.when(first)
+        def _():
+            out_ref[...] = part
+
+        @pl.when(jnp.logical_not(first))
+        def _():
+            out_ref[...] += part
+
+
+def tgmm(lhs, rhs, plan: Plan, held: int, *, name: str, impl: str | None = None,
+         block_n: int = 768):
+    """Per held expert, ``lhs[rows]^T @ rhs[rows]`` over the expert's tiles:
+    lhs [R, K], rhs [R, N] -> [held, K, N] float32 (every expert has a tile,
+    so every block is written)."""
+    R, K = lhs.shape
+    n = rhs.shape[1]
+    tiles = plan.tile_group.shape[0]
+    tm = R // tiles
+    impl = _impl(impl)
+    if impl == "xla":
+        live = (jnp.arange(tiles) < plan.n_active[0])[:, None, None]
+        part = jnp.einsum(
+            "itk,itn->ikn", jnp.where(live, lhs.reshape(tiles, tm, K), 0),
+            jnp.where(live, rhs.reshape(tiles, tm, n), 0),
+            preferred_element_type=jnp.float32)
+        return jnp.zeros((held, K, n), jnp.float32).at[plan.tile_group].add(part)
+    tn = block_n if n % block_n == 0 else n
+
+    def last(i, active):
+        return jnp.minimum(i, active[0] - 1)
+
+    return pl.pallas_call(
+        _tgmm_kernel,
+        out_shape=jax.ShapeDtypeStruct((held, K, n), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n // tn, tiles),
+            in_specs=[
+                pl.BlockSpec((tm, K), lambda j, i, g, a: (last(i, a), 0)),
+                pl.BlockSpec((tm, tn), lambda j, i, g, a: (last(i, a), j)),
+            ],
+            out_specs=pl.BlockSpec(
+                (None, K, tn), lambda j, i, g, a: (g[last(i, a)], 0, j)),
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * R * K * n, transcendentals=0,
+            bytes_accessed=(lhs.size * (n // tn) + rhs.size) * lhs.dtype.itemsize
+            + 4 * held * K * n),
+        interpret=impl == "interpret",
+        name=name,
+    )(plan.tile_group, plan.n_active, lhs, rhs)
+
+
+# ---------------------------------------------------------------------------
+# the experts over a plan
+
+
+def _gather_rows(x, index):
+    """``x[index]`` with zeros where the index is past the last row."""
+    return jnp.take(x, index, axis=0, mode="fill", fill_value=0)
+
+
+def _combine(rows, dest, weights):
+    """out[t] = sum_j weights[t, j] * rows[dest[t, j]] (one gather a choice:
+    the k gathered copies are never held side by side)."""
+    out = None
+    for j in range(dest.shape[1]):
+        part = _gather_rows(rows, dest[:, j])
+        if weights is not None:
+            part = part * weights[:, j, None]
+        out = part if out is None else out + part
+    return out
+
+
+def _ffn_forward(static, m, w, gate, up, down, plan):
+    dtype, impl = static
+    with jax.named_scope("moe.dispatch"):
+        xs = _gather_rows(m.astype(dtype), plan.row_token)
+    with jax.named_scope("moe.experts"):
+        both = jnp.concatenate([gate.astype(dtype), up.astype(dtype)], axis=2)
+        gu = gmm(xs, both, plan, name="moe_gmm_gate_up", impl=impl)
+        f = gate.shape[2]
+        a = (act(gu[:, :f]) * gu[:, f:]).astype(dtype)
+        ys = gmm(a, down.astype(dtype), plan, name="moe_gmm_down", impl=impl)
+    with jax.named_scope("moe.combine"):
+        out = _combine(ys, plan.dest, w)
+    return out, (xs, gu)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def expert_ffn(static, m, w, gate, up, down, plan: Plan):
+    """The held experts' part of the layer's output.  m: [N, D] float32 (the
+    normed stream); w: [N, k] float32 weights of each token's choices; gate,
+    up: [held, D, F]; down: [held, F, D]; ``static`` = (the products' input
+    dtype, the implementation) -> [N, D] float32."""
+    return _ffn_forward(static, m, w, gate, up, down, plan)[0]
+
+
+def _ffn_fwd(static, m, w, gate, up, down, plan):
+    out, (xs, gu) = _ffn_forward(static, m, w, gate, up, down, plan)
+    return out, (xs, gu, w, gate, up, down, plan)
+
+
+def _ffn_bwd(static, res, dout):
+    dtype, impl = static
+    xs, gu, w, gate, up, down, plan = res
+    held, _, f = gate.shape
+    R = xs.shape[0]
+    with jax.named_scope("moe.combine"):
+        # a row's weight, and the output's gradient at the row's token
+        row_w = jnp.zeros((R,), jnp.float32).at[plan.dest.reshape(-1)].set(
+            w.reshape(-1), mode="drop", unique_indices=True)
+        g_rows = _gather_rows(dout, plan.row_token)
+    with jax.named_scope("moe.experts"):
+        g_act, u = act(gu[:, :f]), gu[:, f:]
+        a = g_act * u
+        # d(out) / d(weight of a row) = <dout[token], y[row]> = <dout W_down^T, a>
+        da = gmm(g_rows.astype(dtype), down.astype(dtype), plan,
+                 transpose_rhs=True, name="moe_gmm_down_dlhs", impl=impl)
+        dw_row = jnp.sum(da * a, axis=-1)
+        da = da * row_w[:, None]
+        ddown = tgmm(
+            a.astype(dtype), (g_rows * row_w[:, None]).astype(dtype), plan, held,
+            name="moe_tgmm_down", impl=impl)
+        dgu = jnp.concatenate(
+            [da * u * act_grad(gu[:, :f]), da * g_act], axis=1).astype(dtype)
+        both = jnp.concatenate([gate.astype(dtype), up.astype(dtype)], axis=2)
+        dxs = gmm(dgu, both, plan, transpose_rhs=True,
+                  name="moe_gmm_gate_up_dlhs", impl=impl)
+        dboth = tgmm(xs, dgu, plan, held, name="moe_tgmm_gate_up", impl=impl)
+    with jax.named_scope("moe.dispatch"):
+        dm = _combine(dxs, plan.dest, None)
+        dw = _gather_rows(dw_row, plan.dest.reshape(-1)).reshape(w.shape)
+    return dm, dw, dboth[:, :, :f], dboth[:, :, f:], ddown, None
+
+
+expert_ffn.defvjp(_ffn_fwd, _ffn_bwd)
+
+
+def experts_layer(m, logits, valid, gate, up, down, *, k: int, start: int,
+                  tile: int, dtype, impl: str | None = None):
+    """The held experts' part of a routed layer.  m: [N, D] what the experts
+    read; logits: [N, E] the router's (made by the caller, from what the
+    router reads); valid: [N] bool, false on padding -> (out [N, D], the
+    choices [N, k], the pairs of each held expert [held])."""
+    held = gate.shape[0]
+    with jax.named_scope("moe.route"):
+        idx, w = route(logits, k)
+        plan = make_plan(idx, valid, start, held, tile)
+    out = expert_ffn((dtype, impl), m, w, gate, up, down, plan)
+    return out, idx, plan.counts

@@ -1,4 +1,4 @@
-"""Hybrid sequence models, trainable on packed rows of tokens: two blocks.
+"""Hybrid sequence models, trainable on packed rows of tokens: three blocks.
 
 Layers follow ``layer_types``; the block's FORM is a property of the kind.
 
@@ -30,10 +30,27 @@ multipliers (``MuP``) where the published model has them:
   query heads into the same attention kernel), rotary positions over the
   whole head that RESTART at every segment of a packed row, no q / k norm.
 
+``"global_attention_moe"`` / ``"sliding_attention_moe"`` (the SmallThinker
+block): pre-norm, grouped-query attention with no q / k norm, then a layer of
+routed experts (``ops/moe.py``) in place of the MLP, ``x1 = x + attn(h)``,
+``x2 = x1 + experts(RMSNorm(x1))`` with ``h = RMSNorm(x)``:
+
+* the router reads ``h``, BEFORE attention (a deployment fetches the chosen
+  experts while attention runs): logits over all experts, the ``k`` largest,
+  weights ``softmax`` over those ``k``; experts ReGLU,
+  ``W_down(relu(W_gate m) * W_up m)``.
+* global kind: causal softmax attention over the whole segment, NO rotary
+  positions, through the attention kernel the other blocks call.
+* sliding kind: rotary positions (restarting at every segment) and a window:
+  query t sees key s iff ``0 <= t - s < window`` in its segment; on a TPU the
+  library's block-sparse splash attention (blocks outside the window or the
+  segment are skipped; multi-query: a KV head's query heads in one call).
+
 **The share.**  A deployment divides every layer over ``chips`` chips; this
 process holds one share of it: ``heads`` of the attention heads, ``mlp_cols``
-of the MLP's columns, ``vocab_rows`` rows of the embedding and the head,
-starting at ``vocab_start``.  Every function computes the part of the result
+of the MLP's columns (or ``experts_held`` of the experts, from
+``expert_start``; the router whole), ``vocab_rows`` rows of the embedding and
+the head, starting at ``vocab_start``.  Every function computes the part of the result
 its share gives (the sum over its heads of ``o_h W_o[h]``, the sum over its
 MLP columns, logits and loss over its vocabulary rows; an id outside its rows
 embeds to zero).  Nothing stands in for the other shares or their exchange.
@@ -45,7 +62,9 @@ is over the channels held.
 **Precision.**  Master weights, gradients and Adam moments float32.  The large
 matrix products take bfloat16 inputs and accumulate in float32, in the forward
 and both backward products (``mm``).  Residual stream, norms, the
-convolution, the decay projections and everything of the delta rule float32.
+convolution, the decay projections and everything of the delta rule float32;
+the router's logits, its top-k and the chosen experts' weights float32 at
+``Precision.HIGHEST``.
 
 **Training.**  ``train_steps`` dispatches, for each optimiser step, the rows of
 the step one at a time (forward, per-layer recomputation, backward; gradients
@@ -53,7 +72,10 @@ accumulated in place) and then AdamW; buffers are donated from program to
 program and nothing returns to the host between steps.  Each row also hands
 back the first layer's recurrence (the delta rule's output, the state
 space's ``S_t C_t``) along a seeded vector (``trunk``): what the training
-record holds the carried state's precision by.
+record holds the carried state's precision by.  A routed block hands back
+instead the first layer's experts applied to ``h`` (exact on both sides of a
+comparison) along a seeded vector, every layer's choices, and the pairs each
+held expert computed, which the step's accumulator sums beside the gradients.
 """
 
 from __future__ import annotations
@@ -66,17 +88,23 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from predictionio_tpu.ops import gdn, ssd
+from predictionio_tpu.ops import gdn, moe, ssd
 
 HIGHEST = jax.lax.Precision.HIGHEST
 
 LINEAR = "linear_attention"
 FULL = "full_attention"
 PARALLEL = "parallel_ssm_attention"
+GLOBAL_MOE = "global_attention_moe"
+SLIDING_MOE = "sliding_attention_moe"
+#: the kinds whose feed-forward is a layer of routed experts
+MOE_KINDS = (GLOBAL_MOE, SLIDING_MOE)
+KINDS = (LINEAR, FULL, PARALLEL) + MOE_KINDS
 
 #: what the training record calls the first layer's probe, by its kind
 PROBE_NAME = {LINEAR: "delta_rule_probe", FULL: "delta_rule_probe",
-              PARALLEL: "ssd_probe"}
+              PARALLEL: "ssd_probe", GLOBAL_MOE: "moe_probe",
+              SLIDING_MOE: "moe_probe"}
 
 #: segment id of a row's padding (real segments count from 0)
 PAD_SEGMENT = -1
@@ -120,8 +148,9 @@ def _scaled(x, m: float):
 class SeqConfig:
     """Widths as published, counts as HELD by this share.  The ``lin_*``
     sizes are read by ``"linear_attention"`` layers, ``heads`` / ``head_dim``
-    by both attention mixers, ``kv_heads``, ``rope_theta``, ``ssm_*`` and
-    ``mup`` by ``"parallel_ssm_attention"`` layers."""
+    by every attention mixer, ``kv_heads`` and ``rope_theta`` by the parallel
+    and the routed blocks, ``ssm_*`` and ``mup`` by ``"parallel_ssm_attention"``
+    layers, ``experts*``, ``expert_*`` and ``window`` by the ``*_moe`` kinds."""
 
     hidden: int
     layer_types: tuple[str, ...]
@@ -162,12 +191,36 @@ class SeqConfig:
     #: sequential pass of the state space: None = by backend (ops/ssd.py)
     ssm_impl: str | None = None
     mup: MuP = MuP()
+    #: the routed blocks: the router's width (ALL experts), the experts held
+    #: here and the first of them, experts a token, an expert's width
+    experts: int = 0
+    experts_held: int = 0
+    expert_start: int = 0
+    experts_per_token: int = 0
+    expert_width: int = 0
+    #: keys a query of a sliding layer sees, itself included
+    window: int = 0
+    #: rows of a tile of (token, expert) pairs (ops/moe.py)
+    moe_tile: int = 256
+    #: the grouped products: None = by backend (ops/moe.py)
+    moe_impl: str | None = None
 
     def __post_init__(self):
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
-        bad = set(self.layer_types) - {LINEAR, FULL, PARALLEL}
+        bad = set(self.layer_types) - set(KINDS)
         if bad:
-            raise ValueError(f"unknown layer types {sorted(bad)}")
+            raise ValueError(
+                f"unknown layer types {sorted(bad)}: the kinds are {list(KINDS)}")
+        if set(self.layer_types) & set(MOE_KINDS):
+            if not (self.experts and self.experts_held and self.expert_width
+                    and 0 < self.experts_per_token <= self.experts):
+                raise ValueError("*_moe layers need the experts' sizes")
+            if self.expert_start + self.experts_held > self.experts:
+                raise ValueError("the experts held lie outside the router's width")
+            if self.heads % (self.kv_heads or self.heads):
+                raise ValueError("heads do not divide over their groups")
+            if SLIDING_MOE in self.layer_types and self.window <= 0:
+                raise ValueError(f"{SLIDING_MOE} layers need a window")
         if PARALLEL in self.layer_types:
             if not (self.ssm_heads and self.ssm_head_dim and self.ssm_state):
                 raise ValueError(f"{PARALLEL} layers need the ssm_* sizes")
@@ -177,9 +230,11 @@ class SeqConfig:
 
     @property
     def token_multiple(self) -> int:
-        """Row lengths are multiples of this (the recurrences' chunks)."""
+        """Row lengths are multiples of this (the recurrences' chunks, the
+        windowed attention's smallest block)."""
         return math.lcm(*(
-            self.ssm_chunk if kind == PARALLEL else self.chunk
+            self.ssm_chunk if kind == PARALLEL
+            else 128 if kind in MOE_KINDS else self.chunk
             for kind in self.layer_types))
 
 
@@ -242,6 +297,18 @@ def param_shapes(cfg: SeqConfig) -> dict[str, tuple[int, ...]]:
                 p + "pre_ff_norm": (D,),
                 p + "gate": (D, cfg.mlp_cols), p + "up": (D, cfg.mlp_cols),
                 p + "down": (cfg.mlp_cols, D),
+            })
+            continue
+        elif kind in MOE_KINDS:
+            kv = (cfg.kv_heads or cfg.heads) * cfg.head_dim
+            E, F = cfg.experts_held, cfg.expert_width
+            shapes.update({
+                p + "input_norm": (D,), p + "router": (D, cfg.experts),
+                p + "q": (D, cfg.heads * cfg.head_dim), p + "k": (D, kv),
+                p + "v": (D, kv), p + "o": (cfg.heads * cfg.head_dim, D),
+                p + "post_norm": (D,),
+                p + "experts_gate": (E, D, F), p + "experts_up": (E, D, F),
+                p + "experts_down": (E, F, D),
             })
             continue
         else:
@@ -459,22 +526,94 @@ def linear_attention(cfg: SeqConfig, p: dict, x, seg):
         return y, jnp.concatenate(probes, axis=-1)
 
 
-def _dense_attention(q, k, v, seg, scale):
-    """[B, H, T, d] -> causal softmax attention within the segment."""
+def _dense_attention(q, k, v, seg, scale, window=None):
+    """[B, H, T, d] -> causal softmax attention within the segment (and,
+    with ``window``, over a query's last ``window`` keys, itself included)."""
     T = q.shape[2]
     s = jnp.einsum(
         "bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32) * scale
-    mask = jnp.tril(jnp.ones((T, T), bool)) & (
-        seg[:, None, :, None] == seg[:, None, None, :])
+    causal = jnp.tril(jnp.ones((T, T), bool))
+    if window is not None:
+        causal &= ~jnp.tril(jnp.ones((T, T), bool), -window)
+    mask = causal & (seg[:, None, :, None] == seg[:, None, None, :])
     p = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), axis=-1)
     return jnp.einsum(
         "bhqk,bhkd->bhqd", p.astype(v.dtype), v, preferred_element_type=jnp.float32)
 
 
-def _attend(cfg: SeqConfig, q, k, v, seg):
-    """[B, T, H, d] each -> causal softmax attention within the segment,
-    [B, T, H * d] float32: the library's blocked kernel or ``jax.numpy``."""
+#: splash attention's blocks (queries x keys), forward and both backward
+#: kernels; a shorter row is one block
+WINDOW_BLOCK = 512
+
+
+@functools.lru_cache(maxsize=8)
+def _splash_kernel(T: int, rep: int, window: int, interpret: bool):
+    """The library's block-sparse kernel for ``rep`` query heads on one KV
+    head over rows of T: local + causal mask (a query's last ``window`` keys),
+    blocks outside it skipped; segment ids come with the call."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk, splash_attention_mask as sm)
+
+    b = min(WINDOW_BLOCK, T)
+    mask = sm.MultiHeadMask(
+        [sm.LocalMask((T, T), (window - 1, 0), 0) for _ in range(rep)])
+    with jax.ensure_compile_time_eval():
+        return sk.make_splash_mqa_single_device(
+            mask, interpret=interpret, block_sizes=sk.BlockSizes(
+                block_q=b, block_kv=b, block_kv_compute=b, block_q_dkv=b,
+                block_kv_dkv=b, block_kv_dkv_compute=b, block_q_dq=b,
+                block_kv_dq=b))
+
+
+def _repeat_kv(k, v, rep: int):
+    """[B, T, KV, d] -> each KV head repeated for the ``rep`` query heads it
+    serves.  The barrier keeps the repeat out of the attention's products:
+    folded into them, XLA's CPU backend meets a bf16 dot it cannot run."""
+    if rep == 1:
+        return k, v
+    return jax.lax.optimization_barrier(
+        tuple(jnp.repeat(t, rep, axis=2) for t in (k, v)))
+
+
+def _window_attention(cfg: SeqConfig, q, k, v, seg, window: int):
+    """``_attend``'s windowed branch.  q: [B, T, H, d]; k, v: [B, T, KV, d],
+    NOT repeated: a KV head's query heads go through one multi-query call."""
     B, T, H, d = q.shape
+    KV = k.shape[2]
+    rep = H // KV
+    impl = cfg.attn_impl or (
+        "flash" if jax.default_backend() == "tpu" else "dense")
+    if impl == "dense":
+        k, v = _repeat_kv(k, v, rep)
+        q, k, v = (
+            t.transpose(0, 2, 1, 3).astype(MATMUL_DTYPE) for t in (q, k, v))
+        return _dense_attention(q, k, v, seg, d ** -0.5, window)
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk)
+
+    kernel = _splash_kernel(T, rep, window, impl == "interpret")
+    # the kernel takes its scores unscaled: the scale goes into q
+    q = (q * d ** -0.5).astype(MATMUL_DTYPE).transpose(0, 2, 1, 3)
+    k, v = (t.astype(MATMUL_DTYPE).transpose(0, 2, 1, 3) for t in (k, v))
+    of_group = jax.vmap(kernel, in_axes=(0, 0, 0, None))
+    o = jax.vmap(of_group)(
+        q.reshape(B, KV, rep, T, d), k, v, sk.SegmentIds(q=seg, kv=seg))
+    return o.reshape(B, H, T, d)
+
+
+def _attend(cfg: SeqConfig, q, k, v, seg, window: int | None = None):
+    """[B, T, H, d] each -> causal softmax attention within the segment,
+    [B, T, H * d] float32.  Without a window (``full_attention``,
+    ``parallel_ssm_attention`` and ``global_attention_moe`` layers; k and v
+    already repeated for their query heads): the library's blocked flash
+    kernel or ``jax.numpy``.  With one (``sliding_attention_moe`` layers; k
+    and v as held, [B, T, KV, d]): ``_window_attention``, the library's
+    block-sparse splash kernel or ``jax.numpy`` under a banded mask."""
+    B, T, H, d = q.shape
+    if window is not None:
+        with jax.named_scope("attn.window"):
+            o = _window_attention(cfg, q, k, v, seg, window)
+        return o.astype(jnp.float32).transpose(0, 2, 1, 3).reshape(B, T, H * d)
     q, k, v = (
         t.transpose(0, 2, 1, 3).astype(MATMUL_DTYPE) for t in (q, k, v))
     impl = cfg.attn_impl or (
@@ -535,13 +674,59 @@ def grouped_query_attention(cfg: SeqConfig, p: dict, h, seg):
             pos = segment_positions(seg)
             q = rope(q, pos, cfg.rope_theta)
             k = rope(_scaled(k, mup.key), pos, cfg.rope_theta)
-        rep = q.shape[2] // k.shape[2]
-        if rep > 1:
-            # the barrier keeps the repeat out of the attention's products:
-            # folded into them, XLA's CPU backend meets a bf16 dot it cannot run
-            k, v = jax.lax.optimization_barrier(
-                tuple(jnp.repeat(t, rep, axis=2) for t in (k, v)))
+        k, v = _repeat_kv(k, v, q.shape[2] // k.shape[2])
         return _scaled(mm(_attend(cfg, q, k, v, seg), p["o"]), mup.attention_out)
+
+
+def moe_probe_vector(D: int):
+    """The seeded direction the first routed layer's output is recorded
+    along: standard normal [D] from ``fold_in(PRNGKey(PROBE_SEED), 2**20 + 2)``."""
+    return jax.random.normal(
+        jax.random.fold_in(jax.random.PRNGKey(PROBE_SEED), 2 ** 20 + 2), (D,),
+        jnp.float32)
+
+
+def routed_attention(cfg: SeqConfig, kind: str, p: dict, h, seg):
+    """The share's part of a routed block's attention: its query heads on its
+    KV heads, no q / k norm; the sliding kind with rotary positions that
+    restart at a segment and its window, the global kind with neither."""
+    B, T, _ = h.shape
+    d = cfg.head_dim
+    with jax.named_scope("seq.attn"):
+        q, k, v = (mm(h, p[n]).reshape(B, T, -1, d) for n in ("q", "k", "v"))
+        if kind == SLIDING_MOE:
+            with jax.named_scope("attn.rope"):
+                pos = segment_positions(seg)
+                q, k = rope(q, pos, cfg.rope_theta), rope(k, pos, cfg.rope_theta)
+            return mm(_attend(cfg, q, k, v, seg, cfg.window), p["o"])
+        k, v = _repeat_kv(k, v, q.shape[2] // k.shape[2])
+        return mm(_attend(cfg, q, k, v, seg), p["o"])
+
+
+def routed_layer(cfg: SeqConfig, kind: str, p: dict, x, seg):
+    """One ``*_moe`` layer -> (x after it, (the experts' output on ``h`` along
+    the probe vector [B, T, 1], the router's choices [B, T, k], the pairs of
+    each held expert [held])).  The probe feeds the experts ``h``, which a
+    first layer makes from exact embedding rows in float32: the same on both
+    sides of a comparison, as the stream after attention is not."""
+    B, T, D = x.shape
+    h = rmsnorm(x, p["input_norm"], cfg.eps)
+    with jax.named_scope("seq.moe"), jax.named_scope("moe.route"):
+        logits = mm_f32(h, p["router"]).reshape(B * T, -1)
+    x = x + routed_attention(cfg, kind, p, h, seg)
+    experts = functools.partial(
+        moe.experts_layer, logits=logits, valid=(seg != PAD_SEGMENT).reshape(-1),
+        gate=p["experts_gate"], up=p["experts_up"], down=p["experts_down"],
+        k=cfg.experts_per_token, start=cfg.expert_start, tile=cfg.moe_tile,
+        dtype=MATMUL_DTYPE, impl=cfg.moe_impl)
+    with jax.named_scope("seq.moe"):
+        m = rmsnorm(x, p["post_norm"], cfg.eps)
+        y, choices, pairs = experts(m.reshape(B * T, D))
+        probe = jax.lax.stop_gradient(jnp.matmul(
+            experts(jax.lax.stop_gradient(h).reshape(B * T, D))[0],
+            moe_probe_vector(D), precision=HIGHEST))
+    return x + y.reshape(B, T, D), (
+        probe.reshape(B, T, 1), choices.reshape(B, T, -1), pairs)
 
 
 def gated_group_norm(y, z, w, eps, axis_name=None):
@@ -620,7 +805,13 @@ def mlp(cfg: SeqConfig, p: dict, x):
 
 def layer(cfg: SeqConfig, kind: str, p: dict, x, seg):
     """-> (x after the layer, the layer's probe [B, T, H]: the delta rule's
-    or the state space's; no heads where the layer is full attention)."""
+    or the state space's; no heads where the layer is full attention).  The
+    kind takes the block's path: ``linear_attention`` / ``full_attention``
+    post-norm with one mixer and the MLP, ``parallel_ssm_attention`` pre-norm
+    with two mixers and the MLP, the ``*_moe`` kinds ``routed_layer`` (whose
+    second result is a tuple: probe, choices, pairs)."""
+    if kind in MOE_KINDS:
+        return routed_layer(cfg, kind, p, x, seg)
     if kind == PARALLEL:
         h = rmsnorm(x, p["input_norm"], cfg.eps)
         m, probe = state_space_mixer(cfg, p, h, seg)
@@ -642,14 +833,26 @@ def trunk(cfg: SeqConfig, params: dict, x, seg, remat: bool = False):
     recurrence to its token-by-token form there; deeper layers read a
     residual stream that already carries every earlier rounding).  With
     ``remat`` each layer is recomputed in the backward pass, so that only the
-    residual stream between layers is kept."""
-    first = None
+    residual stream between layers is kept.  Where layers are routed the
+    second result is a dict: the first layer's probe under its name
+    (``PROBE_NAME``), ``choices`` [B, routed layers, T, k] and ``expert_pairs``
+    [routed layers, held]."""
+    first, routed = None, []
     for i, kind in enumerate(cfg.layer_types):
         f = functools.partial(layer, cfg, kind)
         if remat:
             f = jax.checkpoint(f)
         x, probe = f(layer_params(params, i), x, seg)
+        if kind in MOE_KINDS:
+            probe, choices, pairs = probe
+            routed.append((choices, pairs))
         first = probe if first is None else first
+    if routed:
+        first = {
+            PROBE_NAME[cfg.layer_types[0]]: first,
+            "choices": jnp.stack([c for c, _ in routed], axis=1),
+            "expert_pairs": jnp.stack([n for _, n in routed]),
+        }
     return rmsnorm(x, params["final_norm"], cfg.eps), first
 
 
@@ -751,6 +954,11 @@ def init_state(cfg: SeqConfig, seed: int) -> tuple[dict, dict]:
     state = {"params": params, "m": zeros(), "v": zeros(),
              "t": jnp.zeros((), jnp.int32)}
     acc = {"g": zeros(), "loss": jnp.float32(0.0), "count": jnp.float32(0.0)}
+    routed = sum(kind in MOE_KINDS for kind in cfg.layer_types)
+    if routed:
+        # the routing counters of the step, summed beside the gradients
+        acc["expert_pairs"] = jnp.zeros((routed, cfg.experts_held), jnp.int32)
+        acc["pairs_total"] = jnp.zeros((), jnp.int32)
     return state, acc
 
 
@@ -763,9 +971,12 @@ def accumulate_row(cfg: SeqConfig, state: dict, acc: dict, tokens, seg):
     leave)."""
     loss, count, g, probe = row_grads(
         cfg, state["params"], tokens[None], seg[None], acc["g"])
-    return state, {
-        "g": g, "loss": acc["loss"] + loss, "count": acc["count"] + count
-    }, probe[0]
+    out = {"g": g, "loss": acc["loss"] + loss, "count": acc["count"] + count}
+    if "expert_pairs" in acc:
+        out["expert_pairs"] = acc["expert_pairs"] + probe.pop("expert_pairs")
+        out["pairs_total"] = acc["pairs_total"] + cfg.experts_per_token * jnp.sum(
+            seg != PAD_SEGMENT, dtype=jnp.int32)
+    return state, out, jax.tree.map(lambda a: a[0], probe)
 
 
 def grad_probe(n: int, g):
@@ -775,6 +986,8 @@ def grad_probe(n: int, g):
     error is the gradient's own error, undamped and unamplified: what a norm
     cannot show and an Adam step blows up."""
     key = jax.random.fold_in(jax.random.PRNGKey(PROBE_SEED), n)
+    if g.ndim > 2:  # stacked experts: one matrix, the experts' rows on end
+        g = g.reshape(-1, g.shape[-1])
     if g.ndim == 1:
         return jnp.sum(g * jax.random.normal(key, g.shape, jnp.float32))
     kr, kc = jax.random.split(key)
@@ -798,6 +1011,14 @@ def apply_step(opt: AdamW, state: dict, acc: dict):
         "tensor_grad_probe": {
             k: grad_probe(n, g) * scale for n, (k, g) in enumerate(gsum.items())},
     }
+    if "expert_pairs" in acc:
+        # a layer's pairs: all the step's tokens made, those of the experts
+        # held here (the pairs computed), and each held expert's
+        pairs = acc["expert_pairs"]
+        record["moe_expert_pairs"] = pairs
+        record["moe_pairs_held"] = jnp.sum(pairs, axis=-1)
+        record["moe_pairs_total"] = jnp.full(
+            pairs.shape[:1], acc["pairs_total"], jnp.int32)
     t = state["t"] + 1
     tf = t.astype(jnp.float32)
     c1 = 1.0 - opt.b1 ** tf
@@ -833,8 +1054,9 @@ def train_steps(cfg: SeqConfig, opt: AdamW, state: dict, acc: dict, tokens, seg)
     """``tokens``, ``seg``: [steps, rows, T] int32 on the device.  Every row
     and every optimiser step is dispatched at once (nothing is fetched in
     between, so the host never waits for a step) -> (state, acc, the records
-    of the steps, the first-layer probes [T, H] of the FIRST step's rows:
-    those are made from the seeded initial weights; all still on the device)."""
+    of the steps, the first-layer probes [T, H] of the FIRST step's rows
+    (a routed block's dict of them, ``trunk``): those are made from the
+    seeded initial weights; all still on the device)."""
     accumulate, apply = train_programs(cfg, opt)
     records, probes = [], []
     for s in range(tokens.shape[0]):

@@ -1199,8 +1199,11 @@ _TEMPLATE_VARIANTS = {
     # one of two chips' share of one period of the Olmo-Hybrid-7B layer
     # (published widths; 801 M parameters: a 16 GB chip trains it).  The
     # layer kinds default to that block's (linear_attention x 3,
-    # full_attention); the engine's other block, parallel_ssm_attention
-    # (Falcon-H1's), is configured in benchmark/configs/falcon-h1-34b-tp4.json
+    # full_attention); the engine's second block, parallel_ssm_attention
+    # (Falcon-H1's), is configured in benchmark/configs/falcon-h1-34b-tp4.json,
+    # its third, global_attention_moe / sliding_attention_moe (SmallThinker's
+    # routed experts behind global and sliding-window attention), in
+    # benchmark/configs/smallthinker-21b-ep4.json
     "sequence": {
         "engineFactory": "sequence",
         "datasource": {"params": {"appName": "MyApp", "eventNames": ["rate"]}},

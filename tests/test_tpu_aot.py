@@ -366,3 +366,84 @@ def test_falcon_h1_row_program_fits_beside_its_arguments(v5e):
     assert plan.argument_size_in_bytes == pytest.approx(16 * 769_637_472, rel=1e-3)
     assert plan.alias_size_in_bytes >= 0.999 * plan.argument_size_in_bytes  # donated
     assert plan.argument_size_in_bytes + plan.temp_size_in_bytes < 16_909_336_064
+
+
+# the SmallThinker block at its published widths (one of four chips' share: 16
+# of 64 experts of width 768 on a stream of 2560, 6 experts a token, rows of
+# 16384 tokens, tiles of 256 pairs)
+MOE = dict(tokens=16384, hidden=2560, width=768, experts=64, held=16, k=6, tile=256)
+
+
+@pytest.mark.parametrize("passes", ["forward", "forward_backward"])
+def test_moe_grouped_kernels_compile(v5e, passes):
+    """The experts' grouped products as ``ops/moe`` calls them at the cell's
+    size: ``moe_gmm_*`` forward (a weight block [2560, 1536] bf16 resident
+    across an expert's tiles), and under ``expert_ffn``'s own backward the
+    transposed products and ``moe_tgmm_*`` (pairs^T x pairs, contracted over a
+    tile's rows)."""
+    from predictionio_tpu.ops import moe
+
+    sds = _spec_on(SingleDeviceSharding(v5e.devices[0]))
+    N, D, F, E, held, k, tile = (MOE[n] for n in (
+        "tokens", "hidden", "width", "experts", "held", "k", "tile"))
+    assert moe.plan_rows(N, k, held, tile) == N * k + held * tile == 102_400
+
+    def layer(m, logits, valid, gate, up, down):
+        return moe.experts_layer(
+            m, logits, valid, gate, up, down, k=k, start=0, tile=tile,
+            dtype=jnp.bfloat16, impl="pallas")[0].sum()
+
+    fn = jax.jit(layer if passes == "forward" else jax.grad(
+        layer, argnums=(0, 1, 3, 4, 5)))
+    text = _compile(
+        fn, sds((N, D)), sds((N, E)), sds((N,), jnp.bool_), sds((held, D, F)),
+        sds((held, D, F)), sds((held, F, D))).as_text()
+    assert "moe_gmm_gate_up" in text and "moe_gmm_down" in text
+    for name in ("moe_gmm_down_dlhs", "moe_gmm_gate_up_dlhs", "moe_tgmm_down",
+                 "moe_tgmm_gate_up"):
+        assert (name in text) == (passes == "forward_backward"), name
+
+
+def test_smallthinker_row_program_fits_beside_its_arguments(v5e):
+    """The training row of ``smallthinker-21b-ep4.retrain`` as the chip
+    compiles it (flash attention in the global layer, the library's splash
+    kernel under the window, the grouped expert kernels; 16384 tokens,
+    496.4 M parameters at 16 bytes): the compiler plans its temporaries beside
+    7.94 GB of weights, moments and gradient sums, under the 16,909,336,064 B
+    the v5e's allocator reports as its limit (PERF.md).  A plan, not a
+    reading."""
+    import dataclasses
+    import json
+    from pathlib import Path
+
+    from predictionio_tpu.models.sequence import engine as seq
+    from predictionio_tpu.ops import seqmodel
+    from predictionio_tpu.utils.params import extract_params
+
+    body = json.loads((Path(__file__).resolve().parents[1] / "benchmark" / "configs"
+                       / "smallthinker-21b-ep4.json").read_text())
+    algo = seq.SequenceAlgorithm(extract_params(
+        seq.SequenceAlgorithmParams, body["engine_json"]["algorithms"][0]["params"]))
+    cfg = dataclasses.replace(algo.seq_config(), attn_impl="flash", moe_impl="pallas")
+    assert seqmodel.num_params(cfg) == body["share"]["parameters_held"] == 496_376_320
+    by_part = body["share"]["parameters_by_part"]
+    assert 4 * (by_part["attention_a_layer"] + by_part["router_a_layer"]
+                + by_part["experts_a_layer"] + by_part["norms_a_layer"]) + by_part[
+        "embedding_and_head"] + by_part["final_norm"] == 496_376_320
+    row_len = body["engine_json"]["preparator"]["params"]["rowLen"]
+    assert row_len == body["max_position_embeddings"] == 16384
+    sds = _spec_on(SingleDeviceSharding(v5e.devices[0]))
+    state, acc = jax.eval_shape(lambda: seqmodel.init_state(cfg, 3))
+    state, acc = jax.tree.map(lambda a: sds(a.shape, a.dtype), (state, acc))
+    assert acc["expert_pairs"].shape == (4, 16)
+    accumulate, _ = seqmodel.train_programs(cfg, seqmodel.AdamW())
+    compiled = _compile(
+        accumulate, state, acc, sds((row_len,), jnp.int32), sds((row_len,), jnp.int32))
+    text = compiled.as_text()
+    for name in ("moe_gmm_gate_up", "moe_tgmm_down", "splash_mqa_fwd_segmented",
+                 "splash_mqa_dkv_segmented"):
+        assert name in text, name
+    plan = compiled.memory_analysis()
+    assert plan.argument_size_in_bytes == pytest.approx(16 * 496_376_320, rel=1e-3)
+    assert plan.alias_size_in_bytes >= 0.999 * plan.argument_size_in_bytes  # donated
+    assert plan.argument_size_in_bytes + plan.temp_size_in_bytes < 16_909_336_064
