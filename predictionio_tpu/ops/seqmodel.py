@@ -12,9 +12,9 @@ token mixer and a SwiGLU MLP a layer with the OLMo 2/3 residual form
   dt_bias)``; the gated delta rule (``ops/gdn.py``); per-head RMSNorm of the
   output gated by ``SiLU(W_g x)``; output projection.
 * full attention: per-head RMSNorm on q and k, no positional encoding,
-  causal softmax within the segment; on a TPU the blocked Pallas kernel of
-  ``jax.experimental.pallas.ops.tpu.flash_attention`` (no T x T score matrix),
-  elsewhere its ``jax.numpy`` form.
+  causal softmax within the segment; on a TPU the library's block-sparse
+  splash attention under a causal mask (``_attend``: no T x T score matrix,
+  blocks above the diagonal skipped), elsewhere its ``jax.numpy`` form.
 
 ``"parallel_ssm_attention"`` (the Falcon-H1 block): pre-norm, and TWO mixers
 that read one normed input ``h = RMSNorm(x)``, their outputs scaled and
@@ -26,8 +26,8 @@ multipliers (``MuP``) where the published model has them:
   over ``x | B | C``, ``Delta = softplus(dt + dt_bias)``, Mamba-2's selective
   state space in its chunked form (``ops/ssd.py``) with the ``D`` skip,
   ``RMSNorm(y * SiLU(z))`` over each group's channels, output projection.
-* attention mixer: grouped-query attention (each KV head repeated for its
-  query heads into the same attention kernel), rotary positions over the
+* attention mixer: grouped-query attention (a KV head's query heads in one
+  multi-query call of the same attention kernel), rotary positions over the
   whole head that RESTART at every segment of a packed row, no q / k norm.
 
 ``"global_attention_moe"`` / ``"sliding_attention_moe"`` (the SmallThinker
@@ -42,9 +42,9 @@ routed experts (``ops/moe.py``) in place of the MLP, ``x1 = x + attn(h)``,
 * global kind: causal softmax attention over the whole segment, NO rotary
   positions, through the attention kernel the other blocks call.
 * sliding kind: rotary positions (restarting at every segment) and a window:
-  query t sees key s iff ``0 <= t - s < window`` in its segment; on a TPU the
-  library's block-sparse splash attention (blocks outside the window or the
-  segment are skipped; multi-query: a KV head's query heads in one call).
+  query t sees key s iff ``0 <= t - s < window`` in its segment: the same
+  kernel under a local mask (blocks outside the window are skipped; segment
+  ids mask inside a block, no block is skipped for them).
 
 **The share.**  A deployment divides every layer over ``chips`` chips; this
 process holds one share of it: ``heads`` of the attention heads, ``mlp_cols``
@@ -174,7 +174,8 @@ class SeqConfig:
     loss_block: int = 2048
     #: sequential pass of the delta rule: None = by backend (ops/gdn.py)
     gdn_impl: str | None = None
-    #: full attention: None = by backend; "dense" = jax.numpy
+    #: attention: None = by backend; "flash" = the TPU kernel (splash
+    #: attention), "interpret" = it under the interpreter, "dense" = jax.numpy
     attn_impl: str | None = None
     #: KV heads held by the parallel block's attention (None: one a query head)
     kv_heads: int | None = None
@@ -541,92 +542,104 @@ def _dense_attention(q, k, v, seg, scale, window=None):
         "bhqk,bhkd->bhqd", p.astype(v.dtype), v, preferred_element_type=jnp.float32)
 
 
-#: splash attention's blocks (queries x keys), forward and both backward
-#: kernels; a shorter row is one block
-WINDOW_BLOCK = 512
+#: splash attention's blocks, the forward and both backward kernels alike,
+#: under either mask: queries and keys a grid step, and the keys of one
+#: product inside it.  Chosen on the chip over the three cells' rows
+#: (``tests/micro_attention_chip.py``; PERF.md section 6, PR 34).
+ATTN_BLOCK = 1024
+ATTN_BLOCK_COMPUTE = 512
+#: the kernels' blocks are multiples of the TPU's lanes
+_LANES = 128
 
 
-@functools.lru_cache(maxsize=8)
-def _splash_kernel(T: int, rep: int, window: int, interpret: bool):
+def _attn_block(T: int) -> int:
+    """The block a row of T goes through in: ``ATTN_BLOCK``, for a shorter
+    row the power of two that holds it (whole lanes at least)."""
+    return min(ATTN_BLOCK, max(_LANES, 1 << (T - 1).bit_length()))
+
+
+@functools.lru_cache(maxsize=64)
+def _splash_kernel(T: int, rep: int, window: int | None, interpret: bool):
     """The library's block-sparse kernel for ``rep`` query heads on one KV
-    head over rows of T: local + causal mask (a query's last ``window`` keys),
-    blocks outside it skipped; segment ids come with the call."""
+    head over rows of T (a multiple of ``_attn_block(T)``): a causal mask,
+    with ``window`` a local one too (a query's last ``window`` keys); blocks
+    outside the mask are skipped; segment ids come with the call.  The cache
+    holds the masks' metadata a row length and mask: serving's powers of two
+    up to a row of 16,384 under both masks, and the training row's."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk, splash_attention_mask as sm)
 
-    b = min(WINDOW_BLOCK, T)
-    mask = sm.MultiHeadMask(
-        [sm.LocalMask((T, T), (window - 1, 0), 0) for _ in range(rep)])
+    b = _attn_block(T)
+    c = min(ATTN_BLOCK_COMPUTE, b)
+    of_head = (sm.CausalMask((T, T)) if window is None
+               else sm.LocalMask((T, T), (window - 1, 0), 0))
     with jax.ensure_compile_time_eval():
         return sk.make_splash_mqa_single_device(
-            mask, interpret=interpret, block_sizes=sk.BlockSizes(
-                block_q=b, block_kv=b, block_kv_compute=b, block_q_dkv=b,
-                block_kv_dkv=b, block_kv_dkv_compute=b, block_q_dq=b,
+            sm.MultiHeadMask([of_head] * rep), interpret=interpret,
+            block_sizes=sk.BlockSizes(
+                block_q=b, block_kv=b, block_kv_compute=c, block_q_dkv=b,
+                block_kv_dkv=b, block_kv_dkv_compute=c, block_q_dq=b,
                 block_kv_dq=b))
 
 
 def _repeat_kv(k, v, rep: int):
     """[B, T, KV, d] -> each KV head repeated for the ``rep`` query heads it
-    serves.  The barrier keeps the repeat out of the attention's products:
-    folded into them, XLA's CPU backend meets a bf16 dot it cannot run."""
+    serves (the ``jax.numpy`` path; the kernel takes them as held).  The
+    barrier keeps the repeat out of the attention's products: folded into
+    them, XLA's CPU backend meets a bf16 dot it cannot run."""
     if rep == 1:
         return k, v
     return jax.lax.optimization_barrier(
         tuple(jnp.repeat(t, rep, axis=2) for t in (k, v)))
 
 
-def _window_attention(cfg: SeqConfig, q, k, v, seg, window: int):
-    """``_attend``'s windowed branch.  q: [B, T, H, d]; k, v: [B, T, KV, d],
-    NOT repeated: a KV head's query heads go through one multi-query call."""
-    B, T, H, d = q.shape
-    KV = k.shape[2]
-    rep = H // KV
-    impl = cfg.attn_impl or (
-        "flash" if jax.default_backend() == "tpu" else "dense")
-    if impl == "dense":
-        k, v = _repeat_kv(k, v, rep)
-        q, k, v = (
-            t.transpose(0, 2, 1, 3).astype(MATMUL_DTYPE) for t in (q, k, v))
-        return _dense_attention(q, k, v, seg, d ** -0.5, window)
+def _splash_attention(q, k, v, seg, window: int | None, interpret: bool):
+    """``_attend``'s TPU path -> [B, H, T, d].  A KV head's ``rep`` query
+    heads go through one multi-query call.  The one place that says what a
+    row length the block does not divide meets (``_attn_block``: the full
+    block, or for a shorter row the power of two that holds it): the row is
+    padded at its end up to a multiple of the block with tokens of
+    ``PAD_SEGMENT``, which no real token sees and whose outputs are cut off
+    again (rows of the cells, 8192 and 16,384, take the full block and no
+    padding; so does every power of two from 128 that serving pads to)."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk)
 
-    kernel = _splash_kernel(T, rep, window, impl == "interpret")
+    B, T, H, d = q.shape
+    KV = k.shape[2]
+    rep = H // KV
+    pad = -T % _attn_block(T)
+    if pad:
+        q, k, v = (jnp.pad(t, ((0, 0), (0, pad), (0, 0), (0, 0))) for t in (q, k, v))
+        seg = jnp.pad(seg, ((0, 0), (0, pad)), constant_values=PAD_SEGMENT)
+    kernel = _splash_kernel(T + pad, rep, window, interpret)
     # the kernel takes its scores unscaled: the scale goes into q
     q = (q * d ** -0.5).astype(MATMUL_DTYPE).transpose(0, 2, 1, 3)
     k, v = (t.astype(MATMUL_DTYPE).transpose(0, 2, 1, 3) for t in (k, v))
     of_group = jax.vmap(kernel, in_axes=(0, 0, 0, None))
     o = jax.vmap(of_group)(
-        q.reshape(B, KV, rep, T, d), k, v, sk.SegmentIds(q=seg, kv=seg))
-    return o.reshape(B, H, T, d)
+        q.reshape(B, KV, rep, T + pad, d), k, v, sk.SegmentIds(q=seg, kv=seg))
+    return o.reshape(B, H, T + pad, d)[:, :, :T]
 
 
 def _attend(cfg: SeqConfig, q, k, v, seg, window: int | None = None):
-    """[B, T, H, d] each -> causal softmax attention within the segment,
-    [B, T, H * d] float32.  Without a window (``full_attention``,
-    ``parallel_ssm_attention`` and ``global_attention_moe`` layers; k and v
-    already repeated for their query heads): the library's blocked flash
-    kernel or ``jax.numpy``.  With one (``sliding_attention_moe`` layers; k
-    and v as held, [B, T, KV, d]): ``_window_attention``, the library's
-    block-sparse splash kernel or ``jax.numpy`` under a banded mask."""
+    """q: [B, T, H, d]; k, v: [B, T, KV, d] as held, or already repeated for
+    their query heads (KV = H) -> causal softmax attention within the segment
+    (with ``window``, over a query's last ``window`` keys), [B, T, H * d]
+    float32.  On a TPU the library's block-sparse splash kernel under a
+    causal or a local mask (``_splash_attention``), elsewhere ``jax.numpy``
+    under the same mask."""
     B, T, H, d = q.shape
-    if window is not None:
-        with jax.named_scope("attn.window"):
-            o = _window_attention(cfg, q, k, v, seg, window)
-        return o.astype(jnp.float32).transpose(0, 2, 1, 3).reshape(B, T, H * d)
-    q, k, v = (
-        t.transpose(0, 2, 1, 3).astype(MATMUL_DTYPE) for t in (q, k, v))
     impl = cfg.attn_impl or (
         "flash" if jax.default_backend() == "tpu" else "dense")
-    if impl == "flash":
-        from jax.experimental.pallas.ops.tpu import flash_attention as fa
-
-        o = fa.flash_attention(
-            q, k, v, segment_ids=fa.SegmentIds(q=seg, kv=seg),
-            causal=True, sm_scale=d ** -0.5,
-        )
-    else:
-        o = _dense_attention(q, k, v, seg, d ** -0.5)
+    with jax.named_scope("attn.window" if window is not None else "attn.causal"):
+        if impl == "dense":
+            k, v = _repeat_kv(k, v, H // k.shape[2])
+            q, k, v = (
+                t.transpose(0, 2, 1, 3).astype(MATMUL_DTYPE) for t in (q, k, v))
+            o = _dense_attention(q, k, v, seg, d ** -0.5, window)
+        else:
+            o = _splash_attention(q, k, v, seg, window, impl == "interpret")
     return o.astype(jnp.float32).transpose(0, 2, 1, 3).reshape(B, T, H * d)
 
 
@@ -663,8 +676,8 @@ def rope(x, pos, theta: float):
 
 def grouped_query_attention(cfg: SeqConfig, p: dict, h, seg):
     """The share's part of the parallel block's attention mixer: its query
-    heads on its KV heads (each repeated for the query heads it serves, into
-    the kernel ``full_attention`` calls), positions restarting at a segment."""
+    heads on its KV heads (k and v as held, into the kernel ``full_attention``
+    calls), positions restarting at a segment."""
     B, T, _ = h.shape
     d, mup = cfg.head_dim, cfg.mup
     with jax.named_scope("seq.attn"):
@@ -674,7 +687,6 @@ def grouped_query_attention(cfg: SeqConfig, p: dict, h, seg):
             pos = segment_positions(seg)
             q = rope(q, pos, cfg.rope_theta)
             k = rope(_scaled(k, mup.key), pos, cfg.rope_theta)
-        k, v = _repeat_kv(k, v, q.shape[2] // k.shape[2])
         return _scaled(mm(_attend(cfg, q, k, v, seg), p["o"]), mup.attention_out)
 
 
@@ -694,13 +706,13 @@ def routed_attention(cfg: SeqConfig, kind: str, p: dict, h, seg):
     d = cfg.head_dim
     with jax.named_scope("seq.attn"):
         q, k, v = (mm(h, p[n]).reshape(B, T, -1, d) for n in ("q", "k", "v"))
+        window = None
         if kind == SLIDING_MOE:
+            window = cfg.window
             with jax.named_scope("attn.rope"):
                 pos = segment_positions(seg)
                 q, k = rope(q, pos, cfg.rope_theta), rope(k, pos, cfg.rope_theta)
-            return mm(_attend(cfg, q, k, v, seg, cfg.window), p["o"])
-        k, v = _repeat_kv(k, v, q.shape[2] // k.shape[2])
-        return mm(_attend(cfg, q, k, v, seg), p["o"])
+        return mm(_attend(cfg, q, k, v, seg, window), p["o"])
 
 
 def routed_layer(cfg: SeqConfig, kind: str, p: dict, x, seg):

@@ -161,33 +161,76 @@ def test_window_mask_is_the_dense_mask():
     assert float(jnp.abs(got - full).max()) > 1e-2
 
 
-def test_splash_window_kernel_is_the_dense_mask(monkeypatch):
-    """The chip's branch under the Pallas interpreter: blocks of 128 over a
-    row of 512 (blocks outside the window of 100 or the segment are skipped),
-    3 query heads on each of 2 KV heads, against the ``jax.numpy`` branch;
-    forward and the gradients of q, k and v."""
-    monkeypatch.setattr(seqmodel, "WINDOW_BLOCK", 128)
-    monkeypatch.setattr(seqmodel, "MATMUL_DTYPE", jnp.float32)
-    rng = np.random.default_rng(1)
-    q = jnp.asarray(rng.standard_normal((1, 512, 6, 16)).astype(np.float32))
-    k, v = (jnp.asarray(rng.standard_normal((1, 512, 2, 16)).astype(np.float32))
-            for _ in range(2))
-    seg = np.zeros((1, 512), np.int32)
-    seg[0, 300:], seg[0, 330:], seg[0, 500:] = 1, 2, -1
-    seg = jnp.asarray(seg)
+@pytest.fixture()
+def attn_blocks(monkeypatch):
+    """Sets splash attention's blocks for a test; ``_splash_kernel``'s cache
+    is keyed by the row, not by them, so it is emptied before and after."""
+    def set_blocks(block, compute):
+        monkeypatch.setattr(seqmodel, "ATTN_BLOCK", block)
+        monkeypatch.setattr(seqmodel, "ATTN_BLOCK_COMPUTE", compute)
+        monkeypatch.setattr(seqmodel, "MATMUL_DTYPE", jnp.float32)
+        seqmodel._splash_kernel.cache_clear()
+
+    yield set_blocks
+    seqmodel._splash_kernel.cache_clear()
+
+
+def _assert_kernel_is_the_dense_mask(rng, shape, kv_heads, seg, window, repeat=1):
+    """``_attend`` over random q ``shape`` = [B, T, H, d] and k, v of
+    ``kv_heads`` (handed on repeated ``repeat`` times) under the Pallas
+    interpreter against the ``jax.numpy`` branch: forward and the gradients
+    of q, k and v."""
+    B, T, H, d = shape
+    q, k, v = (jnp.asarray(rng.standard_normal((B, T, h, d)).astype(np.float32))
+               for h in (H, kv_heads, kv_heads))
     cfg = seq_config(SHARE)
 
     def attend(impl):
         c = dataclasses.replace(cfg, attn_impl=impl)
-        return lambda q, k, v: seqmodel._attend(c, q, k, v, seg, 100)
+        return lambda q, k, v: seqmodel._attend(
+            c, q, *seqmodel._repeat_kv(k, v, repeat), jnp.asarray(seg), window)
 
     with jax.default_matmul_precision("highest"):
         want, vjp = jax.vjp(attend("dense"), q, k, v)
         got, vjp_k = jax.vjp(attend("interpret"), q, k, v)
+        assert got.shape == want.shape == (B, T, H * d)
         np.testing.assert_allclose(got, want, atol=2e-5)
         r = jnp.asarray(rng.standard_normal(want.shape).astype(np.float32))
         for g, w in zip(vjp_k(r), vjp(r)):
             np.testing.assert_allclose(g, w, atol=1e-4)
+
+
+@pytest.mark.parametrize("layout", ["held", "repeated"])
+@pytest.mark.parametrize("rep", [1, 5, 7])
+@pytest.mark.parametrize("mask", ["causal", "window"])
+def test_splash_kernel_is_the_dense_mask(attn_blocks, mask, rep, layout):
+    """The chip's branch: blocks of 128 over a row of 512 with three segments
+    and padding at its end (blocks outside the causal mask, or outside the
+    window of 100, are skipped), ``rep`` query heads on each of 2 KV heads, k
+    and v as held or already repeated for their query heads."""
+    attn_blocks(128, 128)
+    seg = np.zeros((1, 512), np.int32)
+    seg[0, 300:], seg[0, 330:], seg[0, 500:] = 1, 2, -1
+    _assert_kernel_is_the_dense_mask(
+        np.random.default_rng(1), (1, 512, 2 * rep, 16), 2, seg,
+        100 if mask == "window" else None, rep if layout == "repeated" else 1)
+
+
+@pytest.mark.parametrize("T", [64, 192, 640])
+@pytest.mark.parametrize("mask", ["causal", "window"])
+def test_splash_kernel_on_a_row_its_blocks_do_not_divide(attn_blocks, mask, T):
+    """Row lengths are multiples of ``token_multiple`` alone, and serving pads
+    a short history up to that: a row under the kernel's 128 lanes (64), one
+    under a block that is no power of two (192: one block of 256, of two
+    products), and one past a block that the block does not divide (640 = 2.5
+    blocks) are padded at their end inside ``_splash_attention`` and read the
+    dense mask's numbers at their own length."""
+    attn_blocks(256, 128)
+    seg = np.zeros((2, T), np.int32)
+    seg[0, T // 3:], seg[1, T // 2:], seg[1, T - 7:] = 1, 1, -1
+    _assert_kernel_is_the_dense_mask(
+        np.random.default_rng(2), (2, T, 3, 16), 1, seg,
+        40 if mask == "window" else None)
 
 
 def test_no_rotary_on_the_global_kind(monkeypatch):

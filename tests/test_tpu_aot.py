@@ -279,12 +279,22 @@ def test_gdn_chunk_kernels_compile(v5e, passes):
     assert ("gdn_chunk_bwd" in text) == (passes == "forward_backward")
 
 
-def test_segment_masked_flash_attention_compiles(v5e):
-    """The full-attention layers' library kernel as ``ops/seqmodel`` calls
-    it: 15 heads of 128 over a row of 8192 with segment ids, forward and
-    backward (no 8192 x 8192 score matrix in the program's temporaries)."""
-    import dataclasses
+def _assert_splash_alone(text: str):
+    """The library's splash kernels as a row program names them (the forward
+    keeping the logsumexp for the backward, the two backward kernels), and no
+    call of the flash kernel that stood there before PR 34."""
+    for name in ("splash_mqa_fwd_segmented_residuals", "splash_mqa_dkv_segmented",
+                 "splash_mqa_dq_segmented"):
+        assert name in text, name
+    for name in ("flash_attention", "flash_mha_bwd"):
+        assert name not in text, name
 
+
+def test_segment_masked_splash_attention_compiles(v5e):
+    """The full-attention layers' library kernel as ``ops/seqmodel`` calls
+    it: 15 heads of 128 over a row of 8192 with segment ids under a causal
+    mask, forward and backward (no 8192 x 8192 score matrix in the program's
+    temporaries), and no call of the flash kernel left."""
     from predictionio_tpu.ops import seqmodel
 
     sds = _spec_on(SingleDeviceSharding(v5e.devices[0]))
@@ -298,7 +308,42 @@ def test_segment_masked_flash_attention_compiles(v5e):
     fn = jax.jit(jax.grad(lambda p, x, seg: seqmodel.full_attention(
         cfg, p, x, seg).sum(), argnums=(0, 1)))
     compiled = _compile(fn, shapes, sds((1, 8192, 3840)), sds((1, 8192), jnp.int32))
+    _assert_splash_alone(compiled.as_text())
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 10**9
+
+
+def test_serving_lengths_build_each_splash_kernel_once(v5e):
+    """``batch_predict`` pads a history to a power of two of tokens: the
+    routed block's two masks (causal, window 4096) at every length from 128 to
+    a row of 16,384 compile forward for the chip (7 query heads on one KV
+    head), and the masks' metadata is built once a (length, mask): a second
+    query of each length finds all sixteen in ``_splash_kernel``'s cache."""
+    from predictionio_tpu.ops import seqmodel
+
+    sds = _spec_on(SingleDeviceSharding(v5e.devices[0]))
+    cfg = seqmodel.SeqConfig(
+        hidden=2560, layer_types=(seqmodel.GLOBAL_MOE, seqmodel.SLIDING_MOE),
+        heads=7, kv_heads=1, head_dim=128, lin_heads=0, lin_key_dim=0,
+        lin_value_dim=0, conv_width=4, mlp_cols=0, vocab_rows=18992, experts=64,
+        experts_held=16, experts_per_token=6, expert_width=768, window=4096,
+        attn_impl="flash")
+    lengths = [1 << n for n in range(7, 15)]
+    assert max(lengths) == 16384 and cfg.token_multiple == min(lengths) == 128
+    seqmodel._splash_kernel.cache_clear()
+    for again in (False, True):
+        for T in lengths:
+            for window in (None, cfg.window):
+                fn = jax.jit(lambda q, k, v, seg, window=window: seqmodel._attend(
+                    cfg, q, k, v, seg, window))
+                specs = (sds((1, T, 7, 128)), sds((1, T, 1, 128)), sds((1, T, 1, 128)),
+                         sds((1, T), jnp.int32))
+                if again:
+                    fn.trace(*specs)
+                else:
+                    assert "splash_mqa_fwd_segmented" in _compile(fn, *specs).as_text()
+        info = seqmodel._splash_kernel.cache_info()
+        assert info.misses == info.currsize == 2 * len(lengths), info
+        assert info.hits == (2 * len(lengths) if again else 0), info
 
 
 # the Falcon-H1 block at its published widths (one of four chips' share: 8
@@ -334,7 +379,7 @@ def test_ssd_chunk_kernels_compile(v5e, passes):
 
 def test_falcon_h1_row_program_fits_beside_its_arguments(v5e):
     """The training row of ``falcon-h1-34b-tp4.retrain`` as the chip compiles
-    it (flash attention, the SSD kernels; 8192 tokens, 769.6 M parameters at
+    it (splash attention, the SSD kernels; 8192 tokens, 769.6 M parameters at
     16 bytes): the compiler plans its temporaries beside 12.31 GB of weights,
     moments and gradient sums, under the 16,909,336,064 B the v5e's allocator
     reports as its limit (PERF.md).  A plan, not a reading."""
@@ -362,6 +407,7 @@ def test_falcon_h1_row_program_fits_beside_its_arguments(v5e):
         accumulate, state, acc, sds((row_len,), jnp.int32), sds((row_len,), jnp.int32))
     text = compiled.as_text()
     assert "ssd_chunk_fwd" in text and "ssd_chunk_bwd" in text
+    _assert_splash_alone(text)
     plan = compiled.memory_analysis()
     assert plan.argument_size_in_bytes == pytest.approx(16 * 769_637_472, rel=1e-3)
     assert plan.alias_size_in_bytes >= 0.999 * plan.argument_size_in_bytes  # donated
@@ -406,8 +452,8 @@ def test_moe_grouped_kernels_compile(v5e, passes):
 
 def test_smallthinker_row_program_fits_beside_its_arguments(v5e):
     """The training row of ``smallthinker-21b-ep4.retrain`` as the chip
-    compiles it (flash attention in the global layer, the library's splash
-    kernel under the window, the grouped expert kernels; 16384 tokens,
+    compiles it (the library's splash kernel under the global layer's causal
+    mask and the sliding layers' window, the grouped expert kernels; 16384 tokens,
     496.4 M parameters at 16 bytes): the compiler plans its temporaries beside
     7.94 GB of weights, moments and gradient sums, under the 16,909,336,064 B
     the v5e's allocator reports as its limit (PERF.md).  A plan, not a
@@ -440,9 +486,9 @@ def test_smallthinker_row_program_fits_beside_its_arguments(v5e):
     compiled = _compile(
         accumulate, state, acc, sds((row_len,), jnp.int32), sds((row_len,), jnp.int32))
     text = compiled.as_text()
-    for name in ("moe_gmm_gate_up", "moe_tgmm_down", "splash_mqa_fwd_segmented",
-                 "splash_mqa_dkv_segmented"):
+    for name in ("moe_gmm_gate_up", "moe_tgmm_down"):
         assert name in text, name
+    _assert_splash_alone(text)
     plan = compiled.memory_analysis()
     assert plan.argument_size_in_bytes == pytest.approx(16 * 496_376_320, rel=1e-3)
     assert plan.alias_size_in_bytes >= 0.999 * plan.argument_size_in_bytes  # donated
