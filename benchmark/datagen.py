@@ -1,7 +1,8 @@
 """Seeded inputs: MovieLens-shaped ratings and the event store they go into.
 
-``make_movielens_like`` is the benchmark's own copy of ``bench.py``'s
-generator (the yardstick may not move with the program); every record the
+``make_movielens_like`` is the benchmark's own copy of the generator that
+``bench.py`` held until PR 28 removed it (commit ``1ae2178`` is the last with
+the file; the yardstick may not move with the program); every record the
 repo keeps about ML-20M-sized runs was made with it.  ``thin_ratings`` is the
 serve cells' short cut: few ratings, every user and every item named once, so
 the served tables have the published shape.  ``write_events`` bulk-writes
@@ -21,7 +22,7 @@ RANK_PLANTED = 8
 
 
 #: of every BROWSE_K popularity-drawn candidates the user picks the preferred,
-#: for BROWSE_FRAC of the interactions (bench.py's values)
+#: for BROWSE_FRAC of the interactions (the values of bench.py at 1ae2178)
 BROWSE_K = 8
 BROWSE_FRAC = 0.7
 
@@ -82,7 +83,7 @@ def make_movielens_like(
     user activity, item quality correlated with popularity, planted rank-8
     personal preference structure + noise; for ``BROWSE_FRAC`` of
     interactions the user picks the preferred of ``BROWSE_K``
-    popularity-drawn candidates (see bench.py for the reasoning).
+    popularity-drawn candidates (bench.py at commit 1ae2178 has the reasoning).
 
     WHO rated WHAT (and the planted tastes) come from ``structure_seed``, the
     rating VALUES' noise from ``seed``.  The program's compiled shapes follow

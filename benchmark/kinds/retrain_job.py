@@ -39,6 +39,8 @@ from benchmark import datagen, proc, reference
 from benchmark.proc import BENCH, require
 
 APPS = ("bench-a", "bench-b")
+#: the span that holds a retrain's other spans (``core/workflow.run_train``)
+ROOT_SPAN = "workflow.run_train"
 
 
 def ratings_of(app_index: int, rating: np.ndarray) -> np.ndarray:
@@ -201,6 +203,14 @@ def run(ctx) -> dict:
             ),
         },
         "trace_dir": str(ctx.run.work / "trace") if traced else None,
+        # the program's spans, by which the reducer shares out the idle time:
+        # the names of ``stages`` that are seconds, and the root
+        "trace_spans": {
+            "root": ROOT_SPAN,
+            "spans": [ROOT_SPAN] + sorted(
+                name for name, secs in (traced["stages"] or {}).items()
+                if isinstance(secs, (int, float))),
+        } if traced else None,
         "evidence": {
             # the traced retrain where there is one, else the last
             "retrain": traced or done[-1],

@@ -10,10 +10,15 @@ together leave no half of an iteration unchecked:
   user factors (``last_halfstep_gap_*``; the accumulator's precision shows
   here: one bf16 pass misses by 1e-3);
 * the USER side is where one more half-step would put it, up to what twenty
-  iterations leave of ALS's slowest mode (``user_fixedpoint_gap_*``: the gap
-  between the persisted user rows and their float64 solve over the persisted
-  ITEM factors; a user update that is dropped, solved with another
-  regulariser, or iterated too few times leaves more);
+  iterations leave of ALS's slowest mode (``user_fixedpoint_gap_median``: the
+  gap between the persisted user rows and their float64 solve over the
+  persisted ITEM factors, the checked rows' median; a user update that is
+  dropped, solved with another regulariser, or iterated too few times leaves
+  more).  The rows' MAXIMUM is printed and not compared (since PR 35): on some
+  seeds' ratings a sound fit, in any accumulator precision, is still moving
+  up to 2 % of its user rows by 0.027 or more at its twentieth iteration
+  (the farthest of 3.6 M rows read: 0.32), where one bf16 pass leaves 0.15
+  and a row never updated 0.54-1: no limit separates them (PERF.md section 2);
 * the factors predict the ratings (``train_rmse``: all-zero factors are a
   fixed point of both solves, and predict nothing).
 """
@@ -165,7 +170,5 @@ def check_retrain(ctx, model: dict, status: str, user_idx, item_idx, rating) -> 
                  float(ref["halfstep_gap_max_limit"])),
         Compared("user_fixedpoint_gap_median", float(np.median(user_gaps)),
                  float(ref["user_fixedpoint_gap_median_limit"])),
-        Compared("user_fixedpoint_gap_max", float(user_gaps.max()),
-                 float(ref["user_fixedpoint_gap_max_limit"])),
         Compared("train_rmse", rmse, float(ref["train_rmse_limit"])),
     ]

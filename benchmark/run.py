@@ -28,6 +28,7 @@ from __future__ import annotations
 import argparse
 import importlib
 import json
+import math
 import sys
 import time
 import traceback
@@ -128,10 +129,14 @@ def execute(manifest: dict, workload: str, seed: int, seconds: float, trace: boo
         evidence["params"] = params
         breakdown = None
         if trace:
+            t0 = time.perf_counter()
             reduced = trace_reduce.reduce_trace(
-                trace_reduce.find_xplane(res["trace_dir"])
+                trace_reduce.find_xplane(res["trace_dir"]),
+                **(res.get("trace_spans") or {}),
             )
-            say(f"trace: {json.dumps({k: v for k, v in reduced.items() if k not in ('device_ops', 'idle_gaps')})}")
+            scalars = {k: v for k, v in reduced.items() if not isinstance(v, list)}
+            scalars["reduce_s"] = round(time.perf_counter() - t0, 3)
+            say(f"trace: {json.dumps(scalars)}")
             evidence["trace"] = reduced
             evidence["peaks"] = load_json(root / "peaks.json")
             evidence["device"] = device
@@ -144,7 +149,7 @@ def execute(manifest: dict, workload: str, seed: int, seconds: float, trace: boo
     finally:
         run.close()
 
-    for c in res["compared"]:
+    for c in sorted(res["compared"], key=lambda c: not c.ok):
         say(c.line())
     if trace:
         metrics = layer_metrics(manifest, cell, evidence, root)
@@ -164,6 +169,14 @@ def execute(manifest: dict, workload: str, seed: int, seconds: float, trace: boo
     }
     if breakdown is not None:
         result["breakdown"] = breakdown
+    # last in the line: each number compared beside its limit, those over
+    # their limit last of all (the driver's record keeps the line's end)
+    result["compared"] = {
+        # a reading that is not finite is not JSON: it goes as its name
+        c.name: [float(c.value) if math.isfinite(c.value) else repr(float(c.value)),
+                 ">=" if c.sense == "min" else "<=", float(c.limit)]
+        for c in sorted(res["compared"], key=lambda c: not c.ok)
+    }
     return result, res["compared"]
 
 
@@ -176,7 +189,7 @@ def main(argv: list[str] | None = None) -> int:
     args = ap.parse_args(argv)
     manifest = load_json(REPO / "BENCHMARK.json")
     try:
-        result, _ = execute(
+        result, compared = execute(
             manifest, args.workload, args.seed, args.seconds, bool(args.trace),
             PLATFORM, BENCH / ".work" / args.workload,
         )
@@ -185,6 +198,11 @@ def main(argv: list[str] | None = None) -> int:
         traceback.print_exc()
         return 1
     print(json.dumps(result), flush=True)
+    # and as the last lines of standard error, where the driver's record of
+    # a run that is not correct keeps them; those over their limit last
+    for c in sorted(compared, key=lambda c: not c.ok):
+        print(c.line(), file=sys.stderr)
+    sys.stderr.flush()
     return 0
 
 

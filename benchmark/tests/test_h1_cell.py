@@ -8,6 +8,8 @@ it."""
 import json
 from pathlib import Path
 
+import pytest
+
 from benchmark import run as harness
 from benchmark.tests.tiny_h1 import CELL, tiny_h1_root
 
@@ -15,7 +17,8 @@ SHIM = Path(__file__).parent / "shim_h1"
 SEED = 2**31 + 3001
 
 SPANS = {"seq_group_s", "seq_pack_s", "seq_init_s", "seq_loop_s", "seq_fetch_s",
-         "seq_persist_s"}
+         # the spans every retrain opens, under the names the ALS cell reads them by
+         "scan_s", "sort_s", "decode_s", "vocab_s", "persist_s"}
 
 
 def test_falcon_h1_retrain_cell(tmp_path):
@@ -97,12 +100,23 @@ def test_readers_count_what_the_configuration_says(monkeypatch):
     evidence = {
         "config": cfg, "device": {"kind": "TPU v5 lite"},
         "peaks": harness.load_json(harness.BENCH / "peaks.json"),
-        "trace": {"busy_s": 10.0, "device_ops": [
-            ["fusion.1", 2.0], ["ssd_chunk_fwd.3", 0.05], ["ssd_chunk_bwd.7", 0.1]]},
+        "trace": {"busy_s": 10.0, "ops_by_name": [
+            ["fusion.1", 2.0], ["ssd_chunk_fwd.3", 0.05], ["ssd_chunk_bwd.7", 0.1]],
+            "scopes": [["seq.ssm/ssm.chunk", "forward", 0.05],
+                       ["seq.ssm/ssm.chunk", "recompute", 0.05],
+                       ["seq.ssm/ssm.chunk", "backward", 0.1],
+                       ["seq.ssm/ssm.intra", "forward", 2.0]]},
     }
     assert 0 < h1_mfu.read(evidence, {}) < 100
     assert device_op_prefix.read(evidence, {"prefix": "ssd_chunk_"}) == 0.15000000000000002
-    assert 0 < ssd_roofline.read(evidence, {"prefix": "ssd_chunk_"}) < 100
+    # the share: one forward and one backward of the 8 held heads of each of
+    # the four layers over the 16 rows, over ALL time under the scope (0.2 s)
+    share = ssd_roofline.read(evidence, {"scopes": ["ssm.chunk"]})
+    least_s = 4 * sum(
+        max(flops / 197e12, nbytes / 819e9)
+        for flops, nbytes in (ssd_roofline.site_least(kind, 16, 8, 1, 8192, 128, 128, 256)
+                              for kind in ("fwd", "bwd")))
+    assert share == pytest.approx(100 * least_s / 0.2) and 0 < share < 100
     flops, nbytes = ssd_roofline.site_least("fwd", 16, 8, 1, 8192, 128, 128, 256)
     chunks = 16 * 64
     assert flops == chunks * 8 * 4 * 128 * 256 * 128
@@ -131,9 +145,10 @@ def test_readers_count_what_the_configuration_says(monkeypatch):
         sds(1, 8192, G, N), jax.ShapeDtypeStruct((1, 8192), jnp.int32))
     assert seen == [(8, (1, 64, 128, 256), (1, 64, 256, 128), (8, 64, 128, 128))]
     # the parent's program has no such kernels: nothing to read, no error
-    evidence["trace"]["device_ops"] = [["fusion.1", 2.0]]
+    evidence["trace"].update(
+        ops_by_name=[["fusion.1", 2.0]], scopes=[["seq.mlp", "forward", 2.0]])
     assert device_op_prefix.read(evidence, {"prefix": "ssd_chunk_"}) is None
-    assert ssd_roofline.read(evidence, {"prefix": "ssd_chunk_"}) is None
+    assert ssd_roofline.read(evidence, {"scopes": ["ssm.chunk"]}) is None
     # each block's utilisation reads its own configuration and no other
     olmo = harness.load_json(harness.BENCH / "configs" / "olmo-hybrid-7b-tp2.json")
     assert h1_mfu.read({**evidence, "config": olmo}, {}) is None

@@ -60,7 +60,11 @@ def test_retrain_job(tmp_path, trace):
         assert set(res["metrics"]) == reported(manifest, cell, "end_to_end")
         assert res["metrics"]["retrain_s"]["value"] > 0
         assert res["metrics"]["setup_s"]["value"] > res["metrics"]["retrain_s"]["value"]
-    json.dumps(res)  # the last line is JSON
+    json.dumps(res, allow_nan=False)  # the last line is JSON
+    # ... and ends with every number compared beside its limit
+    assert list(res)[-1] == "compared" and set(res["compared"]) == set(by)
+    value, op, limit = res["compared"]["compilations_inside_window"]
+    assert (value, op, limit) == (0.0, "<=", 0.0)
 
 
 @pytest.mark.parametrize(

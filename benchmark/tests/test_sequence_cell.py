@@ -8,6 +8,8 @@ it."""
 import json
 from pathlib import Path
 
+import pytest
+
 from benchmark import run as harness
 from benchmark.tests.tiny_sequence import CELL, tiny_sequence_root
 
@@ -15,7 +17,8 @@ SHIM = Path(__file__).parent / "shim_sequence"
 SEED = 2**31 + 2601
 
 SPANS = {"seq_group_s", "seq_pack_s", "seq_init_s", "seq_loop_s", "seq_fetch_s",
-         "seq_persist_s"}
+         # the spans every retrain opens, under the names the ALS cell reads them by
+         "scan_s", "sort_s", "decode_s", "vocab_s", "persist_s"}
 
 
 def test_sequence_retrain_cell(tmp_path):
@@ -94,12 +97,22 @@ def test_readers_count_what_the_configuration_says():
     evidence = {
         "config": cfg, "device": {"kind": "TPU v5 lite"},
         "peaks": harness.load_json(harness.BENCH / "peaks.json"),
-        "trace": {"busy_s": 10.0, "device_ops": [
-            ["fusion.1", 2.0], ["gdn_chunk_fwd.3", 0.5], ["gdn_chunk_bwd.7", 1.0]]},
+        "trace": {"busy_s": 10.0, "ops_by_name": [
+            ["fusion.1", 2.0], ["gdn_chunk_fwd.3", 0.5], ["gdn_chunk_bwd.7", 1.0]],
+            "scopes": [["seq.gdn/gdn.chunk", "forward", 0.5],
+                       ["seq.gdn/gdn.chunk", "recompute", 0.6],
+                       ["seq.gdn/gdn.chunk", "backward", 1.4],
+                       ["seq.gdn/gdn.intra", "forward", 2.0]]},
     }
     assert device_op_prefix.read(evidence, {"prefix": "gdn_chunk_"}) == 1.5
-    share = gdn_roofline.read(evidence, {"prefix": "gdn_chunk_"})
-    assert 0 < share < 100
+    # the share: one forward and one backward of all 15 heads of the three
+    # linear layers over the 16 rows, over ALL time under the scope (2.5 s)
+    share = gdn_roofline.read(evidence, {"scopes": ["gdn.chunk"]})
+    least_s = sum(
+        max(flops / 197e12, nbytes / 819e9)
+        for flops, nbytes in (gdn_roofline.site_least(kind, 16, 45, 8192, 64, 96, 192)
+                              for kind in ("fwd", "bwd")))
+    assert share == pytest.approx(100 * least_s / 2.5) and 0 < share < 100
     # a call site is one group of heads, as the program runs them and as
     # tier-1 compiles the kernels for the chip (5 heads x 128 chunks a call)
     from predictionio_tpu.ops import gdn
@@ -112,8 +125,9 @@ def test_readers_count_what_the_configuration_says():
     assert nbytes == calls * 4 * (3 * 64 * 96 + 2 * 64 * 192 + 64 * 64)
     assert 0 < seq_mfu.read(evidence, {}) < 100
     # the parent's program has no such kernels: nothing to read, no error
-    evidence["trace"]["device_ops"] = [["fusion.1", 2.0]]
+    evidence["trace"].update(
+        ops_by_name=[["fusion.1", 2.0]], scopes=[["seq.mlp", "forward", 2.0]])
     assert device_op_prefix.read(evidence, {"prefix": "gdn_chunk_"}) is None
-    assert gdn_roofline.read(evidence, {"prefix": "gdn_chunk_"}) is None
+    assert gdn_roofline.read(evidence, {"scopes": ["gdn.chunk"]}) is None
     als = harness.load_json(harness.BENCH / "configs" / "als-ml20m.json")
     assert seq_mfu.read({**evidence, "config": als}, {}) is None

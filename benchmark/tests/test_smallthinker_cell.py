@@ -17,7 +17,8 @@ SHIM = Path(__file__).parent / "shim_st"
 SEED = 2**31 + 3201
 
 SPANS = {"seq_group_s", "seq_pack_s", "seq_init_s", "seq_loop_s", "seq_fetch_s",
-         "seq_persist_s"}
+         # the spans every retrain opens, under the names the ALS cell reads them by
+         "scan_s", "sort_s", "decode_s", "vocab_s", "persist_s"}
 
 
 def test_smallthinker_retrain_cell(tmp_path):
@@ -119,15 +120,27 @@ def test_readers_count_what_the_configuration_says(monkeypatch):
         "config": cfg, "device": {"kind": "TPU v5 lite"},
         "peaks": harness.load_json(harness.BENCH / "peaks.json"),
         "retrain": {"stages": {"total": 8.0, "counters": counters}},
-        "trace": {"busy_s": 3.0, "device_ops": [
-            ["fusion.1", 0.5], ["moe_gmm_gate_up.3", 0.02], ["moe_tgmm_down.7", 0.03]]},
+        "trace": {"busy_s": 3.0, "ops_by_name": [
+            ["fusion.1", 0.5], ["moe_gmm_gate_up.3", 0.02], ["moe_tgmm_down.7", 0.03]],
+            "scopes": [["seq.moe/moe.experts", "forward", 0.1],
+                       ["seq.moe/moe.experts", "recompute", 0.1],
+                       ["seq.moe/moe.experts", "backward", 0.3],
+                       ["seq.moe/moe.route", "forward", 1.0]]},
     }
     mfu = st_mfu.read(evidence, {})
     assert mfu == pytest.approx(100.0 * 3 * per_token * 258_048 / 197e12 / 3.0)
     assert 25 < mfu < 35
     assert device_op_prefix.read(evidence, {"prefix": "moe_"}) == 0.05
-    assert 0 < moe_roofline.read(evidence, {"prefix": "moe_"}) < 100
+    # the share: the six products of the counted pairs of each of the four
+    # layers, over ALL time under the scope (0.5 s)
     pairs = 387_072.0
+    share = moe_roofline.read(evidence, {"scopes": ["moe.experts"]})
+    least_s = 4 * sum(
+        max(flops / 197e12, nbytes / 819e9)
+        for flops, nbytes in (moe_roofline.site_least(name, pairs, 2560, 768, 16)
+                              for name in moe_roofline.PRODUCTS))
+    assert len(moe_roofline.PRODUCTS) == 6
+    assert share == pytest.approx(100 * least_s / 0.5) and 0 < share < 100
     flops, nbytes = moe_roofline.site_least("moe_gmm_gate_up.3", pairs, 2560, 768, 16)
     assert flops == 2 * pairs * 2560 * 1536
     assert nbytes == pairs * (2560 * 2 + 1536 * 4) + 16 * 2560 * 1536 * 2
@@ -155,10 +168,14 @@ def test_readers_count_what_the_configuration_says(monkeypatch):
                     ("moe_gmm_down", (102_400, 768), (16, 768, 2560))]
     # the parent's program has no such kernels and counts nothing: nothing to
     # read, no error
-    evidence["trace"]["device_ops"] = [["fusion.1", 2.0]]
+    scopes = evidence["trace"]["scopes"]
+    evidence["trace"].update(
+        ops_by_name=[["fusion.1", 2.0]], scopes=[["seq.attn", "forward", 2.0]])
     assert device_op_prefix.read(evidence, {"prefix": "moe_"}) is None
-    assert moe_roofline.read(evidence, {"prefix": "moe_"}) is None
+    assert moe_roofline.read(evidence, {"scopes": ["moe.experts"]}) is None
     evidence["retrain"]["stages"].pop("counters")
+    evidence["trace"]["scopes"] = scopes
+    assert moe_roofline.read(evidence, {"scopes": ["moe.experts"]}) is None
     assert st_mfu.read(evidence, {}) is None
     assert stage_counter.read(evidence, {"key": "moe_pairs_held"}) is None
     # each block's utilisation reads its own configuration and no other

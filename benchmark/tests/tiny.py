@@ -12,7 +12,6 @@ from benchmark import run as harness
 TINY_DATA = {"nnz": 60_000, "num_users": 600, "num_items": 200}
 TINY_FIT_LIMITS = {
     "user_fixedpoint_gap_median_limit": 0.1,
-    "user_fixedpoint_gap_max_limit": 0.5,
 }
 
 
