@@ -322,8 +322,12 @@ def _ffn_bwd(static, res, dout):
         ddown = tgmm(
             a.astype(dtype), (g_rows * row_w[:, None]).astype(dtype), plan, held,
             name="moe_tgmm_down", impl=impl)
+        # each half is cast BEFORE the concatenation: the chip's compiler
+        # splits a cast of the whole into casts it makes itself, which carry
+        # no op name, and the fusion they root reads as ``(no scope)``
         dgu = jnp.concatenate(
-            [da * u * act_grad(gu[:, :f]), da * g_act], axis=1).astype(dtype)
+            [(da * u * act_grad(gu[:, :f])).astype(dtype),
+             (da * g_act).astype(dtype)], axis=1)
         both = jnp.concatenate([gate.astype(dtype), up.astype(dtype)], axis=2)
         dxs = gmm(dgu, both, plan, transpose_rhs=True,
                   name="moe_gmm_gate_up_dlhs", impl=impl)

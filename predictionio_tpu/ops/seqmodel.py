@@ -109,6 +109,27 @@ PROBE_NAME = {LINEAR: "delta_rule_probe", FULL: "delta_rule_probe",
 #: segment id of a row's padding (real segments count from 0)
 PAD_SEGMENT = -1
 
+#: the top-level ``jax.named_scope`` names of a retrain's three programs (the
+#: row program ``accumulate_row``, the step ``apply_step``, the
+#: initialisation ``init_state``): every device operation of them carries
+#: exactly ONE of these in its op name, so they partition the device's busy
+#: time and ``(no scope)`` in a trace holds only what the program did not
+#: write (docs/observability.md; ``tests/test_sequence_scopes.py`` holds both)
+SCOPES = (
+    "seq.embed", "seq.gdn", "seq.ssm", "seq.moe", "seq.attn", "seq.mlp",
+    "seq.loss",
+    # what ``layer`` / ``routed_layer`` / ``trunk`` do to the residual stream
+    # between the mixers: the norms on it and the residual adds, and through
+    # those the adds of the stream's gradient
+    "seq.stream",
+    # a row's gradients, loss, count and routing counters into the step's sums
+    "seq.accumulate",
+    # the whole of ``apply_step``: norms, probes, the routing record, AdamW
+    "seq.step",
+    # the seeded draws of ``init_params``, the zeros of moments and sums
+    "seq.init",
+)
+
 #: what the large matrix products round their inputs to (the configuration's
 #: stated precision; tests set float32 to compare with the plain reference
 #: to rounding error)
@@ -330,7 +351,11 @@ def param_shapes(cfg: SeqConfig) -> dict[str, tuple[int, ...]]:
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2))
-def _init_tensor(leaf: str, shape: tuple, conv_width: int, key):
+@jax.named_scope("seq.init")
+def _init_tensor(leaf: str, shape: tuple, conv_width: int, base, n):
+    # the scope is written INSIDE the jitted function: one around the call
+    # does not reach a program that is dispatched on its own
+    key = jax.random.fold_in(base, n)
     if leaf.endswith("norm") or leaf == "ssm_d":
         return jnp.ones(shape, jnp.float32)
     if "conv" in leaf:
@@ -346,6 +371,12 @@ def _init_tensor(leaf: str, shape: tuple, conv_width: int, key):
         dt = jnp.maximum(dt, 1e-4)
         return dt + jnp.log(-jnp.expm1(-dt))
     return 0.02 * jax.random.normal(key, shape, jnp.float32)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+@jax.named_scope("seq.init")
+def _zeros(shape: tuple, dtype):
+    return jnp.zeros(shape, dtype)
 
 
 def init_params(cfg: SeqConfig, seed: int) -> dict[str, jax.Array]:
@@ -365,7 +396,7 @@ def init_params(cfg: SeqConfig, seed: int) -> dict[str, jax.Array]:
     for n, (name, shape) in enumerate(param_shapes(cfg).items()):
         leaf = name.rsplit(".", 1)[-1]
         width = cfg.ssm_conv_width if leaf.startswith("ssm_") else cfg.conv_width
-        out[name] = _init_tensor(leaf, shape, width, jax.random.fold_in(base, n))
+        out[name] = _init_tensor(leaf, shape, width, base, n)
     return out
 
 
@@ -722,23 +753,31 @@ def routed_layer(cfg: SeqConfig, kind: str, p: dict, x, seg):
     first layer makes from exact embedding rows in float32: the same on both
     sides of a comparison, as the stream after attention is not."""
     B, T, D = x.shape
-    h = rmsnorm(x, p["input_norm"], cfg.eps)
+    with jax.named_scope("seq.stream"):
+        h = rmsnorm(x, p["input_norm"], cfg.eps)
     with jax.named_scope("seq.moe"), jax.named_scope("moe.route"):
         logits = mm_f32(h, p["router"]).reshape(B * T, -1)
-    x = x + routed_attention(cfg, kind, p, h, seg)
-    experts = functools.partial(
-        moe.experts_layer, logits=logits, valid=(seg != PAD_SEGMENT).reshape(-1),
-        gate=p["experts_gate"], up=p["experts_up"], down=p["experts_down"],
-        k=cfg.experts_per_token, start=cfg.expert_start, tile=cfg.moe_tile,
-        dtype=MATMUL_DTYPE, impl=cfg.moe_impl)
+    a = routed_attention(cfg, kind, p, h, seg)
+    with jax.named_scope("seq.stream"):
+        x = x + a
+    # ``post_norm`` is what the experts read and stays under their scope, as
+    # the q / k norms and ``ssm.norm`` stay under their mixers'
     with jax.named_scope("seq.moe"):
+        experts = functools.partial(
+            moe.experts_layer, logits=logits,
+            valid=(seg != PAD_SEGMENT).reshape(-1), gate=p["experts_gate"],
+            up=p["experts_up"], down=p["experts_down"], k=cfg.experts_per_token,
+            start=cfg.expert_start, tile=cfg.moe_tile, dtype=MATMUL_DTYPE,
+            impl=cfg.moe_impl)
         m = rmsnorm(x, p["post_norm"], cfg.eps)
         y, choices, pairs = experts(m.reshape(B * T, D))
         probe = jax.lax.stop_gradient(jnp.matmul(
             experts(jax.lax.stop_gradient(h).reshape(B * T, D))[0],
             moe_probe_vector(D), precision=HIGHEST))
-    return x + y.reshape(B, T, D), (
-        probe.reshape(B, T, 1), choices.reshape(B, T, -1), pairs)
+        y, probe = y.reshape(B, T, D), probe.reshape(B, T, 1)
+        choices = choices.reshape(B, T, -1)
+    with jax.named_scope("seq.stream"):
+        return x + y, (probe, choices, pairs)
 
 
 def gated_group_norm(y, z, w, eps, axis_name=None):
@@ -825,16 +864,25 @@ def layer(cfg: SeqConfig, kind: str, p: dict, x, seg):
     if kind in MOE_KINDS:
         return routed_layer(cfg, kind, p, x, seg)
     if kind == PARALLEL:
-        h = rmsnorm(x, p["input_norm"], cfg.eps)
+        with jax.named_scope("seq.stream"):
+            h = rmsnorm(x, p["input_norm"], cfg.eps)
         m, probe = state_space_mixer(cfg, p, h, seg)
-        x = x + m + grouped_query_attention(cfg, p, h, seg)
-        return x + mlp(cfg, p, rmsnorm(x, p["pre_ff_norm"], cfg.eps)), probe
+        a = grouped_query_attention(cfg, p, h, seg)
+        with jax.named_scope("seq.stream"):
+            x = x + m + a
+            h = rmsnorm(x, p["pre_ff_norm"], cfg.eps)
+        y = mlp(cfg, p, h)
+        with jax.named_scope("seq.stream"):
+            return x + y, probe
     if kind == LINEAR:
         y, probe = linear_attention(cfg, p, x, seg)
     else:
         y, probe = full_attention(cfg, p, x, seg), jnp.zeros(x.shape[:2] + (0,))
-    x = x + rmsnorm(y, p["mixer_norm"], cfg.eps)
-    return x + rmsnorm(mlp(cfg, p, x), p["mlp_norm"], cfg.eps), probe
+    with jax.named_scope("seq.stream"):
+        x = x + rmsnorm(y, p["mixer_norm"], cfg.eps)
+    y = mlp(cfg, p, x)
+    with jax.named_scope("seq.stream"):
+        return x + rmsnorm(y, p["mlp_norm"], cfg.eps), probe
 
 
 def trunk(cfg: SeqConfig, params: dict, x, seg, remat: bool = False):
@@ -860,12 +908,14 @@ def trunk(cfg: SeqConfig, params: dict, x, seg, remat: bool = False):
             routed.append((choices, pairs))
         first = probe if first is None else first
     if routed:
-        first = {
-            PROBE_NAME[cfg.layer_types[0]]: first,
-            "choices": jnp.stack([c for c, _ in routed], axis=1),
-            "expert_pairs": jnp.stack([n for _, n in routed]),
-        }
-    return rmsnorm(x, params["final_norm"], cfg.eps), first
+        with jax.named_scope("seq.moe"):
+            first = {
+                PROBE_NAME[cfg.layer_types[0]]: first,
+                "choices": jnp.stack([c for c, _ in routed], axis=1),
+                "expert_pairs": jnp.stack([n for _, n in routed]),
+            }
+    with jax.named_scope("seq.stream"):
+        return rmsnorm(x, params["final_norm"], cfg.eps), first
 
 
 def hidden_states(cfg: SeqConfig, params: dict, tokens, seg):
@@ -898,9 +948,7 @@ def cross_entropy(cfg: SeqConfig, h, head, targets, weight, dhead):
     if T % blk:
         raise ValueError(f"{T} tokens are not a multiple of the loss block {blk}")
     n = T // blk
-    w16 = head.astype(MATMUL_DTYPE)
     scale = cfg.mup.lm_head
-    local = targets.reshape(n, blk) - cfg.vocab_start
 
     def block(carry, x):
         loss, dw = carry
@@ -920,11 +968,13 @@ def cross_entropy(cfg: SeqConfig, h, head, targets, weight, dhead):
         return (loss, dw), dh
 
     with jax.named_scope("seq.loss"):
+        w16 = head.astype(MATMUL_DTYPE)
+        local = targets.reshape(n, blk) - cfg.vocab_start
         (loss, dhead), dh = jax.lax.scan(
             block, (jnp.float32(0.0), dhead),
             (h.reshape(n, blk, shape[-1]), local, weight.reshape(n, blk)),
         )
-    return loss, dh.reshape(shape), dhead
+        return loss, dh.reshape(shape), dhead
 
 
 def row_grads(cfg: SeqConfig, params: dict, tokens, seg, gsum: dict):
@@ -939,7 +989,9 @@ def row_grads(cfg: SeqConfig, params: dict, tokens, seg, gsum: dict):
     x0 = embed(cfg, params["embed"], tokens)
     h, vjp, probe = jax.vjp(
         lambda p, x: trunk(cfg, p, x, seg, remat=True), inner, x0, has_aux=True)
-    targets, weight = next_item_targets(tokens, seg)
+    with jax.named_scope("seq.loss"):
+        targets, weight = next_item_targets(tokens, seg)
+        count = jnp.sum(weight)
     loss, dh, dhead = cross_entropy(
         cfg, h, params["head"], targets, weight, gsum["head"])
     dinner, dx0 = vjp(dh)
@@ -948,9 +1000,10 @@ def row_grads(cfg: SeqConfig, params: dict, tokens, seg, gsum: dict):
         held = (idx >= 0) & (idx < cfg.vocab_rows)
         dembed = gsum["embed"].at[jnp.where(held, idx, 0)].add(
             _scaled(jnp.where(held[..., None], dx0, 0.0), cfg.mup.embedding))
-    out = {k: gsum[k] + g for k, g in dinner.items()}
+    with jax.named_scope("seq.accumulate"):
+        out = {k: gsum[k] + g for k, g in dinner.items()}
     out["embed"], out["head"] = dembed, dhead
-    return loss, jnp.sum(weight), out, probe
+    return loss, count, out, probe
 
 
 # ---------------------------------------------------------------------------
@@ -962,7 +1015,8 @@ def init_state(cfg: SeqConfig, seed: int) -> tuple[dict, dict]:
     and the accumulator of one optimiser step (the gradients' sum, the loss's
     sum, the positions counted; zero between steps).  16 bytes a parameter."""
     params = init_params(cfg, seed)
-    zeros = lambda: {k: jnp.zeros_like(v) for k, v in params.items()}  # noqa: E731
+    zeros = lambda: {  # noqa: E731
+        k: _zeros(v.shape, v.dtype) for k, v in params.items()}
     state = {"params": params, "m": zeros(), "v": zeros(),
              "t": jnp.zeros((), jnp.int32)}
     acc = {"g": zeros(), "loss": jnp.float32(0.0), "count": jnp.float32(0.0)}
@@ -981,14 +1035,16 @@ def accumulate_row(cfg: SeqConfig, state: dict, acc: dict, tokens, seg):
     program's arguments only so that the compiler plans its temporaries beside
     ALL that is resident (it fits a program into the memory its own arguments
     leave)."""
-    loss, count, g, probe = row_grads(
-        cfg, state["params"], tokens[None], seg[None], acc["g"])
-    out = {"g": g, "loss": acc["loss"] + loss, "count": acc["count"] + count}
-    if "expert_pairs" in acc:
-        out["expert_pairs"] = acc["expert_pairs"] + probe.pop("expert_pairs")
-        out["pairs_total"] = acc["pairs_total"] + cfg.experts_per_token * jnp.sum(
-            seg != PAD_SEGMENT, dtype=jnp.int32)
-    return state, out, jax.tree.map(lambda a: a[0], probe)
+    with jax.named_scope("seq.accumulate"):
+        rows = tokens[None], seg[None]
+    loss, count, g, probe = row_grads(cfg, state["params"], *rows, acc["g"])
+    with jax.named_scope("seq.accumulate"):
+        out = {"g": g, "loss": acc["loss"] + loss, "count": acc["count"] + count}
+        if "expert_pairs" in acc:
+            out["expert_pairs"] = acc["expert_pairs"] + probe.pop("expert_pairs")
+            out["pairs_total"] = acc["pairs_total"] + (
+                cfg.experts_per_token * jnp.sum(seg != PAD_SEGMENT, dtype=jnp.int32))
+        return state, out, jax.tree.map(lambda a: a[0], probe)
 
 
 def grad_probe(n: int, g):
@@ -1008,10 +1064,13 @@ def grad_probe(n: int, g):
     return jnp.sum(rows * mm_f32(g, cols))
 
 
+@jax.named_scope("seq.step")
 def apply_step(opt: AdamW, state: dict, acc: dict):
     """AdamW from the accumulated step -> (state, the accumulator zeroed, the
     step's record: loss, positions, the global gradient norm, and per tensor
-    the gradient's norm and its seeded probe)."""
+    the gradient's norm and its seeded probe).  ONE scope holds all of it: the
+    chip's compiler fuses a tensor's norm and probe with its update, and a
+    fusion carries the op name of whichever of them is its root."""
     scale = 1.0 / jnp.maximum(acc["count"], 1.0)
     gsum = acc["g"]
     sq = {k: jnp.sum(g * g) for k, g in gsum.items()}
@@ -1036,16 +1095,15 @@ def apply_step(opt: AdamW, state: dict, acc: dict):
     c1 = 1.0 - opt.b1 ** tf
     c2 = 1.0 - opt.b2 ** tf
     new_p, new_m, new_v = {}, {}, {}
-    with jax.named_scope("seq.adamw"):
-        for name, p in state["params"].items():
-            g = gsum[name] * scale
-            m = opt.b1 * state["m"][name] + (1.0 - opt.b1) * g
-            v = opt.b2 * state["v"][name] + (1.0 - opt.b2) * g * g
-            step = (m / c1) / (jnp.sqrt(v / c2) + opt.eps)
-            if decays(name):
-                step = step + opt.weight_decay * p
-            new_p[name], new_m[name], new_v[name] = p - opt.lr * step, m, v
-        zeroed = jax.tree.map(jnp.zeros_like, acc)
+    for name, p in state["params"].items():
+        g = gsum[name] * scale
+        m = opt.b1 * state["m"][name] + (1.0 - opt.b1) * g
+        v = opt.b2 * state["v"][name] + (1.0 - opt.b2) * g * g
+        step = (m / c1) / (jnp.sqrt(v / c2) + opt.eps)
+        if decays(name):
+            step = step + opt.weight_decay * p
+        new_p[name], new_m[name], new_v[name] = p - opt.lr * step, m, v
+    zeroed = jax.tree.map(jnp.zeros_like, acc)
     return {"params": new_p, "m": new_m, "v": new_v, "t": t}, zeroed, record
 
 

@@ -243,7 +243,7 @@ def test_pallas_retrain_holds_the_span_tree(parquet_storage, pallas_on_cpu):
 @pytest.mark.parametrize("parquet_storage", ["localfs"], indirect=True)
 def test_persist_span_says_what_the_store_wrote(parquet_storage, monkeypatch):
     """``train.persist.save_models`` keeps its name and its place (the
-    benchmark's ``persist_s`` / ``seq_persist_s`` read it by the prefix
+    benchmark's ``persist_s`` reads it, in all four cells, by the prefix
     ``train.persist``, which no other stage may share), is tagged with what
     the local store wrote, and holds a ``persist.part`` child a part, opened
     from the writers' threads."""

@@ -1,0 +1,131 @@
+"""Every device operation of a sequence retrain under ONE name the program
+wrote (ISSUE 36): in the jaxprs of the three programs (the row program
+``accumulate_row``, the step ``apply_step``, the initialisation
+``init_state``) of the three blocks, backward and recomputed equations
+included, every equation that makes an array of more than a handful of
+elements carries exactly one top-level scope of ``seqmodel.SCOPES`` in its
+name stack.  A trace's ``(no scope)`` then holds only what the compiler made
+without an op name (``benchmark/trace_reduce.py``; ``device_unscoped_s``).
+
+Names and structure only: nothing here runs on a device."""
+
+from __future__ import annotations
+
+import math
+import re
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from predictionio_tpu.ops import seqmodel
+
+#: an equation whose results are all this small is a scalar's bookkeeping
+HANDFUL = 16
+TOP_LEVEL = re.compile(r"seq\.\w+")
+
+#: the mixers' and the feed-forward's scopes a block's row program must hold
+BLOCKS = {
+    "olmo_hybrid": {"seq.gdn", "seq.attn", "seq.mlp"},
+    "falcon_h1": {"seq.ssm", "seq.attn", "seq.mlp"},
+    "smallthinker": {"seq.attn", "seq.moe"},
+}
+
+
+def _config(block: str):
+    if block == "olmo_hybrid":
+        import seq_reference as ref
+        return ref.seq_config(ref.HALF)
+    if block == "falcon_h1":
+        import h1_reference as ref
+    else:
+        import st_reference as ref
+    return ref.seq_config(ref.SHARE)
+
+
+def _subjaxprs(eqn):
+    for value in eqn.params.values():
+        for v in value if isinstance(value, (list, tuple)) else [value]:
+            inner = getattr(v, "jaxpr", v)
+            if hasattr(inner, "eqns"):
+                yield inner
+
+
+def _operations(jaxpr, outer=""):
+    """(primitive, whole name stack, elements of the largest result) of the
+    equations that become device operations: an equation that only holds
+    other equations (a ``jit``, a ``checkpoint``, a loop) hands its name
+    stack down to them, as the lowering does; a kernel is one operation."""
+    for eqn in jaxpr.eqns:
+        stack = f"{outer}/{eqn.source_info.name_stack}"
+        inner = list(_subjaxprs(eqn))
+        if inner and eqn.primitive.name != "pallas_call":
+            for sub in inner:
+                yield from _operations(sub, stack)
+        else:
+            yield eqn.primitive.name, stack, max(
+                (math.prod(v.aval.shape) for v in eqn.outvars), default=0)
+
+
+def _program(cfg, program: str):
+    if program == "init_state":
+        return jax.make_jaxpr(lambda: seqmodel.init_state(cfg, 3))()
+    state, acc = jax.eval_shape(lambda: seqmodel.init_state(cfg, 3))
+    if program == "apply_step":
+        return jax.make_jaxpr(
+            lambda s, a: seqmodel.apply_step(seqmodel.AdamW(), s, a))(state, acc)
+    row = jax.ShapeDtypeStruct((64,), jnp.int32)
+    return jax.make_jaxpr(
+        lambda s, a, t, g: seqmodel.accumulate_row(cfg, s, a, t, g))(
+            state, acc, row, row)
+
+
+@pytest.mark.parametrize("program", ["accumulate_row", "apply_step", "init_state"])
+@pytest.mark.parametrize("block", list(BLOCKS))
+def test_every_operation_carries_one_top_level_scope(block, program):
+    ops = list(_operations(_program(_config(block), program).jaxpr))
+    assert not [s for _, s, _ in ops if "seq.adamw" in s]
+    found: set[str] = set()
+    for primitive, stack, size in ops:
+        if size <= HANDFUL:
+            continue
+        tops = set(TOP_LEVEL.findall(stack))
+        assert len(tops) == 1 and tops <= set(seqmodel.SCOPES), (
+            primitive, stack, size)
+        found |= tops
+    if program == "apply_step":
+        assert found == {"seq.step"}
+    elif program == "init_state":
+        assert found == {"seq.init"}
+    else:
+        assert found == BLOCKS[block] | {
+            "seq.embed", "seq.loss", "seq.stream", "seq.accumulate"}
+        # the adds of the stream's gradient: a transposition adds the
+        # cotangents of a value read twice under the name stack of the
+        # equation that read it FIRST, so the scopes around the norms and the
+        # residual adds name them too, and no fork of the stream is needed
+        adds = [s for p, s, n in ops if p == "add_any" and n > HANDFUL]
+        assert any("seq.stream" in s and "transpose" in s for s in adds)
+        assert all(TOP_LEVEL.search(s) for s in adds)
+
+
+@pytest.mark.parametrize("call", [
+    lambda: seqmodel._init_tensor.lower(
+        "q", (8, 32), 4, jax.random.PRNGKey(0), 1),
+    lambda: seqmodel._zeros.lower((8, 32), jnp.float32),
+], ids=["draw", "zeros"])
+def test_the_scope_reaches_the_programs_initialisation_dispatches(call):
+    """``init_state`` is no program of its own: it dispatches a small one a
+    tensor, and a ``named_scope`` around a dispatch does not reach the
+    program dispatched.  The scope is written inside each of them."""
+    assert 'op_name="jit(' in (text := call().compile().as_text())
+    assert "seq.init/" in text
+
+
+def test_the_docs_draw_the_closed_list_of_scopes():
+    text = (Path(__file__).resolve().parents[1] / "docs"
+            / "observability.md").read_text()
+    for scope in seqmodel.SCOPES:
+        assert scope in text, scope
+    assert "seq.adamw" not in text

@@ -290,6 +290,22 @@ def _assert_splash_alone(text: str):
         assert name not in text, name
 
 
+def _assert_every_named_operation_is_scoped(text: str):
+    """In a row program as the chip compiles it, every op name the PROGRAM
+    wrote (they start with its ``jit(``; the compiler's own ``gather`` or an
+    argument's name do not) holds one of ``seqmodel.SCOPES``; a checkpoint's
+    own copies (``.../remat2``) alone hold none.  What a trace then reads as
+    ``(no scope)`` is what the compiler made without an op name."""
+    import re
+
+    from predictionio_tpu.ops import seqmodel
+
+    bare = {
+        name for name in re.findall(r'op_name="(jit\([^"]*)"', text)
+        if not any(f"{s}/" in name or f"{s})" in name for s in seqmodel.SCOPES)}
+    assert {n.rsplit("/", 1)[-1] for n in bare} <= {"remat2"}, sorted(bare)[:5]
+
+
 def test_segment_masked_splash_attention_compiles(v5e):
     """The full-attention layers' library kernel as ``ops/seqmodel`` calls
     it: 15 heads of 128 over a row of 8192 with segment ids under a causal
@@ -408,6 +424,7 @@ def test_falcon_h1_row_program_fits_beside_its_arguments(v5e):
     text = compiled.as_text()
     assert "ssd_chunk_fwd" in text and "ssd_chunk_bwd" in text
     _assert_splash_alone(text)
+    _assert_every_named_operation_is_scoped(text)
     plan = compiled.memory_analysis()
     assert plan.argument_size_in_bytes == pytest.approx(16 * 769_637_472, rel=1e-3)
     assert plan.alias_size_in_bytes >= 0.999 * plan.argument_size_in_bytes  # donated
@@ -489,6 +506,7 @@ def test_smallthinker_row_program_fits_beside_its_arguments(v5e):
     for name in ("moe_gmm_gate_up", "moe_tgmm_down"):
         assert name in text, name
     _assert_splash_alone(text)
+    _assert_every_named_operation_is_scoped(text)
     plan = compiled.memory_analysis()
     assert plan.argument_size_in_bytes == pytest.approx(16 * 496_376_320, rel=1e-3)
     assert plan.alias_size_in_bytes >= 0.999 * plan.argument_size_in_bytes  # donated
