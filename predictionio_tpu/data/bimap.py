@@ -19,6 +19,12 @@ Anything else — lists, generators, ``U`` arrays, object arrays whose rows are
 mostly distinct objects, where the pass would save no hashing — takes the
 loop a row.  (Not ``pandas.factorize`` by VALUE: its string table compares
 C strings, so ``"a"`` and ``"a\0b"`` would share an index.)
+
+A third way, for a column that comes with the store's own codes (a
+``CodedColumn``: int32 codes into a dictionary of distinct values): the
+pointer pass is what the codes already are, so ``factorize`` ranks the
+dictionary's entries by their first row and renumbers the codes, with no
+pass over pointers at all.  Same vocabulary, same indices.
 """
 
 from __future__ import annotations
@@ -58,10 +64,12 @@ class Factorized(NamedTuple):
     """What ``BiMap.factorize`` made of one column."""
 
     vocab: "BiMap"
-    #: int64 index of every row in ``vocab``
+    #: index of every row in ``vocab``: int64, or int32 from a coded column
     codes: np.ndarray
     #: ``factorize``: one vectorized pass over the rows, Python over the
-    #: distinct objects; ``loop``: Python over every row
+    #: distinct objects; ``loop``: Python over every row; ``codes``: the
+    #: column came coded, Python over the dictionary's entries that have a
+    #: row
     path: str
     #: how many keys Python hashed to build the vocabulary
     hashed: int
@@ -99,7 +107,17 @@ class BiMap(Generic[K]):
     def factorize(cls, keys: Iterable[K]) -> Factorized:
         """Vocabulary in first-seen order AND every key's index in it, from
         one pass over ``keys``: ``from_keys`` + ``to_index_array`` for a
-        column that is needed both ways."""
+        column that is needed both ways.
+
+        ``keys`` may be a ``CodedColumn`` (an offer of the event store's, see
+        ``EventFrame.coded``); the result is the one its object column
+        would give, key for key and index for index.  Dictionary entries
+        without a row stay out of the vocabulary, a null id is the key
+        ``None`` as it is in an object column."""
+        from predictionio_tpu.data.storage.base import CodedColumn
+
+        if isinstance(keys, CodedColumn):
+            return cls._factorize_coded(keys)
         f = _distinct(keys)
         if f is None:
             if not isinstance(keys, (Sequence, np.ndarray)):
@@ -114,6 +132,22 @@ class BiMap(Generic[K]):
             # equal keys held by distinct objects shared an entry
             codes = vocab.to_index_array(distinct)[codes]
         return Factorized(vocab, codes, "factorize", len(distinct))
+
+    @classmethod
+    def _factorize_coded(cls, col) -> Factorized:
+        # the dictionary's entries that have a row, by their first row
+        first = col.first_rows()
+        seen = np.flatnonzero(first < len(col))
+        seen = seen[np.argsort(first[seen], kind="stable")]
+        distinct = col.dictionary[seen].tolist()
+        vocab = cls.from_keys(distinct)
+        rank = np.full(len(first), -1, np.int32)
+        # equal keys at two codes share an entry, as they do in the loop
+        rank[seen] = (
+            np.arange(len(seen)) if len(vocab) == len(distinct)
+            else vocab.to_index_array(distinct)
+        )
+        return Factorized(vocab, col.lookup(rank), "codes", len(distinct))
 
     @classmethod
     def string_int(cls, keys: Iterable[str]) -> "BiMap[str]":

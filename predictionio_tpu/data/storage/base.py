@@ -362,6 +362,107 @@ def ptr_factorize(
     return codes, col[first]
 
 
+#: rows a gather or scatter over a coded column handles at a time.  numpy
+#: copies an int32 index array to intp before it uses it: a quarter of a
+#: million rows of that stay in cache, 20 M rows are 160 MB of fresh pages,
+#: which on a host without transparent huge pages cost more than the gather
+_ROWS_AT_A_TIME = 1 << 18
+
+
+class CodedColumn:
+    """A column held as ``dictionary[codes]``: the store's own encoding of a
+    repetitive column (20 M rows over 165 k ids, or over ten ``properties``
+    documents), kept so that a consumer who wants the codes never pays for
+    20 M object pointers.
+
+    ``codes`` is int32, one a row, every one an index into ``dictionary``
+    (an object array of the distinct values; a null row's code points at an
+    entry that holds the null value, ``None``).  Entries need not have a row,
+    and equal values may sit at two codes: a consumer that needs neither
+    says so itself (``BiMap.factorize``).  Read-only by convention.
+
+    To whoever does not care, it reads as the object array it stands for:
+    ``len``, iteration, ``np.asarray``, ``==`` and an element by its row
+    give what ``dictionary[codes]`` gives (made on first touch, then kept),
+    and any other attribute is that array's.  Rows picked by a mask, a
+    slice or an index array stay coded."""
+
+    __slots__ = ("codes", "dictionary", "_objects")
+
+    def __init__(self, codes: np.ndarray, dictionary: np.ndarray):
+        self.codes = codes
+        self.dictionary = dictionary
+        self._objects = None
+
+    def lookup(self, table: np.ndarray) -> np.ndarray:
+        """``table[codes]`` for a table with a value an entry of the
+        dictionary: what is true of an entry, said of every row."""
+        out = np.empty(len(self.codes), table.dtype)
+        for at in range(0, len(out), _ROWS_AT_A_TIME):
+            rows = slice(at, at + _ROWS_AT_A_TIME)
+            # "clip" only spares numpy a buffer: every code is an index
+            np.take(table, self.codes[rows], out=out[rows], mode="clip")
+        return out
+
+    def first_rows(self) -> np.ndarray:
+        """The row each entry is first seen at, ``len(self)`` for an entry
+        no row uses.  Piece by piece from the top: only the rows whose entry
+        no earlier piece had are scattered (in reverse, so that the last
+        write is the first row), and nothing is read once every entry has
+        a row."""
+        n = len(self.codes)
+        first = np.full(len(self.dictionary), n, np.int64)
+        seen = np.zeros(len(first), bool)
+        for at in range(0, n, _ROWS_AT_A_TIME):
+            codes = self.codes[at:at + _ROWS_AT_A_TIME]
+            new = np.flatnonzero(~seen.take(codes, mode="clip"))
+            if len(new):
+                codes = codes[new]
+                first[codes[::-1]] = (at + new)[::-1]
+                seen[codes] = True
+                if seen.all():
+                    break
+        return first
+
+    @property
+    def objects(self) -> np.ndarray:
+        """The object column, one pointer a row: ``dictionary[codes]``."""
+        if self._objects is None:
+            self._objects = self.lookup(self.dictionary)
+        return self._objects
+
+    def __len__(self) -> int:
+        return len(self.codes)
+
+    def __iter__(self):
+        return iter(self.objects)
+
+    def __array__(self, dtype=None, copy=None):
+        return self.objects
+
+    def __getitem__(self, sel):
+        if isinstance(sel, (int, np.integer)):
+            return self.dictionary[self.codes[sel]]
+        return CodedColumn(self.codes[sel], self.dictionary)
+
+    def __eq__(self, other):
+        if not np.ndim(other):
+            # K compares; then their answers row by row, if any was yes
+            hit = self.dictionary == other
+            return self.lookup(hit) if hit.any() else np.zeros(len(self), bool)
+        return self.objects == np.asarray(other)
+
+    def __ne__(self, other):
+        return ~(self == other)
+
+    __hash__ = None
+
+    def __getattr__(self, name):
+        if name.startswith("__"):
+            raise AttributeError(name)
+        return getattr(self.objects, name)
+
+
 def entity_shard(entity_type: str, entity_id: str, n_shards: int) -> int:
     """The HBEventsUtil.scala:83 row-key hash, reduced to a shard index.
     Every backend's scan sharding (parquet layout, SQL entity-hash scans,
@@ -604,10 +705,31 @@ class EventFrame:
 
     This replaces the reference's ``RDD[Event]`` (PEvents.find, PEvents.scala:80).
     String columns are object arrays (vocab-mapped to index arrays via BiMap
-    before device_put); ``event_time_ms`` is int64 epoch millis; ``properties``
-    is an object array of dicts (often empty).  Use ``property_column`` to pull
-    one numeric property into a float array without materializing Events.
+    before device_put); ``event_time_ms`` is int64 epoch millis; a row of
+    ``properties`` is a dict or, from a bulk scan, the LAZY serialized JSON
+    document (``""`` = empty; see the field).  Use ``property_column`` to
+    pull one numeric property into a float array without materializing
+    Events.
+
+    **Coded columns.**  A builder that holds a string column or
+    ``properties`` as dictionary codes (the parquet scan) may hand it over
+    as a ``CodedColumn`` in place of the array.  Every reader of
+    ``frame.entity_id`` still gets the object array, the one
+    ``dictionary[codes]`` gives, made on first touch and kept; ``take`` and
+    ``select`` carry the codes along.  ``coded(name)`` is the offer to a
+    consumer that wants the codes themselves, and ``None`` is the answer of
+    every frame built from arrays (sqlite / Postgres, remote, fan-out,
+    ``from_events``, ``concat_frames``) and of a column assigned after the
+    frame was built.  No argument chooses it: a consumer asks, and takes
+    the object column where the answer is ``None``.
     """
+
+    #: the columns a builder may hand over coded (not the optional ones: an
+    #: unset attribute of theirs would read as the class-level ``None``)
+    CODABLE = (
+        "event", "entity_type", "entity_id", "target_entity_type",
+        "target_entity_id", "properties",
+    )
 
     event: np.ndarray  # object[str]
     entity_type: np.ndarray  # object[str]
@@ -628,19 +750,55 @@ class EventFrame:
     pr_id: np.ndarray | None = None  # object[str|None]
     creation_time_ms: np.ndarray | None = None  # int64
 
+    def __post_init__(self):
+        # coded columns go aside and leave their attribute unset, so that the
+        # first read of it lands in __getattr__ and makes the object column
+        coded = {
+            name: self.__dict__.pop(name)
+            for name in self.CODABLE
+            if isinstance(self.__dict__[name], CodedColumn)
+        }
+        self.__dict__["_coded"] = coded
+
+    def __getattr__(self, name):
+        col = self.__dict__.get("_coded", {}).get(name)
+        if col is None:
+            raise AttributeError(name)
+        self.__dict__[name] = out = col.objects
+        return out
+
+    def __setattr__(self, name, value):
+        # a column assigned from outside is not the store's any more
+        self.__dict__.get("_coded", {}).pop(name, None)
+        object.__setattr__(self, name, value)
+
+    def coded(self, name: str) -> CodedColumn | None:
+        """Column ``name`` as the builder's ``(codes, dictionary)``, or None
+        where this frame holds it as an array only.  An offer: whoever does
+        not ask reads ``frame.<name>`` as ever."""
+        return self._coded.get(name)
+
+    def column(self, name: str) -> "np.ndarray | CodedColumn | None":
+        """Column ``name`` as the frame holds it: coded where the builder
+        left it so, else the array.  For a consumer that masks, compares or
+        hands it to ``BiMap.factorize``, which take either."""
+        col = self._coded.get(name)
+        return getattr(self, name) if col is None else col
+
     def __len__(self) -> int:
-        return len(self.event)
+        return len(self.column("event"))
 
     def take(self, sel) -> "EventFrame":
-        """Row subset by boolean mask or index array (all columns)."""
+        """Row subset by boolean mask or index array (all columns; a coded
+        column stays coded)."""
         import dataclasses
 
+        def rows(name):
+            v = self.column(name)
+            return v[sel] if v is not None else None
+
         return EventFrame(
-            **{
-                f.name: (v[sel] if v is not None else None)
-                for f in dataclasses.fields(self)
-                for v in [getattr(self, f.name)]
-            }
+            **{f.name: rows(f.name) for f in dataclasses.fields(self)}
         )
 
     @classmethod
@@ -673,25 +831,10 @@ class EventFrame:
         )
 
     def select(self, mask: np.ndarray) -> "EventFrame":
-        def opt(a):
-            return a[mask] if a is not None else None
-
-        return EventFrame(
-            event=self.event[mask],
-            entity_type=self.entity_type[mask],
-            entity_id=self.entity_id[mask],
-            target_entity_type=self.target_entity_type[mask],
-            target_entity_id=self.target_entity_id[mask],
-            event_time_ms=self.event_time_ms[mask],
-            properties=self.properties[mask],
-            event_id=opt(self.event_id),
-            tags=opt(self.tags),
-            pr_id=opt(self.pr_id),
-            creation_time_ms=opt(self.creation_time_ms),
-        )
+        return self.take(mask)
 
     def where_event(self, *names: str) -> "EventFrame":
-        return self.select(np.isin(self.event, list(names)))
+        return self.take(np.isin(self.event, list(names)))
 
     def property_column(
         self, name: str, default: float = np.nan, dtype=np.float32
@@ -704,22 +847,18 @@ class EventFrame:
         # ingest) collapse under pointer identity: parse/coerce each UNIQUE
         # document once and broadcast — a 20M-row rating column is ~20
         # distinct JSON documents
-        f = ptr_factorize(self.properties)
-        if f is not None:
-            codes, uniq = f
-            k = len(uniq)
-            vals = np.empty(k, np.float64)
-            absent = np.zeros(k, bool)
-            for j, p in enumerate(uniq):
+        # ... and a frame that kept the store's codes needs no pass over
+        # pointers to find them
+        col = self.coded("properties")
+        if col is None:
+            f = ptr_factorize(self.properties)
+            col = None if f is None else CodedColumn(*f)
+        if col is not None:
+            vals = np.empty(len(col.dictionary), dtype)
+            for j, p in enumerate(col.dictionary):
                 v = self._row_value(p, name)
-                if v is None:
-                    absent[j] = True
-                    vals[j] = 0.0
-                else:
-                    vals[j] = v
-            out = vals[codes].astype(dtype)
-            out[absent[codes]] = default
-            return out
+                vals[j] = default if v is None else v
+            return col.lookup(vals)
         # branch on row kind (a cheap isinstance sweep) so a lazy row late
         # in a mostly-dict frame doesn't waste a full eager fill
         if any(isinstance(p, str) for p in self.properties):
@@ -924,7 +1063,15 @@ class PEvents(abc.ABC):
         come in an order that two reads of one unchanged store repeat.  A
         filter with ``limit`` or ``reversed`` is answered in time order
         whatever ``ordered`` says.  The defaults return what ``find``
-        always returned."""
+        always returned.
+
+        A backend that holds a string column or ``properties`` as
+        dictionary codes may hand it to the frame as a ``CodedColumn``
+        in place of the object array (``EventFrame``, "Coded columns").
+        That too is the backend's to decide and an offer the consumer may
+        ignore: ``frame.<column>`` reads as the array either way,
+        ``frame.coded(<column>)`` is ``None`` where there are no codes,
+        and nothing the caller passes here asks for them."""
 
     @abc.abstractmethod
     def write(

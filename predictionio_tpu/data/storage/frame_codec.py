@@ -39,7 +39,11 @@ import json
 
 import numpy as np
 
-from predictionio_tpu.data.storage.base import EventFrame, ptr_factorize
+from predictionio_tpu.data.storage.base import (
+    CodedColumn,
+    EventFrame,
+    ptr_factorize,
+)
 
 MAGIC = b"PIOF1\n"
 
@@ -173,14 +177,16 @@ def _decode_str_buffer(buf: memoryview, n: int) -> tuple:
     return arr, n * 4 + total
 
 
-def dictionary_to_objects(arr, null_value=None, transform=None) -> np.ndarray:
-    """Arrow DictionaryArray -> numpy object column, decoding (and
-    optionally ``transform``-ing) each UNIQUE dictionary value once and
-    broadcasting through the int32 codes; null rows become
-    ``null_value``.  The one home of this null-handling sequence — the
-    parquet scan decoders and the wire codec all share it, and the
-    interned output keeps downstream pointer fast paths hot."""
-    n = len(arr)
+def dictionary_to_coded(arr, null_value=None, transform=None) -> CodedColumn:
+    """Arrow DictionaryArray -> ``CodedColumn``: each UNIQUE dictionary
+    value decoded (and optionally ``transform``-ed) once, the int32 indices
+    as they are; null rows share one more entry, which holds
+    ``null_value``.  The one home of this null-handling sequence: the
+    parquet scan decoders and the wire codec all share it.  No pointer a
+    row is made here: ``.objects`` broadcasts them, interned, which keeps
+    downstream pointer fast paths hot."""
+    import pyarrow as pa
+
     if transform is None:
         uniq = np.asarray(
             arr.dictionary.to_numpy(zero_copy_only=False), object
@@ -190,13 +196,21 @@ def dictionary_to_objects(arr, null_value=None, transform=None) -> np.ndarray:
         uniq = np.empty(len(vals), object)
         for j, v in enumerate(vals):
             uniq[j] = transform(v)
-    if not len(uniq):  # all-null column dictionary-encodes to 0 values
-        return np.full(n, null_value, object)
-    codes = arr.indices.fill_null(0).to_numpy(zero_copy_only=False)
-    out = uniq[codes]
+    indices = arr.indices
+    if indices.type != pa.int32():
+        indices = indices.cast(pa.int32())
     if arr.null_count:
-        out[arr.is_null().to_numpy(zero_copy_only=False)] = null_value
-    return out
+        k = len(uniq)
+        uniq = np.concatenate([uniq, np.empty(1, object)])
+        uniq[k] = null_value
+        indices = indices.fill_null(k)
+    return CodedColumn(indices.to_numpy(zero_copy_only=False), uniq)
+
+
+def dictionary_to_objects(arr, null_value=None, transform=None) -> np.ndarray:
+    """Arrow DictionaryArray -> numpy object column: ``dictionary_to_coded``
+    broadcast through its codes."""
+    return dictionary_to_coded(arr, null_value, transform).objects
 
 
 def _arr_to_objects(arr) -> np.ndarray:

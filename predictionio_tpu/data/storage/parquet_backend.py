@@ -69,6 +69,7 @@ import pyarrow.parquet as pq
 from predictionio_tpu.data.datamap import DataMap
 from predictionio_tpu.data.event import Event
 from predictionio_tpu.data.storage.base import (
+    CodedColumn,
     EventFilter,
     EventFrame,
     LEvents,
@@ -78,7 +79,10 @@ from predictionio_tpu.data.storage.base import (
     ptr_factorize,
     run_concurrent,
 )
-from predictionio_tpu.data.storage.frame_codec import dictionary_to_objects
+from predictionio_tpu.data.storage.frame_codec import (
+    dictionary_to_coded,
+    dictionary_to_objects,
+)
 from predictionio_tpu.obs.costs import note_storage_read
 from predictionio_tpu.resilience import faults
 
@@ -1795,17 +1799,18 @@ def _table_to_events(t: pa.Table) -> list[Event]:
     return out
 
 
-def _decode_str_col(chunked) -> np.ndarray:
-    """Arrow string-ish column -> numpy object array.  Dictionary columns
-    decode through the vocabulary: ~unique-many Python strings get
-    materialized instead of one per row (the 20M-row scan win)."""
+def _decode_str_col(chunked) -> np.ndarray | CodedColumn:
+    """Arrow string-ish column -> numpy object array, or, for a dictionary
+    column, the unified dictionary and the rows' codes: ~unique-many Python
+    strings get materialized instead of one per row (the 20M-row scan win),
+    and not one pointer a row until somebody reads the column as objects."""
     arr = (
         chunked.combine_chunks()
         if isinstance(chunked, pa.ChunkedArray)
         else chunked
     )
     if pa.types.is_dictionary(arr.type):
-        return dictionary_to_objects(arr)
+        return dictionary_to_coded(arr)
     return arr.to_numpy(zero_copy_only=False)
 
 
@@ -1833,7 +1838,7 @@ def _decode_tags_col(chunked, n: int) -> np.ndarray:
 def _table_to_frame(t: pa.Table) -> EventFrame:
     present = set(t.column_names)
 
-    def col(name) -> np.ndarray | None:
+    def col(name) -> np.ndarray | CodedColumn | None:
         if name not in present:
             return None
         return _decode_str_col(t.column(name))
@@ -1846,9 +1851,10 @@ def _table_to_frame(t: pa.Table) -> EventFrame:
     # properties stay as RAW JSON strings ("" = empty): the EventFrame
     # contract decodes them lazily (property_column parses columnar at C
     # speed; to_events decodes row-wise) — a 20M-row scan skips 20M
-    # json.loads calls it may never need.  Dictionary decode hands back
-    # INTERNED documents, so property_column's pointer fast path parses
-    # each distinct document once.
+    # json.loads calls it may never need.  The dictionary columns go over
+    # as codes (EventFrame, "Coded columns"): property_column parses each
+    # distinct document once, and a reader of the object column gets
+    # INTERNED rows, so pointer fast paths downstream stay hot.
     return EventFrame(
         event=col("event"),
         entity_type=col("entity_type"),
@@ -2084,8 +2090,13 @@ class ParquetPEvents(PEvents):
             span.tags = {"rows": t.num_rows, "sorted": ordered}
             del tables
         with trace("eventstore.decode") as span:
-            span.tags = {"rows": t.num_rows, "columns": t.num_columns}
             frame = _table_to_frame(t)
+            span.tags = {
+                "rows": t.num_rows, "columns": t.num_columns,
+                # how many of them went over as (codes, dictionary)
+                "coded_columns": sum(
+                    frame.coded(c) is not None for c in EventFrame.CODABLE),
+            }
             del t
         return frame
 

@@ -35,6 +35,7 @@ from predictionio_tpu.core import (
 from predictionio_tpu.core.engine import engine_factory
 from predictionio_tpu.core.warmstart import align_warm_factors, find_warm_start
 from predictionio_tpu.data.bimap import BiMap
+from predictionio_tpu.data.storage.base import CodedColumn
 from predictionio_tpu.obs import device as device_obs
 from predictionio_tpu.obs import provenance
 from predictionio_tpu.obs.tracing import trace
@@ -82,10 +83,13 @@ class PredictedResult:
 
 @dataclass
 class TrainingData:
-    """Raw (user, item, rating) triples as columnar arrays."""
+    """Raw (user, item, rating) triples as columnar arrays.  From a store
+    that offers its codes the two id columns are ``CodedColumn``s: they
+    index, iterate and compare as the object arrays they stand for, and
+    the Preparator reads the codes."""
 
-    users: np.ndarray  # object[str]
-    items: np.ndarray  # object[str]
+    users: np.ndarray | CodedColumn  # object[str]
+    items: np.ndarray | CodedColumn  # object[str]
     ratings: np.ndarray  # float32
 
     def sanity_check(self):
@@ -155,19 +159,39 @@ class RatingsDataSource(DataSource):
             **asked,
         )
         with trace("datasource.columns") as span:
+            # each column as the store's codes where the frame kept them,
+            # else as the object array: either compares and masks like an
+            # array, and property_column parses each distinct document once
+            event, users, items = (
+                frame.column(c)
+                for c in ("event", "entity_id", "target_entity_id")
+            )
             ratings = frame.property_column("rating", default=np.nan)
             # buy events carry no rating property -> fixed implicit rating
-            is_buy = frame.event == "buy"
-            ratings = np.where(is_buy, self.params.buy_rating, ratings)
-            keep = ~np.isnan(ratings)
+            is_buy = event == "buy"
+            if is_buy.any():
+                ratings = np.where(is_buy, self.params.buy_rating, ratings)
+            keep = np.isnan(ratings)
+            np.logical_not(keep, out=keep)
+            if not keep.all():  # else three copies of what is there
+                users, items, ratings = users[keep], items[keep], ratings[keep]
             td = TrainingData(
-                users=frame.entity_id[keep],
-                items=frame.target_entity_id[keep],
-                ratings=ratings[keep].astype(np.float32),
+                users=users, items=items,
+                ratings=ratings.astype(np.float32, copy=False),
             )
             span.tags = tags = {
                 "rows_in": len(keep), "rows_kept": len(td.ratings),
+                # "codes" if no pointer a row was made of what was read
+                "path": (
+                    "objects"
+                    if any(
+                        frame.coded(c) is None
+                        for c in ("event", *asked["columns"])
+                    )
+                    else "codes"
+                ),
             }
+            del event, users, items
             # the frame's other columns go here, inside the span that made
             # them redundant: freeing 20 M decoded rows is not free
             del frame, is_buy
@@ -230,12 +254,13 @@ class RatingsPreparator(Preparator):
             users = BiMap.factorize(td.users)
             items = BiMap.factorize(td.items)
             user_vocab, item_vocab = users.vocab, items.vocab
+            paths = {users.path, items.path}
             span.tags = tags = {
-                # "loop" if either column fell back to Python over every row
+                # "codes" where both columns came coded from the store;
+                # "loop" if either fell back to Python over every row
                 "path": (
-                    "factorize"
-                    if users.path == items.path == "factorize"
-                    else "loop"
+                    "loop" if "loop" in paths
+                    else users.path if len(paths) == 1 else "factorize"
                 ),
                 "rows": len(users.codes),
                 "users": len(user_vocab),
@@ -245,8 +270,8 @@ class RatingsPreparator(Preparator):
                 "item_keys_hashed": items.hashed,
             }
         with trace("prepare.index") as span:
-            user_idx = users.codes.astype(np.int32)
-            item_idx = items.codes.astype(np.int32)
+            user_idx = users.codes.astype(np.int32, copy=False)
+            item_idx = items.codes.astype(np.int32, copy=False)
             span.tags = {"rows": len(user_idx), "path": tags["path"]}
             # the int64 codes go inside the span that made them redundant
             del users, items

@@ -2,6 +2,7 @@ import numpy as np
 import pytest
 
 from predictionio_tpu.data import BiMap
+from predictionio_tpu.data.storage.base import CodedColumn
 
 
 def test_from_keys_dedup_order():
@@ -73,6 +74,28 @@ def _mixed():
     return col
 
 
+def _coded(col=None, spare=("never-a", "never-b"), codes=None):
+    """``col`` as the parquet scan hands a column over (``CodedColumn``):
+    a dictionary that is in no first-seen order and holds ``spare`` entries
+    no row uses, and an int32 code a row (``codes`` to say them outright)."""
+    if col is None:
+        col = _interned()
+    distinct = list({id(k): k for k in col}.values())  # by object
+    dictionary = np.empty(len(distinct) + len(spare), object)
+    dictionary[:] = [*spare[:1], *reversed(distinct), *spare[1:]]
+    if codes is None:
+        code_of = {id(k): j for j, k in enumerate(dictionary.tolist())}
+        codes = np.fromiter((code_of[id(k)] for k in col), np.int32, len(col))
+    return CodedColumn(np.asarray(codes, np.int32), dictionary)
+
+
+def _coded_two_codes_one_key():
+    # a dictionary nobody unified: "id5" sits at two codes, as two objects
+    col = _coded(_two_dictionaries())
+    assert len(set(col.dictionary.tolist())) < len(col.dictionary)
+    return col
+
+
 #: name -> (column maker, the way BiMap.factorize must take)
 COLUMNS = {
     "interned": (_interned, "factorize"),
@@ -96,6 +119,30 @@ COLUMNS = {
     "list": (lambda: _interned().tolist(), "loop"),
     "tuple": (lambda: tuple(_interned(n=50)), "loop"),
     "generator": (lambda: (k for k in _interned().tolist()), "loop"),
+    # a column that comes with the store's codes (ISSUE 37)
+    "coded": (_coded, "codes"),
+    "coded-no-spare-entries": (lambda: _coded(spare=()), "codes"),
+    "coded-null-id": (lambda: _coded(_interned(with_none=True)), "codes"),
+    "coded-two-codes-one-key": (_coded_two_codes_one_key, "codes"),
+    "coded-nul-in-string": (
+        lambda: _coded(np.array(["a", "a\0b", "a", "a\0c", "a\0b"], object)),
+        "codes",
+    ),
+    "coded-rows-picked-by-a-mask": (
+        lambda: _coded()[np.arange(4000) % 3 == 0], "codes"),
+    "coded-strided-codes": (
+        lambda: CodedColumn(_coded().codes[::7], _coded().dictionary),
+        "codes",
+    ),
+    "coded-no-rows": (lambda: _coded(codes=[]), "codes"),
+    "coded-no-rows-no-dictionary": (
+        lambda: CodedColumn(np.empty(0, np.int32), np.empty(0, object)),
+        "codes",
+    ),
+    "coded-mostly-distinct": (
+        # more entries than a quarter of the rows: the pointer pass would
+        # give up and loop, the codes need no such cut-off
+        lambda: _coded(_interned(n=60, k=37)), "codes"),
 }
 
 
@@ -113,7 +160,8 @@ def test_vectorized_pass_equals_the_loop(name):
     make, path = COLUMNS[name]
     keys = make()
     rows = list(keys)  # what a loop over the column sees, row by row
-    if not isinstance(keys, (np.ndarray, list, tuple)):
+    columns = (np.ndarray, list, tuple, CodedColumn)
+    if not isinstance(keys, columns):
         keys = (k for k in rows)  # the generator again: it is read once
     forward = _oracle(rows)
     expected = np.array([forward[k] for k in rows], np.int64)
@@ -125,16 +173,18 @@ def test_vectorized_pass_equals_the_loop(name):
     assert len(f.vocab) == len(forward)
     for (a, i), (b, j) in zip(f.vocab.items(), forward.items()):
         assert (a is b or a == b) and type(a) is type(b) and i == j
-    assert f.codes.dtype == np.int64
+    # the store's codes are int32 and stay so
+    assert f.codes.dtype == (np.int32 if path == "codes" else np.int64)
     np.testing.assert_array_equal(f.codes, expected)
-    # the vectorized pass hashed each distinct object once, the loop each row
-    if path == "factorize":
+    # the vectorized pass hashed each distinct object once (the coded one
+    # each dictionary entry that has a row), the loop each row
+    if path in ("factorize", "codes"):
         assert f.hashed == len({id(k) for k in rows}) <= len(rows)
     else:
         assert f.hashed == len(rows)
 
     # from_keys / to_index_array handed the same column take the same pass
-    if not isinstance(keys, (np.ndarray, list, tuple)):
+    if not isinstance(keys, columns):
         keys = rows
     vocab = BiMap.from_keys(keys)
     assert list(vocab.items()) == list(f.vocab.items())
@@ -148,6 +198,16 @@ def test_vectorized_pass_equals_the_loop(name):
     want = np.array([half.get(k, -7) for k in rows], np.int64)
     assert got.dtype == np.int64
     np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("name", [n for n in COLUMNS if n.startswith("coded")])
+def test_coded_pass_a_few_rows_at_a_time(name, monkeypatch):
+    # a coded column is gathered and scattered in pieces (a quarter of a
+    # million rows at a time): the same answers when a piece is seven rows
+    from predictionio_tpu.data.storage import base
+
+    monkeypatch.setattr(base, "_ROWS_AT_A_TIME", 7)
+    test_vectorized_pass_equals_the_loop(name)
 
 
 def test_first_seen_object_is_the_key():

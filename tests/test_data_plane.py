@@ -706,6 +706,378 @@ class TestConsumerStatedRead:
         assert asked_rows(few) == asked_rows(want)[:4]
 
 
+# -- codes through the frame (ISSUE 37) --------------------------------------
+
+
+def eager(frame: EventFrame) -> EventFrame:
+    """The same rows as every backend but parquet hands them over: arrays
+    only, no column's codes kept."""
+    import dataclasses
+
+    return EventFrame(**{
+        f.name: getattr(frame, f.name) for f in dataclasses.fields(EventFrame)
+    })
+
+
+class _Context:
+    """An EngineContext as far as ``RatingsDataSource`` reads one: the event
+    store facade over app 1 of ``pe``, its frames as the backend made them
+    or, ``arrays_only``, stripped of their codes."""
+
+    def __init__(self, pe, arrays_only=False):
+        self.pe, self.arrays_only = pe, arrays_only
+        self.p_event_store = self
+
+    def find(self, app_name, channel_name=None, event_names=None,
+             columns=None, ordered=True, **flt):
+        frame = self.pe.find(
+            1, None, EventFilter(event_names=tuple(event_names), **flt),
+            columns=columns, ordered=ordered,
+        )
+        return eager(frame) if self.arrays_only else frame
+
+
+def _train_inputs(pe, arrays_only, buy_rating=3.5):
+    """(TrainingData, PreparedData, {span: tags}) of the recommendation
+    engine's read and prepare over ``pe``."""
+    from predictionio_tpu.models.recommendation.engine import (
+        DataSourceParams,
+        RatingsDataSource,
+        RatingsPreparator,
+    )
+    from predictionio_tpu.obs.tracing import trace
+
+    ctx = _Context(pe, arrays_only)
+    with trace("test.codes", ring=False) as root:
+        td = RatingsDataSource(
+            DataSourceParams(buy_rating=buy_rating)).read_training(ctx)
+        pd = RatingsPreparator().prepare(ctx, td)
+    return td, pd, {c.name: c.tags for c in root.children}
+
+
+def _assert_codes_change_nothing(pe, coded_columns=4):
+    """The read that keeps the store's codes and the one that reads object
+    columns hand the algorithm the same arrays; returns the coded side."""
+    from predictionio_tpu.data.storage.base import CodedColumn
+
+    td, pd, spans = _train_inputs(pe, arrays_only=False)
+    td0, pd0, spans0 = _train_inputs(pe, arrays_only=True)
+    took = coded_columns == 4
+    decode = spans.get("eventstore.decode")  # no span where no table was
+    assert (decode and decode["coded_columns"]) == coded_columns
+    assert spans["datasource.columns"]["path"] == (
+        "codes" if took else "objects")
+    assert spans0["datasource.columns"]["path"] == "objects"
+    assert isinstance(td.users, CodedColumn) == took
+    assert type(td0.users) is type(td0.items) is np.ndarray
+    assert spans0["prepare.vocab"]["path"] in ("factorize", "loop")
+    if took:
+        assert spans["prepare.vocab"]["path"] == "codes"
+        assert td.users.codes.dtype == td.items.codes.dtype == np.int32
+    # the DataSource's output, row for row ...
+    assert list(td.users) == list(td0.users)
+    assert list(td.items) == list(td0.items)
+    assert td.ratings.dtype == td0.ratings.dtype == np.float32
+    np.testing.assert_array_equal(td.ratings, td0.ratings)
+    # ... and the Preparator's: key for key in the same first-seen order,
+    # and the index arrays to the element
+    for a, b in ((pd.user_vocab, pd0.user_vocab),
+                 (pd.item_vocab, pd0.item_vocab)):
+        assert list(a.items()) == list(b.items())
+    for a, b in ((pd.user_idx, pd0.user_idx), (pd.item_idx, pd0.item_idx)):
+        assert a.dtype == b.dtype == np.int32
+        np.testing.assert_array_equal(a, b)
+    assert list(pd.user_vocab) == list(dict.fromkeys(td0.users))
+    for key in ("rows", "users", "items", "user_keys_hashed",
+                "item_keys_hashed"):
+        if spans0["prepare.vocab"]["path"] == "factorize":
+            assert spans["prepare.vocab"][key] == spans0["prepare.vocab"][key]
+    return td, pd
+
+
+def _rated(le, pe, rows, app_id=1):
+    """``rows`` of (event, user, item, properties) into the store."""
+    le.insert_batch(
+        [mk(ev, u, j, target=i, props=props)
+         for j, (ev, u, i, props) in enumerate(rows)],
+        app_id,
+    )
+
+
+def _edge_buy_without_rating(le, pe, tmp_path):
+    _rated(le, pe, [("buy", f"u{j % 5}", f"i{j % 3}", None)
+                    for j in range(20)]
+           + [("rate", "u1", "i9", {"rating": 2.0})])
+    td, _ = _assert_codes_change_nothing(pe)
+    assert sorted(td.ratings.tolist()) == [2.0] + [3.5] * 20
+
+
+def _edge_rating_as_string_and_bool(le, pe, tmp_path):
+    _rated(le, pe, [
+        ("rate", "u1", "i1", {"rating": "4.5"}),
+        ("rate", "u2", "i1", {"rating": True}),
+        ("rate", "u3", "i2", {"rating": False}),
+        ("rate", "u4", "i2", {"rating": "n/a"}),  # not a number: dropped
+        ("rate", "u5", "i3", {"rating": 3}),
+    ] * 3)
+    td, pd = _assert_codes_change_nothing(pe)
+    got = sorted(set(zip(td.users, td.ratings.tolist())))
+    assert got == [("u1", 4.5), ("u2", 1.0), ("u3", 0.0), ("u5", 3.0)]
+    assert "u4" not in pd.user_vocab
+
+
+def _edge_rate_without_rating(le, pe, tmp_path):
+    _rated(le, pe, [
+        ("rate", "u1", "i1", {"rating": 4.0}),
+        ("rate", "lonely", "i-unseen", {"stars": 5}),
+        ("rate", "u1", "i-unseen-too", None),
+        ("rate", "u2", "i1", {"rating": 1.0}),
+        ("rate", "lonely", "i1", {}),
+    ] * 4)
+    pe.compact(1)
+    _rated(le, pe, [("rate", "lonely", "i2", {"note": "x"}),
+                    ("rate", "u3", "i2", {"rating": 5.0})])
+    td, pd = _assert_codes_change_nothing(pe)
+    # the rows are dropped, and an id seen only on such rows is in the
+    # store's dictionary and NOT in the vocabulary
+    assert len(td.ratings) == 9
+    assert "lonely" in td.users.dictionary.tolist()
+    assert sorted(pd.user_vocab) == ["u1", "u2", "u3"]
+    assert sorted(pd.item_vocab) == ["i1", "i2"]
+
+
+def _edge_plain_string_segments(le, pe, tmp_path):
+    """A segment from before the columns were dictionary-encoded on disk,
+    beside a compacted segment and a hot head that are."""
+    import pyarrow as pa
+
+    from predictionio_tpu.data.storage.parquet_backend import (
+        _SCHEMA,
+        _event_row,
+        _publish_segment,
+        _segment_stats,
+    )
+
+    pe.write(bulk_frame(120, seed=3), 1)
+    pe.compact(1)
+    pe.write(bulk_frame(60, t0=300, seed=4), 1)
+    client = pe.store.client
+    for shard in range(4):
+        seq = client.seq.reserve()
+        rows = [
+            _event_row(mk("rate", f"old{shard}", j, target=f"i{j % 4}",
+                          props={"rating": float(1 + j % 5)}), seq, None)
+            for j in range(6)
+        ]
+        plain = pa.Table.from_pylist(rows, schema=_SCHEMA)
+        assert not pa.types.is_dictionary(plain.schema.field("entity_id").type)
+        _publish_segment(
+            client.app_dir(1, None) / f"shard={shard}",
+            f"seg-{seq}.parquet", plain, _segment_stats(plain))
+        client.seq.release(seq)
+    td, pd = _assert_codes_change_nothing(pe)
+    assert len(td.ratings) == 120 + 60 + 24
+    assert {f"old{k}" for k in range(4)} <= set(pd.user_vocab)
+
+
+def _edge_empty_store(le, pe, tmp_path):
+    # nothing to scan: the frame is ``from_events([])`` and offers no codes
+    td, pd = _assert_codes_change_nothing(pe, coded_columns=None)
+    assert len(td.ratings) == len(pd.user_idx) == len(pd.user_vocab) == 0
+
+
+def _edge_nothing_rated(le, pe, tmp_path):
+    # rows scanned, none of them a rating: coded columns of no rows
+    _rated(le, pe, [("rate", "u1", "i1", {"stars": 1})] * 3)
+    td, pd = _assert_codes_change_nothing(pe)
+    assert len(td.ratings) == len(pd.user_idx) == len(pd.item_vocab) == 0
+
+
+def _edge_null_target_id(le, pe, tmp_path):
+    frame = bulk_frame(90, seed=5)
+    col = frame.target_entity_id.copy()
+    col[::7] = None  # an item type with no item id: bulk writers may
+    frame.target_entity_id = col
+    pe.write(frame, 1)
+    td, pd = _assert_codes_change_nothing(pe)
+    assert sum(i is None for i in td.items) == 13 and None in pd.item_vocab
+    row = next(j for j, i in enumerate(td.items) if i is None)
+    assert td.items[row] is None
+    assert pd.item_idx[row] == pd.item_vocab[None]
+
+
+CODED_EDGES = {
+    "buy-without-rating": _edge_buy_without_rating,
+    "rating-as-string-and-bool": _edge_rating_as_string_and_bool,
+    "rate-without-rating": _edge_rate_without_rating,
+    "plain-string-segments": _edge_plain_string_segments,
+    "empty-store": _edge_empty_store,
+    "nothing-rated": _edge_nothing_rated,
+    "null-target-id": _edge_null_target_id,
+}
+
+
+class TestCodesThroughTheFrame:
+    """A parquet frame holds its dictionary columns as ``(codes,
+    dictionary)`` (ISSUE 37): whoever asks gets the codes, whoever reads
+    ``frame.entity_id`` gets the object column it always got."""
+
+    def test_training_read_equals_the_object_read(self, mixed_store):
+        """Compacted segments and hot heads, upserts, tombstones, ``buy``
+        events: same TrainingData, same vocabulary in the same order, same
+        index arrays."""
+        _, _, pe = mixed_store
+        td, pd = _assert_codes_change_nothing(pe)
+        assert len(td.ratings) > 500 and 3.5 in td.ratings
+        # first-seen in the store's own order: shard ascending
+        from predictionio_tpu.data.storage.base import frame_shard_of
+
+        users = np.asarray(td.users)
+        shard = frame_shard_of(_const(len(users), "user"), users, 4)
+        assert (np.diff(shard) >= 0).all() and len(set(shard)) == 4
+
+    @pytest.mark.parametrize("edge", list(CODED_EDGES))
+    def test_edges_train_as_the_object_read_does(self, tmp_path, edge):
+        client, le, pe = store_at(tmp_path / "pq")
+        le.init(1)
+        try:
+            CODED_EDGES[edge](le, pe, tmp_path)
+        finally:
+            client.close()
+
+    READS = {
+        "default": {},
+        "training": {"columns": ASKED, "ordered": False},
+        "filtered": {"filter": EventFilter(event_names=("rate",))},
+        "limit-reversed": {"filter": EventFilter(limit=40, reversed=True)},
+    }
+
+    @pytest.mark.parametrize("read", list(READS))
+    def test_object_columns_are_what_they_were(self, mixed_store, read):
+        """Every column of a coded frame, read as ever, is the broadcast
+        ``dictionary[codes]`` the eager decode made (one object a distinct
+        value, so pointer fast paths stay hot), made once."""
+        import dataclasses
+
+        _, _, pe = mixed_store
+        frame = pe.find(1, **self.READS[read])
+        names = [f.name for f in dataclasses.fields(EventFrame)]
+        offered = [c for c in names if frame.coded(c) is not None]
+        assert set(offered) == {
+            c for c in EventFrame.CODABLE if getattr(frame, c) is not None}
+        for c in offered:
+            col = frame.coded(c)
+            assert col.codes.dtype == np.int32 and len(col) == len(frame)
+            want = col.dictionary[col.codes]
+            got = getattr(frame, c)
+            assert type(got) is np.ndarray and got.dtype == object
+            assert got is getattr(frame, c)  # made once, then kept
+            assert all(a is b for a, b in zip(got, want))
+        # a frame of the arrays alone offers nothing and reads the same
+        arrays = eager(frame)
+        assert all(arrays.coded(c) is None for c in names)
+        again = pe.find(1, **self.READS[read])
+        for c in names:
+            a, b = getattr(arrays, c), getattr(again, c)
+            assert (a is None) == (b is None)
+            if a is not None:
+                assert a.tolist() == b.tolist(), c
+
+    SELECTIONS = {
+        "mask": lambda n: np.arange(n) % 3 == 1,
+        "index-array": lambda n: np.random.default_rng(7).permutation(n)[:97],
+        "nothing": lambda n: np.zeros(n, bool),
+        "everything-reversed": lambda n: np.arange(n)[::-1],
+    }
+
+    @pytest.mark.parametrize("how", list(SELECTIONS))
+    @pytest.mark.parametrize("method", ["take", "select"])
+    def test_take_and_select_equal_the_array_frames(
+        self, mixed_store, how, method
+    ):
+        import dataclasses
+
+        _, _, pe = mixed_store
+        frame = pe.find(1)
+        frame.entity_id  # one column already read as objects, the rest not
+        sel = self.SELECTIONS[how](len(frame))
+        got = getattr(frame, method)(sel)
+        want = getattr(eager(frame), method)(sel)
+        assert len(got) == len(want)
+        for f in dataclasses.fields(EventFrame):
+            assert getattr(got, f.name).tolist() == (
+                getattr(want, f.name).tolist()), f.name
+        # the codes came along, the array frame still has none
+        assert got.coded("entity_id") is not None
+        assert want.coded("entity_id") is None
+        np.testing.assert_array_equal(
+            got.coded("properties").codes, frame.coded("properties").codes[sel])
+        assert got.to_events() == want.to_events()
+
+    def test_where_event_and_property_column(self, mixed_store):
+        _, _, pe = mixed_store
+        frame = pe.find(1)
+        arrays = eager(frame)
+        for names in (("buy",), ("rate", "buy"), ("nope",)):
+            got, want = frame.where_event(*names), arrays.where_event(*names)
+            assert asked_rows(got) == asked_rows(want)
+            np.testing.assert_array_equal(
+                got.property_column("rating"), want.property_column("rating"))
+            np.testing.assert_array_equal(
+                got.property_column("rating", default=-1.0, dtype=np.float64),
+                want.property_column("rating", default=-1.0, dtype=np.float64))
+        assert len(frame.where_event("nope")) == 0
+
+    def test_to_events_and_a_write_round_trip(self, mixed_store, tmp_path):
+        _, _, pe = mixed_store
+        frame = pe.find(1)
+        assert frame.to_events() == eager(pe.find(1)).to_events()
+        # find() -> write() into two fresh apps, from the coded frame and
+        # from arrays: the same store either way, ids and all
+        copies = []
+        for app_id, src in ((2, pe.find(1)), (3, eager(pe.find(1)))):
+            pe.write(src, app_id)
+            copies.append(pe.find(app_id))
+        a, b = copies
+        assert len(a) == len(b) == len(frame)
+        assert asked_rows(a) == asked_rows(b)
+        assert sorted(asked_rows(a)) == sorted(asked_rows(frame))
+        assert a.event_id.tolist() == b.event_id.tolist()
+        assert sorted(filter(None, a.event_id)) == sorted(
+            filter(None, frame.event_id))
+        assert a.to_events() == b.to_events()
+
+    def test_wire_codec_carries_a_coded_frame(self, mixed_store):
+        from predictionio_tpu.data.storage.frame_codec import (
+            decode_frame,
+            encode_frame,
+        )
+
+        _, _, pe = mixed_store
+        frame = pe.find(1)
+        assert encode_frame(frame) == encode_frame(eager(pe.find(1)))
+        back = decode_frame(encode_frame(pe.find(1)))
+        # a frame off the wire offers no codes
+        assert all(back.coded(c) is None for c in EventFrame.CODABLE)
+        assert asked_rows(back) == asked_rows(frame)
+
+    def test_an_assigned_column_is_not_the_stores(self, mixed_store):
+        from predictionio_tpu.data.storage.base import concat_frames
+
+        _, _, pe = mixed_store
+        frame = pe.find(1)
+        assert frame.coded("target_entity_id") is not None
+        frame.target_entity_id = frame.target_entity_id[::-1].copy()
+        assert frame.coded("target_entity_id") is None
+        assert frame.coded("entity_id") is not None
+        assert frame.column("target_entity_id") is frame.target_entity_id
+        # shards put end to end by a path that does not carry codes along
+        parts = [f for _, f in pe.iter_shards(1)]
+        assert all(f.coded("event") is not None for f in parts)
+        whole = concat_frames(parts)
+        assert whole.coded("event") is None and len(whole) == len(frame)
+
+
 class TestBackpressure:
     def test_saturated_ingest_sheds_503_with_retry_after(self, tmp_path):
         from predictionio_tpu.data.storage.config import (

@@ -183,15 +183,18 @@ def _check_tree(stages, root, expected, mode):
     assert by_name["eventstore.scan"]["ordered"] is False
     assert by_name["eventstore.sort"]["sorted"] is False
     assert by_name["eventstore.decode"]["columns"] == 4
+    # the store's dictionary codes reach the Preparator's index arrays
+    # (ISSUE 37): all four columns left the store coded, the DataSource read
+    # them so, and the Preparator hashed each id of the dictionary once
+    assert by_name["eventstore.decode"]["coded_columns"] == 4
+    assert by_name["datasource.columns"]["path"] == "codes"
     vocab = by_name["prepare.vocab"]
     assert vocab["users"] <= N_USERS and vocab["items"] <= N_ITEMS
-    # the store decodes ids through their dictionary: one object an id, so
-    # the Preparator hashed each id once and says which way it went
-    assert vocab["path"] == "factorize" and vocab["rows"] == NNZ
+    assert vocab["path"] == "codes" and vocab["rows"] == NNZ
     assert vocab["user_keys_hashed"] == vocab["users"]
     assert vocab["item_keys_hashed"] == vocab["items"]
     assert by_name["prepare.index"]["rows"] == NNZ
-    assert by_name["prepare.index"]["path"] == "factorize"
+    assert by_name["prepare.index"]["path"] == "codes"
     loop = by_name["als.device_loop"]
     assert loop["iterations"] == ITERATIONS and loop["mode"] == mode
     assert by_name["als.fetch"]["bytes"] > 0
@@ -296,10 +299,11 @@ def test_read_spans_keep_their_names_and_say_what_the_read_did(
     assert [c["name"] for c in read["children"]] == [
         "eventstore.scan", "eventstore.sort", "eventstore.decode",
         "datasource.columns"]
-    scan, sort, decode, _ = read["children"]
+    scan, sort, decode, columns = read["children"]
     assert (scan["ordered"], scan["columns"]) == (False, 4)
     assert (sort["sorted"], sort["rows"]) == (False, NNZ)
-    assert decode["columns"] == 4
+    assert (decode["columns"], decode["coded_columns"]) == (4, 4)
+    assert columns["path"] == "codes"
     (store,) = [r.bulk_read for r in caplog.records
                 if hasattr(r, "bulk_read")]
     assert (store["ordered"], store["columns"], store["rows"]) == (
@@ -307,7 +311,7 @@ def test_read_spans_keep_their_names_and_say_what_the_read_did(
     (record,) = [r for r in caplog.records if hasattr(r, "read")]
     assert record.read == {
         "columns": ("entity_id", "target_entity_id", "properties"),
-        "ordered": False, "rows_in": NNZ, "rows_kept": NNZ,
+        "ordered": False, "rows_in": NNZ, "rows_kept": NNZ, "path": "codes",
     }
 
 
@@ -383,7 +387,7 @@ def test_new_read_hands_the_preparator_the_same_ratings(
                  (new.ratings, again.ratings)):
         assert (a == b).all()  # ... and the same one on every read
     shard = frame_shard_of(
-        np.full(len(new.users), "user", object), new.users, 4)
+        np.full(len(new.users), "user", object), np.asarray(new.users), 4)
     assert (np.diff(shard) >= 0).all()
 
     prep = RatingsPreparator()
@@ -431,13 +435,31 @@ def _boxed(col):
     return out
 
 
-#: users column -> the way the Preparator must say it went
+def _coded(col):
+    # what the parquet scan hands over: the store's dictionary (with entries
+    # no row uses, in an order nothing like first-seen) and an int32 code a
+    # row; a null id is one more entry
+    from test_bimap import _coded as coded_column
+
+    return coded_column(col)
+
+
+#: users column -> the way the Preparator must say it went (the items
+#: column comes interned, or coded where the case's name says both)
 PREPARE_CASES = {
     "interned": (lambda u: u, "factorize", PREPARE_USERS),
     # a pointer is a pointer: None is one more distinct object
     "none-user-id": (_with_none, "factorize", PREPARE_USERS + 1),
     "U-dtype": (lambda u: u.astype("U"), "loop", PREPARE_ROWS),
     "object-a-row": (_boxed, "loop", PREPARE_ROWS),
+    # the store's codes (ISSUE 37): Python hashes the dictionary's entries
+    # that have a row, and no pointer a row is ever made
+    "both-coded": (_coded, "codes", PREPARE_USERS),
+    "both-coded-none-user-id": (
+        lambda u: _coded(_with_none(u)), "codes", PREPARE_USERS + 1),
+    # one column of each kind: each goes its own way, the tag names the
+    # slower of the two
+    "coded-users-interned-items": (_coded, "factorize", PREPARE_USERS),
 }
 
 
@@ -449,9 +471,10 @@ def test_preparator_equals_the_loop_and_says_which_way_it_went(case, caplog):
     )
 
     make, path, hashed = PREPARE_CASES[case]
+    items = _ids("i", PREPARE_ITEMS, 26)
     td = TrainingData(
         users=make(_ids("u", PREPARE_USERS, 25)),
-        items=_ids("i", PREPARE_ITEMS, 26),
+        items=_coded(items) if case.startswith("both-coded") else items,
         ratings=np.ones(PREPARE_ROWS, np.float32),
     )
     with caplog.at_level(logging.INFO, "predictionio_tpu"):

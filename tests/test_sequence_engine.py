@@ -310,6 +310,13 @@ def test_the_sequence_read_asks_for_time_order(trained):
     assert by_name["eventstore.sort"]["rows"] == NNZ
     for name in by_name:
         assert stages[name] >= 0, name
+    # the store offers its six dictionary columns as codes (ISSUE 37); this
+    # engine asks for none, reads object columns, and prepares as ever
+    assert by_name["eventstore.decode"]["coded_columns"] == 6
+    prepare = next(
+        c for c in root["children"] if c["name"] == "train.preparator.prepare")
+    vocab = next(c for c in prepare["children"] if c["name"] == "prepare.vocab")
+    assert vocab["path"] == "factorize"
 
 
 def test_template_scaffolds_the_engine(tmp_path):

@@ -358,6 +358,23 @@ class TestLazyProperties:
         assert np.isnan(got_e[0])
         np.testing.assert_allclose(got_e[1:], [1.0, 3.0, 4.5])
 
+    def test_frames_built_from_arrays_or_events_offer_no_codes(self):
+        """``from_events`` and a frame of arrays answer ``None`` for every
+        column's codes, and ``column`` hands back the array itself."""
+        from predictionio_tpu.data.storage.base import EventFrame
+
+        built = self._frame(['{"rating": 4}', "", '{"rating": "2.5"}'])
+        from_events = EventFrame.from_events(built.to_events())
+        for frame in (built, from_events, EventFrame.from_events([])):
+            for c in EventFrame.CODABLE:
+                assert frame.coded(c) is None
+                assert frame.column(c) is getattr(frame, c)
+        import numpy as np
+
+        np.testing.assert_array_equal(
+            from_events.property_column("rating"),
+            built.property_column("rating"))
+
     def test_to_events_decodes_lazy_rows(self):
         lazy = self._frame(['{"rating": 4.5}', ""])
         evs = lazy.to_events()
@@ -413,6 +430,72 @@ class TestLazyProperties:
         got = frame_shard_of(et, ei, 8)
         want = [entity_shard(t, e, 8) for t, e in zip(et, ei)]
         np.testing.assert_array_equal(got, want)
+
+
+class TestCodedColumn:
+    """``CodedColumn`` (ISSUE 37): codes into a dictionary, and to whoever
+    does not care the object array ``dictionary[codes]``."""
+
+    @pytest.fixture(params=[1 << 18, 5], ids=["one-piece", "pieces-of-5"])
+    def col(self, request, monkeypatch):
+        from predictionio_tpu.data.storage import base
+
+        monkeypatch.setattr(base, "_ROWS_AT_A_TIME", request.param)
+        dictionary = np.empty(6, object)
+        dictionary[:] = ["never", "b", None, "a", "b" + "", "c"]
+        codes = np.random.default_rng(37).integers(1, 6, 43).astype(np.int32)
+        return base.CodedColumn(codes, dictionary)
+
+    def test_reads_as_the_object_array(self, col):
+        want = col.dictionary[col.codes]
+        assert len(col) == 43 and list(col) == list(want)
+        got = np.asarray(col)
+        assert got.dtype == object and got.tolist() == want.tolist()
+        assert col.objects is col.objects  # made once
+        assert all(a is b for a, b in zip(col.objects, want))  # interned
+        assert col[7] is want[7] and col[np.int64(-1)] is want[-1]
+        assert col.tolist() == want.tolist()  # any other attribute
+        assert col.dtype == object and col.shape == (43,)
+        with pytest.raises(TypeError):
+            hash(col)
+
+    def test_compares_as_the_object_array(self, col):
+        want = col.dictionary[col.codes]
+        for value in ("b", None, "never", "nowhere", 3):
+            np.testing.assert_array_equal(col == value, want == value)
+            np.testing.assert_array_equal(col != value, want != value)
+        assert (col == "b").sum() == np.isin(col.codes, (1, 4)).sum()
+        other = want.copy()
+        other[::5] = "x"
+        np.testing.assert_array_equal(col == other, want == other)
+        np.testing.assert_array_equal(col == col[:], np.ones(43, bool))
+
+    @pytest.mark.parametrize("sel", [
+        np.arange(43) % 4 == 0, np.array([5, 5, 0, 42]), slice(3, 30, 2),
+        np.zeros(43, bool),
+    ], ids=["mask", "index-array", "slice", "nothing"])
+    def test_rows_picked_stay_coded(self, col, sel):
+        from predictionio_tpu.data.storage.base import CodedColumn
+
+        got = col[sel]
+        assert isinstance(got, CodedColumn) and got.dictionary is col.dictionary
+        assert got.codes.dtype == np.int32
+        assert list(got) == list(col.dictionary[col.codes][sel])
+
+    def test_lookup_and_first_rows(self, col):
+        table = np.arange(6, dtype=np.float32) * 1.5
+        np.testing.assert_array_equal(col.lookup(table), table[col.codes])
+        assert col.lookup(table).dtype == np.float32
+        first = col.first_rows()
+        assert first.dtype == np.int64 and first[0] == len(col)  # "never"
+        for code in range(1, 6):
+            rows = np.flatnonzero(col.codes == code)
+            assert first[code] == (rows[0] if len(rows) else len(col))
+        from predictionio_tpu.data.storage.base import CodedColumn
+
+        empty = CodedColumn(np.empty(0, np.int32), col.dictionary)
+        assert empty.first_rows().tolist() == [0] * 6
+        assert empty.lookup(table).shape == (0,) and list(empty) == []
 
 
 class TestParquetRegressions:
@@ -589,6 +672,69 @@ class TestFacades:
         assert rows(again) == rows(got)  # a repeatable order
         projected = store.find("shop", event_names=["rate"], columns=asked)
         assert rows(projected) == rows(want)  # ordered unless said otherwise
+
+    def test_codes_are_an_offer_a_backend_may_decline(self, storage, request):
+        """``EventFrame.coded`` (ISSUE 37): the parquet store hands its
+        dictionary columns over as codes, every other backend answers
+        ``None``, and the recommendation engine's read and prepare come to
+        the same arrays whichever it was given."""
+        from predictionio_tpu.core import EngineContext
+        from predictionio_tpu.data.storage.base import CodedColumn, EventFrame
+        from predictionio_tpu.models.recommendation.engine import (
+            DataSourceParams,
+            RatingsDataSource,
+            RatingsPreparator,
+        )
+        from predictionio_tpu.obs.tracing import trace
+
+        backend = request.node.callspec.params["storage"]
+        app_id = storage.apps().insert(App(id=0, name="shop"))
+        le = storage.l_events()
+        le.init(app_id)
+        events = [
+            mk("rate", f"u{j % 4}", j, target=f"i{j % 3}",
+               props={"rating": float(1 + j % 5)}) for j in range(24)
+        ] + [
+            mk("buy", "u9", 30, target="i1"),
+            mk("rate", "nobody", 31, target="i-unrated", props={"stars": 2}),
+        ]
+        le.insert_batch(events, app_id)
+        asked = ("entity_id", "target_entity_id", "properties")
+        frame = PEventStore(storage).find(
+            "shop", event_names=["rate", "buy"], columns=asked, ordered=False)
+        offered = {
+            c for c in EventFrame.CODABLE if frame.coded(c) is not None}
+        if backend == "parquet":
+            assert offered == {"event", *asked}
+        else:
+            assert offered == set()
+        assert type(frame.entity_id) is np.ndarray  # whoever asked or not
+
+        ctx = EngineContext(storage=storage)
+        with trace("test.offer", ring=False) as root:
+            td = RatingsDataSource(
+                DataSourceParams(app_name="shop")).read_training(ctx)
+            pd = RatingsPreparator().prepare(ctx, td)
+        tags = {c.name: c.tags for c in root.children}
+        took = backend == "parquet"
+        assert tags["datasource.columns"]["path"] == (
+            "codes" if took else "objects")
+        assert (tags["prepare.vocab"]["path"] == "codes") == took
+        assert isinstance(td.users, CodedColumn) == took
+        # the same ratings under the same ids, whatever order the backend
+        # read them in; "nobody" rated nothing and is in no vocabulary
+        want = sorted(
+            (e.entity_id, e.target_entity_id,
+             float(e.properties.fields.get("rating", 4.0)))
+            for e in events if e.entity_id != "nobody")
+        assert sorted(zip(td.users, td.items, td.ratings.tolist())) == want
+        assert list(pd.user_vocab) == list(dict.fromkeys(td.users))
+        assert list(pd.item_vocab) == list(dict.fromkeys(td.items))
+        assert "nobody" not in pd.user_vocab
+        assert [pd.user_vocab.inverse(int(u)) for u in pd.user_idx] == (
+            list(td.users))
+        assert [pd.item_vocab.inverse(int(i)) for i in pd.item_idx] == (
+            list(td.items))
 
     def test_localfs_models(self, tmp_path):
         from predictionio_tpu.data.storage.localfs_models import LocalFSModels
