@@ -12,7 +12,12 @@ grouped-query attention on one normed input (Falcon-H1's,
 huggingface.co/tiiuae/Falcon-H1-34B-Instruct), or routed experts after
 global (no rotary) and sliding-window (rotary) attention layers, the router
 read before attention (SmallThinker's,
-huggingface.co/PowerInfer/SmallThinker-21BA3B-Instruct) — by next-item cross-entropy
+huggingface.co/PowerInfer/SmallThinker-21BA3B-Instruct), or a looped stack of
+sandwich-norm attention layers run ``totalUtSteps`` times with the same
+weights, an exit head and a gate after every pass (Ouro's,
+huggingface.co/ByteDance/Ouro-2.6B) — by next-item cross-entropy (the looped
+model: the expected cross-entropy under its exit distribution, less
+``exitBeta`` times that distribution's entropy)
 with AdamW: ``stepsPerRetrain`` optimiser steps of ``rowsPerStep`` rows, one
 pass in packed order, from a seeded initialisation.  ``layerTypes`` says
 which block; each kind reads its own sizes.
@@ -289,7 +294,13 @@ class SequenceAlgorithmParams:
     ``rope_theta`` and muP's forward multipliers, 1 where a model has none) or
     ``global_attention_moe`` / ``sliding_attention_moe`` (pre-norm, attention
     then routed experts: the ``moe_*`` sizes with the experts HELD and the first
-    of them, ``sliding_window_size``, ``num_key_value_heads``, ``rope_theta``)."""
+    of them, ``sliding_window_size``, ``num_key_value_heads``, ``rope_theta``)
+    or ``sandwich_attention`` (a norm before and after each sublayer inside
+    the residual, rotary multi-head attention and the MLP;
+    ``num_key_value_heads``, ``rope_theta``; with ``total_ut_steps`` > 1 the
+    layer list is run that many times with the same weights and every pass
+    ends in an exit: one head, one gate, ``exit_beta`` the entropy's weight in
+    the loss)."""
 
     hidden_size: int = 3840
     layer_types: tuple[str, ...] = (
@@ -346,8 +357,14 @@ class SequenceAlgorithmParams:
     moe_num_active_primary_experts: int = 0
     moe_ffn_hidden_size: int = 0
     sliding_window_size: int = 0
+    #: a looped model: passes through the layer list, and the weight of the
+    #: exit distribution's entropy in its loss
+    total_ut_steps: int = 1
+    exit_beta: float = 0.1
 
     params_aliases = {
+        "totalUtSteps": "total_ut_steps",
+        "exitBeta": "exit_beta",
         "moeNumPrimaryExperts": "moe_num_primary_experts",
         "moeExpertsHeld": "moe_experts_held",
         "moeExpertStart": "moe_expert_start",
@@ -409,7 +426,13 @@ class SequenceModel:
     #: (the first layer's experts on its normed input, [.., 1]), ``choices``
     #: [rows a step, routed layers, row_len, experts a token] for the same
     #: rows, and per step and routed layer moe_pairs_total, moe_pairs_held,
-    #: moe_expert_pairs [.., experts held] (``ops/seqmodel.apply_step``)
+    #: moe_expert_pairs [.., experts held] (``ops/seqmodel.apply_step``).  A
+    #: looped model: exit_probe (each position's exit distribution, [.., passes]),
+    #: carry_probe (the mean square of the state each later pass read) and, at
+    #: a few positions, head_probe with head_probe_state (every exit's
+    #: cross-entropy and the exit state it came from) for the same rows, and
+    #: per step loss_by_exit and exit_mass [.., passes], exit_entropy and the
+    #: counters loop_layer_applications, loop_tokens, loop_attention_pairs
     training_record: dict
     config: Any = None
 
@@ -455,6 +478,7 @@ class SequenceAlgorithm(Algorithm):
             expert_start=p.moe_expert_start,
             experts_per_token=p.moe_num_active_primary_experts,
             expert_width=p.moe_ffn_hidden_size, window=p.sliding_window_size,
+            loop_steps=p.total_ut_steps, exit_beta=p.exit_beta,
         )
 
     def train(self, ctx: EngineContext, pd: PackedSequences) -> SequenceModel:
@@ -500,6 +524,7 @@ class SequenceAlgorithm(Algorithm):
                 "steps": p.steps_per_retrain, "rows": need,
                 "tokens": int((pd.segments[:need] != PAD_SEGMENT).sum()),
                 "block": "+".join(dict.fromkeys(cfg.layer_types)),
+                **_loop_tags(cfg),
             }
         with trace("seq.fetch") as span:
             params = {k: np.asarray(v) for k, v in state["params"].items()}
@@ -511,9 +536,15 @@ class SequenceAlgorithm(Algorithm):
                 record.update(first)
             else:
                 record[seqmodel.PROBE_NAME[cfg.layer_types[0]]] = first
-            span.tags = {"bytes": int(sum(v.nbytes for v in params.values()))}
+            span.tags = {"bytes": int(sum(v.nbytes for v in params.values())),
+                         **_loop_tags(cfg)}
             if "moe_expert_pairs" in record:
                 span.tags["counters"] = _routing_counters(record)
+            if "loop_layer_applications" in record:
+                span.tags["counters"] = {
+                    key: int(record[key].sum()) for key in (
+                        "loop_layer_applications", "loop_tokens",
+                        "loop_attention_pairs")}
             del state
         log.info(
             "trained %d steps: loss %s", p.steps_per_retrain,
@@ -604,6 +635,13 @@ class SequenceAlgorithm(Algorithm):
         )
 
 
+def _loop_tags(cfg) -> dict:
+    """What a looped model's spans say of it: its passes, and its exits."""
+    if cfg.loop_steps == 1:
+        return {}
+    return {"loop_steps": cfg.loop_steps, "exits": cfg.loop_steps}
+
+
 def _routing_counters(record: dict) -> dict:
     """The routed layers' counters of a training record as flat numbers (a
     span's ``counters`` tag, the ``stages`` extra's ``counters``): the
@@ -648,6 +686,6 @@ def sequence_engine() -> Engine:
         SequencePreparator,
         # one algorithm under the name of each block's recurrence
         {"gdn": SequenceAlgorithm, "ssd": SequenceAlgorithm,
-         "moe": SequenceAlgorithm},
+         "moe": SequenceAlgorithm, "loop": SequenceAlgorithm},
         FirstServing,
     )

@@ -1,4 +1,4 @@
-"""Hybrid sequence models, trainable on packed rows of tokens: three blocks.
+"""Hybrid sequence models, trainable on packed rows of tokens: four blocks.
 
 Layers follow ``layer_types``; the block's FORM is a property of the kind.
 
@@ -46,6 +46,19 @@ routed experts (``ops/moe.py``) in place of the MLP, ``x1 = x + attn(h)``,
   kernel under a local mask (blocks outside the window are skipped; segment
   ids mask inside a block, no block is skipped for them).
 
+``"sandwich_attention"`` (the Ouro block, a looped model): a norm before AND
+after each sublayer, inside the residual, ``x1 = x + N2(attn(N1(x)))``,
+``x2 = x1 + N4(mlp(N3(x1)))``; attention is plain multi-head (the grouped
+path with one KV head a query head), rotary positions over the whole head
+that restart at every segment, no q / k norm; the MLP SwiGLU.  With
+``loop_steps`` R > 1 the layer list is applied R times with the SAME tensors
+(``trunk``): the one final norm closes every pass and its OUTPUT is both the
+pass's exit state and the next pass's input.  Every exit state goes through
+the one head and through one gate, ``lam_t = sigmoid(x_t . w_g + b_g)``; the
+exit distribution of a position is ``p_t = lam_t prod_{j<t} (1 - lam_j)``,
+the last pass taking the rest, and the loss the expected cross-entropy under
+it less ``exit_beta`` times its entropy (``looped_row_grads``).
+
 **The share.**  A deployment divides every layer over ``chips`` chips; this
 process holds one share of it: ``heads`` of the attention heads, ``mlp_cols``
 of the MLP's columns (or ``experts_held`` of the experts, from
@@ -64,7 +77,9 @@ matrix products take bfloat16 inputs and accumulate in float32, in the forward
 and both backward products (``mm``).  Residual stream, norms, the
 convolution, the decay projections and everything of the delta rule float32;
 the router's logits, its top-k and the chosen experts' weights float32 at
-``Precision.HIGHEST``.
+``Precision.HIGHEST``; so are the state a looped model carries from pass to
+pass, its gates' product, the exit distribution, its entropy and the
+combination of the exits' losses.
 
 **Training.**  ``train_steps`` dispatches, for each optimiser step, the rows of
 the step one at a time (forward, per-layer recomputation, backward; gradients
@@ -76,6 +91,10 @@ record holds the carried state's precision by.  A routed block hands back
 instead the first layer's experts applied to ``h`` (exact on both sides of a
 comparison) along a seeded vector, every layer's choices, and the pairs each
 held expert computed, which the step's accumulator sums beside the gradients.
+A looped block hands back each position's exit distribution, the mean square
+of the state each later pass read, and at a few positions the exit states
+with their cross-entropies; the step's accumulator sums every exit's loss, its
+mass, the entropy and the layer applications made.
 """
 
 from __future__ import annotations
@@ -97,14 +116,15 @@ FULL = "full_attention"
 PARALLEL = "parallel_ssm_attention"
 GLOBAL_MOE = "global_attention_moe"
 SLIDING_MOE = "sliding_attention_moe"
+SANDWICH = "sandwich_attention"
 #: the kinds whose feed-forward is a layer of routed experts
 MOE_KINDS = (GLOBAL_MOE, SLIDING_MOE)
-KINDS = (LINEAR, FULL, PARALLEL) + MOE_KINDS
+KINDS = (LINEAR, FULL, PARALLEL) + MOE_KINDS + (SANDWICH,)
 
 #: what the training record calls the first layer's probe, by its kind
 PROBE_NAME = {LINEAR: "delta_rule_probe", FULL: "delta_rule_probe",
               PARALLEL: "ssd_probe", GLOBAL_MOE: "moe_probe",
-              SLIDING_MOE: "moe_probe"}
+              SLIDING_MOE: "moe_probe", SANDWICH: "exit_probe"}
 
 #: segment id of a row's padding (real segments count from 0)
 PAD_SEGMENT = -1
@@ -118,6 +138,9 @@ PAD_SEGMENT = -1
 SCOPES = (
     "seq.embed", "seq.gdn", "seq.ssm", "seq.moe", "seq.attn", "seq.mlp",
     "seq.loss",
+    # a looped model's gates, exit distribution, entropy and the combination
+    # of its exits' losses, with their backward (the losses stay ``seq.loss``)
+    "seq.exit",
     # what ``layer`` / ``routed_layer`` / ``trunk`` do to the residual stream
     # between the mixers: the norms on it and the residual adds, and through
     # those the adds of the stream's gradient
@@ -129,6 +152,13 @@ SCOPES = (
     # the seeded draws of ``init_params``, the zeros of moments and sums
     "seq.init",
 )
+
+#: the component around pass t of a looped model's trunk, OUTSIDE the
+#: top-level scope of its operations: ``loop.pass2/seq.attn/attn.causal``
+LOOP_PASS = "loop.pass{}"
+#: positions of a row, evenly spaced, whose exit states a looped model's
+#: training record keeps beside their cross-entropies (``head_probe``)
+HEAD_PROBE_POSITIONS = 32
 
 #: what the large matrix products round their inputs to (the configuration's
 #: stated precision; tests set float32 to compare with the plain reference
@@ -169,9 +199,11 @@ def _scaled(x, m: float):
 class SeqConfig:
     """Widths as published, counts as HELD by this share.  The ``lin_*``
     sizes are read by ``"linear_attention"`` layers, ``heads`` / ``head_dim``
-    by every attention mixer, ``kv_heads`` and ``rope_theta`` by the parallel
-    and the routed blocks, ``ssm_*`` and ``mup`` by ``"parallel_ssm_attention"``
-    layers, ``experts*``, ``expert_*`` and ``window`` by the ``*_moe`` kinds."""
+    by every attention mixer, ``kv_heads`` and ``rope_theta`` by the parallel,
+    the routed and the sandwich blocks, ``ssm_*`` and ``mup`` by
+    ``"parallel_ssm_attention"`` layers, ``experts*``, ``expert_*`` and
+    ``window`` by the ``*_moe`` kinds, ``loop_steps`` and ``exit_beta`` by a
+    looped stack of ``"sandwich_attention"`` layers."""
 
     hidden: int
     layer_types: tuple[str, ...]
@@ -226,6 +258,11 @@ class SeqConfig:
     moe_tile: int = 256
     #: the grouped products: None = by backend (ops/moe.py)
     moe_impl: str | None = None
+    #: times the layer list is applied, with the same tensors (a looped
+    #: model's ``total_ut_steps``); more than 1 brings an exit after every pass
+    loop_steps: int = 1
+    #: weight of the exit distribution's entropy in a looped model's loss
+    exit_beta: float = 0.1
 
     def __post_init__(self):
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
@@ -233,6 +270,13 @@ class SeqConfig:
         if bad:
             raise ValueError(
                 f"unknown layer types {sorted(bad)}: the kinds are {list(KINDS)}")
+        if self.loop_steps < 1:
+            raise ValueError("loop_steps counts the passes: at least 1")
+        if self.loop_steps > 1 and set(self.layer_types) != {SANDWICH}:
+            raise ValueError(f"only a stack of {SANDWICH} layers is looped")
+        if SANDWICH in self.layer_types and self.heads % (
+                self.kv_heads or self.heads):
+            raise ValueError("heads do not divide over their groups")
         if set(self.layer_types) & set(MOE_KINDS):
             if not (self.experts and self.experts_held and self.expert_width
                     and 0 < self.experts_per_token <= self.experts):
@@ -270,8 +314,8 @@ class AdamW:
 
 
 #: tensors AdamW does not decay: norms, the decays' parameters, the
-#: convolutions with their bias, the state space's skip
-NO_DECAY = ("norm", "a_log", "dt_bias", "conv", "ssm_d")
+#: convolutions with their bias, the state space's skip, the exit gate's bias
+NO_DECAY = ("norm", "a_log", "dt_bias", "conv", "ssm_d", "exit_gate_bias")
 
 
 def decays(name: str) -> bool:
@@ -333,6 +377,17 @@ def param_shapes(cfg: SeqConfig) -> dict[str, tuple[int, ...]]:
                 p + "experts_down": (E, F, D),
             })
             continue
+        elif kind == SANDWICH:
+            kv = (cfg.kv_heads or cfg.heads) * cfg.head_dim
+            shapes.update({
+                p + "input_norm": (D,),
+                p + "q": (D, cfg.heads * cfg.head_dim), p + "k": (D, kv),
+                p + "v": (D, kv), p + "o": (cfg.heads * cfg.head_dim, D),
+                p + "attn_out_norm": (D,), p + "pre_ff_norm": (D,),
+                p + "gate": (D, cfg.mlp_cols), p + "up": (D, cfg.mlp_cols),
+                p + "down": (cfg.mlp_cols, D), p + "mlp_out_norm": (D,),
+            })
+            continue
         else:
             hd = cfg.heads * cfg.head_dim
             shapes.update({
@@ -347,6 +402,10 @@ def param_shapes(cfg: SeqConfig) -> dict[str, tuple[int, ...]]:
         })
     shapes["final_norm"] = (D,)
     shapes["head"] = (cfg.vocab_rows, D)
+    if cfg.loop_steps > 1:
+        # the one exit gate, a ``Linear(D, 1)`` with bias, read after every pass
+        shapes["exit_gate"] = (D,)
+        shapes["exit_gate_bias"] = ()
     return shapes
 
 
@@ -358,6 +417,8 @@ def _init_tensor(leaf: str, shape: tuple, conv_width: int, base, n):
     key = jax.random.fold_in(base, n)
     if leaf.endswith("norm") or leaf == "ssm_d":
         return jnp.ones(shape, jnp.float32)
+    if leaf == "exit_gate_bias":
+        return jnp.zeros(shape, jnp.float32)
     if "conv" in leaf:
         bound = 1.0 / math.sqrt(conv_width)
         return jax.random.uniform(key, shape, jnp.float32, -bound, bound)
@@ -389,7 +450,8 @@ def init_params(cfg: SeqConfig, seed: int) -> dict[str, jax.Array]:
     Gated DeltaNet release's initialisation; its ``uniform(0, 16)`` floored so
     that the logarithm is finite); the state space's ``ssm_a_log =
     log(uniform(1, 16))``, ``ssm_dt_bias`` as ``dt_bias`` and ``ssm_d = 1``
-    (the Mamba-2 release's).  One small program a tensor: the random bits of
+    (the Mamba-2 release's); a looped model's exit gate normal(0, 0.02) like
+    any matrix, its bias 0.  One small program a tensor: the random bits of
     one tensor are the only temporary."""
     base = jax.random.PRNGKey(seed)
     out = {}
@@ -860,9 +922,22 @@ def layer(cfg: SeqConfig, kind: str, p: dict, x, seg):
     kind takes the block's path: ``linear_attention`` / ``full_attention``
     post-norm with one mixer and the MLP, ``parallel_ssm_attention`` pre-norm
     with two mixers and the MLP, the ``*_moe`` kinds ``routed_layer`` (whose
-    second result is a tuple: probe, choices, pairs)."""
+    second result is a tuple: probe, choices, pairs), ``sandwich_attention``
+    a norm before and after each of its two sublayers (no probe: the exits'
+    record is the looped trunk's)."""
     if kind in MOE_KINDS:
         return routed_layer(cfg, kind, p, x, seg)
+    if kind == SANDWICH:
+        with jax.named_scope("seq.stream"):
+            h = rmsnorm(x, p["input_norm"], cfg.eps)
+        a = grouped_query_attention(cfg, p, h, seg)
+        with jax.named_scope("seq.stream"):
+            x = x + rmsnorm(a, p["attn_out_norm"], cfg.eps)
+            h = rmsnorm(x, p["pre_ff_norm"], cfg.eps)
+        y = mlp(cfg, p, h)
+        with jax.named_scope("seq.stream"):
+            return (x + rmsnorm(y, p["mlp_out_norm"], cfg.eps),
+                    jnp.zeros(x.shape[:2] + (0,)))
     if kind == PARALLEL:
         with jax.named_scope("seq.stream"):
             h = rmsnorm(x, p["input_norm"], cfg.eps)
@@ -896,7 +971,11 @@ def trunk(cfg: SeqConfig, params: dict, x, seg, remat: bool = False):
     residual stream between layers is kept.  Where layers are routed the
     second result is a dict: the first layer's probe under its name
     (``PROBE_NAME``), ``choices`` [B, routed layers, T, k] and ``expert_pairs``
-    [routed layers, held]."""
+    [routed layers, held].  A looped model (``loop_steps`` R > 1) gives the R
+    exit states [R, B, T, D] first, and the carried state's mean squares
+    second (``looped_trunk``)."""
+    if cfg.loop_steps > 1:
+        return looped_trunk(cfg, params, x, seg, remat)
     first, routed = None, []
     for i, kind in enumerate(cfg.layer_types):
         f = functools.partial(layer, cfg, kind)
@@ -918,9 +997,58 @@ def trunk(cfg: SeqConfig, params: dict, x, seg, remat: bool = False):
         return rmsnorm(x, params["final_norm"], cfg.eps), first
 
 
+def loop_pass(cfg: SeqConfig, params: dict, x, seg, remat: bool = False):
+    """One pass of a looped model: the layer list, then the one final norm ->
+    the pass's exit state, which is also what the next pass reads.  With
+    ``remat`` every layer application is recomputed in the backward pass."""
+    for i, kind in enumerate(cfg.layer_types):
+        f = functools.partial(layer, cfg, kind)
+        if remat:
+            f = jax.checkpoint(f)
+        x, _ = f(layer_params(params, i), x, seg)
+    with jax.named_scope("seq.stream"):
+        return rmsnorm(x, params["final_norm"], cfg.eps)
+
+
+def carried_mean_square(x):
+    """The mean square of the state a later pass reads [B, T]: what the
+    training record holds the carried state's precision by.  The final norm
+    leaves it ``ms / (ms + eps)`` times its weights' whatever the layers
+    rounded; a state rounded to bfloat16 on its way to the next pass has lost
+    that in the fourth digit."""
+    with jax.named_scope("seq.stream"):
+        return jax.lax.stop_gradient(jnp.mean(x * x, axis=-1))
+
+
+def _passes(cfg: SeqConfig, x, one_pass):
+    """x through ``loop_steps`` calls of ``one_pass`` -> (the exit states
+    [R, B, T, D], the mean square of the state as each LATER pass read it
+    [B, T, R - 1]).  Pass t's operations carry the component ``loop.pass<t>``
+    outside their scope."""
+    states, carried = [], []
+    for t in range(cfg.loop_steps):
+        if t:
+            carried.append(carried_mean_square(x))
+        with jax.named_scope(LOOP_PASS.format(t)):
+            x = one_pass(x)
+        states.append(x)
+    with jax.named_scope("seq.stream"):
+        return jnp.stack(states), jnp.stack(carried, axis=-1)
+
+
+def looped_trunk(cfg: SeqConfig, params: dict, x, seg, remat: bool = False):
+    """The layer list ``loop_steps`` times over the SAME tensors -> (the exit
+    states, the carried state's mean squares: ``_passes``).  Training takes
+    the passes one at a time (``loop_forward``); this is the whole forward,
+    for serving."""
+    return _passes(cfg, x, lambda x: loop_pass(cfg, params, x, seg, remat))
+
+
 def hidden_states(cfg: SeqConfig, params: dict, tokens, seg):
-    """Final normalised hidden states [B, T, D] of packed rows."""
-    return trunk(cfg, params, embed(cfg, params["embed"], tokens), seg)[0]
+    """Final normalised hidden states [B, T, D] of packed rows (a looped
+    model's LAST pass: no exit is taken early)."""
+    h = trunk(cfg, params, embed(cfg, params["embed"], tokens), seg)[0]
+    return h[-1] if cfg.loop_steps > 1 else h
 
 
 # ---------------------------------------------------------------------------
@@ -936,12 +1064,15 @@ def next_item_targets(tokens, seg):
     return nxt, weight
 
 
-def cross_entropy(cfg: SeqConfig, h, head, targets, weight, dhead):
+def cross_entropy(cfg: SeqConfig, h, head, targets, weight, dhead,
+                  per_token: bool = False):
     """Sum over tokens of ``weight * cross-entropy(h @ head^T, target)`` over
     the held vocabulary rows (the logits times ``mup.lm_head``) AND its
     gradients, a block of tokens at a time:
     the logits of a block exist once, their gradient is made beside them, and
-    the head's gradient is added into ``dhead`` -> (loss, dh, dhead)."""
+    the head's gradient is added into ``dhead`` -> (loss, dh, dhead); with
+    ``per_token`` also every token's own cross-entropy, unweighted (what a
+    weight that is a function of the parameters needs for ITS gradient)."""
     shape = h.shape
     T = math.prod(shape[:-1])
     blk = min(cfg.loss_block, T)
@@ -959,13 +1090,14 @@ def cross_entropy(cfg: SeqConfig, h, head, targets, weight, dhead):
         lse = jax.nn.logsumexp(logits, axis=-1)
         hit = jnp.arange(logits.shape[-1])[None, :] == tx[:, None]
         picked = jnp.sum(jnp.where(hit, logits, 0.0), axis=-1)
-        loss = loss + jnp.sum(wx * (lse - picked))
+        nll = lse - picked
+        loss = loss + jnp.sum(wx * nll)
         dlog = _scaled(
             (jnp.exp(logits - lse[:, None]) - hit) * wx[:, None], scale
         ).astype(MATMUL_DTYPE)
         dh = jnp.matmul(dlog, w16, preferred_element_type=jnp.float32)
         dw = dw + jnp.matmul(dlog.T, h16, preferred_element_type=jnp.float32)
-        return (loss, dw), dh
+        return (loss, dw), ((dh, nll) if per_token else dh)
 
     with jax.named_scope("seq.loss"):
         w16 = head.astype(MATMUL_DTYPE)
@@ -974,6 +1106,9 @@ def cross_entropy(cfg: SeqConfig, h, head, targets, weight, dhead):
             block, (jnp.float32(0.0), dhead),
             (h.reshape(n, blk, shape[-1]), local, weight.reshape(n, blk)),
         )
+        if per_token:
+            dh, nll = dh
+            return loss, dh.reshape(shape), dhead, nll.reshape(shape[:-1])
         return loss, dh.reshape(shape), dhead
 
 
@@ -984,7 +1119,9 @@ def row_grads(cfg: SeqConfig, params: dict, tokens, seg, gsum: dict):
     probe [B, T, H]).  The two vocabulary
     tables' gradients are added into ``gsum`` in place (a scatter of the
     embedded rows' gradient, the loss's own accumulation), never held beside
-    it."""
+    it.  A looped model goes through ``looped_row_grads``."""
+    if cfg.loop_steps > 1:
+        return looped_row_grads(cfg, params, tokens, seg, gsum)
     inner = {k: v for k, v in params.items() if k not in ("embed", "head")}
     x0 = embed(cfg, params["embed"], tokens)
     h, vjp, probe = jax.vjp(
@@ -1002,6 +1139,122 @@ def row_grads(cfg: SeqConfig, params: dict, tokens, seg, gsum: dict):
             _scaled(jnp.where(held[..., None], dx0, 0.0), cfg.mup.embedding))
     with jax.named_scope("seq.accumulate"):
         out = {k: gsum[k] + g for k, g in dinner.items()}
+    out["embed"], out["head"] = dembed, dhead
+    return loss, count, out, probe
+
+
+def exit_log_probs(states, gate, bias):
+    """The exit states [R, B, T, D] through the one gate -> ``log p`` [R, B, T],
+    ``p_t = lam_t prod_{j<t} (1 - lam_j)`` for t < R and the last pass taking
+    the rest, ``lam_t = sigmoid(x_t . gate + bias)``; in logarithms, so that
+    a gate that saturates leaves every ``log p`` finite."""
+    z = jnp.einsum("rbtd,d->rbt", states, gate, precision=HIGHEST) + bias
+    log_lam, log_stay = jax.nn.log_sigmoid(z), jax.nn.log_sigmoid(-z)
+    # log S_t, S_1 = 1: what has not left before pass t
+    reach = jnp.cumsum(log_stay, axis=0) - log_stay
+    return jnp.concatenate([(log_lam + reach)[:-1], reach[-1:]])
+
+
+def loop_forward(cfg: SeqConfig, inner: dict, x, seg):
+    """Embedded rows x [B, T, D] through the R passes, each differentiated on
+    its own -> (the exit states, the carried state's mean squares
+    (``_passes``), the passes' ``vjp``s).  Every layer application is
+    recomputed in its backward pass: ``loop_steps x layers`` residual streams
+    are kept."""
+    pass_vjps = []
+
+    def one_pass(x):
+        x, pass_vjp = jax.vjp(
+            lambda p, x: loop_pass(cfg, p, x, seg, remat=True), inner, x)
+        pass_vjps.append(pass_vjp)
+        return x
+
+    return (*_passes(cfg, x, one_pass), pass_vjps)
+
+
+def loop_backward(pass_vjps: list, dstates, gsum: dict):
+    """Back through the passes, last first -> (``gsum`` + the shared tensors'
+    gradients, the embedded rows' cotangent).  A pass's state takes its
+    exit's cotangent and the next pass's; a shared tensor's gradient is the
+    sum over its R uses, each added into the step's sum as its pass yields
+    it: both additions are written here, under their scopes."""
+    dx = None
+    for t in reversed(range(len(pass_vjps))):
+        with jax.named_scope("seq.stream"):
+            dstate = dstates[t] if dx is None else dstates[t] + dx
+        dinner, dx = pass_vjps[t](dstate)
+        with jax.named_scope("seq.accumulate"):
+            gsum = {k: gsum[k] + g for k, g in dinner.items()}
+    return gsum, dx
+
+
+def looped_row_grads(cfg: SeqConfig, params: dict, tokens, seg, gsum: dict):
+    """``row_grads`` of a looped model: an exit after every pass.  A
+    position's loss is ``sum_t p_t l_t - exit_beta H(p)``, ``l_t`` the
+    next-item cross-entropy of pass t's state through the one head and ``p``
+    the exit distribution its gates give (``exit_log_probs``); nothing is
+    detached.  The gates and ``p`` come first (R products [T, D] x [D]); the
+    blocked loss then runs ONCE over the R states end on end with the weight
+    of a token ``weight x p_t`` and hands back every ``l_t``; the gate's own
+    path, ``dL/dlog p_t = p_t (l_t + exit_beta (log p_t + 1))``, is closed
+    through ``p(lam)`` into the gate's two tensors and into the states'
+    cotangents, beside the loss's own.  The trunk is differentiated a PASS at
+    a time (``loop_forward`` / ``loop_backward``), so that the sum of a shared
+    tensor's gradient over its R uses, and of a state's two cotangents, are
+    the program's own additions under their scopes.
+    -> (the sum of the positions' losses, their count, ``gsum`` + the
+    gradient, {"exit_probe": p [B, T, R], "carry_probe" [B, T, R - 1]
+    (``looped_trunk``), at ``HEAD_PROBE_POSITIONS`` evenly spaced positions
+    "head_probe" [B, n, R] (each ``l_t``) and "head_probe_state" [B, n, R, D]
+    (the exit states those came from: the head's product can be made again
+    from them, which holds ITS precision whatever the trunk rounded), and
+    summed over the positions "exit_loss" [R] (each ``l_t``), "exit_mass" [R]
+    (each ``p_t``), "exit_entropy"})."""
+    R, beta = cfg.loop_steps, cfg.exit_beta
+    outer = ("embed", "head", "exit_gate", "exit_gate_bias")
+    inner = {k: v for k, v in params.items() if k not in outer}
+    x0 = embed(cfg, params["embed"], tokens)
+    states, carried, pass_vjps = loop_forward(cfg, inner, x0, seg)
+    with jax.named_scope("seq.loss"):
+        targets, weight = next_item_targets(tokens, seg)
+        count = jnp.sum(weight)
+    with jax.named_scope("seq.exit"):
+        logp, gate_vjp = jax.vjp(
+            exit_log_probs, states, params["exit_gate"], params["exit_gate_bias"])
+        p = jnp.exp(logp)
+        weighted = weight * p
+        exits = jnp.broadcast_to(targets, (R,) + targets.shape)
+    loss, dstates, dhead, nll = cross_entropy(
+        cfg, states, params["head"], exits, weighted, gsum["head"], per_token=True)
+    with jax.named_scope("seq.loss"):
+        T = tokens.shape[-1]
+        n = min(HEAD_PROBE_POSITIONS, T)
+        at = jnp.arange(n) * (T // n)
+        head_probe = {
+            "head_probe": jnp.moveaxis(nll[:, :, at], 0, -1),
+            "head_probe_state": jnp.moveaxis(states[:, :, at], 0, 2),
+        }
+    with jax.named_scope("seq.exit"):
+        entropy = -jnp.sum(weighted * logp)
+        dgate_states, dgate, dbias = gate_vjp(weighted * (nll + beta * (logp + 1.0)))
+        loss = loss - beta * entropy
+        dstates = dstates + dgate_states
+        probe = {
+            PROBE_NAME[SANDWICH]: jax.lax.stop_gradient(jnp.moveaxis(p, 0, -1)),
+            "carry_probe": carried, **head_probe,
+            "exit_loss": jnp.sum(weight * nll, axis=(1, 2)),
+            "exit_mass": jnp.sum(weighted, axis=(1, 2)),
+            "exit_entropy": entropy,
+        }
+    out, dx = loop_backward(pass_vjps, dstates, {k: gsum[k] for k in inner})
+    with jax.named_scope("seq.embed"):
+        idx = tokens - cfg.vocab_start
+        held = (idx >= 0) & (idx < cfg.vocab_rows)
+        dembed = gsum["embed"].at[jnp.where(held, idx, 0)].add(
+            _scaled(jnp.where(held[..., None], dx, 0.0), cfg.mup.embedding))
+    with jax.named_scope("seq.accumulate"):
+        out["exit_gate"] = gsum["exit_gate"] + dgate
+        out["exit_gate_bias"] = gsum["exit_gate_bias"] + dbias
     out["embed"], out["head"] = dembed, dhead
     return loss, count, out, probe
 
@@ -1025,6 +1278,15 @@ def init_state(cfg: SeqConfig, seed: int) -> tuple[dict, dict]:
         # the routing counters of the step, summed beside the gradients
         acc["expert_pairs"] = jnp.zeros((routed, cfg.experts_held), jnp.int32)
         acc["pairs_total"] = jnp.zeros((), jnp.int32)
+    if cfg.loop_steps > 1:
+        # the exits' sums over the step's positions
+        acc["exit_loss"] = jnp.zeros((cfg.loop_steps,), jnp.float32)
+        acc["exit_mass"] = jnp.zeros((cfg.loop_steps,), jnp.float32)
+        acc["exit_entropy"] = jnp.float32(0.0)
+        # counters: layer applications (passes x layers a row), the real
+        # tokens that went through them, and their (query, key) pairs
+        for key in ("layer_applications", "loop_tokens", "attention_pairs"):
+            acc[key] = jnp.zeros((), jnp.int32)
     return state, acc
 
 
@@ -1044,6 +1306,17 @@ def accumulate_row(cfg: SeqConfig, state: dict, acc: dict, tokens, seg):
             out["expert_pairs"] = acc["expert_pairs"] + probe.pop("expert_pairs")
             out["pairs_total"] = acc["pairs_total"] + (
                 cfg.experts_per_token * jnp.sum(seg != PAD_SEGMENT, dtype=jnp.int32))
+        if "exit_loss" in acc:
+            for key in ("exit_loss", "exit_mass", "exit_entropy"):
+                out[key] = acc[key] + probe.pop(key)
+            real = seg != PAD_SEGMENT
+            out["layer_applications"] = acc["layer_applications"] + (
+                cfg.loop_steps * len(cfg.layer_types))
+            out["loop_tokens"] = acc["loop_tokens"] + jnp.sum(real, dtype=jnp.int32)
+            # a token sees the keys of its segment up to itself
+            out["attention_pairs"] = acc["attention_pairs"] + jnp.sum(
+                jnp.where(real, segment_positions(seg[None])[0] + 1, 0),
+                dtype=jnp.int32)
         return state, out, jax.tree.map(lambda a: a[0], probe)
 
 
@@ -1056,7 +1329,7 @@ def grad_probe(n: int, g):
     key = jax.random.fold_in(jax.random.PRNGKey(PROBE_SEED), n)
     if g.ndim > 2:  # stacked experts: one matrix, the experts' rows on end
         g = g.reshape(-1, g.shape[-1])
-    if g.ndim == 1:
+    if g.ndim <= 1:  # a vector, or a bias of one element
         return jnp.sum(g * jax.random.normal(key, g.shape, jnp.float32))
     kr, kc = jax.random.split(key)
     rows = jax.random.normal(kr, (g.shape[0],), jnp.float32)
@@ -1090,6 +1363,17 @@ def apply_step(opt: AdamW, state: dict, acc: dict):
         record["moe_pairs_held"] = jnp.sum(pairs, axis=-1)
         record["moe_pairs_total"] = jnp.full(
             pairs.shape[:1], acc["pairs_total"], jnp.int32)
+    if "exit_loss" in acc:
+        # an exit's mean cross-entropy and mean mass over the step's
+        # positions, the exit distribution's mean entropy, and the step's
+        # counters: layer applications (passes x layers a row), real tokens
+        # through them, and the (query, key) pairs of their attention
+        record["loss_by_exit"] = acc["exit_loss"] * scale
+        record["exit_mass"] = acc["exit_mass"] * scale
+        record["exit_entropy"] = acc["exit_entropy"] * scale
+        record["loop_layer_applications"] = acc["layer_applications"]
+        record["loop_tokens"] = acc["loop_tokens"]
+        record["loop_attention_pairs"] = acc["attention_pairs"]
     t = state["t"] + 1
     tf = t.astype(jnp.float32)
     c1 = 1.0 - opt.b1 ** tf

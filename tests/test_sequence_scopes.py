@@ -1,7 +1,7 @@
 """Every device operation of a sequence retrain under ONE name the program
 wrote (ISSUE 36): in the jaxprs of the three programs (the row program
 ``accumulate_row``, the step ``apply_step``, the initialisation
-``init_state``) of the three blocks, backward and recomputed equations
+``init_state``) of the four blocks, backward and recomputed equations
 included, every equation that makes an array of more than a handful of
 elements carries exactly one top-level scope of ``seqmodel.SCOPES`` in its
 name stack.  A trace's ``(no scope)`` then holds only what the compiler made
@@ -30,13 +30,20 @@ BLOCKS = {
     "olmo_hybrid": {"seq.gdn", "seq.attn", "seq.mlp"},
     "falcon_h1": {"seq.ssm", "seq.attn", "seq.mlp"},
     "smallthinker": {"seq.attn", "seq.moe"},
+    # the looped block: its exits' scope beside the mixer's and the MLP's
+    "ouro": {"seq.attn", "seq.mlp", "seq.exit"},
 }
+#: the component a looped model writes around pass t, OUTSIDE the top-level scope
+LOOP_PASS = re.compile(r"loop\.pass(\d+)")
 
 
 def _config(block: str):
     if block == "olmo_hybrid":
         import seq_reference as ref
         return ref.seq_config(ref.HALF)
+    if block == "ouro":
+        import ouro_reference as ref
+        return ref.seq_config(ref.TINY)
     if block == "falcon_h1":
         import h1_reference as ref
     else:
@@ -108,6 +115,31 @@ def test_every_operation_carries_one_top_level_scope(block, program):
         adds = [s for p, s, n in ops if p == "add_any" and n > HANDFUL]
         assert any("seq.stream" in s and "transpose" in s for s in adds)
         assert all(TOP_LEVEL.search(s) for s in adds)
+        # only a looped model writes the pass component, and the three
+        # programs this engine had hold the scopes they held
+        passes = {t for _, s, _ in ops for t in LOOP_PASS.findall(s)}
+        assert passes == ({"0", "1", "2", "3"} if block == "ouro" else set())
+
+
+def test_a_pass_component_lies_outside_the_scope_of_its_operations():
+    """``loop.pass<t>`` comes BEFORE the one top-level scope in a name stack
+    (a path then reads ``loop.pass2/seq.attn/attn.causal``), every pass holds
+    the trunk's scopes, forward, recomputed and backward, and the exits and
+    the loss lie under no pass."""
+    ops = [(s, n) for _, s, n in _operations(
+        _program(_config("ouro"), "accumulate_row").jaxpr) if n > HANDFUL]
+    for t in range(4):
+        mine = [s for s, _ in ops if f"loop.pass{t}" in s]
+        assert {top for s in mine for top in TOP_LEVEL.findall(s)} == {
+            "seq.attn", "seq.mlp", "seq.stream"}
+        assert all(s.index(f"loop.pass{t}") < TOP_LEVEL.search(s).start() for s in mine)
+        assert any("rematted_computation" in s for s in mine)
+        assert any("transpose" in s for s in mine)
+    outside = [s for s, _ in ops if not LOOP_PASS.search(s)]
+    assert {"seq.exit", "seq.loss", "seq.embed", "seq.accumulate"} <= {
+        top for s in outside for top in TOP_LEVEL.findall(s)}
+    assert not [s for s, _ in ops if LOOP_PASS.search(s) and (
+        "seq.exit" in s or "seq.loss" in s)]
 
 
 @pytest.mark.parametrize("call", [
