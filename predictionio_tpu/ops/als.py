@@ -419,29 +419,25 @@ def _is_oom_error(e: Exception) -> bool:
     )
 
 
-def _first_rung(nnz: int, rank: int) -> str:
+def _first_rung(nnz: int) -> str:
     """The ladder's first rung, from an estimate of the fused path's HBM.
 
-    The fused single-grid kernel streams the transposed gather output
-    ([nt, k, T] f32) per half-step; any rank runs fused (wide ranks add
-    width slabs, not VMEM), so the only reason to start on the chunk-scan
-    is the gather transient crowding HBM."""
+    The fused single-grid kernel reads the gather's own rows
+    ([nt, T, PARTS_W] bfloat16: 256 bytes a row at every rank it runs) per
+    half-step; any rank runs fused (wide ranks add width slabs, not VMEM),
+    so the only reason to start on the chunk-scan is the gather transient
+    crowding HBM."""
     from predictionio_tpu.ops import als_pallas
 
     est_rows = int(nnz * 1.06) + als_pallas.T  # ~pad factor
-    # Fused-path HBM budget: the transposed gather output cv_t
-    # [k, nt, T] (k padded to the next sublane multiple of 8) is the
-    # big per-half-step transient, the staged wrv [3->8, nt, T] stacks
-    # live for the whole train, and XLA may keep ~2 transients alive
-    # across the double-buffered halves.  (The transposed orientation
-    # keeps minor dims at 1024, so T(8,128) layout padding cannot
-    # exceed the sublane round-up.)
-    k_pad = (rank + 7) // 8 * 8
-    fused_bytes = est_rows * 4 * (2 * k_pad + 2 * 8)
-    # budget ~half of a v5e's 16G HBM for the staged streams + the
-    # per-half-step gather transient (leaves room for XLA
-    # double-buffering and the accumulator); the OOM ladder catches
-    # an underestimate by falling back to chunked
+    # Fused-path HBM budget: the gathered rows are the big per-half-step
+    # transient (one side's at a time: XLA's plan for the ML-20M program,
+    # tests/test_tpu_aot.py), and both sides' wrv [nt, 3->4, T] stacks
+    # live for the whole train.
+    fused_bytes = est_rows * (2 * als_pallas.PARTS_W + 2 * 4 * 4)
+    # budget ~half of a v5e's 16G HBM for these (leaves room for the
+    # staged streams and the accumulator); the OOM ladder catches an
+    # underestimate by falling back to chunked
     return "fused" if fused_bytes <= 8 << 30 else "chunked"
 
 
@@ -454,7 +450,7 @@ def _train_pallas(user_idx, item_idx, rating, num_users, num_items,
     — the chunk scan drops the whole-stream packed transients; per-
     iteration dispatch drops the fori_loop's loop-carried remat copies):
     one OOM must cost a retry, not the train."""
-    mode = _first_rung(len(user_idx), p.rank)
+    mode = _first_rung(len(user_idx))
     ladder = [(mode, False)]
     if mode == "fused":
         ladder.append(("chunked", False))

@@ -11,14 +11,18 @@ TPU runs with a one-hot MXU formulation that contains NO scatter at all:
      run the pallas kernel: for each tile, a [T, S] one-hot of the local
      segment ids is contracted with the update tile on the MXU,
      accumulating into the tile's (VMEM-resident, revisited) output block.
+     The fused kernel (``segment_stats_fused``, the first rung) builds the
+     update rows in VMEM from the gathered rows and never writes them; the
+     chunked one (``segment_stats_pallas``) reads them from HBM a chunk at
+     a time.
 
 Cost is nnz * S * 128 * 2 FLOPs — ~0.65 TFLOP per ML-20M half-step —
 independent of index distribution, versus a TPU scatter that processes one
-row at a time and degrades further under skew.  Measured against the
-chunked-scatter path in identical chip state at ML-20M scale: ~3x faster
-(20-iteration train 34s vs 104s) at equal f32-class accuracy (one-hot
-entries are exact; Precision.HIGHEST keeps the update operand at f32
-fidelity through the bf16 MXU passes).
+row at a time and degrades further under skew.  One-hot entries are exact in
+bfloat16; the precision choices (``_make_kernel``) concern the update rows
+only.  What a half-step costs on the chip, by operation, is in PERF.md
+(sections 5 and 6) and the ledger's ``als_accumulate_device_s``: numbers live
+there, not here.
 """
 
 from __future__ import annotations
@@ -250,28 +254,93 @@ def make_segment_accum(
 #: not VMEM or compile size)
 SLAB_W = 128
 
+#: lanes of a gathered row of the fused path: the three bfloat16 parts of
+#: ``k`` float32 factors side by side, zeros up to one full lane tile
+#: (3 * 32 = 96 <= 128 wherever ``ops.als._use_pallas`` lets the kernel run)
+PARTS_W = 128
+
+
+def split3(x):
+    """The three bfloat16 parts of float32 ``x``, as float32 arrays:
+    ``hi = bf16(x)``, ``mid = bf16(x - hi)``, ``lo = x - hi - mid``.
+
+    A float32's 24 mantissa bits fit three bfloat16's 3 x 8, both
+    subtractions are exact, and the last remainder has at most 7 significant
+    bits, so ``hi + mid + lo == x`` bit for bit, summed in float32 from
+    either end, wherever no part is a subnormal number and ``hi`` is finite
+    (``2**-103 <= |x| <= 3.3895e38``; ``tests/test_als_pallas.py`` says what
+    happens outside).  Rounded with ``reduce_precision``: the chip's
+    compiler removes a cast to bfloat16 and back."""
+    def bf16(v):
+        return jax.lax.reduce_precision(v, exponent_bits=8, mantissa_bits=7)
+
+    hi = bf16(x)
+    rest = x - hi
+    mid = bf16(rest)
+    return hi, mid, rest - mid
+
+
+def split_table(factors):
+    """``[N, k]`` float32 factors -> ``[N, PARTS_W]`` bfloat16 rows
+    ``hi | mid | lo | 0``: what the fused path gathers, 256 bytes a row.
+    The split is made here, on the table's N rows, and not on the P rows
+    the gather writes."""
+    n, k = factors.shape
+    if 3 * k > PARTS_W:
+        raise ValueError(f"rank {k}: three parts do not fit {PARTS_W} lanes")
+    parts = jnp.concatenate(
+        [*split3(factors), jnp.zeros((n, PARTS_W - 3 * k), factors.dtype)],
+        axis=1,
+    )
+    return parts.astype(jnp.bfloat16)  # exact: every part is a bfloat16
+
+
+def selectors(k: int) -> np.ndarray:
+    """The fused kernel's 0/1 selection matrices, one ``[2 * SLAB_W,
+    PARTS_W]`` block a width slab: rows ``[:SLAB_W]`` pick component
+    ``a = r // k`` of update row ``r`` (``r - k*k`` in the rhs block),
+    rows ``[SLAB_W:]`` pick ``b = r % k``, each from all three parts of a
+    ``split_table`` row at once."""
+    kk = k * k
+    r = np.arange(row_width(k))[:, None]
+    lane = np.arange(PARTS_W)[None, :]
+    c = np.where(lane < 3 * k, lane % k, -1)
+    pa = (np.where(r < kk, r // k, r - kk) == c) & (r < kk + k)
+    pb = ((r % k) == c) & (r < kk)
+    sel = np.stack(
+        [pa.reshape(-1, SLAB_W, PARTS_W), pb.reshape(-1, SLAB_W, PARTS_W)],
+        axis=1,
+    )
+    return sel.reshape(-1, PARTS_W).astype(np.float32)
+
 
 def _make_fused_kernel(k: int, precision: str):
     """Whole-stream fused kernel in TRANSPOSED orientation.
 
-    Every HBM-resident per-row array is layout-clean (minor dim T=1024 or
-    128): the opposite factors arrive pre-gathered as ``cv_t [nt, k, T]``
-    and the static weights as ``wrv [nt, 3, T]`` — there is NO tall-narrow
-    ``[P, <128]`` array anywhere, which is what turned the round-4 fused
-    path into 57G of T(8,128)-padded HLO temps (BENCH_r04).
+    The opposite side's rows arrive as the gather wrote them,
+    ``rows [T, PARTS_W]`` bfloat16 (``split_table``: the three bfloat16
+    parts of each factor), and the static weights as ``wrv [nt, 3, T]``.
+    The gather's result is the one tall array of the path (P rows of 256
+    bytes, 5.3 GB at ML-20M); nothing copies it into another layout.
 
     The flat update rows are built IN VMEM as their transpose
     ``updT [SLAB_W, T]`` (one 128-row slab of the full row_width per grid
-    step) without any sublane concatenation: two static one-hot selection
-    matrices (pa picks component a = r//k, pb picks b = r%k, both
-    materialized from iota compares at the slab's global row offset) turn
-    the outer-product block, the rhs block, and the count row into
+    step) without any sublane concatenation.  With ``v`` the rows' float32
+    factors, ``A[r, t] = v[t, r // k]`` and ``B[r, t] = v[t, r % k]`` are
+    selections, and both come from ONE bfloat16 MXU pass that contracts the
+    rows' minor dimension (``sel . rows^T``, the ``q @ k^T`` form): the
+    selector (``selectors``) is 0/1, the parts are exact in bfloat16, the
+    pass accumulates in float32, and ``hi + mid + lo`` is the factor bit for
+    bit (``split3``).  Then
 
-        updT = (pa@cv) * ((pb@cv) * w + sel_rhs * rhs) + sel_val * val
+        updT = A * (B * w + sel_rhs * rhs) + sel_val * val
 
-    — rows r < k*k get cv_a*cv_b*w, rows k*k..k*k+k get cv_c*rhs (pb@cv
-    is zero there), row k*k+k gets val, the rest 0.  The selection matmuls
-    run at Precision.HIGHEST (exact for f32, ~2.6 MFLOP — noise).
+    — rows r < k*k get v_a*v_b*w, rows k*k..k*k+k get v_c*rhs (B is zero
+    there), row k*k+k gets val, the rest 0.  (Selecting from float32 rows
+    with products at Precision.HIGHEST is as exact and costs six MXU passes
+    a product, each over a contraction of k that fills the 128-deep array
+    as 128 would: two thirds of such a kernel's time at ML-20M, PERF.md
+    section 6, PR 39.)
 
     The grid is (n_slabs, n_tiles) with the SLAB AXIS OUTER: within one
     slab the stream sweeps tiles in block-sorted order, so each output
@@ -286,7 +355,8 @@ def _make_fused_kernel(k: int, precision: str):
     """
     kk = k * k
 
-    def kernel(block_map_ref, first_ref, seg_ref, cv_ref, wrv_ref, out_ref):
+    def kernel(block_map_ref, first_ref, seg_ref, rows_ref, wrv_ref, sel_ref,
+               out_ref):
         s = pl.program_id(0)
         i = pl.program_id(1)
         seg = seg_ref[0]  # [T//128, 128] int32
@@ -294,26 +364,15 @@ def _make_fused_kernel(k: int, precision: str):
             seg[:, :, None]
             == jax.lax.broadcasted_iota(jnp.int32, (T // 128, 128, S), 2)
         ).astype(jnp.float32).reshape(T, S)
-        cv = cv_ref[0]    # [k, T]
         wrv = wrv_ref[0]  # [3, T]
         w, rhs, val = wrv[0:1, :], wrv[1:2, :], wrv[2:3, :]
-        r = jax.lax.broadcasted_iota(jnp.int32, (SLAB_W, k), 0) + s * SLAB_W
-        c = jax.lax.broadcasted_iota(jnp.int32, (SLAB_W, k), 1)
-        # select between int32 index maps, not between booleans: Mosaic
-        # cannot truncate an i8 select result to i1
-        a_idx = jnp.where(r < kk, r // k, r - kk)
-        pa = ((a_idx == c) & (r < kk + k))
-        pb = ((r % k) == c) & (r < kk)
-        dn_sel = (((1,), (0,)), ((), ()))
-        hp = jax.lax.Precision.HIGHEST
-        A = jax.lax.dot_general(
-            pa.astype(jnp.float32), cv, dimension_numbers=dn_sel,
-            precision=hp, preferred_element_type=jnp.float32,
+        # [2*SLAB_W, PARTS_W] . [T, PARTS_W]^T: A over B, one bf16 pass
+        ab = jax.lax.dot_general(
+            sel_ref[:], rows_ref[0],
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
         )
-        B = jax.lax.dot_general(
-            pb.astype(jnp.float32), cv, dimension_numbers=dn_sel,
-            precision=hp, preferred_element_type=jnp.float32,
-        )
+        A, B = ab[:SLAB_W], ab[SLAB_W:]
         r1 = (
             jax.lax.broadcasted_iota(jnp.int32, (SLAB_W, 1), 0) + s * SLAB_W
         )
@@ -361,15 +420,16 @@ def make_fused_accum(
     interpret: bool = False,
 ):
     """pallas_call over the WHOLE stream: (block_map[nt], first[nt],
-    seg3[nt, T//128, 128], cv_t[nt, k, T], wrv[nt, 3, T]) -> TRANSPOSED
-    accumulator [n_blocks * width, S] (SLAB_W-row blocks, width-slab
-    grid axis outer so blocks revisit consecutively within a slab).
+    seg3[nt, T//128, 128], rows[nt, T, PARTS_W] bf16, wrv[nt, 3, T],
+    sel[n_slabs * 2 * SLAB_W, PARTS_W] bf16) -> TRANSPOSED accumulator
+    [n_blocks * width, S] (SLAB_W-row blocks, width-slab grid axis outer so
+    blocks revisit consecutively within a slab).
 
-    The per-tile operands are [nt, small, T]: Mosaic wants the last two
+    ``rows`` is the gather's own result, a tile of it one contiguous
+    256 KB block; ``wrv`` is [nt, small, T] (Mosaic wants the last two
     block dims divisible by (8, 128) or equal to the array dims, so the
-    tile axis leads and the small axis (k or 3) spans its whole dimension;
-    HBM sublane padding rounds k up to 8s (1.6x at rank 10 — bounded,
-    unlike the minor-dim 128 round-up a [P, k] layout suffers)."""
+    tile axis leads and the small axis spans its whole dimension); a
+    slab's selectors are fetched when the slab changes."""
     if precision not in ("highest", "hilo", "bf16"):
         raise ValueError(f"unknown precision {precision!r}")
     width = row_width(rank)
@@ -379,8 +439,9 @@ def make_fused_accum(
         grid=(n_slabs, n_tiles),
         in_specs=[
             pl.BlockSpec((1, T // 128, 128), lambda s, i, bm, fr: (i, 0, 0)),
-            pl.BlockSpec((1, rank, T), lambda s, i, bm, fr: (i, 0, 0)),
+            pl.BlockSpec((1, T, PARTS_W), lambda s, i, bm, fr: (i, 0, 0)),
             pl.BlockSpec((1, 3, T), lambda s, i, bm, fr: (i, 0, 0)),
+            pl.BlockSpec((2 * SLAB_W, PARTS_W), lambda s, i, bm, fr: (s, 0)),
         ],
         out_specs=pl.BlockSpec(
             (SLAB_W, S), lambda s, i, bm, fr: (bm[i] * n_slabs + s, 0)
@@ -421,18 +482,20 @@ def segment_stats_fused(
     """Single-grid fused accumulation over the whole stream.  Same output
     contract as segment_stats_pallas ([n_blocks*S, row_width] with columns
     [vec(A) | b | count]); internally everything runs transposed (see
-    _make_fused_kernel) and the per-half-step device work is ONE gather
-    (columns of the transposed factor table, laid out [nt, k, T]) plus
-    the kernel."""
+    _make_fused_kernel) and the per-half-step device work is the table's
+    split, ONE gather of its rows ([nt, T, PARTS_W], read by the kernel
+    where the gather wrote it) and the kernel."""
     block_map, first, seg3 = plan_args
     k = other_factors.shape[1]
     width = row_width(k)
-    # [k, nt, T] gather -> [nt, k, T] tile-major for the BlockSpec
-    cv_t = jnp.take(other_factors.T, other_idx2d, axis=1).transpose(1, 0, 2)
+    rows = jnp.take(
+        split_table(other_factors), other_idx2d, axis=0, mode="clip"
+    )
     accum = make_fused_accum(
         n_tiles, n_blocks, k, precision=precision, interpret=interpret
     )
-    acc_t = accum(block_map, first, seg3, cv_t, wrv)
+    sel = jnp.asarray(selectors(k), jnp.bfloat16)
+    acc_t = accum(block_map, first, seg3, rows, wrv, sel)
     return (
         acc_t.reshape(n_blocks, width, S)
         .transpose(0, 2, 1)

@@ -137,7 +137,7 @@ class TestOOMFallbackLadder:
             return "sentinel-state"
 
         monkeypatch.setattr(als_mod, "_train_pallas_mode", fake_mode)
-        monkeypatch.setattr(als_mod, "_first_rung", lambda nnz, rank: "fused")
+        monkeypatch.setattr(als_mod, "_first_rung", lambda nnz: "fused")
         p = als_mod.ALSParams(rank=4)
         with pytest.warns(RuntimeWarning):
             out = als_mod._train_pallas(
@@ -157,7 +157,7 @@ class TestOOMFallbackLadder:
 
         monkeypatch.setattr(als_mod, "_train_pallas_mode", fake_mode)
         monkeypatch.setattr(
-            als_mod, "_first_rung", lambda nnz, rank: "chunked"
+            als_mod, "_first_rung", lambda nnz: "chunked"
         )
         p = als_mod.ALSParams(rank=4)
         with pytest.raises(ValueError, match="genuine bug"):
@@ -166,16 +166,16 @@ class TestOOMFallbackLadder:
                 np.ones(4, np.float32), 4, 4, p, np.float32,
             )
 
-    @pytest.mark.parametrize("nnz,rank,rung", [
-        # ML-20M: 3.8 GiB and 6.3 GiB of the estimate's 8 GiB budget
-        (20_000_263, 10, "fused"),
-        (20_000_263, 32, "fused"),
-        (100_000_000, 10, "chunked"),  # 19 GiB
+    @pytest.mark.parametrize("nnz,rung", [
+        # the estimate's budget is 8 GiB, at every rank the kernel runs
+        (20_000_263, "fused"),     # ML-20M: 5.7 GiB
+        (28_000_000, "fused"),     # 7.96 GiB
+        (100_000_000, "chunked"),  # 28 GiB
     ])
-    def test_first_rung_follows_the_estimate(self, nnz, rank, rung):
+    def test_first_rung_follows_the_estimate(self, nnz, rung):
         from predictionio_tpu.ops.als import _first_rung
 
-        assert _first_rung(nnz, rank) == rung
+        assert _first_rung(nnz) == rung
 
     @pytest.mark.parametrize("rank", [4, 10, 20])  # 20 > _SOA_MAX_RANK
     @pytest.mark.parametrize("implicit", [False, True],

@@ -6,7 +6,9 @@ the scatter-free path is covered on every platform.
 """
 
 import numpy as np
+import jax
 import jax.numpy as jnp
+import ml_dtypes
 import pytest
 
 from predictionio_tpu.ops import als_pallas as ap
@@ -299,6 +301,18 @@ def test_segment_stats_matches_scatter_semantics():
         np.testing.assert_allclose(acc[:, k * k + k], c_ref, rtol=1e-5)
 
 
+def _staged(plan, oth, rat):
+    """The streams as ``ops/als`` stages them: permuted into the plan's
+    slots, zero in the padding."""
+    oth_p = oth[plan.dest_perm].copy()
+    rat_p = rat[plan.dest_perm].copy()
+    val_p = np.ones(plan.padded_len, np.float32)
+    oth_p[plan.pad_mask] = 0
+    rat_p[plan.pad_mask] = 0
+    val_p[plan.pad_mask] = 0
+    return oth_p, rat_p, val_p
+
+
 def test_segment_stats_fused_matches_scatter_semantics():
     """The single-grid fused kernel (packed rows built in VMEM) must give
     the same A/b/counts as the chunked path and the scatter reference."""
@@ -309,14 +323,7 @@ def test_segment_stats_fused_matches_scatter_semantics():
     rat = rng.uniform(-2, 2, n).astype(np.float32)
     factors = rng.standard_normal((noth, k)).astype(np.float32)
     plan = ap.build_plan(seg.astype(np.int64), nseg)
-    rows = plan.padded_len
-    oth_p = oth[plan.dest_perm].copy()
-    rat_p = rat[plan.dest_perm].copy()
-    val_p = np.ones(rows, np.float32)
-    oth_p[plan.pad_mask] = 0
-    rat_p[plan.pad_mask] = 0
-    val_p[plan.pad_mask] = 0
-
+    oth_p, rat_p, val_p = _staged(plan, oth, rat)
     nt = plan.n_tiles
     for implicit in (False, True):
         wrv = ap.make_wrv(
@@ -367,12 +374,7 @@ def test_fused_wide_rank_slabs():
     factors = rng.standard_normal((noth, k)).astype(np.float32)
     plan = ap.build_plan(seg.astype(np.int64), nseg)
     nt = plan.n_tiles
-    oth_p = oth[plan.dest_perm].copy()
-    rat_p = rat[plan.dest_perm].copy()
-    val_p = np.ones(plan.padded_len, np.float32)
-    oth_p[plan.pad_mask] = 0
-    rat_p[plan.pad_mask] = 0
-    val_p[plan.pad_mask] = 0
+    oth_p, rat_p, val_p = _staged(plan, oth, rat)
     wrv = ap.make_wrv(
         jnp.asarray(rat_p.reshape(nt, ap.T)),
         jnp.asarray(val_p.reshape(nt, ap.T)), False, 1.0,
@@ -398,3 +400,197 @@ def test_fused_wide_rank_slabs():
         acc[:, k * k : k * k + k], b_ref, rtol=1e-4, atol=2e-3
     )
     np.testing.assert_allclose(acc[:, k * k + k], c_ref, rtol=1e-5)
+
+
+def _mixed_magnitude_factors(rng, n_other, k):
+    """Factors of order one with, in a few rows, one column of magnitude
+    1e-30 or 1e30, negatives everywhere and exact zeros: what a selection
+    that was a product in disguise would round, flush or turn to NaN."""
+    factors = rng.standard_normal((n_other, k)).astype(np.float32)
+    factors[rng.random((n_other, k)) < 0.1] = 0.0
+    for row, scale in ((1, 1e-30), (2, -1e-30), (3, 1e30), (4, -1e30)):
+        factors[row, rng.integers(k)] = np.float32(scale) * np.float32(
+            1 + rng.random()
+        )
+    return factors
+
+
+def _accumulator_from_written_out_updates(plan, oth_p, wrv, factors, precision):
+    """The fused accumulator with ``updT`` written out in numpy: ``A`` and
+    ``B`` are the gathered factors' columns BY INDEXING (no product picks
+    them), the update rows are the kernel's float32 expression, and a tile's
+    rows meet its block through the kernel's one-hot contraction at
+    ``precision``, one ``[SLAB_W, T] @ [T, S]`` a slab and tile, summed in
+    the kernel's order (a block's tiles in turn)."""
+    k = factors.shape[1]
+    kk, width = k * k, ap.row_width(k)
+    v = factors[oth_p]                                   # [P, k]
+    r = np.arange(width)
+    A = np.where(r < kk + k, v[:, np.where(r < kk, r // k, (r - kk) % k)], 0)
+    B = np.where(r < kk, v[:, r % k], 0)
+    w, rhs, val = (wrv[:, j, :].reshape(-1, 1) for j in range(3))
+    sel_rhs = ((r >= kk) & (r < kk + k)).astype(np.float32)
+    sel_val = (r == kk + k).astype(np.float32)
+    with np.errstate(over="ignore", invalid="ignore"):
+        upd = (A * (B * w + sel_rhs * rhs) + sel_val * val).astype(np.float32)
+
+    @jax.jit
+    def contract(updT, onehot):
+        dn = (((1,), (0,)), ((), ()))
+        if precision == "highest":
+            return jax.lax.dot_general(
+                updT, onehot, dimension_numbers=dn,
+                preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST,
+            )
+        oh16 = onehot.astype(jnp.bfloat16)
+        hi = updT.astype(jnp.bfloat16)
+        out = jax.lax.dot_general(
+            hi, oh16, dimension_numbers=dn, preferred_element_type=jnp.float32
+        )
+        if precision == "hilo":
+            lo = (updT - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+            out = out + jax.lax.dot_general(
+                lo, oh16, dimension_numbers=dn,
+                preferred_element_type=jnp.float32,
+            )
+        return out
+
+    acc = np.zeros((plan.n_blocks * ap.S, width), np.float32)
+    seg = plan.seg3.reshape(plan.n_tiles, ap.T)
+    for i in range(plan.n_tiles):
+        onehot = (seg[i][:, None] == np.arange(ap.S)).astype(np.float32)
+        updT = upd[i * ap.T:(i + 1) * ap.T].T
+        block = acc[plan.block_map[i] * ap.S:(plan.block_map[i] + 1) * ap.S]
+        for s in range(width // ap.SLAB_W):
+            cols = slice(s * ap.SLAB_W, (s + 1) * ap.SLAB_W)
+            contrib = np.asarray(contract(updT[cols], onehot)).T
+            if plan.first[i]:
+                block[:, cols] = contrib
+            else:
+                block[:, cols] = block[:, cols] + contrib
+    return acc
+
+
+@pytest.mark.parametrize("implicit", [False, True], ids=["explicit", "implicit"])
+@pytest.mark.parametrize("precision", ["highest", "hilo", "bf16"])
+@pytest.mark.parametrize("rank", [4, 10, 17, 32])
+def test_fused_selection_is_exact(rank, precision, implicit):
+    """``segment_stats_fused`` IS the accumulator of update rows whose
+    factors were picked by indexing, bit for bit: the one bfloat16 MXU pass
+    over the table's three parts selects and never rounds, at every rank the
+    kernel runs (one slab, three, nine), whatever the main contraction's
+    precision, with factors of 1e-30 and 1e30 beside ones, negatives and
+    zeros.  (1e30 squared is inf and an inf times the one-hot's zeros is
+    NaN: the accumulator's NaNs have to be the reference's NaNs, and few.)"""
+    rng = np.random.default_rng(100 * rank + len(precision))
+    n, nseg, n_other = 2500, 256, 48
+    seg = rng.integers(0, 250, n)
+    oth = rng.integers(0, n_other, n).astype(np.int32)
+    # segments 250 and 251 see the 1e-30 rows alone: beside factors of order
+    # one a sum would swallow them
+    seg[:6], oth[:6] = [250, 250, 250, 251, 251, 251], [1, 1, 1, 2, 2, 2]
+    rat = rng.uniform(-2, 2, n).astype(np.float32)
+    factors = _mixed_magnitude_factors(rng, n_other, rank)
+    plan = ap.build_plan(seg.astype(np.int64), nseg)
+    nt = plan.n_tiles
+    oth_p, rat_p, val_p = _staged(plan, oth, rat)
+    wrv = ap.make_wrv(
+        jnp.asarray(rat_p.reshape(nt, ap.T)),
+        jnp.asarray(val_p.reshape(nt, ap.T)), implicit, 1.5,
+    )
+    acc = np.asarray(ap.segment_stats_fused(
+        (jnp.asarray(plan.block_map), jnp.asarray(plan.first),
+         jnp.asarray(plan.seg3)),
+        jnp.asarray(oth_p.reshape(nt, ap.T)), wrv, jnp.asarray(factors),
+        nt, plan.n_blocks, precision=precision, interpret=True,
+    ))
+    want = _accumulator_from_written_out_updates(
+        plan, oth_p, np.asarray(wrv), factors, precision
+    )
+    assert np.array_equal(acc, want, equal_nan=True)
+    assert np.isnan(want).mean() < 0.02
+    # the extremes arrived: a sum of order 1e30 and one of order 1e-30
+    finite = np.abs(want[np.isfinite(want)])
+    assert finite.max() > 1e29
+    assert ((finite > 0) & (finite < 1e-28)).any()
+
+
+def test_three_bfloat16_parts_are_the_float32():
+    """``split3``: ``hi + mid + lo == x`` bit for bit, summed in float32
+    from either end, each part a bfloat16, over a million random float32 bit
+    patterns that are finite and normal and over the range a factor table
+    has (1e-3 to 10, both signs).
+
+    Two ends of the float32 range are outside the identity, here as on the
+    chip.  Above bfloat16's largest finite value (3.3895e38) ``hi`` rounds
+    to inf.  Below ``2**-103`` (9.9e-32) ``lo``, 23 binary places under
+    ``x``, can be a SUBNORMAL number, and XLA flushes those to zero on the
+    CPU and on the TPU alike, as it does a subnormal ``x`` itself (whose
+    parts are all zero): the sum then misses ``x`` by less than ``2**-126``,
+    the smallest normal number, and no more.  ALS factors are eight and more
+    decimal orders above that."""
+    rng = np.random.default_rng(39)
+    bits = rng.integers(0, 1 << 32, 1_100_000, dtype=np.uint64).astype(np.uint32)
+    exponent = (bits >> 23) & 0xFF
+    x = bits[(exponent >= 1) & (exponent <= 254)].view(np.float32)
+    x = x[np.abs(x) <= np.float32(3.3895e38)]
+    assert len(x) >= 1_000_000
+    table = (
+        10.0 ** rng.uniform(-3, 1, 200_000) * rng.choice([-1.0, 1.0], 200_000)
+    ).astype(np.float32)
+    x = np.concatenate([x, table, np.float32([1.0, -1.0])])
+
+    hi, mid, lo = (np.asarray(part) for part in jax.jit(ap.split3)(x))
+    for part in (hi, mid, lo):
+        assert part.dtype == np.float32
+        as_bf16 = part.astype(ml_dtypes.bfloat16).astype(np.float32)
+        assert np.array_equal(as_bf16.view(np.uint32), part.view(np.uint32))
+
+    exact = np.abs(x) >= np.float32(2.0 ** -103)
+    for total in ((hi + mid) + lo, hi + (mid + lo)):
+        assert np.array_equal(
+            total[exact].view(np.uint32), x[exact].view(np.uint32)
+        )
+        assert np.abs(total[~exact] - x[~exact]).max() < 2.0 ** -126
+    assert (~exact).sum() > 50_000  # the flushed end was looked at
+
+    # a zero's parts are zeros (the sum of a -0.0's is +0.0)
+    assert not np.asarray(jax.jit(ap.split3)(np.float32([0.0, -0.0]))).any()
+
+    # the rows the gather reads: the parts side by side as bfloat16, zeros
+    # up to the lane tile
+    k = 10
+    rows = np.asarray(ap.split_table(jnp.asarray(table[:5000].reshape(-1, k))))
+    assert rows.dtype == ml_dtypes.bfloat16 and rows.shape == (500, ap.PARTS_W)
+    parts = rows.astype(np.float32)
+    assert not parts[:, 3 * k:].any()
+    total = (parts[:, :k] + parts[:, k:2 * k]) + parts[:, 2 * k:3 * k]
+    assert np.array_equal(total, table[:5000].reshape(-1, k))
+
+
+@pytest.mark.parametrize("rank", [4, 10, 17, 32])
+def test_selectors_pick_each_component_from_all_three_parts(rank):
+    """A slab's selector block is 0/1 with, in update row ``r``, a one over
+    component ``r // k`` (``r - k*k`` in the rhs block) of EACH of the three
+    parts in its upper half and over ``r % k`` in its lower half, and
+    nothing over the zero lanes or past the count row."""
+    sel = ap.selectors(rank)
+    kk, width = rank * rank, ap.row_width(rank)
+    assert sel.shape == (2 * width, ap.PARTS_W)
+    assert set(np.unique(sel)) == {0.0, 1.0}
+    sel = sel.reshape(width // ap.SLAB_W, 2, ap.SLAB_W, ap.PARTS_W)
+    pa = sel[:, 0].reshape(width, ap.PARTS_W)
+    pb = sel[:, 1].reshape(width, ap.PARTS_W)
+    v = np.arange(1, rank + 1, dtype=np.float32)
+    row = np.concatenate(
+        [v, 100 * v, 10_000 * v, np.full(ap.PARTS_W - 3 * rank, 7.0)]
+    )
+    r = np.arange(width)
+    want_a = np.where(
+        r < kk, v[np.minimum(r // rank, rank - 1)],
+        np.where(r < kk + rank, v[(r - kk) % rank], 0),
+    )
+    want_b = np.where(r < kk, v[r % rank], 0)
+    assert np.array_equal(pa @ row, 10_101 * want_a)
+    assert np.array_equal(pb @ row, 10_101 * want_b)
