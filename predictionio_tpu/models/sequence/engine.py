@@ -15,7 +15,11 @@ read before attention (SmallThinker's,
 huggingface.co/PowerInfer/SmallThinker-21BA3B-Instruct), or a looped stack of
 sandwich-norm attention layers run ``totalUtSteps`` times with the same
 weights, an exit head and a gate after every pass (Ouro's,
-huggingface.co/ByteDance/Ouro-2.6B) — by next-item cross-entropy (the looped
+huggingface.co/ByteDance/Ouro-2.6B), or a stack whose layers are ONE sublayer
+each — Mamba-2, or grouped attention with no positions, or relu² experts
+chosen by a sigmoid router beside a shared expert (Nemotron-3-Nano's,
+huggingface.co/nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16) — by next-item
+cross-entropy (the looped
 model: the expected cross-entropy under its exit distribution, less
 ``exitBeta`` times that distribution's entropy)
 with AdamW: ``stepsPerRetrain`` optimiser steps of ``rowsPerStep`` rows, one
@@ -300,7 +304,12 @@ class SequenceAlgorithmParams:
     ``num_key_value_heads``, ``rope_theta``; with ``total_ut_steps`` > 1 the
     layer list is run that many times with the same weights and every pass
     ends in an exit: one head, one gate, ``exit_beta`` the entropy's weight in
-    the loss)."""
+    the loss) or the one-sublayer kinds ``state_space`` (the ``mamba_*``
+    sizes), ``grouped_attention`` (``num_attention_heads`` on
+    ``num_key_value_heads``, no positions) and ``shared_routed_experts`` (the
+    ``moe_*`` sizes with ``moe_shared_expert_columns`` HELD of the shared
+    expert and ``routed_scaling_factor``; the kind fixes the router's rule and
+    the experts' form), mixed in one stack in any order."""
 
     hidden_size: int = 3840
     layer_types: tuple[str, ...] = (
@@ -361,8 +370,14 @@ class SequenceAlgorithmParams:
     #: exit distribution's entropy in its loss
     total_ut_steps: int = 1
     exit_beta: float = 0.1
+    #: ``shared_routed_experts`` layers: the shared expert's columns held, and
+    #: what a token's chosen weights sum to
+    moe_shared_expert_columns: int = 0
+    routed_scaling_factor: float = 1.0
 
     params_aliases = {
+        "moeSharedExpertColumns": "moe_shared_expert_columns",
+        "routedScalingFactor": "routed_scaling_factor",
         "totalUtSteps": "total_ut_steps",
         "exitBeta": "exit_beta",
         "moeNumPrimaryExperts": "moe_num_primary_experts",
@@ -432,7 +447,13 @@ class SequenceModel:
     #: a few positions, head_probe with head_probe_state (every exit's
     #: cross-entropy and the exit state it came from) for the same rows, and
     #: per step loss_by_exit and exit_mass [.., passes], exit_entropy and the
-    #: counters loop_layer_applications, loop_tokens, loop_attention_pairs
+    #: counters loop_layer_applications, loop_tokens, loop_attention_pairs.
+    #: A stack of one-sublayer kinds: ssd_probe (the first state-space
+    #: layer's) AND moe_probe (the first experts layer's ``f`` on the normed
+    #: embedded rows) with moe_grad_probe (that layer's experts' gradients on
+    #: the first row: ``seqmodel.experts_probe``), ``choices`` [rows a step,
+    #: ROUTED layers, row_len, experts a token] and the routing counters over
+    #: the routed layers
     training_record: dict
     config: Any = None
 
@@ -479,6 +500,8 @@ class SequenceAlgorithm(Algorithm):
             experts_per_token=p.moe_num_active_primary_experts,
             expert_width=p.moe_ffn_hidden_size, window=p.sliding_window_size,
             loop_steps=p.total_ut_steps, exit_beta=p.exit_beta,
+            shared_cols=p.moe_shared_expert_columns,
+            routed_scale=p.routed_scaling_factor,
         )
 
     def train(self, ctx: EngineContext, pd: PackedSequences) -> SequenceModel:
@@ -636,7 +659,16 @@ class SequenceAlgorithm(Algorithm):
 
 
 def _loop_tags(cfg) -> dict:
-    """What a looped model's spans say of it: its passes, and its exits."""
+    """What a looped model's spans say of it: its passes, and its exits; a
+    stack of one-sublayer kinds: how many layers of each kind it holds."""
+    from predictionio_tpu.ops import seqmodel
+
+    if set(cfg.layer_types) & set(seqmodel.SUBLAYER_KINDS):
+        return {
+            tag: cfg.layer_types.count(kind) for tag, kind in (
+                ("layers_state_space", seqmodel.STATE_SPACE),
+                ("layers_attention", seqmodel.GROUPED_ATTENTION),
+                ("layers_experts", seqmodel.SHARED_EXPERTS))}
     if cfg.loop_steps == 1:
         return {}
     return {"loop_steps": cfg.loop_steps, "exits": cfg.loop_steps}
@@ -649,6 +681,7 @@ def _routing_counters(record: dict) -> dict:
     the experts held (the pairs computed) and the busiest held expert's."""
     pairs = record["moe_expert_pairs"]
     out = {
+        "moe_routed_layers": int(pairs.shape[1]),
         "moe_experts_held": int(pairs.shape[-1]),
         "moe_pairs_total": int(record["moe_pairs_total"].sum()),
         "moe_pairs_held": int(record["moe_pairs_held"].sum()),
@@ -686,6 +719,7 @@ def sequence_engine() -> Engine:
         SequencePreparator,
         # one algorithm under the name of each block's recurrence
         {"gdn": SequenceAlgorithm, "ssd": SequenceAlgorithm,
-         "moe": SequenceAlgorithm, "loop": SequenceAlgorithm},
+         "moe": SequenceAlgorithm, "loop": SequenceAlgorithm,
+         "hybrid": SequenceAlgorithm},
         FirstServing,
     )

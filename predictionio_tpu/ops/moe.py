@@ -32,6 +32,22 @@ elsewhere ``jax.numpy`` over the same tiles.
 
 ``expert_ffn`` carries its own backward pass: gathers in both directions (a
 pair's row is written once), never a scatter-add of rows.
+
+**A second routing rule and a second expert form** (Nemotron-3-Nano's layer,
+``ops/seqmodel.py`` ``"shared_routed_experts"``).  ``route_sigmoid``: scores
+``s = sigmoid(logits)``, the ``k`` largest of ``s + b`` (``b`` a selection
+bias no gradient reaches), the weights the chosen UNBIASED scores normalised
+to sum to ``scale``.  ``relu2_ffn``: two matrices an expert and no gate,
+
+    u   = xs @ W_up                       K = hidden
+    y   = relu(u)^2 @ W_down
+    out[t] = sum over t's held pairs of  w * y
+
+over the SAME plan, dispatch, combine and grouped kernels (six products under
+the kernels' names with ``up`` in place of ``gate_up``), with its own
+backward.  An expert width that is no multiple of the 128 lanes (1856) goes
+through ``gmm`` as a block of the full width; ``tgmm`` covers it with blocks
+of ``block_n`` columns, the last one partly outside the array (``_tgmm_block``).
 """
 
 from __future__ import annotations
@@ -60,12 +76,36 @@ def act_grad(g):
     return (g > 0).astype(g.dtype)
 
 
+def act2(u):
+    """The two-matrix experts' activation: squared ReLU (``relu2``)."""
+    r = jnp.maximum(u, 0.0)
+    return r * r
+
+
+def act2_grad(u):
+    return 2.0 * jnp.maximum(u, 0.0)
+
+
 def route(logits, k: int):
     """[N, E] float32 logits -> (chosen experts [N, k] int32, their weights
     [N, k]): the ``k`` largest logits, then ``softmax`` over those ``k`` (they
     sum to one)."""
     top, idx = jax.lax.top_k(logits, k)
     return idx.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
+
+
+def route_sigmoid(logits, bias, k: int, scale: float):
+    """[N, E] float32 logits, [E] selection bias -> (chosen experts [N, k]
+    int32, their weights [N, k]): scores ``sigmoid(logits)``, the ``k``
+    largest of ``score + bias``, the weights the chosen scores WITHOUT the
+    bias over their sum (+ 1e-20), times ``scale`` (they sum to ``scale``).
+    The bias only moves the choice: no gradient reaches it, the weights'
+    gradient goes through the scores, the normaliser and the scale."""
+    s = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(s + jax.lax.stop_gradient(bias), k)
+    chosen = jnp.take_along_axis(s, idx, axis=-1)
+    w = scale * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), w
 
 
 class Plan(NamedTuple):
@@ -205,10 +245,14 @@ def _tgmm_kernel(group_ref, active_ref, lhs_ref, rhs_ref, out_ref):
 
 
 def tgmm(lhs, rhs, plan: Plan, held: int, *, name: str, impl: str | None = None,
-         block_n: int = 768):
+         block_n: int | None = None):
     """Per held expert, ``lhs[rows]^T @ rhs[rows]`` over the expert's tiles:
     lhs [R, K], rhs [R, N] -> [held, K, N] float32 (every expert has a tile,
-    so every block is written)."""
+    so every block is written).  The columns go in ``ceil(N / block_n)``
+    blocks, the last one partly outside the array where ``block_n`` does not
+    divide N (what it reads there reaches only columns that are not written);
+    with no ``block_n`` in blocks of 768 where that divides N, else in one
+    block of the full width."""
     R, K = lhs.shape
     n = rhs.shape[1]
     tiles = plan.tile_group.shape[0]
@@ -221,7 +265,7 @@ def tgmm(lhs, rhs, plan: Plan, held: int, *, name: str, impl: str | None = None,
             jnp.where(live, rhs.reshape(tiles, tm, n), 0),
             preferred_element_type=jnp.float32)
         return jnp.zeros((held, K, n), jnp.float32).at[plan.tile_group].add(part)
-    tn = block_n if n % block_n == 0 else n
+    tn = block_n or (768 if n % 768 == 0 else n)
 
     def last(i, active):
         return jnp.minimum(i, active[0] - 1)
@@ -231,7 +275,7 @@ def tgmm(lhs, rhs, plan: Plan, held: int, *, name: str, impl: str | None = None,
         out_shape=jax.ShapeDtypeStruct((held, K, n), jnp.float32),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(n // tn, tiles),
+            grid=(-(-n // tn), tiles),
             in_specs=[
                 pl.BlockSpec((tm, K), lambda j, i, g, a: (last(i, a), 0)),
                 pl.BlockSpec((tm, tn), lambda j, i, g, a: (last(i, a), j)),
@@ -244,7 +288,7 @@ def tgmm(lhs, rhs, plan: Plan, held: int, *, name: str, impl: str | None = None,
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         cost_estimate=pl.CostEstimate(
             flops=2 * R * K * n, transcendentals=0,
-            bytes_accessed=(lhs.size * (n // tn) + rhs.size) * lhs.dtype.itemsize
+            bytes_accessed=(lhs.size * -(-n // tn) + rhs.size) * lhs.dtype.itemsize
             + 4 * held * K * n),
         interpret=impl == "interpret",
         name=name,
@@ -270,6 +314,23 @@ def _combine(rows, dest, weights):
             part = part * weights[:, j, None]
         out = part if out is None else out + part
     return out
+
+
+def _cotangent_rows(plan: Plan, w, dout, rows: int):
+    """The backward's way into the buffer: a row's weight, and the output's
+    gradient at the row's token."""
+    with jax.named_scope("moe.combine"):
+        row_w = jnp.zeros((rows,), jnp.float32).at[plan.dest.reshape(-1)].set(
+            w.reshape(-1), mode="drop", unique_indices=True)
+        return row_w, _gather_rows(dout, plan.row_token)
+
+
+def _cotangent_tokens(plan: Plan, w, dxs, dw_row):
+    """And out of it: the tokens' gradient summed over their held pairs, and
+    each choice's weight's."""
+    with jax.named_scope("moe.dispatch"):
+        dm = _combine(dxs, plan.dest, None)
+        return dm, _gather_rows(dw_row, plan.dest.reshape(-1)).reshape(w.shape)
 
 
 def _ffn_forward(static, m, w, gate, up, down, plan):
@@ -305,12 +366,7 @@ def _ffn_bwd(static, res, dout):
     dtype, impl = static
     xs, gu, w, gate, up, down, plan = res
     held, _, f = gate.shape
-    R = xs.shape[0]
-    with jax.named_scope("moe.combine"):
-        # a row's weight, and the output's gradient at the row's token
-        row_w = jnp.zeros((R,), jnp.float32).at[plan.dest.reshape(-1)].set(
-            w.reshape(-1), mode="drop", unique_indices=True)
-        g_rows = _gather_rows(dout, plan.row_token)
+    row_w, g_rows = _cotangent_rows(plan, w, dout, xs.shape[0])
     with jax.named_scope("moe.experts"):
         g_act, u = act(gu[:, :f]), gu[:, f:]
         a = g_act * u
@@ -332,24 +388,94 @@ def _ffn_bwd(static, res, dout):
         dxs = gmm(dgu, both, plan, transpose_rhs=True,
                   name="moe_gmm_gate_up_dlhs", impl=impl)
         dboth = tgmm(xs, dgu, plan, held, name="moe_tgmm_gate_up", impl=impl)
-    with jax.named_scope("moe.dispatch"):
-        dm = _combine(dxs, plan.dest, None)
-        dw = _gather_rows(dw_row, plan.dest.reshape(-1)).reshape(w.shape)
+    dm, dw = _cotangent_tokens(plan, w, dxs, dw_row)
     return dm, dw, dboth[:, :, :f], dboth[:, :, f:], ddown, None
 
 
 expert_ffn.defvjp(_ffn_fwd, _ffn_bwd)
 
 
+#: columns of a ``tgmm`` block where the width is no multiple of the lanes
+#: (``relu2_ffn``: 1856 columns in three blocks of 640, the last 576 wide)
+COVER_BLOCK = 640
+
+
+def _tgmm_block(n: int) -> int:
+    """The block in which ``relu2_ffn``'s weight gradients take N columns:
+    whole lanes in the largest block that divides them (2688 = 3 x 896), else
+    ``COVER_BLOCK``, the last block partly outside the array."""
+    if n % 128:
+        return COVER_BLOCK
+    return next(b for b in (1024, 896, 768, 640, 512, 384, 256, 128) if n % b == 0)
+
+
+def _relu2_forward(static, m, w, up, down, plan):
+    dtype, impl = static
+    with jax.named_scope("moe.dispatch"):
+        xs = _gather_rows(m.astype(dtype), plan.row_token)
+    with jax.named_scope("moe.experts"):
+        u = gmm(xs, up.astype(dtype), plan, name="moe_gmm_up", impl=impl)
+        ys = gmm(act2(u).astype(dtype), down.astype(dtype), plan,
+                 name="moe_gmm_down", impl=impl)
+    with jax.named_scope("moe.combine"):
+        out = _combine(ys, plan.dest, w)
+    return out, (xs, u)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def relu2_ffn(static, m, w, up, down, plan: Plan):
+    """``expert_ffn`` for experts of two matrices, ``W_down relu(W_up m)^2``.
+    up: [held, D, F]; down: [held, F, D]; everything else as there."""
+    return _relu2_forward(static, m, w, up, down, plan)[0]
+
+
+def _relu2_fwd(static, m, w, up, down, plan):
+    out, (xs, u) = _relu2_forward(static, m, w, up, down, plan)
+    return out, (xs, u, w, up, down, plan)
+
+
+def _relu2_bwd(static, res, dout):
+    dtype, impl = static
+    xs, u, w, up, down, plan = res
+    held = up.shape[0]
+    row_w, g_rows = _cotangent_rows(plan, w, dout, xs.shape[0])
+    with jax.named_scope("moe.experts"):
+        a = act2(u)
+        da = gmm(g_rows.astype(dtype), down.astype(dtype), plan,
+                 transpose_rhs=True, name="moe_gmm_down_dlhs", impl=impl)
+        dw_row = jnp.sum(da * a, axis=-1)
+        da = da * row_w[:, None]
+        ddown = tgmm(
+            a.astype(dtype), (g_rows * row_w[:, None]).astype(dtype), plan, held,
+            name="moe_tgmm_down", impl=impl, block_n=_tgmm_block(down.shape[2]))
+        du = (da * act2_grad(u)).astype(dtype)
+        dxs = gmm(du, up.astype(dtype), plan, transpose_rhs=True,
+                  name="moe_gmm_up_dlhs", impl=impl)
+        dup = tgmm(xs, du, plan, held, name="moe_tgmm_up", impl=impl,
+                   block_n=_tgmm_block(up.shape[2]))
+    dm, dw = _cotangent_tokens(plan, w, dxs, dw_row)
+    return dm, dw, dup, ddown, None
+
+
+relu2_ffn.defvjp(_relu2_fwd, _relu2_bwd)
+
+
 def experts_layer(m, logits, valid, gate, up, down, *, k: int, start: int,
-                  tile: int, dtype, impl: str | None = None):
+                  tile: int, dtype, impl: str | None = None, bias=None,
+                  scale: float = 1.0):
     """The held experts' part of a routed layer.  m: [N, D] what the experts
     read; logits: [N, E] the router's (made by the caller, from what the
     router reads); valid: [N] bool, false on padding -> (out [N, D], the
-    choices [N, k], the pairs of each held expert [held])."""
-    held = gate.shape[0]
+    choices [N, k], the pairs of each held expert [held]).  With a selection
+    ``bias`` [E] the choice and weights are ``route_sigmoid``'s (the weights
+    sum to ``scale``); with no ``gate`` the experts are ``relu2_ffn``'s."""
+    held = up.shape[0]
     with jax.named_scope("moe.route"):
-        idx, w = route(logits, k)
+        idx, w = (route(logits, k) if bias is None
+                  else route_sigmoid(logits, bias, k, scale))
         plan = make_plan(idx, valid, start, held, tile)
-    out = expert_ffn((dtype, impl), m, w, gate, up, down, plan)
+    if gate is None:
+        out = relu2_ffn((dtype, impl), m, w, up, down, plan)
+    else:
+        out = expert_ffn((dtype, impl), m, w, gate, up, down, plan)
     return out, idx, plan.counts

@@ -1,4 +1,4 @@
-"""Hybrid sequence models, trainable on packed rows of tokens: four blocks.
+"""Hybrid sequence models, trainable on packed rows of tokens: five blocks.
 
 Layers follow ``layer_types``; the block's FORM is a property of the kind.
 
@@ -59,10 +59,29 @@ exit distribution of a position is ``p_t = lam_t prod_{j<t} (1 - lam_j)``,
 the last pass taking the rest, and the loss the expected cross-entropy under
 it less ``exit_beta`` times its entropy (``looped_row_grads``).
 
+``"state_space"`` / ``"grouped_attention"`` / ``"shared_routed_experts"`` (the
+Nemotron-H stack): a layer is ONE sublayer, ``x + f(RMSNorm(x))``, and one
+stack mixes the three kinds (``sublayer``); nothing is multiplied (``MuP()``):
+
+* state space: the parallel block's mixer alone (``state_space_mixer``), the
+  gated norm's group the B / C group's channels.
+* grouped attention: the global routed kind's attention alone, no rotary
+  positions and no window (``routed_attention``): the state-space layers
+  carry position.
+* shared routed experts: the router reads the layer's one normed input, which
+  also feeds the experts: scores ``sigmoid(h W_r)``, the ``k`` largest of
+  ``score + b`` (``router_bias``, a selection bias no gradient reaches and no
+  step moves), weights the chosen UNBIASED scores over their sum, times
+  ``routed_scale``; experts of two matrices, ``W_down relu(W_up h)^2``
+  (``ops/moe.py`` ``relu2_ffn``); beside them a shared expert of the same form
+  on every token (``shared_expert``), its result added before the residual.
+
 **The share.**  A deployment divides every layer over ``chips`` chips; this
 process holds one share of it: ``heads`` of the attention heads, ``mlp_cols``
 of the MLP's columns (or ``experts_held`` of the experts, from
-``expert_start``; the router whole), ``vocab_rows`` rows of the embedding and
+``expert_start``; the router and its selection bias whole; ``shared_cols`` of
+a shared expert's columns), ``ssm_heads`` of the state-space heads in
+``ssm_groups`` of the groups, ``vocab_rows`` rows of the embedding and
 the head, starting at ``vocab_start``.  Every function computes the part of the result
 its share gives (the sum over its heads of ``o_h W_o[h]``, the sum over its
 MLP columns, logits and loss over its vocabulary rows; an id outside its rows
@@ -76,8 +95,8 @@ is over the channels held.
 matrix products take bfloat16 inputs and accumulate in float32, in the forward
 and both backward products (``mm``).  Residual stream, norms, the
 convolution, the decay projections and everything of the delta rule float32;
-the router's logits, its top-k and the chosen experts' weights float32 at
-``Precision.HIGHEST``; so are the state a looped model carries from pass to
+the router's logits, its scores, its top-k and the chosen experts' weights
+float32 at ``Precision.HIGHEST``; so are the state a looped model carries from pass to
 pass, its gates' product, the exit distribution, its entropy and the
 combination of the exits' losses.
 
@@ -91,6 +110,13 @@ record holds the carried state's precision by.  A routed block hands back
 instead the first layer's experts applied to ``h`` (exact on both sides of a
 comparison) along a seeded vector, every layer's choices, and the pairs each
 held expert computed, which the step's accumulator sums beside the gradients.
+A stack of one-sublayer kinds hands back the first state-space layer's
+``S_t C_t`` with the routed layers' choices and pairs, the counters summed
+over the layers that route and not over all of them; the first experts
+layer's ``f`` applied to the embedded rows under its own norm (exact inputs,
+which the stream that layer reads is not) comes from a small program of its
+own run on the first step's rows, with the experts' backward on the first of
+them (``experts_probe``).
 A looped block hands back each position's exit distribution, the mean square
 of the state each later pass read, and at a few positions the exit states
 with their cross-entropies; the step's accumulator sums every exit's loss, its
@@ -117,14 +143,23 @@ PARALLEL = "parallel_ssm_attention"
 GLOBAL_MOE = "global_attention_moe"
 SLIDING_MOE = "sliding_attention_moe"
 SANDWICH = "sandwich_attention"
+STATE_SPACE = "state_space"
+GROUPED_ATTENTION = "grouped_attention"
+SHARED_EXPERTS = "shared_routed_experts"
 #: the kinds whose feed-forward is a layer of routed experts
 MOE_KINDS = (GLOBAL_MOE, SLIDING_MOE)
-KINDS = (LINEAR, FULL, PARALLEL) + MOE_KINDS + (SANDWICH,)
+#: the kinds that are ONE sublayer, ``x + f(RMSNorm(x))``
+SUBLAYER_KINDS = (STATE_SPACE, GROUPED_ATTENTION, SHARED_EXPERTS)
+#: every kind that routes: its layers have the routing counters
+ROUTED_KINDS = MOE_KINDS + (SHARED_EXPERTS,)
+KINDS = (LINEAR, FULL, PARALLEL) + MOE_KINDS + (SANDWICH,) + SUBLAYER_KINDS
 
-#: what the training record calls the first layer's probe, by its kind
+#: what the training record calls the first layer's probe, by its kind (a
+#: stack of one-sublayer kinds records the first layer's of EACH name)
 PROBE_NAME = {LINEAR: "delta_rule_probe", FULL: "delta_rule_probe",
               PARALLEL: "ssd_probe", GLOBAL_MOE: "moe_probe",
-              SLIDING_MOE: "moe_probe", SANDWICH: "exit_probe"}
+              SLIDING_MOE: "moe_probe", SANDWICH: "exit_probe",
+              STATE_SPACE: "ssd_probe", SHARED_EXPERTS: "moe_probe"}
 
 #: segment id of a row's padding (real segments count from 0)
 PAD_SEGMENT = -1
@@ -200,10 +235,15 @@ class SeqConfig:
     """Widths as published, counts as HELD by this share.  The ``lin_*``
     sizes are read by ``"linear_attention"`` layers, ``heads`` / ``head_dim``
     by every attention mixer, ``kv_heads`` and ``rope_theta`` by the parallel,
-    the routed and the sandwich blocks, ``ssm_*`` and ``mup`` by
-    ``"parallel_ssm_attention"`` layers, ``experts*``, ``expert_*`` and
-    ``window`` by the ``*_moe`` kinds, ``loop_steps`` and ``exit_beta`` by a
-    looped stack of ``"sandwich_attention"`` layers."""
+    the routed and the sandwich blocks and ``"grouped_attention"`` layers,
+    ``ssm_*`` and ``mup`` by ``"parallel_ssm_attention"`` and ``"state_space"``
+    layers, ``experts*``, ``expert_*`` and ``window`` by the ``*_moe`` kinds,
+    ``experts*``, ``expert_*``, ``shared_cols`` and ``routed_scale`` by
+    ``"shared_routed_experts"`` layers (the KIND fixes the routing rule and the
+    experts' form: softmax over the chosen logits to gated experts for the
+    ``*_moe`` kinds, normalised sigmoid scores to relu^2 experts here),
+    ``loop_steps`` and ``exit_beta`` by a looped stack of
+    ``"sandwich_attention"`` layers."""
 
     hidden: int
     layer_types: tuple[str, ...]
@@ -263,6 +303,10 @@ class SeqConfig:
     loop_steps: int = 1
     #: weight of the exit distribution's entropy in a looped model's loss
     exit_beta: float = 0.1
+    #: ``"shared_routed_experts"`` layers: the shared expert's columns held,
+    #: and what a token's chosen weights sum to
+    shared_cols: int = 0
+    routed_scale: float = 1.0
 
     def __post_init__(self):
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
@@ -277,30 +321,40 @@ class SeqConfig:
         if SANDWICH in self.layer_types and self.heads % (
                 self.kv_heads or self.heads):
             raise ValueError("heads do not divide over their groups")
-        if set(self.layer_types) & set(MOE_KINDS):
+        if set(self.layer_types) & set(ROUTED_KINDS):
             if not (self.experts and self.experts_held and self.expert_width
                     and 0 < self.experts_per_token <= self.experts):
                 raise ValueError("*_moe layers need the experts' sizes")
             if self.expert_start + self.experts_held > self.experts:
                 raise ValueError("the experts held lie outside the router's width")
+            if SHARED_EXPERTS in self.layer_types and self.shared_cols <= 0:
+                raise ValueError(
+                    f"{SHARED_EXPERTS} layers need the shared expert's columns")
+        if set(self.layer_types) & set(MOE_KINDS):
             if self.heads % (self.kv_heads or self.heads):
                 raise ValueError("heads do not divide over their groups")
             if SLIDING_MOE in self.layer_types and self.window <= 0:
                 raise ValueError(f"{SLIDING_MOE} layers need a window")
-        if PARALLEL in self.layer_types:
+        if GROUPED_ATTENTION in self.layer_types and self.heads % (
+                self.kv_heads or self.heads):
+            raise ValueError("heads do not divide over their groups")
+        if {PARALLEL, STATE_SPACE} & set(self.layer_types):
             if not (self.ssm_heads and self.ssm_head_dim and self.ssm_state):
-                raise ValueError(f"{PARALLEL} layers need the ssm_* sizes")
-            if self.ssm_heads % self.ssm_groups or self.heads % (
-                    self.kv_heads or self.heads):
+                raise ValueError("state-space layers need the ssm_* sizes")
+            if self.ssm_heads % self.ssm_groups:
                 raise ValueError("heads do not divide over their groups")
+        if PARALLEL in self.layer_types and self.heads % (
+                self.kv_heads or self.heads):
+            raise ValueError("heads do not divide over their groups")
 
     @property
     def token_multiple(self) -> int:
         """Row lengths are multiples of this (the recurrences' chunks, the
         windowed attention's smallest block)."""
         return math.lcm(*(
-            self.ssm_chunk if kind == PARALLEL
-            else 128 if kind in MOE_KINDS else self.chunk
+            self.ssm_chunk if kind in (PARALLEL, STATE_SPACE)
+            else 128 if kind in MOE_KINDS + (GROUPED_ATTENTION, SHARED_EXPERTS)
+            else self.chunk
             for kind in self.layer_types))
 
 
@@ -314,8 +368,10 @@ class AdamW:
 
 
 #: tensors AdamW does not decay: norms, the decays' parameters, the
-#: convolutions with their bias, the state space's skip, the exit gate's bias
-NO_DECAY = ("norm", "a_log", "dt_bias", "conv", "ssm_d", "exit_gate_bias")
+#: convolutions with their bias, the state space's skip, the exit gate's
+#: bias, the router's selection bias (no gradient reaches it either)
+NO_DECAY = ("norm", "a_log", "dt_bias", "conv", "ssm_d", "exit_gate_bias",
+            "router_bias")
 
 
 def decays(name: str) -> bool:
@@ -377,6 +433,37 @@ def param_shapes(cfg: SeqConfig) -> dict[str, tuple[int, ...]]:
                 p + "experts_down": (E, F, D),
             })
             continue
+        elif kind == STATE_SPACE:
+            ch = cfg.ssm_heads * cfg.ssm_head_dim
+            bc = cfg.ssm_groups * cfg.ssm_state
+            shapes.update({
+                p + "input_norm": (D,),
+                p + "ssm_in": (D, 2 * ch + 2 * bc + cfg.ssm_heads),
+                p + "ssm_conv": (cfg.ssm_conv_width, ch + 2 * bc),
+                p + "ssm_conv_bias": (ch + 2 * bc,),
+                p + "ssm_a_log": (cfg.ssm_heads,), p + "ssm_d": (cfg.ssm_heads,),
+                p + "ssm_dt_bias": (cfg.ssm_heads,), p + "ssm_norm": (ch,),
+                p + "ssm_out": (ch, D),
+            })
+            continue
+        elif kind == GROUPED_ATTENTION:
+            kv = (cfg.kv_heads or cfg.heads) * cfg.head_dim
+            shapes.update({
+                p + "input_norm": (D,),
+                p + "q": (D, cfg.heads * cfg.head_dim), p + "k": (D, kv),
+                p + "v": (D, kv), p + "o": (cfg.heads * cfg.head_dim, D),
+            })
+            continue
+        elif kind == SHARED_EXPERTS:
+            E, F = cfg.experts_held, cfg.expert_width
+            shapes.update({
+                p + "input_norm": (D,), p + "router": (D, cfg.experts),
+                p + "router_bias": (cfg.experts,),
+                p + "shared_up": (D, cfg.shared_cols),
+                p + "shared_down": (cfg.shared_cols, D),
+                p + "experts_up": (E, D, F), p + "experts_down": (E, F, D),
+            })
+            continue
         elif kind == SANDWICH:
             kv = (cfg.kv_heads or cfg.heads) * cfg.head_dim
             shapes.update({
@@ -417,7 +504,7 @@ def _init_tensor(leaf: str, shape: tuple, conv_width: int, base, n):
     key = jax.random.fold_in(base, n)
     if leaf.endswith("norm") or leaf == "ssm_d":
         return jnp.ones(shape, jnp.float32)
-    if leaf == "exit_gate_bias":
+    if leaf in ("exit_gate_bias", "router_bias"):
         return jnp.zeros(shape, jnp.float32)
     if "conv" in leaf:
         bound = 1.0 / math.sqrt(conv_width)
@@ -451,8 +538,8 @@ def init_params(cfg: SeqConfig, seed: int) -> dict[str, jax.Array]:
     that the logarithm is finite); the state space's ``ssm_a_log =
     log(uniform(1, 16))``, ``ssm_dt_bias`` as ``dt_bias`` and ``ssm_d = 1``
     (the Mamba-2 release's); a looped model's exit gate normal(0, 0.02) like
-    any matrix, its bias 0.  One small program a tensor: the random bits of
-    one tensor are the only temporary."""
+    any matrix, its bias 0; a router's selection bias 0.  One small program a
+    tensor: the random bits of one tensor are the only temporary."""
     base = jax.random.PRNGKey(seed)
     out = {}
     for n, (name, shape) in enumerate(param_shapes(cfg).items()):
@@ -783,11 +870,12 @@ def grouped_query_attention(cfg: SeqConfig, p: dict, h, seg):
         return _scaled(mm(_attend(cfg, q, k, v, seg), p["o"]), mup.attention_out)
 
 
-def moe_probe_vector(D: int):
+def moe_probe_vector(D: int, n: int = 2):
     """The seeded direction the first routed layer's output is recorded
-    along: standard normal [D] from ``fold_in(PRNGKey(PROBE_SEED), 2**20 + 2)``."""
+    along: standard normal [D] from ``fold_in(PRNGKey(PROBE_SEED), 2**20 + 2)``
+    (``n`` 3: the one ``experts_probe`` contracts the experts' gradients with)."""
     return jax.random.normal(
-        jax.random.fold_in(jax.random.PRNGKey(PROBE_SEED), 2 ** 20 + 2), (D,),
+        jax.random.fold_in(jax.random.PRNGKey(PROBE_SEED), 2 ** 20 + n), (D,),
         jnp.float32)
 
 
@@ -840,6 +928,96 @@ def routed_layer(cfg: SeqConfig, kind: str, p: dict, x, seg):
         choices = choices.reshape(B, T, -1)
     with jax.named_scope("seq.stream"):
         return x + y, (probe, choices, pairs)
+
+
+def shared_expert(p: dict, h):
+    """The share's columns of the shared expert, ``relu(h W_up)^2 W_down``,
+    on every token."""
+    with jax.named_scope("moe.shared"):
+        return mm(moe.act2(mm(h, p["shared_up"])), p["shared_down"])
+
+
+def shared_routed_experts(cfg: SeqConfig, p: dict, h, seg):
+    """What a ``"shared_routed_experts"`` layer adds to the stream: the held
+    routed experts' part (sigmoid scores chosen under the selection bias, two
+    relu^2 matrices an expert) plus the shared expert's held columns, both
+    from ``h`` [B, T, D], which the router reads too -> (the sum [B, T, D],
+    the choices [B, T, k], the pairs of each held expert [held])."""
+    B, T, D = h.shape
+    with jax.named_scope("seq.moe"):
+        with jax.named_scope("moe.route"):
+            logits = mm_f32(h, p["router"]).reshape(B * T, -1)
+        y, choices, pairs = moe.experts_layer(
+            h.reshape(B * T, D), logits, (seg != PAD_SEGMENT).reshape(-1), None,
+            p["experts_up"], p["experts_down"], k=cfg.experts_per_token,
+            start=cfg.expert_start, tile=cfg.moe_tile, dtype=MATMUL_DTYPE,
+            impl=cfg.moe_impl, bias=p["router_bias"], scale=cfg.routed_scale)
+        y = y.reshape(B, T, D) + shared_expert(p, h)
+        return y, choices.reshape(B, T, -1), pairs
+
+
+def sublayer(cfg: SeqConfig, kind: str, p: dict, x, seg):
+    """One layer of ONE sublayer, ``x + f(RMSNorm(x))`` -> (x after it, what
+    its ``f`` records: the state space's probe [B, T, H], nothing [B, T, 0]
+    for attention, (choices, pairs) for the experts)."""
+    with jax.named_scope("seq.stream"):
+        h = rmsnorm(x, p["input_norm"], cfg.eps)
+    if kind == STATE_SPACE:
+        y, record = state_space_mixer(cfg, p, h, seg)
+    elif kind == GROUPED_ATTENTION:
+        # the global routed kind's attention: no rotary, no window
+        y = routed_attention(cfg, kind, p, h, seg)
+        record = jnp.zeros(x.shape[:2] + (0,))
+    else:
+        y, *record = shared_routed_experts(cfg, p, h, seg)
+    with jax.named_scope("seq.stream"):
+        return x + y, record
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def experts_probe(cfg: SeqConfig, backward: bool, table, p: dict, tokens, seg):
+    """What the training record holds the experts' path by (route, dispatch,
+    the grouped products, combine, shared expert): a ``"shared_routed_experts"``
+    layer's ``f`` applied to the EMBEDDED row ``tokens`` [T] under its own
+    norm.  Exact embedding rows normed in float32 are the same numbers on both
+    sides of a comparison, which the stream a deeper layer reads is not.  A
+    program of its own, run on the first step's rows and not in the row
+    program: -> {"moe_probe": ``f`` along the probe vector [T, 1],
+    "moe_grad_probe": with ``backward`` the gradient of the ROUTED part's sum
+    along that vector, {"up", "down": the two stacked matrices' [held, F],
+    each contracted over the hidden axis with a second seeded vector,
+    "input": what reaches the experts' input, summed over the tokens [D]}
+    (the four backward products); zeros without}."""
+    T, D, F = tokens.shape[0], cfg.hidden, cfg.expert_width
+    with jax.named_scope("seq.embed"):
+        rows = tokens[None]
+    x0 = embed(cfg, table, rows)
+    with jax.named_scope("seq.moe"):
+        h = rmsnorm(x0, p["input_norm"], cfg.eps)
+        r, q = moe_probe_vector(D), moe_probe_vector(D, 3)
+        with jax.named_scope("moe.route"):
+            logits = mm_f32(h, p["router"]).reshape(T, -1)
+
+        def routed(m, up, down):
+            return moe.experts_layer(
+                m, logits, seg != PAD_SEGMENT, None, up, down,
+                k=cfg.experts_per_token, start=cfg.expert_start,
+                tile=cfg.moe_tile, dtype=MATMUL_DTYPE, impl=cfg.moe_impl,
+                bias=p["router_bias"], scale=cfg.routed_scale)[0]
+
+        y, vjp = jax.vjp(routed, h[0], p["experts_up"], p["experts_down"])
+        probe = jnp.matmul(y + shared_expert(p, h)[0], r, precision=HIGHEST)
+        if backward:
+            dm, dup, ddown = vjp(jnp.broadcast_to(r, y.shape))
+            grads = {
+                "up": jnp.einsum("edf,d->ef", dup, q, precision=HIGHEST),
+                "down": jnp.einsum("efd,d->ef", ddown, q, precision=HIGHEST),
+                "input": jnp.sum(dm, axis=0)}
+        else:
+            held = cfg.experts_held
+            grads = {"up": jnp.zeros((held, F)), "down": jnp.zeros((held, F)),
+                     "input": jnp.zeros((D,))}
+        return {"moe_probe": probe[:, None], "moe_grad_probe": grads}
 
 
 def gated_group_norm(y, z, w, eps, axis_name=None):
@@ -924,9 +1102,11 @@ def layer(cfg: SeqConfig, kind: str, p: dict, x, seg):
     with two mixers and the MLP, the ``*_moe`` kinds ``routed_layer`` (whose
     second result is a tuple: probe, choices, pairs), ``sandwich_attention``
     a norm before and after each of its two sublayers (no probe: the exits'
-    record is the looped trunk's)."""
+    record is the looped trunk's), the one-sublayer kinds ``sublayer``."""
     if kind in MOE_KINDS:
         return routed_layer(cfg, kind, p, x, seg)
+    if kind in SUBLAYER_KINDS:
+        return sublayer(cfg, kind, p, x, seg)
     if kind == SANDWICH:
         with jax.named_scope("seq.stream"):
             h = rmsnorm(x, p["input_norm"], cfg.eps)
@@ -971,12 +1151,16 @@ def trunk(cfg: SeqConfig, params: dict, x, seg, remat: bool = False):
     residual stream between layers is kept.  Where layers are routed the
     second result is a dict: the first layer's probe under its name
     (``PROBE_NAME``), ``choices`` [B, routed layers, T, k] and ``expert_pairs``
-    [routed layers, held].  A looped model (``loop_steps`` R > 1) gives the R
+    [routed layers, held].  A stack of one-sublayer kinds gives a dict too:
+    the FIRST state-space layer's probe as ``ssd_probe`` and the routed
+    layers' ``choices`` and ``expert_pairs``, each where the stack has such a
+    layer (its experts' probe is a program of its own, ``experts_probe``).
+    A looped model (``loop_steps`` R > 1) gives the R
     exit states [R, B, T, D] first, and the carried state's mean squares
     second (``looped_trunk``)."""
     if cfg.loop_steps > 1:
         return looped_trunk(cfg, params, x, seg, remat)
-    first, routed = None, []
+    first, routed, named = None, [], {}
     for i, kind in enumerate(cfg.layer_types):
         f = functools.partial(layer, cfg, kind)
         if remat:
@@ -985,14 +1169,22 @@ def trunk(cfg: SeqConfig, params: dict, x, seg, remat: bool = False):
         if kind in MOE_KINDS:
             probe, choices, pairs = probe
             routed.append((choices, pairs))
+        elif kind == SHARED_EXPERTS:
+            routed.append(tuple(probe))
+            continue
+        elif kind == STATE_SPACE:
+            named.setdefault(PROBE_NAME[kind], probe)
         first = probe if first is None else first
+    if set(cfg.layer_types) & set(SUBLAYER_KINDS):
+        first = named
+    elif routed:
+        first = {PROBE_NAME[cfg.layer_types[0]]: first}
     if routed:
         with jax.named_scope("seq.moe"):
-            first = {
-                PROBE_NAME[cfg.layer_types[0]]: first,
+            first.update({
                 "choices": jnp.stack([c for c, _ in routed], axis=1),
                 "expert_pairs": jnp.stack([n for _, n in routed]),
-            }
+            })
     with jax.named_scope("seq.stream"):
         return rmsnorm(x, params["final_norm"], cfg.eps), first
 
@@ -1273,7 +1465,7 @@ def init_state(cfg: SeqConfig, seed: int) -> tuple[dict, dict]:
     state = {"params": params, "m": zeros(), "v": zeros(),
              "t": jnp.zeros((), jnp.int32)}
     acc = {"g": zeros(), "loss": jnp.float32(0.0), "count": jnp.float32(0.0)}
-    routed = sum(kind in MOE_KINDS for kind in cfg.layer_types)
+    routed = sum(kind in ROUTED_KINDS for kind in cfg.layer_types)
     if routed:
         # the routing counters of the step, summed beside the gradients
         acc["expert_pairs"] = jnp.zeros((routed, cfg.experts_held), jnp.int32)
@@ -1409,14 +1601,22 @@ def train_steps(cfg: SeqConfig, opt: AdamW, state: dict, acc: dict, tokens, seg)
     and every optimiser step is dispatched at once (nothing is fetched in
     between, so the host never waits for a step) -> (state, acc, the records
     of the steps, the first-layer probes [T, H] of the FIRST step's rows
-    (a routed block's dict of them, ``trunk``): those are made from the
-    seeded initial weights; all still on the device)."""
+    (a routed block's dict of them, ``trunk``; a stack of one-sublayer
+    kinds' with ``experts_probe``'s beside): those are made from the seeded
+    initial weights; all still on the device)."""
     accumulate, apply = train_programs(cfg, opt)
     records, probes = [], []
     for s in range(tokens.shape[0]):
         for r in range(tokens.shape[1]):
             state, acc, probe = accumulate(state, acc, tokens[s, r], seg[s, r])
             if s == 0:
+                if SHARED_EXPERTS in cfg.layer_types:
+                    # the first experts layer on exact inputs; its backward
+                    # on the first row alone
+                    probe.update(experts_probe(
+                        cfg, r == 0, state["params"]["embed"], layer_params(
+                            state["params"], cfg.layer_types.index(SHARED_EXPERTS)),
+                        tokens[s, r], seg[s, r]))
                 probes.append(probe)
         state, acc, record = apply(state, acc)
         records.append(record)

@@ -1,7 +1,7 @@
 """Every device operation of a sequence retrain under ONE name the program
 wrote (ISSUE 36): in the jaxprs of the three programs (the row program
 ``accumulate_row``, the step ``apply_step``, the initialisation
-``init_state``) of the four blocks, backward and recomputed equations
+``init_state``) of the five blocks, backward and recomputed equations
 included, every equation that makes an array of more than a handful of
 elements carries exactly one top-level scope of ``seqmodel.SCOPES`` in its
 name stack.  A trace's ``(no scope)`` then holds only what the compiler made
@@ -32,6 +32,8 @@ BLOCKS = {
     "smallthinker": {"seq.attn", "seq.moe"},
     # the looped block: its exits' scope beside the mixer's and the MLP's
     "ouro": {"seq.attn", "seq.mlp", "seq.exit"},
+    # layers of ONE sublayer each: no MLP, and no top-level name of its own
+    "nemotron_h": {"seq.ssm", "seq.attn", "seq.moe"},
 }
 #: the component a looped model writes around pass t, OUTSIDE the top-level scope
 LOOP_PASS = re.compile(r"loop\.pass(\d+)")
@@ -43,6 +45,9 @@ def _config(block: str):
         return ref.seq_config(ref.HALF)
     if block == "ouro":
         import ouro_reference as ref
+        return ref.seq_config(ref.TINY)
+    if block == "nemotron_h":
+        import nemotron_reference as ref
         return ref.seq_config(ref.TINY)
     if block == "falcon_h1":
         import h1_reference as ref
@@ -140,6 +145,53 @@ def test_a_pass_component_lies_outside_the_scope_of_its_operations():
         top for s in outside for top in TOP_LEVEL.findall(s)}
     assert not [s for s, _ in ops if LOOP_PASS.search(s) and (
         "seq.exit" in s or "seq.loss" in s)]
+
+
+def test_the_experts_probe_program_is_scoped_like_the_row_program():
+    """``experts_probe`` is a program of its own (the first step's rows): its
+    forward and the experts' backward carry exactly one top-level scope each,
+    ``seq.embed`` for the rows and ``seq.moe`` with the row program's
+    components for the rest."""
+    cfg = _config("nemotron_h")
+    params = jax.eval_shape(lambda: seqmodel.init_state(cfg, 3))[0]["params"]
+    first = cfg.layer_types.index(seqmodel.SHARED_EXPERTS)
+    row = jax.ShapeDtypeStruct((64,), jnp.int32)
+    ops = [(s, n) for _, s, n in _operations(jax.make_jaxpr(
+        lambda table, p, t, g: seqmodel.experts_probe(cfg, True, table, p, t, g))(
+            params["embed"], seqmodel.layer_params(params, first), row, row).jaxpr)
+        if n > HANDFUL]
+    assert all(len(set(TOP_LEVEL.findall(s))) == 1 for s, _ in ops), [
+        s for s, _ in ops if len(set(TOP_LEVEL.findall(s))) != 1]
+    assert {t for s, _ in ops for t in TOP_LEVEL.findall(s)} == {"seq.embed", "seq.moe"}
+    assert {c for s, _ in ops for c in re.findall(r"moe\.\w+", s)} == {
+        "moe.route", "moe.dispatch", "moe.experts", "moe.combine", "moe.shared"}
+    assert any("transpose" in s and "moe.experts" in s for s, _ in ops)  # the backward
+
+
+def test_the_shared_expert_is_a_component_of_the_routed_layers_scope():
+    """An ``E`` layer's work is ``seq.moe`` with the components that exist
+    (``moe.route``, ``moe.dispatch``, ``moe.experts``, ``moe.combine``) and one
+    new, ``moe.shared``, forward, recomputed and backward; the four older
+    blocks' programs hold no such component."""
+    ops = [s for _, s, n in _operations(
+        _program(_config("nemotron_h"), "accumulate_row").jaxpr) if n > HANDFUL]
+    shared = [s for s in ops if "moe.shared" in s]
+    assert shared and all("seq.moe" in s for s in shared)
+    assert all(s.index("seq.moe") < s.index("moe.shared") for s in shared)
+    assert any("rematted_computation" in s for s in shared)
+    assert any("transpose" in s for s in shared)
+    components = {c for s in ops if "seq.moe" in s for c in re.findall(r"moe\.\w+", s)}
+    assert components == {
+        "moe.route", "moe.dispatch", "moe.experts", "moe.combine", "moe.shared"}
+    # a state-space layer's and the attention layer's components are the
+    # Falcon and SmallThinker blocks' own
+    assert {c for s in ops for c in re.findall(r"ssm\.\w+", s)} == {
+        "ssm.proj", "ssm.conv", "ssm.intra", "ssm.chunk", "ssm.norm"}
+    assert any("seq.attn/attn.causal" in s for s in ops)
+    assert not [s for s in ops if "attn.rope" in s or "attn.window" in s]
+    for block in ("olmo_hybrid", "falcon_h1", "smallthinker", "ouro"):
+        older = _operations(_program(_config(block), "accumulate_row").jaxpr)
+        assert not [s for _, s, _ in older if "moe.shared" in s], block
 
 
 @pytest.mark.parametrize("call", [

@@ -561,3 +561,130 @@ def test_ouro_row_program_fits_beside_its_arguments(v5e):
     assert plan.argument_size_in_bytes == pytest.approx(16 * 612_438_017, rel=1e-3)
     assert plan.alias_size_in_bytes >= 0.999 * plan.argument_size_in_bytes  # donated
     assert plan.temp_size_in_bytes < 8 * 10**9
+
+
+# the Nemotron-H stack at its published widths (one of eight chips' share: 16
+# of 128 two-matrix experts of width 1856 = 14.5 lane tiles on a stream of
+# 2688, 6 a token, rows of 8192 tokens, tiles of 256 pairs; 8 state-space
+# heads of 64 channels = half a lane tile, state 128, one group)
+RELU2 = dict(tokens=8192, hidden=2688, width=1856, experts=128, held=16, k=6, tile=256)
+
+
+@pytest.mark.parametrize("passes", ["forward", "forward_backward"])
+def test_relu2_grouped_kernels_compile_at_a_width_of_no_whole_lane_tiles(v5e, passes):
+    """The two-matrix experts' grouped products at F = 1856: ``gmm`` takes the
+    weight block [2688, 1856] at its full width, ``relu2_ffn``'s own backward
+    the transposed products and ``moe_tgmm_up`` over three blocks of 640
+    columns, the last one 576 wide (``moe._tgmm_block``)."""
+    from predictionio_tpu.ops import moe
+
+    sds = _spec_on(SingleDeviceSharding(v5e.devices[0]))
+    N, D, F, E, held, k, tile = (RELU2[n] for n in (
+        "tokens", "hidden", "width", "experts", "held", "k", "tile"))
+    assert F % 128 == 64 and moe.plan_rows(N, k, held, tile) == 53_248
+    assert (moe._tgmm_block(F), moe._tgmm_block(D)) == (640, 896)
+
+    def layer(m, logits, valid, up, down, bias):
+        return moe.experts_layer(
+            m, logits, valid, None, up, down, k=k, start=0, tile=tile,
+            dtype=jnp.bfloat16, impl="pallas", bias=bias, scale=2.5)[0].sum()
+
+    fn = jax.jit(layer if passes == "forward" else jax.grad(
+        layer, argnums=(0, 1, 3, 4)))
+    text = _compile(
+        fn, sds((N, D)), sds((N, E)), sds((N,), jnp.bool_), sds((held, D, F)),
+        sds((held, F, D)), sds((E,))).as_text()
+    assert "moe_gmm_up" in text and "moe_gmm_down" in text
+    assert "moe_gmm_gate_up" not in text
+    for name in ("moe_gmm_down_dlhs", "moe_gmm_up_dlhs", "moe_tgmm_down",
+                 "moe_tgmm_up"):
+        assert (name in text) == (passes == "forward_backward"), name
+
+
+def test_ssd_chunk_kernels_compile_at_half_a_lane_tile_a_head(v5e):
+    """``ssd_chunk_fwd`` / ``ssd_chunk_bwd`` at 8 heads of 64 channels and a
+    state of 128 (Falcon-H1's are 128 and 256), four heads a grid step."""
+    from predictionio_tpu.ops import ssd
+
+    sds = _spec_on(SingleDeviceSharding(v5e.devices[0]))
+    h, g, nc, c, p, n = 8, 1, 64, 128, 64, 128
+    fn = jax.jit(jax.grad(
+        lambda *a: ssd.chunk_pallas(*a, False).sum(), argnums=tuple(range(4))))
+    assert ssd.heads_per_block(h // g) == 4
+    text = _compile(
+        fn, sds((1, g, nc, c, n)), sds((1, g, nc, c, n)), sds((1, h, nc, c, p)),
+        sds((1, h, nc))).as_text()
+    assert "ssd_chunk_fwd" in text and "ssd_chunk_bwd" in text
+
+
+def test_nemotron_row_program_fits_beside_its_arguments(v5e):
+    """The training row of ``nemotron3-nano-30b-ep8.retrain`` as the chip
+    compiles it at the PUBLISHED widths (nine layers of one sublayer each: the
+    SSD kernels at 64 channels a head, splash attention without positions,
+    the grouped expert kernels at a width of 1856 beside the shared expert;
+    8192 tokens, 760.9 M parameters at 16 bytes): the compiler plans its
+    temporaries beside 12.17 GB of weights, moments and gradient sums, under
+    the 16,909,336,064 B the v5e's allocator reports as its limit (PERF.md).
+    A plan, not a reading."""
+    import dataclasses
+    import json
+    from pathlib import Path
+
+    from predictionio_tpu.models.sequence import engine as seq
+    from predictionio_tpu.ops import seqmodel
+    from predictionio_tpu.utils.params import extract_params
+
+    body = json.loads((Path(__file__).resolve().parents[1] / "benchmark" / "configs"
+                       / "nemotron3-nano-30b-ep8.json").read_text())
+    algo = seq.SequenceAlgorithm(extract_params(
+        seq.SequenceAlgorithmParams, body["engine_json"]["algorithms"][0]["params"]))
+    cfg = dataclasses.replace(
+        algo.seq_config(), attn_impl="flash", ssm_impl="pallas", moe_impl="pallas")
+    assert (cfg.hidden, cfg.ssm_head_dim, cfg.ssm_state, cfg.head_dim,
+            cfg.expert_width, 8 * cfg.shared_cols) == (
+        body["hidden_size"], body["mamba_head_dim"], body["ssm_state_size"],
+        body["head_dim"], body["moe_intermediate_size"],
+        body["moe_shared_expert_intermediate_size"]) == (2688, 64, 128, 128, 1856, 3712)
+    assert (cfg.experts, cfg.experts_per_token, cfg.routed_scale) == (128, 6, 2.5)
+    kinds = {"M": seqmodel.STATE_SPACE, "*": seqmodel.GROUPED_ATTENTION,
+             "E": seqmodel.SHARED_EXPERTS}
+    assert cfg.layer_types == tuple(kinds[c] for c in body["hybrid_override_pattern"])
+    assert seqmodel.num_params(cfg) == body["share"]["parameters_held"] == 760_856_416
+    by_part = body["share"]["parameters_by_part"]
+    assert 4 * by_part["state_space_a_layer"] + by_part["attention_a_layer"] + 4 * by_part[
+        "experts_a_layer"] + by_part["embedding_and_head"] + by_part[
+        "final_norm"] == 760_856_416
+    row_len = body["engine_json"]["preparator"]["params"]["rowLen"]
+    sds = _spec_on(SingleDeviceSharding(v5e.devices[0]))
+    state, acc = jax.eval_shape(lambda: seqmodel.init_state(cfg, 3))
+    state, acc = jax.tree.map(lambda a: sds(a.shape, a.dtype), (state, acc))
+    assert acc["expert_pairs"].shape == (4, 16)  # the FOUR routed layers of nine
+    accumulate, _ = seqmodel.train_programs(cfg, seqmodel.AdamW())
+    compiled = _compile(
+        accumulate, state, acc, sds((row_len,), jnp.int32), sds((row_len,), jnp.int32))
+    text = compiled.as_text()
+    for name in ("moe_gmm_up", "moe_tgmm_up", "moe_tgmm_down", "ssd_chunk_fwd",
+                 "ssd_chunk_bwd", "moe.shared"):
+        assert name in text, name
+    _assert_splash_alone(text)
+    _assert_every_named_operation_is_scoped(text)
+    plan = compiled.memory_analysis()
+    assert plan.argument_size_in_bytes == pytest.approx(16 * 760_856_416, rel=1e-3)
+    assert plan.alias_size_in_bytes >= 0.999 * plan.argument_size_in_bytes  # donated
+    assert plan.argument_size_in_bytes + plan.temp_size_in_bytes < 16_909_336_064
+    # the first step's probe of the experts (forward, and their backward on
+    # the first row) runs beside the same resident state
+    resident = plan.argument_size_in_bytes
+    first = cfg.layer_types.index(seqmodel.SHARED_EXPERTS)
+    probe = seqmodel.experts_probe.lower(
+        cfg, True, state["params"]["embed"],
+        seqmodel.layer_params(state["params"], first),
+        sds((row_len,), jnp.int32), sds((row_len,), jnp.int32)).compile()
+    text = probe.as_text()
+    for name in ("moe_gmm_up", "moe_gmm_down", "moe_gmm_down_dlhs", "moe_gmm_up_dlhs",
+                 "moe_tgmm_down", "moe_tgmm_up", "moe.shared"):
+        assert name in text, name
+    _assert_every_named_operation_is_scoped(text)
+    plan = probe.memory_analysis()
+    assert resident + plan.temp_size_in_bytes + plan.output_size_in_bytes < 16_909_336_064
+    print("experts_probe plan:", plan.temp_size_in_bytes, plan.output_size_in_bytes)
