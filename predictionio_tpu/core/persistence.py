@@ -3,8 +3,8 @@
 The reference Kryo-serializes the whole Seq[model] into the MODELDATA
 repository (workflow/CoreWorkflow.scala:76-81).  Here models are arbitrary
 Python objects whose array leaves may be jax device arrays: every jax array
-is pulled to host numpy (device_get) before pickling, so checkpoint contents
-never depend on device topology.
+is pulled to host numpy (device_get) before its bytes are made, so checkpoint
+contents never depend on device topology.
 
 Large array leaves (NCF embedding tables, ALS factor matrices) do not
 round-trip through one monolithic pickle: ``serialize_models_sharded`` spills
@@ -25,33 +25,67 @@ sandbox at 3.0 GB of float32 leaves, the largest 770 MB: host peak over the
 arrays +1.52 GB through the bytes, +0.001 GB through the files; PERF.md,
 PR 31.  Before ``LazyParts`` every part's bytes were held until the last was
 written: +3.24 GB at 3.2 GB; PERF.md, PR 26).
+
+A ``jax.Array`` leaf of part size leaves the device inside the write, not
+before it: it becomes a part as it is, and ``LazyParts.write_part`` fetches it
+when a store's writer asks for that part, in pieces of ``FETCH_PIECE_BYTES``
+(span ``persist.fetch`` around each wait), so the device's copies run under
+the writers' disk writes and the host holds a few pieces of the model, never
+all of it (PERF.md, PR 41).
 """
 
 from __future__ import annotations
 
+import functools
 import io
+import math
 import pickle
+import threading
 from collections.abc import Mapping
-from typing import Any, BinaryIO, Callable, Iterator
+from typing import Any, BinaryIO, Callable, Iterable, Iterator
 
 import jax
 import numpy as np
 
+from predictionio_tpu.obs.tracing import current_span, trace
+
 #: leaves at or above this many bytes become standalone parts
 PART_THRESHOLD = 1 << 20
 
+#: a device leaf larger than this leaves the device in pieces of about this
+#: size, each fetched, written and let go.  What sets the size is the host's
+#: memory, not the device: a copy into a NEW buffer pays a page fault for every
+#: 4 KiB of it, beside writers that are taking new page-cache pages for the
+#: same bytes, and the allocator hands a freed buffer back to the next request
+#: (instead of unmapping it) only up to 32 MiB.  Read on the chip's host, the
+#: Olmo block's 3.2 GB in 33 parts: fetched whole 1.9-2.0 GB/s alone and
+#: 3.5-3.7 s fetched under the write, in pieces of 128 MiB the same, in pieces
+#: of 32 MiB 4.3 GB/s alone and 2.8 s (PERF.md section 6, PR 41)
+FETCH_PIECE_BYTES = 16 << 20
 
-def _to_host(obj: Any) -> Any:
-    """Map jax arrays to numpy throughout an arbitrary pytree-ish object."""
+#: pieces of the part a writer is on whose copies run while it writes one
+FETCH_PIECES_AHEAD = 3
+
+
+def _to_host(obj: Any, part_threshold: int | None = None) -> Any:
+    """Map jax arrays to numpy throughout an arbitrary pytree-ish object;
+    those of ``part_threshold`` bytes or more stay where they are (they
+    become parts, fetched when they are written)."""
+    def pull(x: Any) -> Any:
+        if not isinstance(x, jax.Array):
+            return x
+        if part_threshold is not None and x.nbytes >= part_threshold:
+            return x
+        return np.asarray(jax.device_get(x))
+
     return jax.tree_util.tree_map(
-        lambda x: np.asarray(jax.device_get(x)) if isinstance(x, jax.Array) else x,
-        obj,
-        is_leaf=lambda x: isinstance(x, jax.Array),
+        pull, obj, is_leaf=lambda x: isinstance(x, jax.Array)
     )
 
 
 class _ShardingPickler(pickle.Pickler):
-    """Pickler that spills big ndarray leaves into a side table of parts.
+    """Pickler that spills big array leaves (host or device) into a side
+    table of parts.
 
     ``persistent_id`` sees every object in the graph, registered pytree or
     not — dataclasses, dicts, BiMaps — so any reachable large array is
@@ -61,7 +95,7 @@ class _ShardingPickler(pickle.Pickler):
     def __init__(self, buf: io.BytesIO, threshold: int):
         super().__init__(buf, protocol=pickle.HIGHEST_PROTOCOL)
         #: part name -> the array it holds (its bytes are made on demand)
-        self.leaves: dict[str, np.ndarray] = {}
+        self.leaves: dict[str, np.ndarray | jax.Array] = {}
         self.threshold = threshold
         # persistent_id runs before pickle's own memoization, so aliased
         # arrays (one table referenced from two fields) must be deduped here
@@ -70,7 +104,10 @@ class _ShardingPickler(pickle.Pickler):
         self._seen: dict[int, str] = {}
 
     def persistent_id(self, obj: Any):
-        if isinstance(obj, np.ndarray) and obj.nbytes >= self.threshold:
+        if (
+            isinstance(obj, (np.ndarray, jax.Array))
+            and obj.nbytes >= self.threshold
+        ):
             name = self._seen.get(id(obj))
             if name is None:
                 name = f"leaf{len(self.leaves):05d}"
@@ -80,13 +117,79 @@ class _ShardingPickler(pickle.Pickler):
         return None
 
 
+@functools.partial(jax.jit, static_argnums=2)
+def _rows(leaf: jax.Array, start, rows: int) -> jax.Array:
+    """``rows`` rows of the leaf seen as a matrix of its last axis' rows, as a
+    device array of its own: one program a leaf's shape and ``rows``,
+    whatever ``start`` is."""
+    # the span's name on the device too: a traced retrain reads the slices'
+    # time under it and not as operations nobody named
+    with jax.named_scope("persist.fetch"):
+        view = leaf.reshape(-1, leaf.shape[-1]) if leaf.ndim > 1 else leaf.reshape(-1)
+        return jax.lax.dynamic_slice_in_dim(view, start, rows)
+
+
+class _DevicePart:
+    """A leaf that is still on the device, on its way to the host: whole
+    where it is small, else in pieces of whole rows of its last axis (about
+    ``FETCH_PIECE_BYTES`` each, in C order, every piece a device array of its
+    own), so that a piece's bytes are written while the next leave the
+    device, and the host holds a few pieces of a large part and never all of
+    it.  A piece's copy is started (``start``) before it is waited for
+    (``take``); the pieces are taken in order, by one thread."""
+
+    def __init__(self, leaf: jax.Array):
+        self.leaf = leaf
+        self.length = math.prod(leaf.shape[:-1]) if leaf.ndim > 1 else leaf.size
+        pieces = min(self.length, max(1, -(-leaf.nbytes // FETCH_PIECE_BYTES)))
+        self.rows = -(-self.length // pieces)
+        self.pieces = -(-self.length // self.rows)
+        self._started: dict[int, jax.Array] = {}
+
+    def _first_row(self, k: int) -> int:
+        # the last piece reaches back over rows the one before it held, so
+        # that every piece of a leaf is the one program's
+        return min(k * self.rows, self.length - self.rows)
+
+    def start(self, k: int) -> None:
+        if k >= self.pieces or k in self._started:
+            return
+        piece = self.leaf
+        if self.pieces > 1:
+            piece = _rows(self.leaf, self._first_row(k), self.rows)
+        piece.copy_to_host_async()
+        self._started[k] = piece
+
+    def take(self, k: int) -> np.ndarray:
+        """Piece ``k``'s rows on the host.  Of a leaf in several pieces
+        nothing here keeps the copy: it goes with the array returned."""
+        host = np.asarray(self._started.pop(k))
+        held = k * self.rows - self._first_row(k)
+        return host[held:] if held else host
+
+
 class LazyParts(Mapping):
     """Part name -> raw ``.npy`` bytes, serialized when asked for and not
     kept: a store that writes part after part holds one part's bytes, and a
-    store that hands ``write_part`` an open file holds none."""
+    store that hands ``write_part`` an open file holds none.
 
-    def __init__(self, leaves: dict[str, np.ndarray]):
+    A part whose leaf is a ``jax.Array`` leaves the device when it is asked
+    for, piece by piece (``_DevicePart``), each wait inside a span
+    ``persist.fetch`` (a child of whatever span the asking thread has open:
+    the local store's ``persist.part``); ``fetched`` says which parts came
+    that way, and how many bytes."""
+
+    def __init__(self, leaves: dict[str, np.ndarray | jax.Array]):
         self._leaves = leaves
+        self._on_device = {
+            name: _DevicePart(leaf) for name, leaf in leaves.items()
+            if isinstance(leaf, jax.Array)
+        }
+        #: the device's copies are started from several writers' threads
+        self._lock = threading.Lock()
+        self._written: set[str] = set()
+        #: part name -> bytes, of the parts fetched from the device so far
+        self.fetched: dict[str, int] = {}
 
     def __getitem__(self, name: str) -> bytes:
         part = io.BytesIO()
@@ -97,12 +200,43 @@ class LazyParts(Mapping):
         """Write the bytes ``self[name]`` would return into ``file``.  Onto a
         real file ``np.save`` writes the header and then the array's buffer
         from its own memory (``ndarray.tofile``, which releases the GIL);
-        into anything else it copies the array chunk by chunk."""
-        np.save(file, self._leaves[name], allow_pickle=False)
+        into anything else it copies the array chunk by chunk.  A device
+        leaf's bytes are the same ``.npy``: its header, then piece after
+        piece as each arrives, the next one's copy running under the write."""
+        part = self._on_device.get(name)
+        if part is None:
+            np.save(file, self._leaves[name], allow_pickle=False)
+            return
+        leaf = part.leaf
+        np.lib.format.write_array_header_1_0(file, {
+            "descr": np.lib.format.dtype_to_descr(leaf.dtype),
+            "fortran_order": False, "shape": leaf.shape,
+        })
+        fetched = 0
+        for k in range(part.pieces):
+            with trace("persist.fetch", ring=False) as span:
+                with self._lock:
+                    for ahead in range(k, k + 1 + FETCH_PIECES_AHEAD):
+                        part.start(ahead)
+                host = part.take(k)
+                span.tags = {"part": name, "piece": k, "bytes": host.nbytes}
+            file.write(host.reshape(-1).view(np.uint8).data)
+            fetched += host.nbytes
+        self.fetched[name] = fetched
+        self._written.add(name)
+
+    def fetch_ahead(self, names: Iterable[str]) -> None:
+        """Start the copy off the device of these parts' first pieces and do
+        not wait for it: the writer that asks for one of them later finds it
+        on its way or there.  Asking twice starts nothing twice."""
+        with self._lock:
+            for name in names:
+                if name in self._on_device and name not in self._written:
+                    self._on_device[name].start(0)
 
     def part_nbytes(self, name: str) -> int:
         """The part's size without its ``.npy`` header (what a store orders
-        its writes by, without making the bytes)."""
+        its writes by, without making the bytes or fetching the leaf)."""
         return self._leaves[name].nbytes
 
     def __iter__(self) -> Iterator[str]:
@@ -148,10 +282,11 @@ def serialize_models_sharded(
     models: list[Any], threshold: int = PART_THRESHOLD
 ) -> tuple[bytes, Mapping[str, bytes]]:
     """Return (manifest blob, {part name: raw .npy bytes}); the mapping makes
-    a part's bytes each time it is read (``LazyParts``)."""
+    a part's bytes each time it is read (``LazyParts``), and fetches the part
+    then where its leaf is a device array."""
     buf = io.BytesIO()
     p = _ShardingPickler(buf, threshold)
-    p.dump([_to_host(m) for m in models])
+    p.dump([_to_host(m, threshold) for m in models])
     return buf.getvalue(), LazyParts(p.leaves)
 
 
@@ -176,6 +311,13 @@ def save_models(
         models, threshold if threshold is not None else PART_THRESHOLD
     )
     models_store.insert_parts(instance_id, manifest, parts)
+    span = current_span()
+    if span is not None:
+        span.tags = {
+            **(span.tags or {}),
+            "fetched_parts": len(parts.fetched),
+            "fetched_bytes": sum(parts.fetched.values()),
+        }
 
 
 def load_models(models_store, instance_id: str) -> list[Any] | None:

@@ -20,6 +20,14 @@ from predictionio_tpu.data.storage import base
 #: wall; 4 keeps the small parts off their threads.
 PART_WRITERS = 4
 
+#: parts, from the one a writer takes on in the write's order, whose first
+#: piece's copy off the device has been started (``LazyParts.fetch_ahead``;
+#: host arrays have none): the ``PART_WRITERS`` being written and two behind
+#: them.  The device's copies are served in the order they were asked for, so
+#: more ahead delays the pieces the writers are waiting for now (8 ahead with
+#: one piece a writer: 2.1 s of a writer's 3.7 in waits; PERF.md, PR 41)
+PARTS_FETCHING = PART_WRITERS + 2
+
 
 class LocalFSModels(base.Models):
     def __init__(self, path: str | Path):
@@ -87,9 +95,12 @@ class LocalFSModels(base.Models):
         manifest last) with ``PART_WRITERS`` parts in flight, largest first.
 
         A mapping that offers ``write_part(name, file)`` (``LazyParts``) has
-        each part written from its array's memory; any other mapping's
-        values are the parts' bytes.  Every part's file is fsynced before
-        its rename, and the directory before the manifest is written, so a
+        each part written from its array's memory, and a part that is still
+        on the device fetched by its writer as it writes it (``persist.fetch``
+        under ``persist.part``), the first copies of the next
+        ``PARTS_FETCHING`` parts started ahead; any other mapping's values
+        are the parts' bytes.  Every part's file is fsynced before its
+        rename, and the directory before the manifest is written, so a
         manifest that can be seen names parts that are all on the disk; the
         manifest goes through ``insert``, and this returns after its
         directory fsync.  On an error every writer is joined, each has
@@ -105,7 +116,11 @@ class LocalFSModels(base.Models):
         streamed = hasattr(parts, "write_part")
         if streamed:
             size_of, write = parts.part_nbytes, parts.write_part
+            fetch_ahead = parts.fetch_ahead
         else:
+            def fetch_ahead(names: list[str]) -> None:
+                """Bytes are on the host already."""
+
             def size_of(name: str) -> int:
                 return len(parts[name])
 
@@ -113,22 +128,24 @@ class LocalFSModels(base.Models):
                 file.write(parts[name])
 
         parent = current_span()
+        order = sorted(parts, key=size_of, reverse=True)
 
-        def publish(name: str) -> int:
+        def publish(at: int) -> int:
+            name = order[at]
             with trace("persist.part", ring=False, parent=parent) as span:
+                fetch_ahead(order[at : at + PARTS_FETCHING])
                 size = self._publish(
                     f"{instance_id}:part:{name}", partial(write, name)
                 )
                 span.tags = {"part": name, "bytes": size}
             return size
 
-        order = sorted(parts, key=size_of, reverse=True)
         writers = min(PART_WRITERS, len(order))
         sizes = []
         if order:
             with ThreadPoolExecutor(writers) as pool:
                 sizes = base.run_concurrent(
-                    pool, [partial(publish, name) for name in order]
+                    pool, [partial(publish, at) for at in range(len(order))]
                 )
             # the parts' names reach the disk before the manifest's can.
             # One flush for all of them: a directory fsync after each rename

@@ -426,7 +426,8 @@ class SequenceAlgorithmParams:
 
 @dataclass
 class SequenceModel:
-    #: flat name -> float32 array (``ops/seqmodel.param_shapes``)
+    #: flat name -> float32 array (``ops/seqmodel.param_shapes``): on the
+    #: device out of ``train``, on the host out of ``load_persistent_model``
     params: dict
     item_vocab: BiMap
     entity_vocab: BiMap
@@ -550,17 +551,22 @@ class SequenceAlgorithm(Algorithm):
                 **_loop_tags(cfg),
             }
         with trace("seq.fetch") as span:
-            params = {k: np.asarray(v) for k, v in state["params"].items()}
-            record = jax.tree.map(
-                lambda *xs: np.stack([np.asarray(x) for x in xs]), *records)
-            first = jax.tree.map(
-                lambda *xs: np.stack([np.asarray(x) for x in xs]), *probes)
+            # the weights stay on the device: the model store's writers fetch
+            # them part by part, under the write (``persist.fetch``)
+            params = state["params"]
+            # every copy started before the first is waited for: a step's
+            # record is a few hundred small arrays, 0.4 ms each one by one
+            records, probes = jax.device_get((records, probes))
+            record = jax.tree.map(lambda *xs: np.stack(xs), *records)
+            first = jax.tree.map(lambda *xs: np.stack(xs), *probes)
             if isinstance(first, dict):  # a routed block names its own
                 record.update(first)
             else:
                 record[seqmodel.PROBE_NAME[cfg.layer_types[0]]] = first
-            span.tags = {"bytes": int(sum(v.nbytes for v in params.values())),
-                         **_loop_tags(cfg)}
+            span.tags = {
+                "bytes": int(sum(
+                    v.nbytes for v in jax.tree.leaves(record))),
+                **_loop_tags(cfg)}
             if "moe_expert_pairs" in record:
                 span.tags["counters"] = _routing_counters(record)
             if "loop_layer_applications" in record:

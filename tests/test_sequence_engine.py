@@ -59,6 +59,10 @@ def store(tmp_path):
         "PIO_STORAGE_SOURCES_PARQUET_PATH": str(home / "events_parquet"),
         "PIO_STORAGE_SOURCES_PARQUET_NSHARDS": "4",
         "PIO_STORAGE_REPOSITORIES_EVENTDATA_SOURCE": "PARQUET",
+        # the models on the local filesystem, as the benchmark's are
+        "PIO_STORAGE_SOURCES_LOCALFS_TYPE": "localfs",
+        "PIO_STORAGE_SOURCES_LOCALFS_PATH": str(home / "models"),
+        "PIO_STORAGE_REPOSITORIES_MODELDATA_SOURCE": "LOCALFS",
     }))
     app = commands.app_new(rt, "seq").app
     rng = np.random.default_rng(26)
@@ -224,8 +228,16 @@ class _Stages(logging.Handler):
             self.stages = record.stages
 
 
+#: a weight of this many bytes is a part of its own at the tiny widths (the
+#: 64 x 64 projections; the norms and the convolutions stay in the manifest)
+TINY_PART_THRESHOLD = 4096
+
+
 @pytest.fixture()
-def trained(store):
+def trained(store, monkeypatch):
+    from predictionio_tpu.core import persistence
+
+    monkeypatch.setattr(persistence, "PART_THRESHOLD", TINY_PART_THRESHOLD)
     rt, data = store
     seen = _Stages()
     log = logging.getLogger("predictionio_tpu.workflow")
@@ -243,6 +255,13 @@ def trained(store):
         log.setLevel(level)
     assert instance.status == "COMPLETED"
     return rt, data, engine, params, instance, seen.stages
+
+
+def _retrain_root(instance):
+    from predictionio_tpu.obs.tracing import recent_traces
+
+    return next(
+        t for t in recent_traces(5) if t.get("request_id") == instance.id)
 
 
 def test_train_persist_load_predict_round_trip(trained):
@@ -284,7 +303,8 @@ def test_every_span_of_the_engine_appears_once_in_stages(trained):
     for name in SPANS + ("train.algorithm.gdn", "train.persist.save_models",
                          "train.datasource.read", "train.preparator.prepare"):
         assert name in stages and stages[name] >= 0, name
-    assert "parallel" not in stages  # nothing ran side by side
+    # nothing ran side by side but the model store's writers
+    assert stages["parallel"] == ["persist.fetch", "persist.part"]
     for part, whole in (("datasource.sequences", "train.datasource.read"),
                         ("prepare.pack", "train.preparator.prepare"),
                         ("seq.device_loop", "train.algorithm.gdn")):
@@ -294,11 +314,8 @@ def test_every_span_of_the_engine_appears_once_in_stages(trained):
 def test_the_sequence_read_asks_for_time_order(trained):
     """The engine that NEEDS the store's order runs the ordered path: its
     retrain's ``eventstore.scan`` says ``ordered: true``, every column."""
-    from predictionio_tpu.obs.tracing import recent_traces
-
     instance, stages = trained[-2:]
-    root = next(
-        t for t in recent_traces(5) if t.get("request_id") == instance.id)
+    root = _retrain_root(instance)
     read = next(
         c for c in root["children"] if c["name"] == "train.datasource.read")
     by_name = {c["name"]: c for c in read["children"]}
@@ -345,3 +362,54 @@ def test_importing_the_engines_loads_no_kernel_code():
         [sys.executable, "-c", code], capture_output=True, text=True, timeout=300)
     assert out.returncode == 0, out.stderr[-2000:]
     assert out.stdout.strip() == "[]"
+
+
+
+def test_the_weights_leave_the_device_inside_the_part_writers(trained):
+    """``seq.fetch`` brings the training record alone; every weight of part
+    size is fetched by the writer of its part (``persist.fetch`` under
+    ``persist.part``), and ``train.persist.save_models`` says how many were
+    (ISSUE 41)."""
+    from predictionio_tpu.ops import seqmodel
+
+    rt, _, engine, params, instance, stages = trained
+    root = _retrain_root(instance)
+    algo = next(c for c in root["children"] if c["name"] == "train.algorithm.gdn")
+    assert [c["name"] for c in algo["children"]] == [
+        "seq.init", "seq.device_loop", "seq.fetch"]
+    shapes = seqmodel.param_shapes(engine.instantiate(params)[2][0].seq_config())
+    sizes = {k: 4 * int(np.prod(shape)) for k, shape in shapes.items()}
+    big = sorted(
+        (n for n in sizes.values() if n >= TINY_PART_THRESHOLD), reverse=True)
+    assert len(big) > 4 and len(big) < len(sizes)
+    # the record's few numbers, not the weights' bytes
+    assert 0 < algo["children"][2]["bytes"] < min(big)
+    persist = next(
+        c for c in root["children"] if c["name"] == "train.persist.save_models")
+    assert persist["parts"] == persist["streamed_parts"] == len(big)
+    assert persist["fetched_parts"] == len(big)
+    assert persist["fetched_bytes"] == sum(big)
+    writers = persist["children"]
+    assert [w["name"] for w in writers] == ["persist.part"] * len(big)
+    for w in writers:
+        (fetch,) = w["children"]
+        assert (fetch["name"], fetch["part"]) == ("persist.fetch", w["part"])
+    assert sorted((w["children"][0]["bytes"] for w in writers), reverse=True) == big
+    # the longest writer's seconds in fetches, inside the span that waited
+    assert 0 <= stages["persist.fetch"] <= stages["persist.part"]
+    assert stages["persist.part"] <= stages["train.persist.save_models"] + 1e-4
+    (data,) = load_models(rt.models(), instance.id)
+    assert {k: v.nbytes for k, v in data["params"].items()} == sizes
+    assert all(type(v) is np.ndarray for v in data["params"].values())
+
+
+def test_no_weight_outlives_the_retrain(trained):
+    """After ``run_train`` has returned nothing holds a weight on the device
+    (not the parts' mapping, not a span's tags, not a cached copy): the next
+    retrain's ``seq.init`` finds the memory this one trained in."""
+    import jax
+
+    assert [
+        (a.shape, a.dtype) for a in jax.live_arrays()
+        if a.nbytes >= TINY_PART_THRESHOLD
+    ] == []
