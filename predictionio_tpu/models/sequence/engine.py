@@ -442,7 +442,9 @@ class SequenceModel:
     #: (the first layer's experts on its normed input, [.., 1]), ``choices``
     #: [rows a step, routed layers, row_len, experts a token] for the same
     #: rows, and per step and routed layer moe_pairs_total, moe_pairs_held,
-    #: moe_expert_pairs [.., experts held] (``ops/seqmodel.apply_step``).  A
+    #: moe_expert_pairs [.., experts held], moe_rows_live and moe_rows_planned
+    #: (the pair buffers' live tiles' rows, and all they have;
+    #: ``ops/seqmodel.apply_step``).  A
     #: looped model: exit_probe (each position's exit distribution, [.., passes]),
     #: carry_probe (the mean square of the state each later pass read) and, at
     #: a few positions, head_probe with head_probe_state (every exit's
@@ -686,11 +688,18 @@ def _routing_counters(record: dict) -> dict:
     retrain's sums, and a step and layer the pairs all tokens made, those of
     the experts held (the pairs computed) and the busiest held expert's."""
     pairs = record["moe_expert_pairs"]
+    live = int(record["moe_rows_live"].sum())
+    planned = int(record["moe_rows_planned"].sum())
     out = {
         "moe_routed_layers": int(pairs.shape[1]),
         "moe_experts_held": int(pairs.shape[-1]),
         "moe_pairs_total": int(record["moe_pairs_total"].sum()),
         "moe_pairs_held": int(record["moe_pairs_held"].sum()),
+        # the pair buffers' rows the layers' gathers and maps ran over (the
+        # live tiles'), of the rows the buffers have
+        "moe_rows_live": live,
+        "moe_rows_planned": planned,
+        "moe_rows_live_pct": 100.0 * live / planned,
     }
     for s, l in np.ndindex(pairs.shape[:2]):
         at = f".step{s}.layer{l}"

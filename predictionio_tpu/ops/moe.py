@@ -16,7 +16,12 @@ so every pair of a held expert is already here.
 gradient is written).  The buffer is sized for the worst case, every token
 choosing ``min(k, held)`` held experts, so capacity is never a reason to drop
 a pair; the grouped products work through the tiles that hold pairs and skip
-the rest.  Padding tokens (``valid`` false) make no pair.
+the rest, and so does everything else whose leading dimension is the
+buffer's: the gathers into it run over windows of ``WINDOW_TILES`` tiles, as
+many as ``plan.n_active`` says at run time (``_live_windows``), and the maps
+between the products over the products' own grid of tiles (``_tile_maps``),
+into buffers whose other rows nothing writes or reads.  Padding tokens
+(``valid`` false) make no pair.
 
 **The products.**  One tile of pairs times its expert's matrix, bfloat16
 inputs and float32 accumulation, in the forward and in both products of the
@@ -66,6 +71,11 @@ HIGHEST = jax.lax.Precision.HIGHEST
 #: over, a tile of pairs and its result (the default of 16 MiB is less)
 VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
+#: tiles of the pair buffer a window of ``_live_windows`` takes (16 x 256 rows
+#: divide both routed cells' buffers, 400 and 208 tiles; chosen on the chip:
+#: ``benchmark/tests/micro_moe_rows_chip.py window``)
+WINDOW_TILES = 16
+
 
 def act(g):
     """The experts' gate activation: ReLU (the published ReGLU)."""
@@ -114,14 +124,19 @@ class Plan(NamedTuple):
     #: [N, k]: the row of pair (t, j); ``plan_rows`` where the pair's expert
     #: is not held here (or the token is padding)
     dest: jax.Array
-    #: [R]: the token a row reads; N on a row no pair lies on
-    row_token: jax.Array
+    #: [R]: the pair a row holds, ``t * k + j``; N * k on a row no pair lies on
+    row_pair: jax.Array
     #: [R / tile]: the held expert (0 ..) a tile belongs to
     tile_group: jax.Array
     #: [1]: tiles that hold pairs (or an empty expert's zeros), from the front
     n_active: jax.Array
     #: [held]: pairs of each held expert
     counts: jax.Array
+
+    @property
+    def row_token(self):
+        """[R]: the token a row reads; N on a row no pair lies on."""
+        return self.row_pair // self.dest.shape[1]
 
 
 def plan_rows(tokens: int, k: int, held: int, tile: int) -> int:
@@ -130,6 +145,13 @@ def plan_rows(tokens: int, k: int, held: int, tile: int) -> int:
     expert (a whole tile for an expert with no pair)."""
     worst = tokens * min(k, held)
     return -(-worst // tile) * tile + held * tile
+
+
+def expert_tiles(counts, tile: int):
+    """Tiles of the buffer each held expert takes: its pairs' (``counts``
+    [..., held]), or one of zeros for an expert with no pair.  Their sum is
+    ``plan.n_active``, the live tiles."""
+    return jnp.maximum(-(-counts // tile), 1)
 
 
 def make_plan(idx, valid, start: int, held: int, tile: int) -> Plan:
@@ -146,19 +168,18 @@ def make_plan(idx, valid, start: int, held: int, tile: int) -> Plan:
         dtype=jnp.int32)
     before = jnp.cumsum(chosen, axis=0) - chosen
     counts = jnp.sum(chosen, axis=0)
-    tiles = jnp.maximum(-(-counts // tile), 1)
+    tiles = expert_tiles(counts, tile)
     ends = jnp.cumsum(tiles)
     first_row = (ends - tiles) * tile
     at = jnp.clip(local, 0, held - 1)
     rank = jnp.take_along_axis(before, at, axis=1)
     dest = jnp.where(mine, first_row[at] + rank, R).astype(jnp.int32)
-    row_token = jnp.full((R,), N, jnp.int32).at[dest.reshape(-1)].set(
-        jnp.repeat(jnp.arange(N, dtype=jnp.int32), k), mode="drop",
-        unique_indices=True)
+    row_pair = jnp.full((R,), N * k, jnp.int32).at[dest.reshape(-1)].set(
+        jnp.arange(N * k, dtype=jnp.int32), mode="drop", unique_indices=True)
     tile_group = jnp.minimum(
         jnp.searchsorted(ends, jnp.arange(R // tile), side="right"), held - 1
     ).astype(jnp.int32)
-    return Plan(dest, row_token, tile_group, ends[-1:].astype(jnp.int32), counts)
+    return Plan(dest, row_pair, tile_group, ends[-1:].astype(jnp.int32), counts)
 
 
 # ---------------------------------------------------------------------------
@@ -304,6 +325,114 @@ def _gather_rows(x, index):
     return jnp.take(x, index, axis=0, mode="fill", fill_value=0)
 
 
+def _buffer(shape, dtype, impl: str):
+    """What the loop over the live windows writes into.  No row past the live
+    windows is ever read (the products and the maps skip their tiles, no
+    token's ``dest`` points there), so beside the kernels the buffer is left
+    as the allocator hands it, by a kernel that writes nothing: a broadcast of
+    zeros over all ``plan_rows`` rows, every gathered buffer of every layer
+    and pass, is time no pair needs and carries no operation's name.  The
+    kernel takes no operand: the compiler is then free to make every layer's
+    buffers at the program's start, side by side (6.7 GB of temporaries in the
+    SmallThinker cell's row program for 4.6, which fit), where an operand that
+    ties a buffer to what its gathers read, the whole array or one row of it,
+    is copied on the chip (0.18 s a retrain).  On the ``"xla"`` path, whose
+    products and maps read every row, zeros."""
+    if impl == "xla":
+        return jnp.zeros(shape, dtype)
+    return pl.pallas_call(
+        lambda out: None, out_shape=jax.ShapeDtypeStruct(shape, dtype),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        interpret=impl == "interpret", name="moe_unwritten")()
+
+
+def _live_windows(plan: Plan, impl: str | None, fn, *ins):
+    """The gathers into the buffer, over its live rows: ``fn`` takes
+    ``WINDOW_TILES`` tiles of every ``ins`` [R, ...] (the plan's indices) and
+    gives a tuple of as many rows, written in place into buffers of R rows ->
+    those buffers.  The windows run from the front, as many as hold
+    ``plan.n_active`` tiles (counted on the device: a loop with a traced
+    bound); a last window that would pass the buffer's end starts earlier and
+    writes the rows it shares a second time.  Rows past the live windows are
+    never written (``_buffer``): no pair lies on them."""
+    R, tiles = plan.row_pair.shape[0], plan.tile_group.shape[0]
+    span = min(WINDOW_TILES, tiles)
+    rows = span * (R // tiles)
+
+    def window(x):
+        return jax.ShapeDtypeStruct((rows,) + x.shape[1:], x.dtype)
+
+    def body(i, bufs):
+        at = jnp.minimum(i * rows, R - rows)
+        parts = fn(*(jax.lax.dynamic_slice_in_dim(x, at, rows) for x in ins))
+        return tuple(jax.lax.dynamic_update_slice_in_dim(buf, part, at, 0)
+                     for buf, part in zip(bufs, parts))
+
+    bufs = tuple(_buffer((R,) + part.shape[1:], part.dtype, _impl(impl))
+                 for part in jax.eval_shape(fn, *map(window, ins)))
+    return jax.lax.fori_loop(0, -(-plan.n_active[0] // span), body, bufs)
+
+
+def _tile_maps(plan: Plan, impl: str | None, fn, *ins, name: str):
+    """The maps between the products, over the buffer's live tiles: ``fn``
+    takes one tile of every ``ins`` [R, ...] and gives a tuple of as many
+    rows -> arrays of R rows.  One grid step a tile as in ``gmm``: the tiles
+    past ``plan.n_active`` are neither read nor written (their rows are what
+    the allocator hands out; nothing reads them).  On the ``"xla"`` path
+    ``fn`` of the whole buffer."""
+    impl = _impl(impl)
+    if impl == "xla":
+        return fn(*ins)
+    R, tiles = plan.row_pair.shape[0], plan.tile_group.shape[0]
+    tm = R // tiles
+
+    def tile(x):
+        return jax.ShapeDtypeStruct((tm,) + x.shape[1:], x.dtype)
+
+    def block(x):
+        return pl.BlockSpec(
+            (tm,) + x.shape[1:],
+            lambda i, a: (jnp.minimum(i, a[0] - 1),) + (0,) * (len(x.shape) - 1))
+
+    outs = jax.eval_shape(fn, *map(tile, ins))
+
+    def kernel(active_ref, *refs):
+        @pl.when(pl.program_id(0) < active_ref[0])
+        def _():
+            parts = fn(*(ref[...] for ref in refs[:len(ins)]))
+            for ref, part in zip(refs[len(ins):], parts):
+                ref[...] = part
+
+    moved = sum(x.size * x.dtype.itemsize for x in ins) + sum(
+        tiles * o.size * o.dtype.itemsize for o in outs)
+    return pl.pallas_call(
+        kernel,
+        out_shape=[jax.ShapeDtypeStruct((R,) + o.shape[1:], o.dtype) for o in outs],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(tiles,),
+            in_specs=[block(x) for x in ins],
+            out_specs=[block(o) for o in outs],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        cost_estimate=pl.CostEstimate(
+            flops=8 * max(x.size for x in ins), transcendentals=0,
+            bytes_accessed=moved),
+        interpret=impl == "interpret",
+        name=name,
+    )(plan.n_active, *ins)
+
+
+def _dispatch(plan: Plan, impl: str | None, m):
+    """The forward's way into the buffer: every live row's token's ``m``."""
+    k = plan.dest.shape[1]
+    with jax.named_scope("moe.dispatch"):
+        return _live_windows(
+            plan, impl, lambda pair: (_gather_rows(m, pair // k),), plan.row_pair)[0]
+
+
 def _combine(rows, dest, weights):
     """out[t] = sum_j weights[t, j] * rows[dest[t, j]] (one gather a choice:
     the k gathered copies are never held side by side)."""
@@ -316,18 +445,25 @@ def _combine(rows, dest, weights):
     return out
 
 
-def _cotangent_rows(plan: Plan, w, dout, rows: int):
-    """The backward's way into the buffer: a row's weight, and the output's
-    gradient at the row's token."""
+def _cotangent_rows(plan: Plan, static, w, dout):
+    """The backward's way into the buffer: a row's weight ([R, 1], read from
+    the row's side, zero where no pair lies), and the output's gradient at
+    the row's token as the products read it, plain and times the row's
+    weight; the float32 rows exist a window at a time."""
+    dtype, impl = static
+
+    def rows(pair):
+        row_w = _gather_rows(w.reshape(-1, 1), pair)
+        g = _gather_rows(dout, pair // w.shape[1])
+        return row_w, g.astype(dtype), (g * row_w).astype(dtype)
+
     with jax.named_scope("moe.combine"):
-        row_w = jnp.zeros((rows,), jnp.float32).at[plan.dest.reshape(-1)].set(
-            w.reshape(-1), mode="drop", unique_indices=True)
-        return row_w, _gather_rows(dout, plan.row_token)
+        return _live_windows(plan, impl, rows, plan.row_pair)
 
 
 def _cotangent_tokens(plan: Plan, w, dxs, dw_row):
     """And out of it: the tokens' gradient summed over their held pairs, and
-    each choice's weight's."""
+    each choice's weight's (``dw_row`` [R, 1])."""
     with jax.named_scope("moe.dispatch"):
         dm = _combine(dxs, plan.dest, None)
         return dm, _gather_rows(dw_row, plan.dest.reshape(-1)).reshape(w.shape)
@@ -335,13 +471,14 @@ def _cotangent_tokens(plan: Plan, w, dxs, dw_row):
 
 def _ffn_forward(static, m, w, gate, up, down, plan):
     dtype, impl = static
-    with jax.named_scope("moe.dispatch"):
-        xs = _gather_rows(m.astype(dtype), plan.row_token)
+    xs = _dispatch(plan, impl, m.astype(dtype))
     with jax.named_scope("moe.experts"):
         both = jnp.concatenate([gate.astype(dtype), up.astype(dtype)], axis=2)
         gu = gmm(xs, both, plan, name="moe_gmm_gate_up", impl=impl)
         f = gate.shape[2]
-        a = (act(gu[:, :f]) * gu[:, f:]).astype(dtype)
+        a, = _tile_maps(
+            plan, impl, lambda gu: ((act(gu[:, :f]) * gu[:, f:]).astype(dtype),), gu,
+            name="moe_map_act")
         ys = gmm(a, down.astype(dtype), plan, name="moe_gmm_down", impl=impl)
     with jax.named_scope("moe.combine"):
         out = _combine(ys, plan.dest, w)
@@ -366,24 +503,28 @@ def _ffn_bwd(static, res, dout):
     dtype, impl = static
     xs, gu, w, gate, up, down, plan = res
     held, _, f = gate.shape
-    row_w, g_rows = _cotangent_rows(plan, w, dout, xs.shape[0])
-    with jax.named_scope("moe.experts"):
+    row_w, g_rows, gw_rows = _cotangent_rows(plan, static, w, dout)
+
+    def maps(gu, da, row_w):
         g_act, u = act(gu[:, :f]), gu[:, f:]
         a = g_act * u
         # d(out) / d(weight of a row) = <dout[token], y[row]> = <dout W_down^T, a>
-        da = gmm(g_rows.astype(dtype), down.astype(dtype), plan,
-                 transpose_rhs=True, name="moe_gmm_down_dlhs", impl=impl)
-        dw_row = jnp.sum(da * a, axis=-1)
-        da = da * row_w[:, None]
-        ddown = tgmm(
-            a.astype(dtype), (g_rows * row_w[:, None]).astype(dtype), plan, held,
-            name="moe_tgmm_down", impl=impl)
+        dw_row = jnp.sum(da * a, axis=-1, keepdims=True)
+        da = da * row_w
         # each half is cast BEFORE the concatenation: the chip's compiler
         # splits a cast of the whole into casts it makes itself, which carry
         # no op name, and the fusion they root reads as ``(no scope)``
         dgu = jnp.concatenate(
             [(da * u * act_grad(gu[:, :f])).astype(dtype),
              (da * g_act).astype(dtype)], axis=1)
+        return a.astype(dtype), dw_row, dgu
+
+    with jax.named_scope("moe.experts"):
+        da = gmm(g_rows, down.astype(dtype), plan,
+                 transpose_rhs=True, name="moe_gmm_down_dlhs", impl=impl)
+        a, dw_row, dgu = _tile_maps(
+            plan, impl, maps, gu, da, row_w, name="moe_map_act_grad")
+        ddown = tgmm(a, gw_rows, plan, held, name="moe_tgmm_down", impl=impl)
         both = jnp.concatenate([gate.astype(dtype), up.astype(dtype)], axis=2)
         dxs = gmm(dgu, both, plan, transpose_rhs=True,
                   name="moe_gmm_gate_up_dlhs", impl=impl)
@@ -411,12 +552,12 @@ def _tgmm_block(n: int) -> int:
 
 def _relu2_forward(static, m, w, up, down, plan):
     dtype, impl = static
-    with jax.named_scope("moe.dispatch"):
-        xs = _gather_rows(m.astype(dtype), plan.row_token)
+    xs = _dispatch(plan, impl, m.astype(dtype))
     with jax.named_scope("moe.experts"):
         u = gmm(xs, up.astype(dtype), plan, name="moe_gmm_up", impl=impl)
-        ys = gmm(act2(u).astype(dtype), down.astype(dtype), plan,
-                 name="moe_gmm_down", impl=impl)
+        a, = _tile_maps(
+            plan, impl, lambda u: (act2(u).astype(dtype),), u, name="moe_map_act")
+        ys = gmm(a, down.astype(dtype), plan, name="moe_gmm_down", impl=impl)
     with jax.named_scope("moe.combine"):
         out = _combine(ys, plan.dest, w)
     return out, (xs, u)
@@ -438,17 +579,21 @@ def _relu2_bwd(static, res, dout):
     dtype, impl = static
     xs, u, w, up, down, plan = res
     held = up.shape[0]
-    row_w, g_rows = _cotangent_rows(plan, w, dout, xs.shape[0])
-    with jax.named_scope("moe.experts"):
+    row_w, g_rows, gw_rows = _cotangent_rows(plan, static, w, dout)
+
+    def maps(u, da, row_w):
         a = act2(u)
-        da = gmm(g_rows.astype(dtype), down.astype(dtype), plan,
+        dw_row = jnp.sum(da * a, axis=-1, keepdims=True)
+        da = da * row_w
+        return a.astype(dtype), dw_row, (da * act2_grad(u)).astype(dtype)
+
+    with jax.named_scope("moe.experts"):
+        da = gmm(g_rows, down.astype(dtype), plan,
                  transpose_rhs=True, name="moe_gmm_down_dlhs", impl=impl)
-        dw_row = jnp.sum(da * a, axis=-1)
-        da = da * row_w[:, None]
-        ddown = tgmm(
-            a.astype(dtype), (g_rows * row_w[:, None]).astype(dtype), plan, held,
-            name="moe_tgmm_down", impl=impl, block_n=_tgmm_block(down.shape[2]))
-        du = (da * act2_grad(u)).astype(dtype)
+        a, dw_row, du = _tile_maps(
+            plan, impl, maps, u, da, row_w, name="moe_map_act_grad")
+        ddown = tgmm(a, gw_rows, plan, held, name="moe_tgmm_down", impl=impl,
+                     block_n=_tgmm_block(down.shape[2]))
         dxs = gmm(du, up.astype(dtype), plan, transpose_rhs=True,
                   name="moe_gmm_up_dlhs", impl=impl)
         dup = tgmm(xs, du, plan, held, name="moe_tgmm_up", impl=impl,

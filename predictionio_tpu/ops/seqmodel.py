@@ -1470,6 +1470,10 @@ def init_state(cfg: SeqConfig, seed: int) -> tuple[dict, dict]:
         # the routing counters of the step, summed beside the gradients
         acc["expert_pairs"] = jnp.zeros((routed, cfg.experts_held), jnp.int32)
         acc["pairs_total"] = jnp.zeros((), jnp.int32)
+        # rows of the pair buffer the layers' work ran over (the live tiles'),
+        # and the rows the buffer has
+        acc["rows_live"] = jnp.zeros((routed,), jnp.int32)
+        acc["rows_planned"] = jnp.zeros((), jnp.int32)
     if cfg.loop_steps > 1:
         # the exits' sums over the step's positions
         acc["exit_loss"] = jnp.zeros((cfg.loop_steps,), jnp.float32)
@@ -1495,9 +1499,15 @@ def accumulate_row(cfg: SeqConfig, state: dict, acc: dict, tokens, seg):
     with jax.named_scope("seq.accumulate"):
         out = {"g": g, "loss": acc["loss"] + loss, "count": acc["count"] + count}
         if "expert_pairs" in acc:
-            out["expert_pairs"] = acc["expert_pairs"] + probe.pop("expert_pairs")
+            pairs = probe.pop("expert_pairs")
+            out["expert_pairs"] = acc["expert_pairs"] + pairs
             out["pairs_total"] = acc["pairs_total"] + (
                 cfg.experts_per_token * jnp.sum(seg != PAD_SEGMENT, dtype=jnp.int32))
+            out["rows_live"] = acc["rows_live"] + cfg.moe_tile * jnp.sum(
+                moe.expert_tiles(pairs, cfg.moe_tile), axis=-1)
+            out["rows_planned"] = acc["rows_planned"] + moe.plan_rows(
+                tokens.shape[0], cfg.experts_per_token, cfg.experts_held,
+                cfg.moe_tile)
         if "exit_loss" in acc:
             for key in ("exit_loss", "exit_mass", "exit_entropy"):
                 out[key] = acc[key] + probe.pop(key)
@@ -1555,6 +1565,11 @@ def apply_step(opt: AdamW, state: dict, acc: dict):
         record["moe_pairs_held"] = jnp.sum(pairs, axis=-1)
         record["moe_pairs_total"] = jnp.full(
             pairs.shape[:1], acc["pairs_total"], jnp.int32)
+        # the pair buffer's rows a layer's gathers and maps ran over (its
+        # live tiles', summed over the step's rows) and the rows it has
+        record["moe_rows_live"] = acc["rows_live"]
+        record["moe_rows_planned"] = jnp.full(
+            pairs.shape[:1], acc["rows_planned"], jnp.int32)
     if "exit_loss" in acc:
         # an exit's mean cross-entropy and mean mass over the step's
         # positions, the exit distribution's mean entropy, and the step's

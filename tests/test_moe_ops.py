@@ -114,6 +114,111 @@ def test_no_pair_is_dropped_when_every_token_chooses_the_same_experts(impl):
     assert float(jnp.abs(want).max()) > 1e-2
 
 
+def _live_load(load: str):
+    """(tokens, logits [N, 32]) of a layer of 16 held experts of 32, 3 a
+    token, in tiles of 4 pairs: ``none`` no token chooses a held expert (16
+    tiles of zeros, one window of 16); ``even`` seeded logits; ``same`` every
+    token chooses the same three held experts (61 of the buffer's 64 tiles
+    are live: every window runs); ``odd`` the same on 60 tokens, whose 61
+    tiles the window does not divide (the last window starts early)."""
+    N = 60 if load == "odd" else 64
+    logits = jax.random.normal(jax.random.PRNGKey(7), (N, 32))
+    if load == "none":
+        logits = logits.at[:, :16].add(-50.0)
+    elif load != "even":
+        logits = 1e-3 * logits + jnp.zeros((N, 32)).at[:, jnp.array([1, 5, 11])].set(4.0)
+    return N, logits
+
+
+def _live_case(load: str, form: str, seed: int):
+    """(plan, run) of that load: ``run(impl)`` gives ``out`` and every
+    gradient of ``expert_ffn`` (``gated``) or ``relu2_ffn`` under the plan."""
+    N, logits = _live_load(load)
+    held, k, tile, D, F = 16, 3, 4, 32, 16
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    m, dout = jax.random.normal(ks[0], (N, D)), jax.random.normal(ks[1], (N, D))
+    gate, up = (0.2 * jax.random.normal(key, (held, D, F)) for key in ks[2:4])
+    down = 0.2 * jax.random.normal(ks[4], (held, F, D))
+    idx, w = moe.route(logits, k)
+    plan = moe.make_plan(idx, jnp.ones(N, bool), 0, held, tile)
+
+    def run(impl):
+        static = (jnp.bfloat16, impl)
+        if form == "gated":
+            out, vjp = jax.vjp(
+                lambda *a: moe.expert_ffn(static, *a, plan), m, w, gate, up, down)
+        else:
+            out, vjp = jax.vjp(
+                lambda *a: moe.relu2_ffn(static, *a, plan), m, w, up, down)
+        return (out,) + vjp(dout)
+
+    return plan, run
+
+
+@pytest.mark.parametrize("impl", ["xla", "interpret"])
+@pytest.mark.parametrize("form", ["gated", "relu2"])
+@pytest.mark.parametrize("load", ["none", "even", "same", "odd"])
+def test_live_windows_are_the_whole_buffer_bit_for_bit(monkeypatch, load, form, impl):
+    """The gathers into the pair buffer run over windows of ``WINDOW_TILES``
+    tiles, as many as hold the live tiles (``moe._live_windows``), and the
+    maps between the products over the live tiles, one kernel step a tile
+    (``moe._tile_maps``): ``out`` and every gradient of ``expert_ffn`` and
+    ``relu2_ffn`` are bit for bit those of ONE window that is the whole
+    buffer and of maps of the whole buffer (every row gathered and mapped, a
+    pair on it or not), but the weights' gradient under the kernels: a row's
+    ``<da, a>`` is a float32 sum over the expert width, which a kernel makes
+    over its tile in its own order (the same terms; the last bits move)."""
+    plan, run = _live_case(load, form, 3)
+    N, tile = plan.dest.shape[0], 4
+    tiles = plan.tile_group.shape[0]
+    assert tiles == (61 if load == "odd" else 64)
+    live = int(plan.n_active[0])
+    assert live == int(moe.expert_tiles(plan.counts, tile).sum())
+    if load == "none":
+        assert live == 16 and int(plan.counts.sum()) == 0
+    elif load == "even":
+        assert 16 < live < 48
+    else:
+        assert live == 3 * (N // tile) + 13 > tiles - 16  # the last window too
+
+    monkeypatch.setattr(moe, "WINDOW_TILES", 16)
+    windows = run(impl)
+    monkeypatch.setattr(moe, "WINDOW_TILES", tiles)
+    monkeypatch.setattr(moe, "_tile_maps", lambda plan, impl, fn, *ins, name: fn(*ins))
+    whole = run(impl)
+    assert len(windows) == (6 if form == "gated" else 5)
+    for at, (got, want) in enumerate(zip(windows, whole)):
+        assert got.dtype == want.dtype == jnp.float32
+        got, want = np.asarray(got), np.asarray(want)
+        if at == 2 and impl == "interpret":  # (out, dm, dw, ...)
+            np.testing.assert_allclose(got, want, rtol=0, atol=1e-6 * np.abs(want).max())
+        else:
+            np.testing.assert_array_equal(got, want)
+    if load != "none":
+        assert all(float(jnp.abs(g).max()) > 0 for g in windows)
+
+
+@pytest.mark.parametrize("form", ["gated", "relu2"])
+@pytest.mark.parametrize("load", ["none", "even", "same", "odd"])
+def test_nothing_reads_a_row_the_live_windows_did_not_write(monkeypatch, load, form):
+    """Beside the kernels the loops' buffers start as the allocator hands them
+    (``moe._buffer``: a kernel that writes nothing).  With every such buffer
+    started as NaN instead, ``out`` and every gradient are finite and bit for
+    bit what they are from zeros: the products skip the tiles past the live
+    ones, the maps stay within their rows, and no token's ``dest`` points
+    past the live rows."""
+    _, run = _live_case(load, form, 4)
+    monkeypatch.setattr(
+        moe, "_buffer", lambda shape, dtype, impl: jnp.zeros(shape, dtype))
+    zeros = run("interpret")
+    monkeypatch.setattr(
+        moe, "_buffer", lambda shape, dtype, impl: jnp.full(shape, jnp.nan, dtype))
+    poisoned = run("interpret")
+    for got, want in zip(poisoned, zeros):
+        assert np.isfinite(np.asarray(got)).all()
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_the_weights_are_a_softmax_over_the_chosen():
     logits = jax.random.normal(jax.random.PRNGKey(2), (10, 8))
     idx, w = moe.route(logits, 3)
@@ -501,6 +606,13 @@ def test_training_steps_are_the_references_adamw(f32_matmuls):
         assert got["moe_pairs_held"].tolist() == want["moe_pairs_held"]
         assert got["moe_pairs_total"].tolist() == [want["moe_pairs_total"]] * 2
         assert got["moe_expert_pairs"].sum(-1).tolist() == want["moe_pairs_held"]
+        # one row a step: the live tiles of its two layers' pair buffers (an
+        # expert's pairs in whole tiles, one tile for an expert with none)
+        tile = cfg.moe_tile
+        tiles = np.maximum(-(-np.asarray(got["moe_expert_pairs"]) // tile), 1).sum(-1)
+        assert got["moe_rows_live"].tolist() == (tile * tiles).tolist()
+        assert got["moe_rows_planned"].tolist() == [
+            moe.plan_rows(128, cfg.experts_per_token, cfg.experts_held, tile)] * 2
         assert float(got["grad_norm"]) == pytest.approx(want["grad_norm"], rel=1e-3)
     # the first step's choices, by history, are the program's by row
     at = 0
@@ -514,3 +626,4 @@ def test_training_steps_are_the_references_adamw(f32_matmuls):
         assert gap <= 0.05 * moved + 1e-7, name
     # the accumulator is zeroed between steps, counters and all
     assert int(acc["pairs_total"]) == 0 and not np.asarray(acc["expert_pairs"]).any()
+    assert int(acc["rows_planned"]) == 0 and not np.asarray(acc["rows_live"]).any()

@@ -665,6 +665,15 @@ def test_counters_and_tags_reach_the_stages_extra_and_the_trace_ring(trained):
     assert counters["moe_routed_layers"] == 2 and counters["moe_experts_held"] == 4
     assert counters["moe_pairs_total"] == int(record["moe_pairs_total"].sum())
     assert counters["moe_pairs_held"] == int(record["moe_pairs_held"].sum())
+    # the pair buffers' rows in live tiles (the buffer work done) of all they
+    # have: a step and layer in the record, the retrain's sums and share here
+    assert record["moe_rows_live"].shape == record["moe_rows_planned"].shape == (2, 2)
+    assert counters["moe_rows_live"] == int(record["moe_rows_live"].sum()) > 0
+    assert counters["moe_rows_planned"] == int(record["moe_rows_planned"].sum())
+    assert counters["moe_rows_live_pct"] == pytest.approx(
+        100.0 * counters["moe_rows_live"] / counters["moe_rows_planned"])
+    assert (record["moe_rows_live"] > 0).all()  # a tile an expert at the least
+    assert (record["moe_rows_live"] <= record["moe_rows_planned"]).all()
     for s in range(2):
         for layer in range(2):
             at = f".step{s}.layer{layer}"

@@ -362,14 +362,16 @@ def test_the_other_blocks_keep_their_accumulator_and_their_record(block):
     cfg = _config(block)
     assert cfg.loop_steps == 1
     state, acc = jax.eval_shape(lambda: seqmodel.init_state(cfg, 3))
-    routed = {"expert_pairs", "pairs_total"} if block == "smallthinker" else set()
+    routed = ({"expert_pairs", "pairs_total", "rows_live", "rows_planned"}
+              if block == "smallthinker" else set())
     assert set(acc) == {"g", "loss", "count"} | routed
     assert not [k for k in state["params"] if k.startswith("exit_")]
     record = jax.eval_shape(
         lambda s, a: seqmodel.apply_step(seqmodel.AdamW(), s, a), state, acc)[2]
     assert set(record) == {
         "loss", "tokens", "grad_norm", "tensor_grad_norm", "tensor_grad_probe"} | (
-        {"moe_expert_pairs", "moe_pairs_held", "moe_pairs_total"} if routed else set())
+        {"moe_expert_pairs", "moe_pairs_held", "moe_pairs_total", "moe_rows_live",
+         "moe_rows_planned"} if routed else set())
 
 
 # ---------------------------------------------------------------------------

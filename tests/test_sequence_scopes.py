@@ -80,6 +80,18 @@ def _operations(jaxpr, outer=""):
                 (math.prod(v.aval.shape) for v in eqn.outvars), default=0)
 
 
+def _loops(jaxpr, outer=""):
+    """(whole name stack, the operations inside) of every loop with a traced
+    bound (``while``) under ``seq.moe``, wherever it is nested."""
+    for eqn in jaxpr.eqns:
+        stack = f"{outer}/{eqn.source_info.name_stack}"
+        if eqn.primitive.name == "while" and "seq.moe" in stack:
+            yield stack, [op for sub in _subjaxprs(eqn) for op in _operations(sub, stack)]
+            continue
+        for sub in _subjaxprs(eqn):
+            yield from _loops(sub, stack)
+
+
 def _program(cfg, program: str):
     if program == "init_state":
         return jax.make_jaxpr(lambda: seqmodel.init_state(cfg, 3))()
@@ -192,6 +204,44 @@ def test_the_shared_expert_is_a_component_of_the_routed_layers_scope():
     for block in ("olmo_hybrid", "falcon_h1", "smallthinker", "ouro"):
         older = _operations(_program(_config(block), "accumulate_row").jaxpr)
         assert not [s for _, s, _ in older if "moe.shared" in s], block
+
+
+@pytest.mark.parametrize("block", ["smallthinker", "nemotron_h"])
+def test_the_live_window_loops_lie_inside_the_routed_layers_components(block):
+    """The loops over the pair buffer's live windows (``moe._live_windows``)
+    are work of the components that were there: every window's write
+    (``dynamic_update_slice``) and every gather of rows into the buffer lies
+    under ``seq.moe`` and ONE of ``moe.dispatch`` (the forward's gather, twice:
+    the recomputed one too) and ``moe.combine`` (the backward's gather);
+    nothing of a loop carries another name or none.  The maps between the
+    products are no loop (``moe._tile_maps``: kernels under ``moe.experts``
+    on the chip, the whole buffer's map here)."""
+    loops = list(_loops(_program(_config(block), "accumulate_row").jaxpr))
+    assert loops and all("seq.moe" in stack for stack, _ in loops)
+    writes = []
+    for stack, ops in loops:
+        # one top-level scope, one component: the loop's and all it holds
+        assert len(set(TOP_LEVEL.findall(stack))) == 1, stack
+        assert len(set(re.findall(r"moe\.\w+", stack))) == 1, stack
+        assert all(s.startswith(stack) and set(re.findall(r"(?:seq|moe)\.\w+", s)) == set(
+            re.findall(r"(?:seq|moe)\.\w+", stack)) for _, s, _ in ops), stack
+        assert [p for p, _, _ in ops].count("dynamic_update_slice") >= 1
+        writes.append(stack)
+    by_component = {
+        c: [s for s in writes if c in s]
+        for c in ("moe.dispatch", "moe.combine")}
+    assert sum(map(len, by_component.values())) == len(writes)
+    for component, mine in by_component.items():
+        assert mine, component
+    assert any("rematted_computation" in s for s in by_component["moe.dispatch"])
+    # the backward's own gather (not a recomputed forward's) is moe.combine's
+    assert all("rematted_computation" in s for s in by_component["moe.dispatch"]
+               if "transpose" in s)
+    assert all("transpose" in s and "rematted_computation" not in s
+               for s in by_component["moe.combine"])
+    # and no block without routed layers holds such a loop
+    assert not [s for _, s, _ in _operations(
+        _program(_config("falcon_h1"), "accumulate_row").jaxpr) if "moe." in s]
 
 
 @pytest.mark.parametrize("call", [

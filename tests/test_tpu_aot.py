@@ -290,6 +290,40 @@ def _assert_splash_alone(text: str):
         assert name not in text, name
 
 
+def _assert_buffer_work_follows_the_live_tiles(text: str, rows: int):
+    """A routed row program as the chip compiles it gathers no array of the
+    pair buffer's ``rows`` rows in one operation: the gathers into the buffer
+    run window by window inside loops under ``moe.dispatch`` (the forward's,
+    the recomputed forward's) and ``moe.combine`` (the backward's;
+    ``moe._live_windows``: as many windows as hold the live tiles), and the
+    maps between the products are kernels on the products' grid of tiles
+    under ``moe.experts`` (``moe._tile_maps``), where no loop is and no
+    operation of the compiler's own maps an array of ``rows`` rows."""
+    import re
+
+    from predictionio_tpu.ops import moe
+
+    whole = re.findall(rf"= \w+\[{rows},\d+\]\S* gather\(", text)
+    assert not whole, whole[:3]
+    for component in ("moe.dispatch", "moe.combine"):
+        assert re.search(rf'{component}/while"', text), component
+    assert not re.search(r'moe\.experts/while"', text)
+    for name in ("moe_map_act", "moe_map_act_grad"):
+        assert re.search(rf'moe\.experts/{name}/pallas_call', text), name
+    # between the products, nothing but the kernels writes ``rows`` rows
+    # (the compiler's relayouts of the [rows, 1] weights apart)
+    mapped = re.findall(
+        rf"= \w+\[{rows},(\d+)\]\S* (?:fusion|convert|multiply|maximum|select)\(", text)
+    assert not [n for n in mapped if int(n) > 1], mapped[:3]
+    # the loops' buffers start as the allocator hands them: a kernel that
+    # writes nothing each, and no broadcast over the buffer's rows
+    assert "moe_unwritten" in text
+    assert not re.findall(rf"= \w+\[{rows},\d+\]\S* broadcast\(", text)
+    windows = re.findall(
+        rf"= \w+\[(\d+),\d+\]\S* fusion\([^\n]*moe\.(?:dispatch|combine)/while/body/jit\(_take\)/gather", text)
+    assert windows and {int(n) for n in windows} == {moe.WINDOW_TILES * 256}, set(windows)
+
+
 def _assert_every_named_operation_is_scoped(text: str):
     """In a row program as the chip compiles it, every op name the PROGRAM
     wrote (they start with its ``jit(``; the compiler's own ``gather`` or an
@@ -508,10 +542,18 @@ def test_smallthinker_row_program_fits_beside_its_arguments(v5e):
         assert name in text, name
     _assert_splash_alone(text)
     _assert_every_named_operation_is_scoped(text)
+    _assert_buffer_work_follows_the_live_tiles(text, 102_400)
     plan = compiled.memory_analysis()
     assert plan.argument_size_in_bytes == pytest.approx(16 * 496_376_320, rel=1e-3)
     assert plan.alias_size_in_bytes >= 0.999 * plan.argument_size_in_bytes  # donated
     assert plan.argument_size_in_bytes + plan.temp_size_in_bytes < 16_909_336_064
+    # ABOVE PR 41's plan (4.54 GB of temporaries; this program's 6.68): the
+    # kernel that leaves the loops' buffers unwritten takes no operand, so the
+    # compiler makes every layer's buffers at the program's start, side by
+    # side, in the room the arguments leave; an operand that ties a buffer to
+    # what its gathers read holds the plan at 4.56 GB and is copied on the
+    # chip (0.18 s a retrain: PERF.md section 6, PR 42 and PR 43)
+    assert plan.temp_size_in_bytes < 6.9 * 10**9
 
 
 def test_ouro_row_program_fits_beside_its_arguments(v5e):
@@ -668,10 +710,21 @@ def test_nemotron_row_program_fits_beside_its_arguments(v5e):
         assert name in text, name
     _assert_splash_alone(text)
     _assert_every_named_operation_is_scoped(text)
+    _assert_buffer_work_follows_the_live_tiles(text, 53_248)
     plan = compiled.memory_analysis()
     assert plan.argument_size_in_bytes == pytest.approx(16 * 760_856_416, rel=1e-3)
     assert plan.alias_size_in_bytes >= 0.999 * plan.argument_size_in_bytes  # donated
     assert plan.argument_size_in_bytes + plan.temp_size_in_bytes < 16_909_336_064
+    # the compiler plans into the room the arguments leave (4.11 GB of
+    # temporaries in 4.47 GB; PR 41's program planned 3.62): held to that
+    # room by 0.8 GiB of ballast beside the arguments, this one plans under it
+    squeezed = _compile(
+        jax.jit(lambda state, acc, tokens, seg, ballast: (
+            seqmodel.accumulate_row(cfg, state, acc, tokens, seg), ballast + 1.0),
+            donate_argnums=(0, 1)),
+        state, acc, sds((row_len,), jnp.int32), sds((row_len,), jnp.int32),
+        sds((int(0.8 * 2**28),), jnp.float32))
+    assert squeezed.memory_analysis().temp_size_in_bytes < 3.62 * 10**9
     # the first step's probe of the experts (forward, and their backward on
     # the first row) runs beside the same resident state
     resident = plan.argument_size_in_bytes
