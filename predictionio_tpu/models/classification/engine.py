@@ -79,8 +79,6 @@ class DataSourceParams:
     app_name: str = "default"
     eval_k: int | None = None
 
-    params_aliases = {"appName": "app_name", "evalK": "eval_k"}
-
 
 _ATTRS = ("attr0", "attr1", "attr2")
 
@@ -194,11 +192,7 @@ class LogisticRegressionParams:
     learning_rate: float = 0.5
     num_iterations: int = 300
 
-    params_aliases = {
-        "learningRate": "learning_rate",
-        "numIterations": "num_iterations",
-        "lambda": "reg",
-    }
+    params_aliases = {"lambda": "reg"}
 
 
 class LogisticRegressionAlgorithm(Algorithm):

@@ -50,8 +50,6 @@ class Query:
     white_list: tuple[str, ...] | None = None
     black_list: tuple[str, ...] | None = None
 
-    params_aliases = {"whiteList": "white_list", "blackList": "black_list"}
-
 
 @dataclass(frozen=True)
 class ItemScore:
@@ -103,12 +101,6 @@ class DataSourceParams:
     channel_name: str | None = None
     #: interaction events read for training ("view" + "buy" + optional "rate")
     event_names: tuple[str, ...] = ("view", "buy")
-
-    params_aliases = {
-        "appName": "app_name",
-        "channelName": "channel_name",
-        "eventNames": "event_names",
-    }
 
 
 class ECommDataSource(DataSource):
@@ -201,15 +193,7 @@ class ECommAlgorithmParams:
     #: events used to build the training matrix; "rate" keeps its rating
     train_events: tuple[str, ...] = ("view", "buy")
 
-    params_aliases = {
-        "appName": "app_name",
-        "unseenOnly": "unseen_only",
-        "seenEvents": "seen_events",
-        "similarEvents": "similar_events",
-        "numIterations": "num_iterations",
-        "lambda": "reg",
-        "trainEvents": "train_events",
-    }
+    params_aliases = {"lambda": "reg"}
 
 
 @dataclass

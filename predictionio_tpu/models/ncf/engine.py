@@ -76,20 +76,6 @@ class NCFAlgorithmParams:
     pretrain: str = ""
     seed: int = 3
 
-    params_aliases = {
-        "embedDim": "embed_dim",
-        "mlpLayers": "mlp_layers",
-        "learningRate": "learning_rate",
-        "numEpochs": "num_epochs",
-        "batchSize": "batch_size",
-        "positiveThreshold": "positive_threshold",
-        "negativesPerPositive": "negatives_per_positive",
-        "negPower": "neg_power",
-        "itemBias": "item_bias",
-        "weightDecay": "weight_decay",
-        "shardServing": "shard_serving",
-    }
-
     def __post_init__(self):
         if self.pretrain not in ("", "als"):
             raise ValueError(f"unknown pretrain {self.pretrain!r}")

@@ -131,12 +131,6 @@ class DataSourceParams:
     eval_params: EvalParams | None = None
     buy_rating: float = 4.0  # implicit rating assigned to `buy` events
 
-    params_aliases = {
-        "appName": "app_name",
-        "channelName": "channel_name",
-        "evalParams": "eval_params",
-    }
-
 
 class RatingsDataSource(DataSource):
     params_class = DataSourceParams
@@ -308,11 +302,7 @@ class ALSAlgorithmParams:
     shard_serving: bool = False
 
     # reference engine.json spellings (customize-serving/engine.json:14-21)
-    params_aliases = {
-        "lambda": "reg",
-        "numIterations": "num_iterations",
-        "shardServing": "shard_serving",
-    }
+    params_aliases = {"lambda": "reg"}
 
 
 @dataclass

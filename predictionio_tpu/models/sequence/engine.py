@@ -80,14 +80,6 @@ class SequenceDataSourceParams:
     entity_type: str = "user"
     target_entity_type: str = "item"
 
-    params_aliases = {
-        "appName": "app_name",
-        "channelName": "channel_name",
-        "eventNames": "event_names",
-        "entityType": "entity_type",
-        "targetEntityType": "target_entity_type",
-    }
-
 
 @dataclass
 class SequenceData:
@@ -162,14 +154,6 @@ class SequencePreparatorParams:
     vocab_size: int = 50176
     #: the first id of that share
     vocab_start: int = 0
-
-    params_aliases = {
-        "rowLen": "row_len",
-        "maxLen": "max_len",
-        "rowsPerStep": "rows_per_step",
-        "vocabSize": "vocab_size",
-        "vocabStart": "vocab_start",
-    }
 
     def __post_init__(self):
         if self.max_len > self.row_len:
@@ -375,54 +359,6 @@ class SequenceAlgorithmParams:
     moe_shared_expert_columns: int = 0
     routed_scaling_factor: float = 1.0
 
-    params_aliases = {
-        "moeSharedExpertColumns": "moe_shared_expert_columns",
-        "routedScalingFactor": "routed_scaling_factor",
-        "totalUtSteps": "total_ut_steps",
-        "exitBeta": "exit_beta",
-        "moeNumPrimaryExperts": "moe_num_primary_experts",
-        "moeExpertsHeld": "moe_experts_held",
-        "moeExpertStart": "moe_expert_start",
-        "moeNumActivePrimaryExperts": "moe_num_active_primary_experts",
-        "moeFfnHiddenSize": "moe_ffn_hidden_size",
-        "slidingWindowSize": "sliding_window_size",
-        "numKeyValueHeads": "num_key_value_heads",
-        "ropeTheta": "rope_theta",
-        "mambaNHeads": "mamba_n_heads",
-        "mambaNGroups": "mamba_n_groups",
-        "mambaDHead": "mamba_d_head",
-        "mambaDState": "mamba_d_state",
-        "mambaDConv": "mamba_d_conv",
-        "mambaChunkSize": "mamba_chunk_size",
-        "embeddingMultiplier": "embedding_multiplier",
-        "lmHeadMultiplier": "lm_head_multiplier",
-        "ssmInMultiplier": "ssm_in_multiplier",
-        "ssmMultipliers": "ssm_multipliers",
-        "ssmOutMultiplier": "ssm_out_multiplier",
-        "attentionInMultiplier": "attention_in_multiplier",
-        "attentionOutMultiplier": "attention_out_multiplier",
-        "keyMultiplier": "key_multiplier",
-        "mlpMultipliers": "mlp_multipliers",
-        "hiddenSize": "hidden_size",
-        "layerTypes": "layer_types",
-        "numAttentionHeads": "num_attention_heads",
-        "headDim": "head_dim",
-        "linearNumHeads": "linear_num_heads",
-        "linearKeyHeadDim": "linear_key_head_dim",
-        "linearValueHeadDim": "linear_value_head_dim",
-        "linearConvKernelDim": "linear_conv_kernel_dim",
-        "linearAllowNegEigval": "linear_allow_neg_eigval",
-        "intermediateSize": "intermediate_size",
-        "vocabSize": "vocab_size",
-        "vocabStart": "vocab_start",
-        "rmsNormEps": "rms_norm_eps",
-        "rowsPerStep": "rows_per_step",
-        "stepsPerRetrain": "steps_per_retrain",
-        "learningRate": "learning_rate",
-        "adamEps": "adam_eps",
-        "weightDecay": "weight_decay",
-    }
-
 
 @dataclass
 class SequenceModel:
@@ -560,11 +496,7 @@ class SequenceAlgorithm(Algorithm):
             # record is a few hundred small arrays, 0.4 ms each one by one
             records, probes = jax.device_get((records, probes))
             record = jax.tree.map(lambda *xs: np.stack(xs), *records)
-            first = jax.tree.map(lambda *xs: np.stack(xs), *probes)
-            if isinstance(first, dict):  # a routed block names its own
-                record.update(first)
-            else:
-                record[seqmodel.PROBE_NAME[cfg.layer_types[0]]] = first
+            record.update(jax.tree.map(lambda *xs: np.stack(xs), *probes))
             span.tags = {
                 "bytes": int(sum(
                     v.nbytes for v in jax.tree.leaves(record))),
@@ -673,10 +605,8 @@ def _loop_tags(cfg) -> dict:
 
     if set(cfg.layer_types) & set(seqmodel.SUBLAYER_KINDS):
         return {
-            tag: cfg.layer_types.count(kind) for tag, kind in (
-                ("layers_state_space", seqmodel.STATE_SPACE),
-                ("layers_attention", seqmodel.GROUPED_ATTENTION),
-                ("layers_experts", seqmodel.SHARED_EXPERTS))}
+            seqmodel.LAYER_KINDS[kind].sublayer_tag: cfg.layer_types.count(kind)
+            for kind in seqmodel.SUBLAYER_KINDS}
     if cfg.loop_steps == 1:
         return {}
     return {"loop_steps": cfg.loop_steps, "exits": cfg.loop_steps}

@@ -50,12 +50,6 @@ class Query:
     white_list: tuple[str, ...] | None = None
     black_list: tuple[str, ...] | None = None
 
-    params_aliases = {
-        "categoryBlackList": "category_black_list",
-        "whiteList": "white_list",
-        "blackList": "black_list",
-    }
-
 
 @dataclass(frozen=True)
 class ItemScore:
@@ -111,13 +105,6 @@ class DataSourceParams:
     #: variants, "user" for the recommended-user variant (users viewing
     #: users, recommended-user/DataSource.scala)
     target_entity_type: str = "item"
-
-    params_aliases = {
-        "appName": "app_name",
-        "channelName": "channel_name",
-        "eventNames": "event_names",
-        "targetEntityType": "target_entity_type",
-    }
 
 
 class SimilarProductDataSource(DataSource):
@@ -186,7 +173,7 @@ class ALSAlgorithmParams:
     alpha: float = 1.0
     seed: int = 3
 
-    params_aliases = {"numIterations": "num_iterations", "lambda": "reg"}
+    params_aliases = {"lambda": "reg"}
 
 
 @dataclass
@@ -574,8 +561,6 @@ class UserQuery:
     num: int = 10
     white_list: tuple[str, ...] | None = None
     black_list: tuple[str, ...] | None = None
-
-    params_aliases = {"whiteList": "white_list", "blackList": "black_list"}
 
 
 class RecommendedUserAlgorithm(ALSAlgorithm):

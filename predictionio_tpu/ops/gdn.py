@@ -49,8 +49,11 @@ NO_SEGMENT = -2
 
 
 def use_pallas() -> bool:
-    """The sequential pass runs in the Pallas kernels on a TPU and as the
-    ``lax.scan`` everywhere else (the way ``ops/als._use_pallas`` chooses)."""
+    """The sequence path's ONE backend choice: on a TPU the kernels (the
+    sequential pass of the delta rule here and of ``ops/ssd``, the grouped
+    products of ``ops/moe``, ``seqmodel._attend``'s splash attention),
+    everywhere else their plain forms (the way ``ops/als._use_pallas``
+    chooses, which also asks the rank)."""
     return jax.default_backend() == "tpu"
 
 

@@ -65,6 +65,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from predictionio_tpu.ops.gdn import use_pallas
+
 HIGHEST = jax.lax.Precision.HIGHEST
 
 #: scoped VMEM the kernels may use: a weight block [2560, 1536] bf16 twice
@@ -187,7 +189,7 @@ def make_plan(idx, valid, start: int, held: int, tile: int) -> Plan:
 
 
 def _impl(impl: str | None) -> str:
-    return impl or ("pallas" if jax.default_backend() == "tpu" else "xla")
+    return impl or ("pallas" if use_pallas() else "xla")
 
 
 def _gmm_kernel(group_ref, active_ref, lhs_ref, rhs_ref, out_ref, *, transpose_rhs):

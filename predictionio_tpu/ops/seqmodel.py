@@ -1,80 +1,12 @@
 """Hybrid sequence models, trainable on packed rows of tokens: five blocks.
 
-Layers follow ``layer_types``; the block's FORM is a property of the kind.
-
-``"linear_attention"`` / ``"full_attention"`` (the Olmo-Hybrid block): one
-token mixer and a SwiGLU MLP a layer with the OLMo 2/3 residual form
-``x + RMSNorm(f(x))``:
-
-* linear attention: q, k, v projections, each through a causal depthwise
-  convolution and SiLU; q, k L2-normalised per head; ``beta = 2 sigmoid(.)``
-  (negative eigenvalues) or ``sigmoid(.)``; ``g = -exp(A_log) softplus(. +
-  dt_bias)``; the gated delta rule (``ops/gdn.py``); per-head RMSNorm of the
-  output gated by ``SiLU(W_g x)``; output projection.
-* full attention: per-head RMSNorm on q and k, no positional encoding,
-  causal softmax within the segment; on a TPU the library's block-sparse
-  splash attention under a causal mask (``_attend``: no T x T score matrix,
-  blocks above the diagonal skipped), elsewhere its ``jax.numpy`` form.
-
-``"parallel_ssm_attention"`` (the Falcon-H1 block): pre-norm, and TWO mixers
-that read one normed input ``h = RMSNorm(x)``, their outputs scaled and
-summed, ``x + ssm(h) + attn(h)``, then ``x + mlp(RMSNorm(x))``; muP's forward
-multipliers (``MuP``) where the published model has them:
-
-* state-space mixer: one projection to ``z | x | B | C | dt`` (each zone
-  times its multiplier), a causal depthwise convolution with a bias and SiLU
-  over ``x | B | C``, ``Delta = softplus(dt + dt_bias)``, Mamba-2's selective
-  state space in its chunked form (``ops/ssd.py``) with the ``D`` skip,
-  ``RMSNorm(y * SiLU(z))`` over each group's channels, output projection.
-* attention mixer: grouped-query attention (a KV head's query heads in one
-  multi-query call of the same attention kernel), rotary positions over the
-  whole head that RESTART at every segment of a packed row, no q / k norm.
-
-``"global_attention_moe"`` / ``"sliding_attention_moe"`` (the SmallThinker
-block): pre-norm, grouped-query attention with no q / k norm, then a layer of
-routed experts (``ops/moe.py``) in place of the MLP, ``x1 = x + attn(h)``,
-``x2 = x1 + experts(RMSNorm(x1))`` with ``h = RMSNorm(x)``:
-
-* the router reads ``h``, BEFORE attention (a deployment fetches the chosen
-  experts while attention runs): logits over all experts, the ``k`` largest,
-  weights ``softmax`` over those ``k``; experts ReGLU,
-  ``W_down(relu(W_gate m) * W_up m)``.
-* global kind: causal softmax attention over the whole segment, NO rotary
-  positions, through the attention kernel the other blocks call.
-* sliding kind: rotary positions (restarting at every segment) and a window:
-  query t sees key s iff ``0 <= t - s < window`` in its segment: the same
-  kernel under a local mask (blocks outside the window are skipped; segment
-  ids mask inside a block, no block is skipped for them).
-
-``"sandwich_attention"`` (the Ouro block, a looped model): a norm before AND
-after each sublayer, inside the residual, ``x1 = x + N2(attn(N1(x)))``,
-``x2 = x1 + N4(mlp(N3(x1)))``; attention is plain multi-head (the grouped
-path with one KV head a query head), rotary positions over the whole head
-that restart at every segment, no q / k norm; the MLP SwiGLU.  With
-``loop_steps`` R > 1 the layer list is applied R times with the SAME tensors
-(``trunk``): the one final norm closes every pass and its OUTPUT is both the
-pass's exit state and the next pass's input.  Every exit state goes through
-the one head and through one gate, ``lam_t = sigmoid(x_t . w_g + b_g)``; the
-exit distribution of a position is ``p_t = lam_t prod_{j<t} (1 - lam_j)``,
-the last pass taking the rest, and the loss the expected cross-entropy under
-it less ``exit_beta`` times its entropy (``looped_row_grads``).
-
-``"state_space"`` / ``"grouped_attention"`` / ``"shared_routed_experts"`` (the
-Nemotron-H stack): a layer is ONE sublayer, ``x + f(RMSNorm(x))``, and one
-stack mixes the three kinds (``sublayer``); nothing is multiplied (``MuP()``):
-
-* state space: the parallel block's mixer alone (``state_space_mixer``), the
-  gated norm's group the B / C group's channels.
-* grouped attention: the global routed kind's attention alone, no rotary
-  positions and no window (``routed_attention``): the state-space layers
-  carry position.
-* shared routed experts: the router reads the layer's one normed input, which
-  also feeds the experts: scores ``sigmoid(h W_r)``, the ``k`` largest of
-  ``score + b`` (``router_bias``, a selection bias no gradient reaches and no
-  step moves), weights the chosen UNBIASED scores over their sum, times
-  ``routed_scale``; experts of two matrices, ``W_down relu(W_up h)^2``
-  (``ops/moe.py`` ``relu2_ffn``); beside them a shared expert of the same form
-  on every token (``shared_expert``), its result added before the residual.
+Layers follow ``layer_types``; the block's FORM is a property of the kind, and
+a kind is ONE entry of ``LAYER_KINDS``: its tensors, the sizes it needs, its
+row-length multiple, its function and what that records.  Each kind's form is
+described where it is written: ``linear_attention`` and ``full_attention``
+(the Olmo-Hybrid block), ``parallel_layer`` (Falcon-H1), ``routed_layer``
+(SmallThinker), ``sandwich_layer`` with ``looped_row_grads`` (Ouro, a looped
+model), ``sublayer`` (the Nemotron-H stack's three kinds).
 
 **The share.**  A deployment divides every layer over ``chips`` chips; this
 process holds one share of it: ``heads`` of the attention heads, ``mlp_cols``
@@ -128,6 +60,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
+from collections.abc import Callable
 
 import jax
 import jax.numpy as jnp
@@ -146,20 +79,6 @@ SANDWICH = "sandwich_attention"
 STATE_SPACE = "state_space"
 GROUPED_ATTENTION = "grouped_attention"
 SHARED_EXPERTS = "shared_routed_experts"
-#: the kinds whose feed-forward is a layer of routed experts
-MOE_KINDS = (GLOBAL_MOE, SLIDING_MOE)
-#: the kinds that are ONE sublayer, ``x + f(RMSNorm(x))``
-SUBLAYER_KINDS = (STATE_SPACE, GROUPED_ATTENTION, SHARED_EXPERTS)
-#: every kind that routes: its layers have the routing counters
-ROUTED_KINDS = MOE_KINDS + (SHARED_EXPERTS,)
-KINDS = (LINEAR, FULL, PARALLEL) + MOE_KINDS + (SANDWICH,) + SUBLAYER_KINDS
-
-#: what the training record calls the first layer's probe, by its kind (a
-#: stack of one-sublayer kinds records the first layer's of EACH name)
-PROBE_NAME = {LINEAR: "delta_rule_probe", FULL: "delta_rule_probe",
-              PARALLEL: "ssd_probe", GLOBAL_MOE: "moe_probe",
-              SLIDING_MOE: "moe_probe", SANDWICH: "exit_probe",
-              STATE_SPACE: "ssd_probe", SHARED_EXPERTS: "moe_probe"}
 
 #: segment id of a row's padding (real segments count from 0)
 PAD_SEGMENT = -1
@@ -232,18 +151,9 @@ def _scaled(x, m: float):
 
 @dataclasses.dataclass(frozen=True)
 class SeqConfig:
-    """Widths as published, counts as HELD by this share.  The ``lin_*``
-    sizes are read by ``"linear_attention"`` layers, ``heads`` / ``head_dim``
-    by every attention mixer, ``kv_heads`` and ``rope_theta`` by the parallel,
-    the routed and the sandwich blocks and ``"grouped_attention"`` layers,
-    ``ssm_*`` and ``mup`` by ``"parallel_ssm_attention"`` and ``"state_space"``
-    layers, ``experts*``, ``expert_*`` and ``window`` by the ``*_moe`` kinds,
-    ``experts*``, ``expert_*``, ``shared_cols`` and ``routed_scale`` by
-    ``"shared_routed_experts"`` layers (the KIND fixes the routing rule and the
-    experts' form: softmax over the chosen logits to gated experts for the
-    ``*_moe`` kinds, normalised sigmoid scores to relu^2 experts here),
-    ``loop_steps`` and ``exit_beta`` by a looped stack of
-    ``"sandwich_attention"`` layers."""
+    """Widths as published, counts as HELD by this share.  A layer's kind says
+    which of the sizes it reads: its entry of ``LAYER_KINDS`` has the tensors
+    it makes of them and the check that they are there."""
 
     hidden: int
     layer_types: tuple[str, ...]
@@ -310,52 +220,24 @@ class SeqConfig:
 
     def __post_init__(self):
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
-        bad = set(self.layer_types) - set(KINDS)
+        bad = set(self.layer_types) - set(LAYER_KINDS)
         if bad:
             raise ValueError(
-                f"unknown layer types {sorted(bad)}: the kinds are {list(KINDS)}")
+                f"unknown layer types {sorted(bad)}: the kinds are {list(LAYER_KINDS)}")
         if self.loop_steps < 1:
             raise ValueError("loop_steps counts the passes: at least 1")
         if self.loop_steps > 1 and set(self.layer_types) != {SANDWICH}:
             raise ValueError(f"only a stack of {SANDWICH} layers is looped")
-        if SANDWICH in self.layer_types and self.heads % (
-                self.kv_heads or self.heads):
-            raise ValueError("heads do not divide over their groups")
-        if set(self.layer_types) & set(ROUTED_KINDS):
-            if not (self.experts and self.experts_held and self.expert_width
-                    and 0 < self.experts_per_token <= self.experts):
-                raise ValueError("*_moe layers need the experts' sizes")
-            if self.expert_start + self.experts_held > self.experts:
-                raise ValueError("the experts held lie outside the router's width")
-            if SHARED_EXPERTS in self.layer_types and self.shared_cols <= 0:
-                raise ValueError(
-                    f"{SHARED_EXPERTS} layers need the shared expert's columns")
-        if set(self.layer_types) & set(MOE_KINDS):
-            if self.heads % (self.kv_heads or self.heads):
-                raise ValueError("heads do not divide over their groups")
-            if SLIDING_MOE in self.layer_types and self.window <= 0:
-                raise ValueError(f"{SLIDING_MOE} layers need a window")
-        if GROUPED_ATTENTION in self.layer_types and self.heads % (
-                self.kv_heads or self.heads):
-            raise ValueError("heads do not divide over their groups")
-        if {PARALLEL, STATE_SPACE} & set(self.layer_types):
-            if not (self.ssm_heads and self.ssm_head_dim and self.ssm_state):
-                raise ValueError("state-space layers need the ssm_* sizes")
-            if self.ssm_heads % self.ssm_groups:
-                raise ValueError("heads do not divide over their groups")
-        if PARALLEL in self.layer_types and self.heads % (
-                self.kv_heads or self.heads):
-            raise ValueError("heads do not divide over their groups")
+        for kind in dict.fromkeys(self.layer_types):
+            for check in LAYER_KINDS[kind].checks:
+                check(self)
 
     @property
     def token_multiple(self) -> int:
         """Row lengths are multiples of this (the recurrences' chunks, the
         windowed attention's smallest block)."""
         return math.lcm(*(
-            self.ssm_chunk if kind in (PARALLEL, STATE_SPACE)
-            else 128 if kind in MOE_KINDS + (GROUPED_ATTENTION, SHARED_EXPERTS)
-            else self.chunk
-            for kind in self.layer_types))
+            LAYER_KINDS[kind].token_multiple(self) for kind in self.layer_types))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -383,110 +265,15 @@ def decays(name: str) -> bool:
 
 
 def param_shapes(cfg: SeqConfig) -> dict[str, tuple[int, ...]]:
-    """Flat name -> shape of every held tensor, in a fixed order."""
+    """Flat name -> shape of every held tensor, in a fixed order: the
+    embedding, each layer's tensors as its kind lists them, the final norm,
+    the head, a looped model's exit gate."""
     D = cfg.hidden
     shapes: dict[str, tuple[int, ...]] = {"embed": (cfg.vocab_rows, D)}
     for i, kind in enumerate(cfg.layer_types):
-        p = f"layer{i}."
-        if kind == LINEAR:
-            qk = cfg.lin_heads * cfg.lin_key_dim
-            vv = cfg.lin_heads * cfg.lin_value_dim
-            shapes.update({
-                p + "q": (D, qk), p + "k": (D, qk), p + "v": (D, vv),
-                p + "g": (D, vv), p + "a": (D, cfg.lin_heads),
-                p + "b": (D, cfg.lin_heads),
-                p + "conv_q": (cfg.conv_width, qk),
-                p + "conv_k": (cfg.conv_width, qk),
-                p + "conv_v": (cfg.conv_width, vv),
-                p + "a_log": (cfg.lin_heads,), p + "dt_bias": (cfg.lin_heads,),
-                p + "o_norm": (cfg.lin_value_dim,), p + "o": (vv, D),
-            })
-        elif kind == PARALLEL:
-            ch = cfg.ssm_heads * cfg.ssm_head_dim
-            bc = cfg.ssm_groups * cfg.ssm_state
-            shapes.update({
-                p + "input_norm": (D,),
-                p + "ssm_in": (D, 2 * ch + 2 * bc + cfg.ssm_heads),
-                p + "ssm_conv": (cfg.ssm_conv_width, ch + 2 * bc),
-                p + "ssm_conv_bias": (ch + 2 * bc,),
-                p + "ssm_a_log": (cfg.ssm_heads,), p + "ssm_d": (cfg.ssm_heads,),
-                p + "ssm_dt_bias": (cfg.ssm_heads,), p + "ssm_norm": (ch,),
-                p + "ssm_out": (ch, D),
-                p + "q": (D, cfg.heads * cfg.head_dim),
-                p + "k": (D, (cfg.kv_heads or cfg.heads) * cfg.head_dim),
-                p + "v": (D, (cfg.kv_heads or cfg.heads) * cfg.head_dim),
-                p + "o": (cfg.heads * cfg.head_dim, D),
-                p + "pre_ff_norm": (D,),
-                p + "gate": (D, cfg.mlp_cols), p + "up": (D, cfg.mlp_cols),
-                p + "down": (cfg.mlp_cols, D),
-            })
-            continue
-        elif kind in MOE_KINDS:
-            kv = (cfg.kv_heads or cfg.heads) * cfg.head_dim
-            E, F = cfg.experts_held, cfg.expert_width
-            shapes.update({
-                p + "input_norm": (D,), p + "router": (D, cfg.experts),
-                p + "q": (D, cfg.heads * cfg.head_dim), p + "k": (D, kv),
-                p + "v": (D, kv), p + "o": (cfg.heads * cfg.head_dim, D),
-                p + "post_norm": (D,),
-                p + "experts_gate": (E, D, F), p + "experts_up": (E, D, F),
-                p + "experts_down": (E, F, D),
-            })
-            continue
-        elif kind == STATE_SPACE:
-            ch = cfg.ssm_heads * cfg.ssm_head_dim
-            bc = cfg.ssm_groups * cfg.ssm_state
-            shapes.update({
-                p + "input_norm": (D,),
-                p + "ssm_in": (D, 2 * ch + 2 * bc + cfg.ssm_heads),
-                p + "ssm_conv": (cfg.ssm_conv_width, ch + 2 * bc),
-                p + "ssm_conv_bias": (ch + 2 * bc,),
-                p + "ssm_a_log": (cfg.ssm_heads,), p + "ssm_d": (cfg.ssm_heads,),
-                p + "ssm_dt_bias": (cfg.ssm_heads,), p + "ssm_norm": (ch,),
-                p + "ssm_out": (ch, D),
-            })
-            continue
-        elif kind == GROUPED_ATTENTION:
-            kv = (cfg.kv_heads or cfg.heads) * cfg.head_dim
-            shapes.update({
-                p + "input_norm": (D,),
-                p + "q": (D, cfg.heads * cfg.head_dim), p + "k": (D, kv),
-                p + "v": (D, kv), p + "o": (cfg.heads * cfg.head_dim, D),
-            })
-            continue
-        elif kind == SHARED_EXPERTS:
-            E, F = cfg.experts_held, cfg.expert_width
-            shapes.update({
-                p + "input_norm": (D,), p + "router": (D, cfg.experts),
-                p + "router_bias": (cfg.experts,),
-                p + "shared_up": (D, cfg.shared_cols),
-                p + "shared_down": (cfg.shared_cols, D),
-                p + "experts_up": (E, D, F), p + "experts_down": (E, F, D),
-            })
-            continue
-        elif kind == SANDWICH:
-            kv = (cfg.kv_heads or cfg.heads) * cfg.head_dim
-            shapes.update({
-                p + "input_norm": (D,),
-                p + "q": (D, cfg.heads * cfg.head_dim), p + "k": (D, kv),
-                p + "v": (D, kv), p + "o": (cfg.heads * cfg.head_dim, D),
-                p + "attn_out_norm": (D,), p + "pre_ff_norm": (D,),
-                p + "gate": (D, cfg.mlp_cols), p + "up": (D, cfg.mlp_cols),
-                p + "down": (cfg.mlp_cols, D), p + "mlp_out_norm": (D,),
-            })
-            continue
-        else:
-            hd = cfg.heads * cfg.head_dim
-            shapes.update({
-                p + "q": (D, hd), p + "k": (D, hd), p + "v": (D, hd),
-                p + "q_norm": (cfg.head_dim,), p + "k_norm": (cfg.head_dim,),
-                p + "o": (hd, D),
-            })
         shapes.update({
-            p + "mixer_norm": (D,),
-            p + "gate": (D, cfg.mlp_cols), p + "up": (D, cfg.mlp_cols),
-            p + "down": (cfg.mlp_cols, D), p + "mlp_norm": (D,),
-        })
+            f"layer{i}.{leaf}": shape
+            for leaf, shape in LAYER_KINDS[kind].tensors(cfg).items()})
     shapes["final_norm"] = (D,)
     shapes["head"] = (cfg.vocab_rows, D)
     if cfg.loop_steps > 1:
@@ -638,7 +425,10 @@ def _head_group(p: dict, lo: int, hi: int, heads: int) -> dict:
 
 def delta_inputs(cfg: SeqConfig, p: dict, x, seg):
     """What the delta rule of the heads in ``p`` reads -> (q, k [B, T, H, dk],
-    v [B, T, H, dv], g, beta [B, T, H]) and the output gate's projection."""
+    v [B, T, H, dv], g, beta [B, T, H]) and the output gate's projection: q,
+    k, v projections, each through a causal depthwise convolution and SiLU; q,
+    k L2-normalised per head; ``beta = 2 sigmoid(.)`` (negative eigenvalues)
+    or ``sigmoid(.)``; ``g = -exp(A_log) softplus(. + dt_bias)``."""
     B, T, _ = x.shape
     H, dk, dv = p["a_log"].shape[0], cfg.lin_key_dim, cfg.lin_value_dim
     with jax.named_scope("gdn.proj"):
@@ -691,7 +481,9 @@ def _linear_heads(cfg: SeqConfig, p: dict, x, seg):
 def linear_attention(cfg: SeqConfig, p: dict, x, seg):
     """The share's part of a gated delta-rule layer's output (before the
     residual norm), the sum over its heads of ``o_h W_o[h]``, and the rule's
-    output along the probe vector [B, T, H].  The heads go through in groups
+    output along the probe vector [B, T, H]: ``delta_inputs``, the gated delta
+    rule (``ops/gdn.py``), per-head RMSNorm of the output gated by
+    ``SiLU(W_g x)``, output projection.  The heads go through in groups
     of ``gdn.heads_per_block`` (the heads one step of the kernel's grid works
     on side by side: 5 of 15), one group after another, each recomputed in its
     own backward pass: what a group keeps live is that share of the layer's."""
@@ -810,8 +602,7 @@ def _attend(cfg: SeqConfig, q, k, v, seg, window: int | None = None):
     causal or a local mask (``_splash_attention``), elsewhere ``jax.numpy``
     under the same mask."""
     B, T, H, d = q.shape
-    impl = cfg.attn_impl or (
-        "flash" if jax.default_backend() == "tpu" else "dense")
+    impl = cfg.attn_impl or ("flash" if gdn.use_pallas() else "dense")
     with jax.named_scope("attn.window" if window is not None else "attn.causal"):
         if impl == "dense":
             k, v = _repeat_kv(k, v, H // k.shape[2])
@@ -824,7 +615,10 @@ def _attend(cfg: SeqConfig, q, k, v, seg, window: int | None = None):
 
 
 def full_attention(cfg: SeqConfig, p: dict, x, seg):
-    """The share's part of a full-attention layer's output."""
+    """The share's part of a full-attention layer's output: per-head RMSNorm
+    on q and k, no positional encoding, causal softmax within the segment
+    (``_attend``: on a TPU no T x T score matrix, blocks above the diagonal
+    skipped)."""
     B, T, _ = x.shape
     d = cfg.head_dim
     H = p["q"].shape[1] // d
@@ -856,8 +650,10 @@ def rope(x, pos, theta: float):
 
 def grouped_query_attention(cfg: SeqConfig, p: dict, h, seg):
     """The share's part of the parallel block's attention mixer: its query
-    heads on its KV heads (k and v as held, into the kernel ``full_attention``
-    calls), positions restarting at a segment."""
+    heads on its KV heads (k and v as held: a KV head's query heads in one
+    multi-query call of the kernel ``full_attention`` calls), rotary positions
+    over the whole head that RESTART at every segment of a packed row, no
+    q / k norm."""
     B, T, _ = h.shape
     d, mup = cfg.head_dim, cfg.mup
     with jax.named_scope("seq.attn"):
@@ -881,8 +677,12 @@ def moe_probe_vector(D: int, n: int = 2):
 
 def routed_attention(cfg: SeqConfig, kind: str, p: dict, h, seg):
     """The share's part of a routed block's attention: its query heads on its
-    KV heads, no q / k norm; the sliding kind with rotary positions that
-    restart at a segment and its window, the global kind with neither."""
+    KV heads, no q / k norm, through the attention kernel the other blocks
+    call; the global kind causal over the whole segment with NO rotary
+    positions; the sliding kind with rotary positions that restart at a
+    segment and its window: query t sees key s iff ``0 <= t - s < window`` in
+    its segment (a local mask: blocks outside the window are skipped; segment
+    ids mask inside a block, no block is skipped for them)."""
     B, T, _ = h.shape
     d = cfg.head_dim
     with jax.named_scope("seq.attn"):
@@ -897,9 +697,16 @@ def routed_attention(cfg: SeqConfig, kind: str, p: dict, h, seg):
 
 
 def routed_layer(cfg: SeqConfig, kind: str, p: dict, x, seg):
-    """One ``*_moe`` layer -> (x after it, (the experts' output on ``h`` along
-    the probe vector [B, T, 1], the router's choices [B, T, k], the pairs of
-    each held expert [held])).  The probe feeds the experts ``h``, which a
+    """One ``*_moe`` layer (the SmallThinker block): pre-norm, grouped-query
+    attention with no q / k norm, then a layer of routed experts
+    (``ops/moe.py``) in place of the MLP, ``x1 = x + attn(h)``,
+    ``x2 = x1 + experts(RMSNorm(x1))`` with ``h = RMSNorm(x)``.  The router
+    reads ``h``, BEFORE attention (a deployment fetches the chosen experts
+    while attention runs): logits over all experts, the ``k`` largest, weights
+    ``softmax`` over those ``k``; experts ReGLU, ``W_down(relu(W_gate m) *
+    W_up m)`` -> (x after it, (the experts' output on ``h`` along the probe
+    vector [B, T, 1], the router's choices [B, T, k], the pairs of each held
+    expert [held])).  The probe feeds the experts ``h``, which a
     first layer makes from exact embedding rows in float32: the same on both
     sides of a comparison, as the stream after attention is not."""
     B, T, D = x.shape
@@ -939,10 +746,14 @@ def shared_expert(p: dict, h):
 
 def shared_routed_experts(cfg: SeqConfig, p: dict, h, seg):
     """What a ``"shared_routed_experts"`` layer adds to the stream: the held
-    routed experts' part (sigmoid scores chosen under the selection bias, two
-    relu^2 matrices an expert) plus the shared expert's held columns, both
-    from ``h`` [B, T, D], which the router reads too -> (the sum [B, T, D],
-    the choices [B, T, k], the pairs of each held expert [held])."""
+    routed experts' part plus the shared expert's held columns
+    (``shared_expert``), both from the layer's one normed input ``h``
+    [B, T, D], which the router reads too: scores ``sigmoid(h W_r)``, the
+    ``k`` largest of ``score + b`` (``router_bias``, a selection bias no
+    gradient reaches and no step moves), weights the chosen UNBIASED scores
+    over their sum, times ``routed_scale``; experts of two matrices,
+    ``W_down relu(W_up h)^2`` (``ops/moe.py`` ``relu2_ffn``) -> (the sum
+    [B, T, D], the choices [B, T, k], the pairs of each held expert [held])."""
     B, T, D = h.shape
     with jax.named_scope("seq.moe"):
         with jax.named_scope("moe.route"):
@@ -957,19 +768,25 @@ def shared_routed_experts(cfg: SeqConfig, p: dict, h, seg):
 
 
 def sublayer(cfg: SeqConfig, kind: str, p: dict, x, seg):
-    """One layer of ONE sublayer, ``x + f(RMSNorm(x))`` -> (x after it, what
-    its ``f`` records: the state space's probe [B, T, H], nothing [B, T, 0]
-    for attention, (choices, pairs) for the experts)."""
+    """One layer of the Nemotron-H stack, which is ONE sublayer,
+    ``x + f(RMSNorm(x))``, and mixes three kinds in any order; nothing is
+    multiplied (``MuP()``).  ``f`` is the parallel block's state-space mixer
+    alone (the gated norm's group the B / C group's channels), or the global
+    routed kind's attention alone (no rotary positions and no window: the
+    state-space layers carry position), or ``shared_routed_experts`` -> (x
+    after it, what ``f`` records: the state space's probe [B, T, H], nothing
+    for attention, the choices and the pairs for the experts)."""
     with jax.named_scope("seq.stream"):
         h = rmsnorm(x, p["input_norm"], cfg.eps)
     if kind == STATE_SPACE:
-        y, record = state_space_mixer(cfg, p, h, seg)
+        y, probe = state_space_mixer(cfg, p, h, seg)
+        record = {PROBE_NAME[kind]: probe}
     elif kind == GROUPED_ATTENTION:
         # the global routed kind's attention: no rotary, no window
-        y = routed_attention(cfg, kind, p, h, seg)
-        record = jnp.zeros(x.shape[:2] + (0,))
+        y, record = routed_attention(cfg, kind, p, h, seg), {}
     else:
-        y, *record = shared_routed_experts(cfg, p, h, seg)
+        y, choices, pairs = shared_routed_experts(cfg, p, h, seg)
+        record = {"choices": choices, "expert_pairs": pairs}
     with jax.named_scope("seq.stream"):
         return x + y, record
 
@@ -1064,7 +881,12 @@ def ssm_inputs(cfg: SeqConfig, p: dict, h, seg):
 
 def state_space_mixer(cfg: SeqConfig, p: dict, h, seg, norm_axis=None):
     """The share's part of the parallel block's state-space mixer (the sum
-    over its heads' rows of the output projection) and the recurrence's own
+    over its heads' rows of the output projection): one projection to
+    ``z | x | B | C | dt`` (each zone times its multiplier), a causal depthwise
+    convolution with a bias and SiLU over ``x | B | C``, ``Delta = softplus(dt
+    + dt_bias)`` (``ssm_inputs``), Mamba-2's selective state space in its
+    chunked form (``ops/ssd.py``) with the ``D`` skip, ``RMSNorm(y * SiLU(z))``
+    over each group's channels, output projection; and the recurrence's own
     output ``S_t C_t`` along the probe vector [B, T, H], before the ``D`` skip
     (which is alike on both sides of any comparison and 100 x larger at the
     seeded weights) and any later rounding."""
@@ -1094,99 +916,295 @@ def mlp(cfg: SeqConfig, p: dict, x):
         return _scaled(mm(gate * mm(x, p["up"]), p["down"]), mup.mlp_down)
 
 
-def layer(cfg: SeqConfig, kind: str, p: dict, x, seg):
-    """-> (x after the layer, the layer's probe [B, T, H]: the delta rule's
-    or the state space's; no heads where the layer is full attention).  The
-    kind takes the block's path: ``linear_attention`` / ``full_attention``
-    post-norm with one mixer and the MLP, ``parallel_ssm_attention`` pre-norm
-    with two mixers and the MLP, the ``*_moe`` kinds ``routed_layer`` (whose
-    second result is a tuple: probe, choices, pairs), ``sandwich_attention``
-    a norm before and after each of its two sublayers (no probe: the exits'
-    record is the looped trunk's), the one-sublayer kinds ``sublayer``."""
-    if kind in MOE_KINDS:
-        return routed_layer(cfg, kind, p, x, seg)
-    if kind in SUBLAYER_KINDS:
-        return sublayer(cfg, kind, p, x, seg)
-    if kind == SANDWICH:
-        with jax.named_scope("seq.stream"):
-            h = rmsnorm(x, p["input_norm"], cfg.eps)
-        a = grouped_query_attention(cfg, p, h, seg)
-        with jax.named_scope("seq.stream"):
-            x = x + rmsnorm(a, p["attn_out_norm"], cfg.eps)
-            h = rmsnorm(x, p["pre_ff_norm"], cfg.eps)
-        y = mlp(cfg, p, h)
-        with jax.named_scope("seq.stream"):
-            return (x + rmsnorm(y, p["mlp_out_norm"], cfg.eps),
-                    jnp.zeros(x.shape[:2] + (0,)))
-    if kind == PARALLEL:
-        with jax.named_scope("seq.stream"):
-            h = rmsnorm(x, p["input_norm"], cfg.eps)
-        m, probe = state_space_mixer(cfg, p, h, seg)
-        a = grouped_query_attention(cfg, p, h, seg)
-        with jax.named_scope("seq.stream"):
-            x = x + m + a
-            h = rmsnorm(x, p["pre_ff_norm"], cfg.eps)
-        y = mlp(cfg, p, h)
-        with jax.named_scope("seq.stream"):
-            return x + y, probe
-    if kind == LINEAR:
-        y, probe = linear_attention(cfg, p, x, seg)
-    else:
-        y, probe = full_attention(cfg, p, x, seg), jnp.zeros(x.shape[:2] + (0,))
+def _post_norm_layer(cfg: SeqConfig, p: dict, x, y):
+    """The OLMo 2/3 residual form around a mixer's output ``y`` and the
+    SwiGLU MLP, ``x + RMSNorm(f(x))`` -> x after the layer."""
     with jax.named_scope("seq.stream"):
         x = x + rmsnorm(y, p["mixer_norm"], cfg.eps)
     y = mlp(cfg, p, x)
     with jax.named_scope("seq.stream"):
-        return x + rmsnorm(y, p["mlp_norm"], cfg.eps), probe
+        return x + rmsnorm(y, p["mlp_norm"], cfg.eps)
+
+
+def linear_layer(cfg: SeqConfig, p: dict, x, seg):
+    """The Olmo-Hybrid block's ``"linear_attention"`` layer (post-norm, one
+    mixer and the MLP) -> (x after it, {the delta rule's probe [B, T, H]})."""
+    y, probe = linear_attention(cfg, p, x, seg)
+    return _post_norm_layer(cfg, p, x, y), {PROBE_NAME[LINEAR]: probe}
+
+
+def parallel_layer(cfg: SeqConfig, p: dict, x, seg):
+    """The Falcon-H1 block, ``"parallel_ssm_attention"``: pre-norm, and TWO
+    mixers that read one normed input ``h = RMSNorm(x)``, their outputs scaled
+    and summed, ``x + ssm(h) + attn(h)``, then ``x + mlp(RMSNorm(x))``; muP's
+    forward multipliers (``MuP``) where the published model has them -> (x
+    after it, {the state space's probe [B, T, H]})."""
+    with jax.named_scope("seq.stream"):
+        h = rmsnorm(x, p["input_norm"], cfg.eps)
+    m, probe = state_space_mixer(cfg, p, h, seg)
+    a = grouped_query_attention(cfg, p, h, seg)
+    with jax.named_scope("seq.stream"):
+        x = x + m + a
+        h = rmsnorm(x, p["pre_ff_norm"], cfg.eps)
+    y = mlp(cfg, p, h)
+    with jax.named_scope("seq.stream"):
+        return x + y, {PROBE_NAME[PARALLEL]: probe}
+
+
+def sandwich_layer(cfg: SeqConfig, p: dict, x, seg):
+    """The Ouro block, ``"sandwich_attention"``: a norm before AND after each
+    sublayer, inside the residual, ``x1 = x + N2(attn(N1(x)))``,
+    ``x2 = x1 + N4(mlp(N3(x1)))``; attention is plain multi-head (the grouped
+    path with one KV head a query head), rotary positions over the whole head
+    that restart at every segment, no q / k norm; the MLP SwiGLU.  Nothing is
+    recorded here: the exits' record is the looped trunk's."""
+    with jax.named_scope("seq.stream"):
+        h = rmsnorm(x, p["input_norm"], cfg.eps)
+    a = grouped_query_attention(cfg, p, h, seg)
+    with jax.named_scope("seq.stream"):
+        x = x + rmsnorm(a, p["attn_out_norm"], cfg.eps)
+        h = rmsnorm(x, p["pre_ff_norm"], cfg.eps)
+    y = mlp(cfg, p, h)
+    with jax.named_scope("seq.stream"):
+        return x + rmsnorm(y, p["mlp_out_norm"], cfg.eps), {}
+
+
+# ---------------------------------------------------------------------------
+# the layer kinds
+
+
+def _norms(cfg: SeqConfig, *names: str) -> dict:
+    return {name: (cfg.hidden,) for name in names}
+
+
+def _delta_rule_tensors(cfg: SeqConfig) -> dict:
+    D, H = cfg.hidden, cfg.lin_heads
+    qk, vv = H * cfg.lin_key_dim, H * cfg.lin_value_dim
+    return {
+        "q": (D, qk), "k": (D, qk), "v": (D, vv), "g": (D, vv), "a": (D, H),
+        "b": (D, H), "conv_q": (cfg.conv_width, qk), "conv_k": (cfg.conv_width, qk),
+        "conv_v": (cfg.conv_width, vv), "a_log": (H,), "dt_bias": (H,),
+        "o_norm": (cfg.lin_value_dim,), "o": (vv, D)}
+
+
+def _attention_tensors(cfg: SeqConfig, grouped: bool = True) -> dict:
+    """q / k / v / o of an attention mixer: k and v on the KV heads held, or
+    (not ``grouped``: the full-attention kind) a KV head a query head and a
+    per-head norm on q and k."""
+    D, hd = cfg.hidden, cfg.heads * cfg.head_dim
+    kv = (cfg.kv_heads or cfg.heads) * cfg.head_dim if grouped else hd
+    norms = {} if grouped else {"q_norm": (cfg.head_dim,), "k_norm": (cfg.head_dim,)}
+    return {"q": (D, hd), "k": (D, kv), "v": (D, kv), **norms, "o": (hd, D)}
+
+
+def _state_space_tensors(cfg: SeqConfig) -> dict:
+    D, H = cfg.hidden, cfg.ssm_heads
+    ch, bc = H * cfg.ssm_head_dim, cfg.ssm_groups * cfg.ssm_state
+    return {
+        "ssm_in": (D, 2 * ch + 2 * bc + H),
+        "ssm_conv": (cfg.ssm_conv_width, ch + 2 * bc),
+        "ssm_conv_bias": (ch + 2 * bc,), "ssm_a_log": (H,), "ssm_d": (H,),
+        "ssm_dt_bias": (H,), "ssm_norm": (ch,), "ssm_out": (ch, D)}
+
+
+def _mlp_tensors(cfg: SeqConfig) -> dict:
+    D, F = cfg.hidden, cfg.mlp_cols
+    return {"gate": (D, F), "up": (D, F), "down": (F, D)}
+
+
+def _experts_tensors(cfg: SeqConfig, gated: bool = True) -> dict:
+    """The held experts, stacked: three matrices an expert, or (not
+    ``gated``: the relu^2 experts) two."""
+    D, E, F = cfg.hidden, cfg.experts_held, cfg.expert_width
+    gate = {"experts_gate": (E, D, F)} if gated else {}
+    return {**gate, "experts_up": (E, D, F), "experts_down": (E, F, D)}
+
+
+def _need(ok, message: str):
+    if not ok:
+        raise ValueError(message)
+
+
+def _check_groups(heads: int, groups: int):
+    _need(heads % groups == 0, "heads do not divide over their groups")
+
+
+def _check_attention(cfg: SeqConfig):
+    _check_groups(cfg.heads, cfg.kv_heads or cfg.heads)
+
+
+def _check_state_space(cfg: SeqConfig):
+    _need(cfg.ssm_heads and cfg.ssm_head_dim and cfg.ssm_state,
+          "state-space layers need the ssm_* sizes")
+    _check_groups(cfg.ssm_heads, cfg.ssm_groups)
+
+
+def _check_experts(cfg: SeqConfig):
+    _need(cfg.experts and cfg.experts_held and cfg.expert_width
+          and 0 < cfg.experts_per_token <= cfg.experts,
+          "*_moe layers need the experts' sizes")
+    _need(cfg.expert_start + cfg.experts_held <= cfg.experts,
+          "the experts held lie outside the router's width")
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerKind:
+    """What a layer's kind decides, written once: ``param_shapes``,
+    ``SeqConfig``'s checks and row length, ``layer``, ``trunk``,
+    ``init_state`` and ``train_steps`` read it here (docs/engines.md,
+    "Adding a block")."""
+
+    #: leaf name -> shape of the layer's tensors, in order
+    tensors: Callable[[SeqConfig], dict]
+    #: (cfg, p, x, seg) -> (x after the layer, what it records: a dict of named
+    #: arrays, its probe under ``probe`` and a routed layer's ``choices``
+    #: [B, T, k] and ``expert_pairs`` [held]).  A function of THIS module is
+    #: named in the body and looked up when the layer runs, so that one
+    #: replaced after import (``benchmark/tests/control_*``) is what runs
+    apply: Callable
+    #: what a row's length is a multiple of (the recurrences' chunks, the
+    #: windowed attention's smallest block)
+    token_multiple: Callable[[SeqConfig], int]
+    #: each raises ``ValueError`` where a size the kind reads is missing
+    checks: tuple[Callable[[SeqConfig], None], ...] = ()
+    #: what the training record calls the probe of a stack of this kind: the
+    #: first layer's recurrence or experts, a looped stack's exit distribution
+    probe: str | None = None
+    #: its layers route tokens to experts: the step sums their counters
+    routed: bool = False
+    #: a layer that is ONE sublayer, ``x + f(RMSNorm(x))``: the span tag that
+    #: counts such layers of a stack
+    sublayer_tag: str | None = None
+    #: a program of its own, run on the first step's rows for the FIRST layer
+    #: of the kind: (cfg, backward, embedding, p, tokens, seg) -> more probes
+    first_step_probe: Callable | None = None
+
+
+def _moe_kind(kind: str, *checks) -> LayerKind:
+    """A SmallThinker layer's entry: ``routed_layer`` with its record named."""
+    def apply(cfg: SeqConfig, p: dict, x, seg):
+        x, (probe, choices, pairs) = routed_layer(cfg, kind, p, x, seg)
+        return x, {
+            PROBE_NAME[kind]: probe, "choices": choices, "expert_pairs": pairs}
+
+    return LayerKind(
+        tensors=lambda cfg: (
+            _norms(cfg, "input_norm") | {"router": (cfg.hidden, cfg.experts)}
+            | _attention_tensors(cfg) | _norms(cfg, "post_norm")
+            | _experts_tensors(cfg)),
+        apply=apply, token_multiple=lambda cfg: _LANES,
+        checks=(_check_experts, _check_attention) + checks, probe="moe_probe",
+        routed=True)
+
+
+LAYER_KINDS = {
+    LINEAR: LayerKind(
+        tensors=lambda cfg: (
+            _delta_rule_tensors(cfg) | _norms(cfg, "mixer_norm")
+            | _mlp_tensors(cfg) | _norms(cfg, "mlp_norm")),
+        apply=linear_layer, token_multiple=lambda cfg: cfg.chunk,
+        probe="delta_rule_probe"),
+    # the Olmo-Hybrid block's other layer, in the same residual form: it
+    # records nothing, the stack's probe is a linear layer's
+    FULL: LayerKind(
+        tensors=lambda cfg: (
+            _attention_tensors(cfg, grouped=False) | _norms(cfg, "mixer_norm")
+            | _mlp_tensors(cfg) | _norms(cfg, "mlp_norm")),
+        apply=lambda cfg, p, x, seg: (
+            _post_norm_layer(cfg, p, x, full_attention(cfg, p, x, seg)), {}),
+        token_multiple=lambda cfg: cfg.chunk, probe="delta_rule_probe"),
+    PARALLEL: LayerKind(
+        tensors=lambda cfg: (
+            _norms(cfg, "input_norm") | _state_space_tensors(cfg)
+            | _attention_tensors(cfg) | _norms(cfg, "pre_ff_norm")
+            | _mlp_tensors(cfg)),
+        apply=parallel_layer, token_multiple=lambda cfg: cfg.ssm_chunk,
+        checks=(_check_state_space, _check_attention), probe="ssd_probe"),
+    GLOBAL_MOE: _moe_kind(GLOBAL_MOE),
+    SLIDING_MOE: _moe_kind(SLIDING_MOE, lambda cfg: _need(
+        cfg.window > 0, f"{SLIDING_MOE} layers need a window")),
+    SANDWICH: LayerKind(
+        tensors=lambda cfg: (
+            _norms(cfg, "input_norm") | _attention_tensors(cfg)
+            | _norms(cfg, "attn_out_norm", "pre_ff_norm") | _mlp_tensors(cfg)
+            | _norms(cfg, "mlp_out_norm")),
+        apply=sandwich_layer, token_multiple=lambda cfg: cfg.chunk,
+        checks=(_check_attention,), probe="exit_probe"),
+    STATE_SPACE: LayerKind(
+        tensors=lambda cfg: _norms(cfg, "input_norm") | _state_space_tensors(cfg),
+        apply=lambda cfg, p, x, seg: sublayer(cfg, STATE_SPACE, p, x, seg),
+        token_multiple=lambda cfg: cfg.ssm_chunk, checks=(_check_state_space,),
+        probe="ssd_probe", sublayer_tag="layers_state_space"),
+    GROUPED_ATTENTION: LayerKind(
+        tensors=lambda cfg: _norms(cfg, "input_norm") | _attention_tensors(cfg),
+        apply=lambda cfg, p, x, seg: sublayer(cfg, GROUPED_ATTENTION, p, x, seg),
+        token_multiple=lambda cfg: _LANES, checks=(_check_attention,),
+        sublayer_tag="layers_attention"),
+    SHARED_EXPERTS: LayerKind(
+        tensors=lambda cfg: (
+            _norms(cfg, "input_norm") | {
+                "router": (cfg.hidden, cfg.experts), "router_bias": (cfg.experts,),
+                "shared_up": (cfg.hidden, cfg.shared_cols),
+                "shared_down": (cfg.shared_cols, cfg.hidden)}
+            | _experts_tensors(cfg, gated=False)),
+        apply=lambda cfg, p, x, seg: sublayer(cfg, SHARED_EXPERTS, p, x, seg),
+        token_multiple=lambda cfg: _LANES,
+        checks=(_check_experts, lambda cfg: _need(
+            cfg.shared_cols > 0,
+            f"{SHARED_EXPERTS} layers need the shared expert's columns")),
+        # its probe is not the row program's: ``experts_probe`` makes it
+        probe="moe_probe", routed=True, sublayer_tag="layers_experts",
+        first_step_probe=lambda *args: experts_probe(*args)),
+}
+
+KINDS = tuple(LAYER_KINDS)
+#: every kind that routes: its layers have the routing counters
+ROUTED_KINDS = tuple(k for k, e in LAYER_KINDS.items() if e.routed)
+#: the kinds that are ONE sublayer, ``x + f(RMSNorm(x))``
+SUBLAYER_KINDS = tuple(k for k, e in LAYER_KINDS.items() if e.sublayer_tag)
+#: the kinds whose feed-forward is a layer of routed experts after attention
+MOE_KINDS = tuple(k for k in ROUTED_KINDS if k not in SUBLAYER_KINDS)
+#: what the training record calls a stack's probe, by its layers' kind
+PROBE_NAME = {k: e.probe for k, e in LAYER_KINDS.items() if e.probe}
+
+
+def layer(cfg: SeqConfig, kind: str, p: dict, x, seg):
+    """One layer of ``kind`` -> (x after it, what it records: ``LayerKind.apply``)."""
+    return LAYER_KINDS[kind].apply(cfg, p, x, seg)
 
 
 def trunk(cfg: SeqConfig, params: dict, x, seg, remat: bool = False):
     """The layers and the final norm over embedded rows x [B, T, D] -> (the
-    normalised hidden states, the FIRST layer's probe, the delta rule's or the
-    state space's: its inputs are products of exact embedding rows (normed
-    first, in the pre-norm block), so the training record can hold the
-    recurrence to its token-by-token form there; deeper layers read a
-    residual stream that already carries every earlier rounding).  With
-    ``remat`` each layer is recomputed in the backward pass, so that only the
-    residual stream between layers is kept.  Where layers are routed the
-    second result is a dict: the first layer's probe under its name
-    (``PROBE_NAME``), ``choices`` [B, routed layers, T, k] and ``expert_pairs``
-    [routed layers, held].  A stack of one-sublayer kinds gives a dict too:
-    the FIRST state-space layer's probe as ``ssd_probe`` and the routed
-    layers' ``choices`` and ``expert_pairs``, each where the stack has such a
-    layer (its experts' probe is a program of its own, ``experts_probe``).
-    A looped model (``loop_steps`` R > 1) gives the R
-    exit states [R, B, T, D] first, and the carried state's mean squares
-    second (``looped_trunk``)."""
+    normalised hidden states, the stack's record: under each probe's name the
+    FIRST layer's that records one (a first layer's inputs are products of
+    exact embedding rows, normed first in a pre-norm block, so the training
+    record can hold the recurrence to its token-by-token form there; deeper
+    layers read a residual stream that already carries every earlier
+    rounding), and where layers route ``choices`` [B, routed layers, T, k] and
+    ``expert_pairs`` [routed layers, held]).  With ``remat`` each layer is
+    recomputed in the backward pass, so that only the residual stream between
+    layers is kept.  A looped model (``loop_steps`` R > 1) gives the R exit
+    states [R, B, T, D] first, and the carried state's mean squares second
+    (``looped_trunk``)."""
     if cfg.loop_steps > 1:
         return looped_trunk(cfg, params, x, seg, remat)
-    first, routed, named = None, [], {}
+    kept, routed = {}, []
     for i, kind in enumerate(cfg.layer_types):
         f = functools.partial(layer, cfg, kind)
         if remat:
             f = jax.checkpoint(f)
-        x, probe = f(layer_params(params, i), x, seg)
-        if kind in MOE_KINDS:
-            probe, choices, pairs = probe
-            routed.append((choices, pairs))
-        elif kind == SHARED_EXPERTS:
-            routed.append(tuple(probe))
-            continue
-        elif kind == STATE_SPACE:
-            named.setdefault(PROBE_NAME[kind], probe)
-        first = probe if first is None else first
-    if set(cfg.layer_types) & set(SUBLAYER_KINDS):
-        first = named
-    elif routed:
-        first = {PROBE_NAME[cfg.layer_types[0]]: first}
+        x, record = f(layer_params(params, i), x, seg)
+        record = dict(record)
+        if "choices" in record:
+            routed.append((record.pop("choices"), record.pop("expert_pairs")))
+        for name, probe in record.items():
+            kept.setdefault(name, probe)
     if routed:
         with jax.named_scope("seq.moe"):
-            first.update({
-                "choices": jnp.stack([c for c, _ in routed], axis=1),
-                "expert_pairs": jnp.stack([n for _, n in routed]),
-            })
+            kept["choices"] = jnp.stack([c for c, _ in routed], axis=1)
+            kept["expert_pairs"] = jnp.stack([n for _, n in routed])
     with jax.named_scope("seq.stream"):
-        return rmsnorm(x, params["final_norm"], cfg.eps), first
+        return rmsnorm(x, params["final_norm"], cfg.eps), kept
 
 
 def loop_pass(cfg: SeqConfig, params: dict, x, seg, remat: bool = False):
@@ -1304,35 +1322,44 @@ def cross_entropy(cfg: SeqConfig, h, head, targets, weight, dhead,
         return loss, dh.reshape(shape), dhead
 
 
-def row_grads(cfg: SeqConfig, params: dict, tokens, seg, gsum: dict):
-    """Forward, per-layer recomputation and backward of packed rows
-    [B, T] -> (sum of the next-item cross-entropy over their real positions,
-    how many those are, ``gsum`` + the sum's gradient, the first layer's
-    probe [B, T, H]).  The two vocabulary
-    tables' gradients are added into ``gsum`` in place (a scatter of the
-    embedded rows' gradient, the loss's own accumulation), never held beside
-    it.  A looped model goes through ``looped_row_grads``."""
-    if cfg.loop_steps > 1:
-        return looped_row_grads(cfg, params, tokens, seg, gsum)
-    inner = {k: v for k, v in params.items() if k not in ("embed", "head")}
-    x0 = embed(cfg, params["embed"], tokens)
-    h, vjp, probe = jax.vjp(
-        lambda p, x: trunk(cfg, p, x, seg, remat=True), inner, x0, has_aux=True)
+def counted_targets(tokens, seg):
+    """``next_item_targets`` and how many positions they weigh."""
     with jax.named_scope("seq.loss"):
         targets, weight = next_item_targets(tokens, seg)
-        count = jnp.sum(weight)
-    loss, dh, dhead = cross_entropy(
-        cfg, h, params["head"], targets, weight, gsum["head"])
-    dinner, dx0 = vjp(dh)
+        return targets, weight, jnp.sum(weight)
+
+
+def table_grads(cfg: SeqConfig, gsum: dict, tokens, dx0, dhead) -> dict:
+    """A row's sums of the two vocabulary tables' gradients: the embedded
+    rows' cotangent ``dx0`` scattered into ``gsum["embed"]`` in place, never
+    held beside it, and the head's as the loss accumulated it."""
     with jax.named_scope("seq.embed"):
         idx = tokens - cfg.vocab_start
         held = (idx >= 0) & (idx < cfg.vocab_rows)
         dembed = gsum["embed"].at[jnp.where(held, idx, 0)].add(
             _scaled(jnp.where(held[..., None], dx0, 0.0), cfg.mup.embedding))
+    return {"embed": dembed, "head": dhead}
+
+
+def row_grads(cfg: SeqConfig, params: dict, tokens, seg, gsum: dict):
+    """Forward, per-layer recomputation and backward of packed rows
+    [B, T] -> (sum of the next-item cross-entropy over their real positions,
+    how many those are, ``gsum`` + the sum's gradient, the stack's record
+    (``trunk``)).  A looped model goes through ``looped_row_grads``."""
+    if cfg.loop_steps > 1:
+        return looped_row_grads(cfg, params, tokens, seg, gsum)
+    inner = {k: v for k, v in params.items() if k not in ("embed", "head")}
+    x0 = embed(cfg, params["embed"], tokens)
+    h, vjp, record = jax.vjp(
+        lambda p, x: trunk(cfg, p, x, seg, remat=True), inner, x0, has_aux=True)
+    targets, weight, count = counted_targets(tokens, seg)
+    loss, dh, dhead = cross_entropy(
+        cfg, h, params["head"], targets, weight, gsum["head"])
+    dinner, dx0 = vjp(dh)
+    tables = table_grads(cfg, gsum, tokens, dx0, dhead)
     with jax.named_scope("seq.accumulate"):
         out = {k: gsum[k] + g for k, g in dinner.items()}
-    out["embed"], out["head"] = dembed, dhead
-    return loss, count, out, probe
+    return loss, count, out | tables, record
 
 
 def exit_log_probs(states, gate, bias):
@@ -1407,9 +1434,7 @@ def looped_row_grads(cfg: SeqConfig, params: dict, tokens, seg, gsum: dict):
     inner = {k: v for k, v in params.items() if k not in outer}
     x0 = embed(cfg, params["embed"], tokens)
     states, carried, pass_vjps = loop_forward(cfg, inner, x0, seg)
-    with jax.named_scope("seq.loss"):
-        targets, weight = next_item_targets(tokens, seg)
-        count = jnp.sum(weight)
+    targets, weight, count = counted_targets(tokens, seg)
     with jax.named_scope("seq.exit"):
         logp, gate_vjp = jax.vjp(
             exit_log_probs, states, params["exit_gate"], params["exit_gate_bias"])
@@ -1439,16 +1464,11 @@ def looped_row_grads(cfg: SeqConfig, params: dict, tokens, seg, gsum: dict):
             "exit_entropy": entropy,
         }
     out, dx = loop_backward(pass_vjps, dstates, {k: gsum[k] for k in inner})
-    with jax.named_scope("seq.embed"):
-        idx = tokens - cfg.vocab_start
-        held = (idx >= 0) & (idx < cfg.vocab_rows)
-        dembed = gsum["embed"].at[jnp.where(held, idx, 0)].add(
-            _scaled(jnp.where(held[..., None], dx, 0.0), cfg.mup.embedding))
+    tables = table_grads(cfg, gsum, tokens, dx, dhead)
     with jax.named_scope("seq.accumulate"):
         out["exit_gate"] = gsum["exit_gate"] + dgate
         out["exit_gate_bias"] = gsum["exit_gate_bias"] + dbias
-    out["embed"], out["head"] = dembed, dhead
-    return loss, count, out, probe
+    return loss, count, out | tables, probe
 
 
 # ---------------------------------------------------------------------------
@@ -1465,7 +1485,7 @@ def init_state(cfg: SeqConfig, seed: int) -> tuple[dict, dict]:
     state = {"params": params, "m": zeros(), "v": zeros(),
              "t": jnp.zeros((), jnp.int32)}
     acc = {"g": zeros(), "loss": jnp.float32(0.0), "count": jnp.float32(0.0)}
-    routed = sum(kind in ROUTED_KINDS for kind in cfg.layer_types)
+    routed = sum(LAYER_KINDS[kind].routed for kind in cfg.layer_types)
     if routed:
         # the routing counters of the step, summed beside the gradients
         acc["expert_pairs"] = jnp.zeros((routed, cfg.experts_held), jnp.int32)
@@ -1488,11 +1508,11 @@ def init_state(cfg: SeqConfig, seed: int) -> tuple[dict, dict]:
 
 def accumulate_row(cfg: SeqConfig, state: dict, acc: dict, tokens, seg):
     """One packed row [T] through forward, recomputation and backward, added
-    into the step's accumulator -> (state, acc, the row's first-layer probe
-    [T, H]).  The state goes in and comes out untouched: the moments are this
-    program's arguments only so that the compiler plans its temporaries beside
-    ALL that is resident (it fits a program into the memory its own arguments
-    leave)."""
+    into the step's accumulator -> (state, acc, the row's record: ``trunk``'s
+    probes, each [T, ..]).  The state goes in and comes out untouched: the
+    moments are this program's arguments only so that the compiler plans its
+    temporaries beside ALL that is resident (it fits a program into the memory
+    its own arguments leave)."""
     with jax.named_scope("seq.accumulate"):
         rows = tokens[None], seg[None]
     loss, count, g, probe = row_grads(cfg, state["params"], *rows, acc["g"])
@@ -1615,23 +1635,23 @@ def train_steps(cfg: SeqConfig, opt: AdamW, state: dict, acc: dict, tokens, seg)
     """``tokens``, ``seg``: [steps, rows, T] int32 on the device.  Every row
     and every optimiser step is dispatched at once (nothing is fetched in
     between, so the host never waits for a step) -> (state, acc, the records
-    of the steps, the first-layer probes [T, H] of the FIRST step's rows
-    (a routed block's dict of them, ``trunk``; a stack of one-sublayer
-    kinds' with ``experts_probe``'s beside): those are made from the seeded
-    initial weights; all still on the device)."""
+    of the steps, the records of the FIRST step's rows (``accumulate_row``'s,
+    with a kind's ``first_step_probe`` beside): those are made from the
+    seeded initial weights; all still on the device)."""
     accumulate, apply = train_programs(cfg, opt)
     records, probes = [], []
     for s in range(tokens.shape[0]):
         for r in range(tokens.shape[1]):
             state, acc, probe = accumulate(state, acc, tokens[s, r], seg[s, r])
             if s == 0:
-                if SHARED_EXPERTS in cfg.layer_types:
-                    # the first experts layer on exact inputs; its backward
-                    # on the first row alone
-                    probe.update(experts_probe(
-                        cfg, r == 0, state["params"]["embed"], layer_params(
-                            state["params"], cfg.layer_types.index(SHARED_EXPERTS)),
-                        tokens[s, r], seg[s, r]))
+                for kind in dict.fromkeys(cfg.layer_types):
+                    if LAYER_KINDS[kind].first_step_probe:
+                        # the kind's first layer on exact inputs; its backward
+                        # on the first row alone
+                        probe.update(LAYER_KINDS[kind].first_step_probe(
+                            cfg, r == 0, state["params"]["embed"], layer_params(
+                                state["params"], cfg.layer_types.index(kind)),
+                            tokens[s, r], seg[s, r]))
                 probes.append(probe)
         state, acc, record = apply(state, acc)
         records.append(record)

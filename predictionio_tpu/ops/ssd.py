@@ -46,16 +46,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from predictionio_tpu.ops.gdn import use_pallas
+
 HIGHEST = jax.lax.Precision.HIGHEST
 
 #: segment id no token carries: the "previous chunk" of a row's first chunk
 NO_SEGMENT = -2
-
-
-def use_pallas() -> bool:
-    """The sequential pass runs in the Pallas kernels on a TPU and as the
-    ``lax.scan`` everywhere else (the way ``ops/gdn.use_pallas`` chooses)."""
-    return jax.default_backend() == "tpu"
 
 
 def _mm(a, b):

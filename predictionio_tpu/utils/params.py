@@ -34,9 +34,10 @@ class EmptyParams(Params):
 def extract_params(cls: Type[T], payload: Mapping[str, Any] | None) -> T:
     """Build a params dataclass from a JSON object, coercing nested fields.
 
-    A ``params_aliases`` classvar (dict json-name -> field-name) lets params
-    classes accept the reference's JSON spellings (e.g. ``lambda`` -> ``reg``,
-    which cannot be a Python field name).
+    Every field also answers to its camelCase, the reference's JSON spelling
+    (``numIterations`` -> ``num_iterations``); a ``params_aliases`` classvar
+    (dict json-name -> field-name) names the spellings that are not that rule
+    (``lambda`` -> ``reg``, which cannot be a Python field name).
     """
     payload = dict(payload or {})
     if not dataclasses.is_dataclass(cls):
@@ -69,11 +70,18 @@ def _class_info(cls):
     serving hot path extracts a Query per request)."""
     fields = dataclasses.fields(cls)
     return (
-        dict(getattr(cls, "params_aliases", {})),
+        {_camel(f.name): f.name for f in fields if "_" in f.name}
+        | dict(getattr(cls, "params_aliases", {})),
         typing.get_type_hints(cls),
         fields,
         frozenset(f.name for f in fields),
     )
+
+
+def _camel(name: str) -> str:
+    """``num_iterations`` -> ``numIterations``."""
+    head, *rest = name.split("_")
+    return head + "".join(part.capitalize() for part in rest)
 
 
 def _coerce(value: Any, typ: Any, name: str) -> Any:

@@ -197,7 +197,7 @@ def test_program_is_the_reference_on_a_packed_step(f32_matmuls, packed_step, imp
         lambda w: seqmodel.row_grads(cfg, w, tok, seg, jax.tree.map(jnp.zeros_like, w))
     )(w)
     assert float(count) == sum(len(s) - 1 for s in segs)
-    assert probe.shape == (1, 64, SHARE["ssm_heads_held"])
+    assert probe["ssd_probe"].shape == (1, 64, SHARE["ssm_heads_held"])
     assert float(loss) == pytest.approx(float(want_loss), rel=1e-5)
     assert set(got) == set(want) == set(seqmodel.param_shapes(cfg))
     for name in want:
@@ -255,7 +255,7 @@ def _probe_gap(monkeypatch, broken: bool) -> float:
         tok, seg = pack(r, 64)
         got.append(jax.jit(lambda w, tok=tok, seg=seg: seqmodel.row_grads(
             cfg, w, jnp.asarray(tok)[None], jnp.asarray(seg)[None],
-            jax.tree.map(jnp.zeros_like, w))[3])(w)[0])
+            jax.tree.map(jnp.zeros_like, w))[3]["ssd_probe"])(w)[0])
     hist = [s for r in rows for s in r]
     want = reference.first_step_probe(SHARE, 3, hist, [[0, 1, 2], [3]], 64)
     assert want.shape == (2, 64, 2)
@@ -341,7 +341,8 @@ def test_training_steps_are_the_references_adamw(f32_matmuls):
     state, acc = seqmodel.init_state(cfg, 3)
     w0 = {k: jnp.array(v) for k, v in state["params"].items()}
     state, acc, records, probes = seqmodel.train_steps(cfg, opt, state, acc, tokens, segs)
-    assert len(probes) == 1 and probes[0].shape == (64, 2)  # the first step's row
+    # the first step's row
+    assert len(probes) == 1 and probes[0]["ssd_probe"].shape == (64, 2)
     hist = [s for r in rows for s in r]
     steps = [[0, 1], [2], [3, 4, 5], [6, 7]]
     ref_opt = {"lr": opt.lr, "beta1": opt.b1, "beta2": opt.b2, "eps": opt.eps,

@@ -351,8 +351,8 @@ def test_no_rotary_on_the_global_kind(monkeypatch):
     seg = jnp.zeros((1, 32), jnp.int32)
     seqmodel.layer(cfg, seqmodel.GLOBAL_MOE, seqmodel.layer_params(w, 0), x, seg)
     assert calls == []
-    _, (_, choices, _) = seqmodel.layer(
-        cfg, seqmodel.SLIDING_MOE, seqmodel.layer_params(w, 1), x, seg)
+    choices = seqmodel.layer(
+        cfg, seqmodel.SLIDING_MOE, seqmodel.layer_params(w, 1), x, seg)[1]["choices"]
     assert calls == [1.5e6, 1.5e6]  # q and k
     p = seqmodel.layer_params(w, 1)
     h = seqmodel.rmsnorm(x, p["input_norm"], cfg.eps)[0]

@@ -481,3 +481,130 @@ class TestRecommendedUser:
         a = algo.predict(model, UserQuery(users=("u7",), num=3))
         b = algo.predict(loaded, UserQuery(users=("u7",), num=3))
         assert [s.item for s in a.item_scores] == [s.item for s in b.item_scores]
+
+
+#: every ``params_aliases`` table at commit af3b99e: the JSON spellings the
+#: engines' params took then.  All but ``lambda`` are the camelCase of their
+#: field, which ``extract_params`` now derives (ISSUE 44)
+SPELLINGS = {
+    "similarproduct.Query": {
+        "categoryBlackList": "category_black_list", "whiteList": "white_list",
+        "blackList": "black_list"},
+    "similarproduct.DataSourceParams": {
+        "appName": "app_name", "channelName": "channel_name",
+        "eventNames": "event_names", "targetEntityType": "target_entity_type"},
+    "similarproduct.ALSAlgorithmParams": {
+        "numIterations": "num_iterations", "lambda": "reg"},
+    "similarproduct.UserQuery": {
+        "whiteList": "white_list", "blackList": "black_list"},
+    "sequence.SequenceDataSourceParams": {
+        "appName": "app_name", "channelName": "channel_name",
+        "eventNames": "event_names", "entityType": "entity_type",
+        "targetEntityType": "target_entity_type"},
+    "sequence.SequencePreparatorParams": {
+        "rowLen": "row_len", "maxLen": "max_len", "rowsPerStep": "rows_per_step",
+        "vocabSize": "vocab_size", "vocabStart": "vocab_start"},
+    "sequence.SequenceAlgorithmParams": {
+        "moeSharedExpertColumns": "moe_shared_expert_columns",
+        "routedScalingFactor": "routed_scaling_factor",
+        "totalUtSteps": "total_ut_steps", "exitBeta": "exit_beta",
+        "moeNumPrimaryExperts": "moe_num_primary_experts",
+        "moeExpertsHeld": "moe_experts_held", "moeExpertStart": "moe_expert_start",
+        "moeNumActivePrimaryExperts": "moe_num_active_primary_experts",
+        "moeFfnHiddenSize": "moe_ffn_hidden_size",
+        "slidingWindowSize": "sliding_window_size",
+        "numKeyValueHeads": "num_key_value_heads", "ropeTheta": "rope_theta",
+        "mambaNHeads": "mamba_n_heads", "mambaNGroups": "mamba_n_groups",
+        "mambaDHead": "mamba_d_head", "mambaDState": "mamba_d_state",
+        "mambaDConv": "mamba_d_conv", "mambaChunkSize": "mamba_chunk_size",
+        "embeddingMultiplier": "embedding_multiplier",
+        "lmHeadMultiplier": "lm_head_multiplier",
+        "ssmInMultiplier": "ssm_in_multiplier", "ssmMultipliers": "ssm_multipliers",
+        "ssmOutMultiplier": "ssm_out_multiplier",
+        "attentionInMultiplier": "attention_in_multiplier",
+        "attentionOutMultiplier": "attention_out_multiplier",
+        "keyMultiplier": "key_multiplier", "mlpMultipliers": "mlp_multipliers",
+        "hiddenSize": "hidden_size", "layerTypes": "layer_types",
+        "numAttentionHeads": "num_attention_heads", "headDim": "head_dim",
+        "linearNumHeads": "linear_num_heads",
+        "linearKeyHeadDim": "linear_key_head_dim",
+        "linearValueHeadDim": "linear_value_head_dim",
+        "linearConvKernelDim": "linear_conv_kernel_dim",
+        "linearAllowNegEigval": "linear_allow_neg_eigval",
+        "intermediateSize": "intermediate_size", "vocabSize": "vocab_size",
+        "vocabStart": "vocab_start", "rmsNormEps": "rms_norm_eps",
+        "rowsPerStep": "rows_per_step", "stepsPerRetrain": "steps_per_retrain",
+        "learningRate": "learning_rate", "adamEps": "adam_eps",
+        "weightDecay": "weight_decay"},
+    "ncf.NCFAlgorithmParams": {
+        "embedDim": "embed_dim", "mlpLayers": "mlp_layers",
+        "learningRate": "learning_rate", "numEpochs": "num_epochs",
+        "batchSize": "batch_size", "positiveThreshold": "positive_threshold",
+        "negativesPerPositive": "negatives_per_positive", "negPower": "neg_power",
+        "itemBias": "item_bias", "weightDecay": "weight_decay",
+        "shardServing": "shard_serving"},
+    "ecommerce.Query": {"whiteList": "white_list", "blackList": "black_list"},
+    "ecommerce.DataSourceParams": {
+        "appName": "app_name", "channelName": "channel_name",
+        "eventNames": "event_names"},
+    "ecommerce.ECommAlgorithmParams": {
+        "appName": "app_name", "unseenOnly": "unseen_only",
+        "seenEvents": "seen_events", "similarEvents": "similar_events",
+        "numIterations": "num_iterations", "lambda": "reg",
+        "trainEvents": "train_events"},
+    "recommendation.DataSourceParams": {
+        "appName": "app_name", "channelName": "channel_name",
+        "evalParams": "eval_params"},
+    "recommendation.ALSAlgorithmParams": {
+        "lambda": "reg", "numIterations": "num_iterations",
+        "shardServing": "shard_serving"},
+    "external.ExternalAlgorithmParams": {"featureColumns": "feature_columns"},
+    "classification.DataSourceParams": {"appName": "app_name", "evalK": "eval_k"},
+    "classification.NaiveBayesParams": {"lambda": "lam"},
+    "classification.LogisticRegressionParams": {
+        "learningRate": "learning_rate", "numIterations": "num_iterations",
+        "lambda": "reg"},
+}
+
+
+def _sample(hint):
+    """A JSON value ``extract_params`` coerces to ``hint``."""
+    import dataclasses
+    import types
+    import typing
+
+    origin, args = typing.get_origin(hint), typing.get_args(hint)
+    if origin in (typing.Union, types.UnionType):
+        return _sample(next(a for a in args if a is not type(None)))
+    if origin in (list, tuple, set):
+        return [_sample(args[0])] if args else []
+    if dataclasses.is_dataclass(hint):
+        return {}
+    # 8192: a row length and a history length the sequence Preparator takes
+    return {bool: True, int: 8192, float: 0.5, str: "a"}.get(hint, "a")
+
+
+@pytest.mark.parametrize("where", SPELLINGS)
+def test_every_json_spelling_an_engine_took_still_lands_in_its_field(where):
+    import dataclasses
+    import importlib
+
+    from predictionio_tpu.utils.params import ParamsError, _class_info, extract_params
+
+    module, name = where.split(".")
+    cls = getattr(
+        importlib.import_module(f"predictionio_tpu.models.{module}.engine"), name)
+    hints = _class_info(cls)[1]
+    required = {
+        f.name: _sample(hints[f.name]) for f in dataclasses.fields(cls)
+        if f.default is dataclasses.MISSING
+        and f.default_factory is dataclasses.MISSING}
+    changed = 0
+    for json_name, field in SPELLINGS[where].items():
+        value = _sample(hints[field])
+        got = extract_params(cls, {**required, json_name: value})
+        assert got == extract_params(cls, {**required, field: value}), json_name
+        changed += got != extract_params(cls, required)
+    assert changed  # a sample is not every field's default: the value was read
+    with pytest.raises(ParamsError, match="unknown fields"):
+        extract_params(cls, {**required, "noSuchField": 1})
